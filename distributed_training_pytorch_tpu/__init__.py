@@ -39,8 +39,8 @@ offline evaluator — rebuilt TPU-first:
   preflight with batch/microbatch recommendations
   (``Trainer(preflight=...)``), shared live ``memory_stats`` telemetry +
   growth detection (docs/memory.md; gate: ``scripts/memory_probe.py``).
-* ``compat``    — JAX version shims (``shard_map`` API move, ambient-mesh
-  helpers) so one codebase spans the supported JAX range.
+* ``compat``    — ``force_host_devices``: the virtual multi-device CPU rig
+  tests and CPU harnesses share (no version shims: jax 0.9.0 only).
 * ``trainer``   — the epoch-loop orchestrator with the reference's 9 hook names.
 * ``utils``     — logging, profiling/tracing (``utils.profiling``), TPU perf
   defaults (``utils.tpu``).

@@ -6,8 +6,7 @@ XLA actually compiled. Three invariants, each grounded in a measured cost:
 **Donation** — every param/optimizer-state input buffer of the train step
 must be input-output aliased (``donate_argnums`` honored end to end). An
 undonated state doubles its memory for the duration of the step AND forces
-a copy; ROADMAP item 3 names a donation/buffer-aliasing audit of the
-chained scan as part of closing the mfu 0.71 vs mfu_exec 0.49 gap. The
+a copy. The
 check parses the compiled module's ``input_output_alias`` header and sizes
 any undonated leaf with ``utils.hlo_flops.aval_bytes``.
 

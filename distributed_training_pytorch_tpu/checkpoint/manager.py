@@ -251,9 +251,9 @@ class CheckpointManager:
         be detected and logged as a resharding restore
         (docs/parallelism.md).
 
-        ``data_state`` is the streaming reader's checkpoint-carried state
-        (``data.streaming.state.ReaderState.to_json()``: epoch, global
-        record cursor, shuffle seed, shard structure, assignment version).
+        ``data_state`` is a resumable reader's checkpoint-carried state (a
+        JSON-able dict from the loader's ``reader_state()`` — docs/data.md:
+        epoch, global record cursor, shuffle seed, shard structure).
         It rides as its own ``data/`` composite item under the same rule as
         the loss-scale item: present only when the run streams, and a
         missing item means "fresh cursor" — so pre-streaming checkpoints,

@@ -13,12 +13,6 @@ from distributed_training_pytorch_tpu.data.records import (  # noqa: F401
     pack_image_folder,
     write_shards,
 )
-from distributed_training_pytorch_tpu.data.streaming import (  # noqa: F401
-    DecodePool,
-    ReaderState,
-    StreamingLoader,
-    shard_array_source,
-)
 from distributed_training_pytorch_tpu.data.prefetch import (  # noqa: F401
     device_prefetch,
     device_prefetch_chained,

@@ -9,8 +9,8 @@ randomness keyed identically to the Python pipeline
 (``data/transforms.philox_key``) so results are deterministic across hosts.
 
 The library is compiled on first use (``make -C csrc``) and cached next to
-this file; everything degrades gracefully to the pure-Python path when a
-toolchain isn't available — ``available()`` reports which path is active.
+this file. When the build fails the pure-Python path is used and a warning
+names the failed command — ``available()`` reports which path is active.
 """
 
 from __future__ import annotations
@@ -19,6 +19,7 @@ import ctypes
 import os
 import subprocess
 import threading
+import warnings
 from typing import Sequence
 
 import numpy as np
@@ -65,16 +66,22 @@ def _load():
                     capture_output=True,
                     timeout=120,
                 )
-            except (subprocess.SubprocessError, OSError):
+            except (subprocess.SubprocessError, OSError) as e:
+                # Never silent: the Python path is correct but slower, and a
+                # run that wanted the native one must be able to see why it
+                # did not get it (chip_smoke.py treats this as a failure).
+                detail = (getattr(e, "stderr", b"") or b"").decode(errors="replace")
+                reason = (detail.strip().splitlines() or [repr(e)])[-1]
                 if not os.path.exists(path):
                     _build_failed = True
+                    warnings.warn(
+                        f"building {_LIB_NAME} failed (`make -C {_CSRC}`: "
+                        f"{reason}); using the pure-Python input path"
+                    )
                     return None
-                # stale library + failed rebuild: better than nothing, but loud
-                import warnings
-
                 warnings.warn(
                     f"{_LIB_NAME} is older than csrc sources and rebuilding "
-                    "failed; using the stale library"
+                    f"failed ({reason}); using the stale library"
                 )
         if not os.path.exists(path):
             _build_failed = True

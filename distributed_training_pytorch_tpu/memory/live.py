@@ -118,7 +118,9 @@ def memory_skew(devices=None) -> dict:
 
 def is_oom_error(err: BaseException) -> bool:
     """Whether an exception is a DEVICE out-of-memory: XLA surfaces
-    allocator exhaustion as ``XlaRuntimeError("RESOURCE_EXHAUSTED: ...")``
+    allocator exhaustion as ``jax.errors.JaxRuntimeError("RESOURCE_EXHAUSTED:
+    ...")`` — or, from some allocation paths, the same class worded "Out of
+    memory ..." without the status name
     (the bench sweep's per-entry net catches exactly this and emits a
     structured ``{"oom": true}`` line instead of killing the sweep).
     Host-side ``MemoryError`` is deliberately NOT classified: the net must
@@ -127,4 +129,7 @@ def is_oom_error(err: BaseException) -> bool:
     text = str(err)
     if "RESOURCE_EXHAUSTED" in text:
         return True
-    return type(err).__name__ == "XlaRuntimeError" and "out of memory" in text.lower()
+    return (
+        isinstance(err, jax.errors.JaxRuntimeError)
+        and "out of memory" in text.lower()
+    )

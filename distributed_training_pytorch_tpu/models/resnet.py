@@ -27,10 +27,9 @@ conv_kernel_init = nn.initializers.variance_scaling(2.0, "fan_out", "normal")
 
 class PallasConv1x1(nn.Module):
     """1x1 conv as a Pallas GEMM (``ops.pallas.conv1x1_bn_act_diff`` with an
-    identity epilogue) — the r5 probe measured XLA's conv kernel at ~45% of
-    the HBM bandwidth floor on ResNet stage-1's 56x56x(64<->256) shapes while
-    the hand-tiled GEMM reaches ~72% (BASELINE.md "ResNet-50" r5 row); this
-    module swaps the bandwidth-bound 1x1s onto that kernel. Kernel param
+    identity epilogue): swaps ResNet stage-1's bandwidth-bound
+    56x56x(64<->256) 1x1 convs onto the hand-tiled GEMM. Kernel vs XLA conv
+    is not measured on today's chip (scripts/resnet_pallas_probe.py). Kernel param
     keeps nn.Conv's ``[1, 1, Cin, Cout]`` layout; stride subsamples rows
     before the GEMM (a strided 1x1 conv reads only those pixels)."""
 
@@ -116,12 +115,12 @@ class ResNet(nn.Module):
     # Route the bandwidth-bound stage-1 1x1 convs (input spatial >= 56, see
     # BottleneckBlock.conv1x1's gate) through the Pallas GEMM (PallasConv1x1).
     # Changes the param tree (module names), so flip only on fresh inits.
-    # Measured slower end-to-end (fusion-barrier cost, BASELINE.md r5) — a
-    # measurement knob, not a perf default.
+    # Not measured on today's chip (an earlier round found it slower inside
+    # the step: a fusion barrier) — a measurement knob, not a perf default.
     pallas_1x1: bool = False
     # The unified kernel-policy knob (ops/dispatch.py): overrides pallas_1x1
-    # when not None. Auto (None) resolves to OFF — the fused 1x1 path is
-    # measured slower end-to-end, so promotion stays evidence-gated.
+    # when not None. Auto (None) resolves to OFF — promotion of the fused
+    # 1x1 path stays evidence-gated (ROADMAP S2(c)).
     pallas: Optional[bool] = None
 
     @nn.compact

@@ -27,8 +27,8 @@ vit            attention                historical ``use_flash`` tri-state
                                         flash on TPU when ``T >= 512``)
 transformer_lm attention                historical ``attention_impl`` string
                                         (default "auto" → flash on TPU)
-resnet         conv1x1_bn_act           **off** — measured slower end-to-end
-                                        (fusion-barrier cost, BASELINE.md r5);
+resnet         conv1x1_bn_act           **off** — not measured on today's chip
+                                        (an earlier round: slower in-step);
                                         also changes the param tree, so it is
                                         opt-in for fresh inits only
 convnext       dense_gelu epilogue      **off** — opt in via ``pallas=True`` /
@@ -256,14 +256,15 @@ def conv1x1_policy(
     *,
     legacy: bool = False,
     op: str = "conv1x1_bn_act",
-    auto_off_reason: str = "auto: measured slower end-to-end (BASELINE.md r5) — opt in with pallas=True",
+    auto_off_reason: str = "auto: off until measured faster end-to-end on today's chip — opt in with pallas=True",
 ) -> bool:
     """Resolve + record the fused-GEMM-epilogue policy for ``model``.
 
     Auto (``pallas=None`` and ``legacy`` False) stays **off**: the fused
-    1x1-conv path measured slower end-to-end than XLA's own fusions
-    (BASELINE.md r5), so promotion is evidence-gated — the autotuner or an
-    explicit ``pallas=True`` flips it, never a silent default.
+    1x1-conv path is not measured on today's chip (an earlier round found it
+    slower end-to-end than XLA's own fusions), so promotion is
+    evidence-gated — an explicit ``pallas=True`` flips it, never a silent
+    default.
     """
     on = resolve(pallas, legacy)
     if on:

@@ -31,20 +31,35 @@ so the [T, T] score matrix is never materialized in either direction.
 from __future__ import annotations
 
 import functools
+import math
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.sharding import PartitionSpec as P
 
 NEG_INF = -1e30  # large-negative logit for masked positions (f32-safe)
 
-# Swept on v5e (GPT-small shapes, fwd+bwd, bf16, D=64): 1024-blocks are
-# 2.9x faster than 128-blocks at T=1024 and 4.3x at T=8192 (128: 105/294 ms;
-# 1024: 36.8/67.8 ms) — bigger q-tiles amortize the K/V streaming loop and
-# fill the MXU; (bq,bk) beyond (1024,1024) exceeds scoped VMEM at long T.
-# Blocks auto-clamp to T (rounded up to the 128-lane tile, _block_size),
-# so short sequences are unaffected.
+
+def resolve_interpret(interpret: Optional[bool] = None) -> bool:
+    """THE interpret decision, for every ``pl.pallas_call`` in the package
+    (the flash kernels here, ``conv1x1_bn_act``, and through
+    ``flash_block_fwd/bwd`` the ring path): on a TPU a kernel is always handed
+    to the Mosaic compiler; the Pallas interpreter runs only where a caller
+    asks for it (``interpret=True`` — parity tests) or where there is no TPU
+    to compile for, which is the CPU test rig: the kernel-dispatch policy
+    (``ops/dispatch.py``) routes every *auto* selection to the plain XLA path
+    off-TPU, so off-TPU interpretation is reached only by a forced knob."""
+    if interpret is None:
+        return jax.default_backend() != "tpu"
+    return interpret
+
+
+# 1024-blocks: bigger q-tiles amortize the K/V streaming loop and fill the
+# MXU (block sizes not swept on today's chip). Blocks auto-clamp to T
+# (rounded up to the 128-lane tile, _block_size), so short sequences are
+# unaffected.
 _DEFAULT_BLOCK_Q = 1024
 _DEFAULT_BLOCK_K = 1024
 
@@ -54,8 +69,7 @@ def _block_size(block: int, t: int) -> int:
 
     A raw ``min(block, t)`` leaves ragged blocks at short T (ViT-B's 197),
     and a 197-wide tile maps terribly onto the 128-lane MXU / (8,128) VMEM
-    tiling — re-measured on v5e at T=197: aligned 256-blocks run the
-    fwd+bwd kernels 2.3x faster than 197-blocks. Padded rows/cols are
+    tiling. Padded rows/cols are
     masked by ``seq_len`` inside the kernels (K side) or sliced off by the
     callers (q side), so alignment costs only the pad FLOPs.
     """
@@ -404,8 +418,7 @@ def flash_block_fwd(
     block-normalized, lse = log-sum-exp of this block's logits per q row
     (what the cross-block online merge needs). Not differentiable — the ring
     owns the VJP."""
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = resolve_interpret(interpret)
     tq, tk = q.shape[1], k.shape[1]
     qt, kt, vt, bq, bk = _ring_pad(q, k, v, block_q, block_k)
     o, lse = _fwd_call(qt, kt, vt, tk, causal, bq, bk, interpret)
@@ -418,8 +431,7 @@ def flash_block_bwd(
 ):
     """One block's backward contributions ``(dq, dk, dv)`` given the global
     ``lse``/``delta`` ``[B, H, Tq]`` of the resident q shard."""
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = resolve_interpret(interpret)
     b, tq, h, d = q.shape
     tk = k.shape[1]
     qt, kt, vt, bq, bk = _ring_pad(q, k, v, block_q, block_k)
@@ -451,8 +463,8 @@ def flash_attention(
 
     Numerics match ``models.vit.dot_product_attention`` (softmax statistics in
     float32, scale ``D**-0.5``); memory is O(T) per (batch, head) instead of
-    the O(T^2) score tensor. ``interpret=None`` auto-selects: compiled on TPU,
-    Pallas interpreter elsewhere (slow — tests only). ``valid_len`` masks key
+    the O(T^2) score tensor. ``interpret=None`` auto-selects
+    (:func:`resolve_interpret`). ``valid_len`` masks key
     positions >= it — for caller-padded sequences (``ViT.pad_seq_to``); the
     kernels' own seq_len masking does the work, no score tensor or bias mask
     is ever built.
@@ -464,17 +476,61 @@ def flash_attention(
             raise ValueError("valid_len composes with non-causal attention only")
         if not 0 < valid_len <= q.shape[1]:
             raise ValueError(f"valid_len {valid_len} out of range for T={q.shape[1]}")
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    return _flash(q, k, v, causal, block_q, block_k, interpret, valid_len)
+    interpret = resolve_interpret(interpret)
+
+    def kernel(q, k, v):
+        return _flash(q, k, v, causal, block_q, block_k, interpret, valid_len)
+
+    spec = _ambient_shard_spec(q.shape)
+    if spec is None:
+        return kernel(q, k, v)
+    return jax.shard_map(
+        kernel, in_specs=(spec, spec, spec), out_specs=spec, check_vma=False
+    )(q, k, v)
+
+
+def _ambient_shard_spec(shape):
+    """How a ``[B, T, H, D]`` attention operand is split over the ambient
+    mesh (the one ``TrainEngine``/``InferEngine`` set around their jits), or
+    None when the kernel should be called as is.
+
+    XLA has no partitioning rule for the Mosaic custom call: inside a jit
+    over a multi-device mesh jax 0.9 refuses it outright ("Mosaic kernels
+    cannot be automatically partitioned. Please wrap the call in a
+    shard_map" — v5e x4, PR 21), and a partitioner that accepted it could only
+    gather q/k/v and run the whole global batch on every chip.
+    Attention is independent per (batch row, head), so the kernel runs under
+    ``shard_map`` instead: batch over the batch-sharded axes (``data`` x
+    ``fsdp``), heads over ``tensor`` — each where the extent divides (the
+    batch-1 example input of ``model.init`` stays whole). No ambient mesh, a
+    one-device mesh, or a caller already inside a manual region (the ring and
+    Ulysses paths, ``pipeline_apply``) means no wrapping."""
+    mesh = jax.sharding.get_abstract_mesh()
+    if not mesh.axis_names or mesh.manual_axes:
+        return None
+    from distributed_training_pytorch_tpu.parallel.mesh import (
+        DATA_AXIS,
+        FSDP_AXIS,
+        TENSOR_AXIS,
+    )
+
+    b, _, h, _ = shape
+    sizes = mesh.shape
+    batch_axes = tuple(a for a in (DATA_AXIS, FSDP_AXIS) if sizes.get(a, 1) > 1)
+    if b % math.prod(sizes[a] for a in batch_axes):
+        batch_axes = ()
+    heads = sizes.get(TENSOR_AXIS, 1)
+    head_axis = TENSOR_AXIS if heads > 1 and h % heads == 0 else None
+    if not batch_axes and head_axis is None:
+        return None
+    return P(batch_axes or None, None, head_axis, None)
 
 
 # Below this sequence length the plain O(T^2) XLA path wins: the score tensor
 # is small enough to live in VMEM-friendly fusions, while the kernel pays
-# layout transposes + block padding. Re-measured on v5e with the 1024-block
-# tiles (fwd+bwd, bf16, D=64): T=197 (ViT-B) 0.75x, T=256 1.0x, T=512 1.2x,
-# and the gap widens with T (the plain path OOMs outright at T=8192 beyond
-# batch 1 — 12GB score tensors).
+# layout transposes + block padding. The crossover is not measured on
+# today's chip (ROADMAP S2(c)); the plain path's [B,H,T,T] score tensor
+# grows as T^2 and cannot fit at T=8192 beyond a tiny batch.
 FLASH_MIN_SEQ_LEN = 512
 
 
@@ -514,8 +570,7 @@ def _causal_plain(q, k, v):
 
 
 # ---------------------------------------------------------------------------
-# Fused 1x1-conv + BN-apply + ReLU (r4 VERDICT item 2: testing the ResNet
-# "not reachable from user-level JAX" claim with the one tractable kernel).
+# Fused 1x1-conv + BN-apply + ReLU.
 #
 # A 1x1 conv IS a GEMM: NHWC input flattened to [N, Cin] against [Cin, Cout],
 # with the BatchNorm apply folded to a per-output-channel affine
@@ -525,7 +580,7 @@ def _causal_plain(q, k, v):
 # ~28 FLOP/byte on a 240 FLOP/byte v5e — pure bandwidth — so the question is
 # only whether a hand-tiled GEMM+epilogue moves more bytes/s than XLA's
 # conv+fusion at these shapes (scripts/resnet_pallas_probe.py measures both;
-# BASELINE.md records the verdict).
+# not measured on today's chip).
 
 
 def _resolve_act(relu: bool, act: Optional[str]) -> Optional[str]:
@@ -538,13 +593,34 @@ def _resolve_act(relu: bool, act: Optional[str]) -> Optional[str]:
     return act
 
 
-def _resolve_interpret(interpret: Optional[bool]) -> bool:
-    """``interpret=None`` auto-selects like flash_attention: compiled on TPU,
-    Pallas interpreter elsewhere — the CPU fallback that lets the fused paths
-    run (slowly) under JAX_PLATFORMS=cpu for parity tests."""
-    if interpret is None:
-        return jax.default_backend() != "tpu"
-    return interpret
+# What the kernel's block buffers may take of the compiler's 16.00M default
+# scoped-VMEM limit. Measured on the v5e (libtpu 0.0.34) with Cout whole,
+# ConvNeXt-L's last expand (1536 -> 6144) is refused: "Ran out of memory in
+# memory space vmem ... Scoped allocation with size 21.87M"; tiled to 1536
+# columns it still asks for 18.87M — exactly the x tile plus the
+# double-buffered weight and output tiles — "and limit 16.00M". So the plan
+# below counts every block buffer, double-buffered, and keeps the sum under
+# 12 MiB, leaving the rest of the limit to Mosaic's own temporaries.
+_CONV1X1_VMEM_BUDGET = 12 * 2**20
+
+
+def _conv1x1_block_cols(block_rows, cin, cout, x_bytes, w_bytes, out_bytes) -> int:
+    """The Cout tile: all of Cout where the double-buffered x, weight and
+    output blocks fit the budget (every ResNet stage-1 shape, ConvNeXt-L up
+    to 384 -> 1536), else the largest multiple of the 128-lane tile dividing
+    Cout that does (1024 for 768 -> 3072, 512 for 1536 -> 6144). A Cout with
+    no such divisor stays whole and the compiler decides."""
+
+    def buffers(bn):
+        return 2 * (block_rows * cin * x_bytes + cin * bn * w_bytes + block_rows * bn * out_bytes)
+
+    if buffers(cout) <= _CONV1X1_VMEM_BUDGET:
+        return cout
+    fitting = [
+        bn for bn in range(128, cout, 128)
+        if cout % bn == 0 and buffers(bn) <= _CONV1X1_VMEM_BUDGET
+    ]
+    return max(fitting, default=cout)
 
 
 def _conv1x1_kernel(x_ref, w_ref, a_ref, b_ref, o_ref, *, act):
@@ -580,13 +656,14 @@ def conv1x1_bn_act(
     ``[Cout]`` — the folded BN apply (identity: ones/zeros). The epilogue
     activation is ``act`` (``"relu"``/``"gelu"``/``None``); when ``act`` is
     unset the legacy ``relu`` bool picks relu vs identity. Grid over row
-    blocks; Cin/Cout stay whole (<= a few hundred channels at ResNet shapes,
-    so the weight slab and one x tile sit comfortably in VMEM). Matmul on
+    blocks x Cout tiles; Cin stays whole, and so does Cout wherever the
+    block buffers fit VMEM (every ResNet shape; ConvNeXt-L's two widest
+    expands are tiled — :func:`_conv1x1_block_cols`). Matmul on
     the MXU in f32 accumulation; epilogue on the VPU; output cast to
-    ``out_dtype`` (default: x.dtype). ``interpret=None`` auto-selects:
-    compiled on TPU, Pallas interpreter elsewhere."""
+    ``out_dtype`` (default: x.dtype). ``interpret=None`` auto-selects
+    (:func:`resolve_interpret`)."""
     act = _resolve_act(relu, act)
-    interpret = _resolve_interpret(interpret)
+    interpret = resolve_interpret(interpret)
     lead = x.shape[:-1]
     cin = x.shape[-1]
     if w.shape[0] != cin:
@@ -602,16 +679,22 @@ def conv1x1_bn_act(
         x2 = jnp.pad(x2, ((0, n_pad - n), (0, 0)))
     a2 = scale.reshape(1, cout).astype(jnp.float32)
     b2 = bias.reshape(1, cout).astype(jnp.float32)
+    bn = _conv1x1_block_cols(
+        block_rows, cin, cout,
+        x2.dtype.itemsize, w.dtype.itemsize, jnp.dtype(out_dtype).itemsize,
+    )
+    # Cout tiles innermost: the x tile's block index does not change across
+    # them, so it is fetched once per row block.
     out = pl.pallas_call(
         functools.partial(_conv1x1_kernel, act=act),
-        grid=(n_pad // block_rows,),
+        grid=(n_pad // block_rows, cout // bn),
         in_specs=[
-            pl.BlockSpec((block_rows, cin), lambda i: (i, 0)),
-            pl.BlockSpec((cin, cout), lambda i: (0, 0)),
-            pl.BlockSpec((1, cout), lambda i: (0, 0)),
-            pl.BlockSpec((1, cout), lambda i: (0, 0)),
+            pl.BlockSpec((block_rows, cin), lambda i, j: (i, 0)),
+            pl.BlockSpec((cin, bn), lambda i, j: (0, j)),
+            pl.BlockSpec((1, bn), lambda i, j: (0, j)),
+            pl.BlockSpec((1, bn), lambda i, j: (0, j)),
         ],
-        out_specs=pl.BlockSpec((block_rows, cout), lambda i: (i, 0)),
+        out_specs=pl.BlockSpec((block_rows, bn), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((n_pad, cout), out_dtype),
         interpret=interpret,
     )(x2, w, a2, b2)
@@ -704,5 +787,5 @@ def conv1x1_bn_act_diff(
     gelu recomputes z for its derivative regardless)."""
     return _conv1x1_diff(
         x, w, scale, bias, _resolve_act(relu, act), block_rows,
-        out_dtype or x.dtype, _resolve_interpret(interpret), affine_grads,
+        out_dtype or x.dtype, resolve_interpret(interpret), affine_grads,
     )

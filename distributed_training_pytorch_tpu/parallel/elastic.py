@@ -77,7 +77,6 @@ __all__ = [
     "replan_accum",
     "replan_absorbing",
     "replan_excluding",
-    "replan_reader",
     "nearest_divisible_accum",
 ]
 
@@ -206,48 +205,6 @@ def replan_accum(
     # Unreachable: accum == max_accum always qualifies (divides by the guard
     # above, and its 1 row/shard <= old_rows which is clamped >= 1).
     raise AssertionError("replan_accum: no divisible accumulation factor")
-
-
-def replan_reader(
-    plan_or_axes,
-    *,
-    shard_sizes,
-    global_batch_size: int,
-    cursor: int,
-    process_index: int = 0,
-    process_count: int = 1,
-) -> dict:
-    """Re-split the streaming shard assignment for a re-planned topology —
-    the data-plane half of an elastic resume (docs/data.md "elastic
-    re-split ritual").
-
-    The global record sequence is a pure function of ``(seed, epoch, shard
-    structure)`` and never moves; what changes across N→M is only *which
-    slice of it each host feeds*. Given the solved :class:`ElasticPlan` (or
-    bare new mesh axes) and the checkpoint's global ``cursor``, this derives
-    the new per-host row-range assignment + its version for the new
-    ``data x fsdp`` batch extent — pure index arithmetic, no data movement,
-    no communication (every host derives the identical answer).
-    """
-    from distributed_training_pytorch_tpu.data.streaming.state import (
-        shard_assignment,
-    )
-
-    if isinstance(plan_or_axes, ElasticPlan):
-        axes = plan_or_axes.new_axes
-    else:
-        axes = record_axes(plan_or_axes)
-    extent = max(
-        1, int(axes.get(DATA_AXIS, 1)) * int(axes.get(FSDP_AXIS, 1))
-    )
-    return shard_assignment(
-        shard_sizes=shard_sizes,
-        global_batch_size=global_batch_size,
-        process_index=process_index,
-        process_count=process_count,
-        batch_extent=extent,
-        cursor=cursor,
-    )
 
 
 def nearest_divisible_accum(

@@ -21,12 +21,13 @@ Two dispatch implementations with identical routing semantics (parity-tested):
   Best at small group sizes (the one-hots stay tiny and everything fuses).
 * ``dispatch_impl="sort"`` — argsort/cummax ranking + scatter-add into the
   ``[E, C, d]`` buffers and gather back; memory and compute O(S·k + E·C·d)
-  per group, no quadratic one-hots. Best at large group sizes. The measured
-  single-chip crossover is recorded in BASELINE.md (``bench.py`` moe mode).
+  per group, no quadratic one-hots. Best at large group sizes. The
+  crossover is not measured on today's chip.
 * ``dispatch_impl="auto"`` (default) — picks per call site from the static
   group size: ``sort`` at >= :data:`SORT_DISPATCH_MIN_GROUP` tokens/group
-  (the measured ~4k crossover), ``einsum`` below. Group size is shape-derived,
-  so the choice is made at trace time — no runtime branch under jit.
+  (an earlier round's ~4k crossover), ``einsum`` below. Group size is
+  shape-derived, so the choice is made at trace time — no runtime branch
+  under jit.
 
 Inference: ``__call__(x, decode=True)`` routes capacity-free — every token
 computes its top-k experts by direct weight gather (no buffers, no drops), the
@@ -47,7 +48,6 @@ import numpy as np
 from flax import linen as nn
 from jax.sharding import PartitionSpec as P
 
-from distributed_training_pytorch_tpu import compat
 from distributed_training_pytorch_tpu.parallel.mesh import DATA_AXIS, EXPERT_AXIS
 
 __all__ = [
@@ -60,10 +60,9 @@ __all__ = [
     "router_z_loss",
 ]
 
-# Measured einsum/sort crossover (single v5e chip, fwd+bwd, E=8 k=2 d=512
-# h=1024 bf16 — BASELINE.md "MoE dispatch crossover"): einsum wins at 1k
-# tokens/group (20.4 vs 23.1 ms), ties at 4k, loses 2x at 16k (40.4 vs
-# 20.3 ms). "auto" flips to sort at this group size.
+# einsum/sort crossover from an earlier round (E=8 k=2 d=512 h=1024 bf16);
+# not measured on today's chip (ROADMAP D5/R1). "auto" flips to sort at this
+# group size.
 SORT_DISPATCH_MIN_GROUP = 4096
 
 
@@ -83,11 +82,11 @@ def _constrain(x: jax.Array, axes: tuple, *, activation: bool = False) -> jax.Ar
     (spmd_partitioner_util.cc "partition_group_list ... num_devices_per_group",
     bisected on jax 0.9/CPU) — so they are skipped there, and expert layout
     flows from the weights."""
-    mesh = compat.get_abstract_mesh()
-    mesh_axes = getattr(mesh, "axis_names", ()) if mesh is not None else ()
+    mesh = jax.sharding.get_abstract_mesh()
+    mesh_axes = mesh.axis_names
     if not mesh_axes:
         return x
-    if activation and compat.manual_axes_of(mesh):
+    if activation and mesh.manual_axes:
         return x
     spec = P(*[a if (a is not None and a in mesh_axes) else None for a in axes])
     return jax.lax.with_sharding_constraint(x, spec)
@@ -338,7 +337,7 @@ def manual_expert_mlp(
     dtype: Any = jnp.float32,
 ) -> jax.Array:
     """MoE FFN forward with expert parallelism expressed MANUALLY — the
-    workaround for the data x expert x pipe composition (r4 VERDICT item 7).
+    workaround for the data x expert x pipe composition.
 
     :class:`MoEMlp` expresses expert parallelism as sharding constraints and
     lets GSPMD insert the token<->expert all-to-all. Inside
@@ -383,14 +382,12 @@ def manual_expert_mlp(
     ``num_experts`` by ``expert_size``. Differentiable; aux losses are not
     sow'd on this path (compute them from a separate router call if needed).
     """
-    from distributed_training_pytorch_tpu.compat import shard_map
-
     # Inside a traced context the shard_map must receive the ambient ABSTRACT
     # mesh (it carries e.g. pipe's Manual axis type from an enclosing
     # pipeline_apply region); the concrete mesh arg is the fallback for
     # un-nested use outside set_mesh.
-    ctx = compat.get_abstract_mesh()
-    if ctx is not None and getattr(ctx, "axis_names", ()):
+    ctx = jax.sharding.get_abstract_mesh()
+    if ctx.axis_names:
         mesh = ctx
     elif mesh is None:
         raise ValueError("manual_expert_mlp needs a mesh (arg or ambient set_mesh)")
@@ -462,7 +459,7 @@ def manual_expert_mlp(
             top_k=top_k, capacity=capacity, dtype=dtype,
         )
 
-    if compat.manual_axes_of(mesh):
+    if mesh.manual_axes:
         raise ValueError(
             "manual_expert_mlp cannot nest inside an enclosing shard_map "
             "(Shardy rejects both re-binding a parent's manual axis and a "
@@ -482,7 +479,7 @@ def manual_expert_mlp(
         body, x_spec = body_a2a, _present(data_axis, expert_axis)
     else:
         body, x_spec = body_psum, _present(data_axis)
-    fn = shard_map(
+    fn = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(x_spec, P(), P(), w_spec, w_spec),

@@ -50,11 +50,9 @@ from typing import Any, Callable
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
+from jax.lax import pcast
 from jax.sharding import Mesh, PartitionSpec as P
-
-# Partial-manual shard_map (`axis_names`): the compat shim maps it onto the
-# old experimental API's complementary `auto=` parameter on pre-0.6 JAX.
-from distributed_training_pytorch_tpu.compat import pcast, shard_map
 
 from distributed_training_pytorch_tpu.parallel.mesh import PIPE_AXIS
 

@@ -23,12 +23,9 @@ the 8-device CPU mesh).
 
 from __future__ import annotations
 
-
 import jax
 import jax.numpy as jnp
-from jax import lax
-
-from distributed_training_pytorch_tpu.compat import shard_map
+from jax import lax, shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from distributed_training_pytorch_tpu.parallel.mesh import SEQ_AXIS
@@ -162,7 +159,6 @@ def _ring_attention_flash(q, k, v, mesh, *, axis, causal):
     owns the schedule (autodiff never sees the kernel internals).
     """
     s = mesh.shape[axis]
-    interpret = jax.default_backend() != "tpu"
     from distributed_training_pytorch_tpu.ops.pallas import (
         flash_block_bwd,
         flash_block_fwd,
@@ -183,7 +179,7 @@ def _ring_attention_flash(q, k, v, mesh, *, axis, causal):
 
         def fwd_block(step, k_blk, v_blk):
             if not causal:
-                return flash_block_fwd(q, k_blk, v_blk, causal=False, interpret=interpret)
+                return flash_block_fwd(q, k_blk, v_blk, causal=False)
 
             def skip(_k, _v):
                 return (
@@ -195,8 +191,8 @@ def _ring_attention_flash(q, k, v, mesh, *, axis, causal):
                 block_type(step),
                 [
                     skip,
-                    lambda kb, vb: flash_block_fwd(q, kb, vb, causal=True, interpret=interpret),
-                    lambda kb, vb: flash_block_fwd(q, kb, vb, causal=False, interpret=interpret),
+                    lambda kb, vb: flash_block_fwd(q, kb, vb, causal=True),
+                    lambda kb, vb: flash_block_fwd(q, kb, vb, causal=False),
                 ],
                 k_blk,
                 v_blk,
@@ -232,7 +228,7 @@ def _ring_attention_flash(q, k, v, mesh, *, axis, causal):
         def bwd_block(step, k_blk, v_blk):
             if not causal:
                 return flash_block_bwd(
-                    q, k_blk, v_blk, g, lse, delta, causal=False, interpret=interpret
+                    q, k_blk, v_blk, g, lse, delta, causal=False
                 )
 
             def skip(_k, _v):
@@ -247,10 +243,10 @@ def _ring_attention_flash(q, k, v, mesh, *, axis, causal):
                 [
                     skip,
                     lambda kb, vb: flash_block_bwd(
-                        q, kb, vb, g, lse, delta, causal=True, interpret=interpret
+                        q, kb, vb, g, lse, delta, causal=True
                     ),
                     lambda kb, vb: flash_block_bwd(
-                        q, kb, vb, g, lse, delta, causal=False, interpret=interpret
+                        q, kb, vb, g, lse, delta, causal=False
                     ),
                 ],
                 k_blk,

@@ -4,10 +4,8 @@ PR 6 made ONE run exhaustively explainable (``StepProfile``: per-category
 device-wall attribution + the idle dispatch gap, fractions summing to 1 by
 construction). This module is the *across-runs* layer: two StepProfiles in,
 one :class:`ProfileDiff` out, answering the question the ROADMAP actually
-asks — *where did the step_ms delta come from?* BENCH r02→r05 sat flat at
-~76.85 ms for four rounds and nothing could say which category refused to
-move; ROADMAP item 2's Pallas/XLA-flag PR needs exactly this before/after
-evidence to claim a win.
+asks — *where did the step_ms delta come from?* A claimed win needs exactly
+this before/after evidence.
 
 Conventions, inherited from StepProfile so the diff cannot invent time:
 

@@ -1,7 +1,6 @@
 """Perf-regression gate logic (ISSUE 6): measurement vs committed baseline.
 
-Four flat bench rounds (BENCH_r02 -> r05, ~54k img/s/chip) happened silently
-because nothing *failed* when step time stood still or slipped. The gate
+Nothing *failed* when step time stood still or slipped. The gate
 makes perf a CI contract: ``scripts/perf_gate.py`` measures a step time,
 this module compares it against the committed ``PERF_BASELINE.json`` with a
 relative tolerance, and a regression past the tolerance is a nonzero exit in

@@ -11,8 +11,7 @@ itemization, so a hot op carries FLOPs + bytes + arithmetic intensity — its
 roofline position: is this op compute-bound (intensity above the chip's
 ridge point) or memory-bound?
 
-The ``idle`` bucket is the dispatch-gap audit: the prime suspect for the
-BENCH ``mfu`` 0.70 vs ``mfu_exec`` 0.49 gap is device wall spent *between*
+The ``idle`` bucket is the dispatch-gap audit: device wall spent *between*
 programs (per-step dispatch, H2D waits), which no per-op table can show —
 only the gaps between event intervals can.
 

@@ -40,7 +40,6 @@ import jax
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from distributed_training_pytorch_tpu import compat
 from distributed_training_pytorch_tpu.parallel import mesh as mesh_lib
 from distributed_training_pytorch_tpu.parallel import sharding as sharding_lib
 from distributed_training_pytorch_tpu.serving.batcher import pick_bucket
@@ -111,7 +110,7 @@ class InferEngine:
     def _ambient_mesh(self):
         # Same reason as TrainEngine._ambient_mesh: in-model bare
         # PartitionSpec constraints resolve against the ambient mesh.
-        return compat.set_mesh(self.mesh)
+        return jax.sharding.set_mesh(self.mesh)
 
     def _sharding_for(self, params) -> Any:
         leaf_shapes = jax.tree.map(

@@ -33,9 +33,9 @@ preemption events, loss-scale state) into one surface:
   ``XLA_FLAGS``, mesh/dtype/chain_steps) stamped on bench lines, dryrun
   entries, and ``run_start`` events so comparisons are attributable
   (ISSUE 14);
-* :mod:`~.history`  — the committed ``BENCH_r*``/``MULTICHIP_r*`` rounds as
-  per-metric trajectories with flat-streak + regression detection
-  (``scripts/bench_history.py``; the r02→r05 plateau is the self-test);
+* :mod:`~.history`  — a directory of ``BENCH_r*``/``MULTICHIP_r*`` round
+  files as per-metric trajectories with flat-streak + regression detection
+  (``scripts/bench_history.py``);
 * :mod:`~.monitor`  — the live-operations layer (ISSUE 15): a streaming
   doctor tailing events.jsonl through the shared
   :class:`~.events.EventFollower`, re-deriving the doctor's verdicts

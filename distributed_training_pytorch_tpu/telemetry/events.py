@@ -94,7 +94,8 @@ axes, ``device_ids``, requests ``shed`` past the drain deadline,
 ``replans``, the elastic solver's ``plan_reason``)), and the
 streaming-data layer's records
 (ISSUE 19, emitted by the Trainer for any loader speaking the
-reader-state surface (``data/streaming``): ``shard_assignment`` — one per
+reader-state surface (docs/data.md; no in-tree loader does today):
+``shard_assignment`` — one per
 attempt, on start and on every elastic resume (the assignment ``version``
 fingerprint, ``record_count``/``shard_count``, ``global_batch_size``, this
 host's ``row_lo``/``row_hi`` slice, the ``batch_extent`` it feeds, the

@@ -1,18 +1,15 @@
-"""Bench-history ledger (ISSUE 14): the committed rounds as trajectories.
+"""Bench-history reader (ISSUE 14): round files as trajectories.
 
-The repo commits its own benchmark record — ``BENCH_r*.json`` /
-``MULTICHIP_r*.json``, one file per round, each carrying the bench's JSON
-line(s) — but nothing ever *read* it: BENCH r02→r05 sat flat at ~76.85 ms /
-``mfu_exec`` 0.49 for four consecutive rounds and no instrument noticed,
-because every instrument looked at one run. This module ingests the
-committed rounds into per-metric trajectories and runs two detectors over
-them:
+A directory of ``BENCH_r*.json`` / ``MULTICHIP_r*.json`` round files — one
+file per round, each carrying the bench's JSON line(s) — is a trajectory no
+per-run instrument reads. This module ingests such a directory into
+per-metric trajectories and runs two detectors over them (the repo commits
+no round files of its own; the driver's ``PERF_LEDGER.jsonl`` is the record
+of measured performance):
 
 * **flat streak** — ``min_rounds`` consecutive rounds whose values all sit
   within a relative band (spread/mean <= ``rel_tol``). A plateau is the
-  signature of perf work not landing (the motivating r02→r05 case — the
-  committed files are this module's own self-test,
-  ``scripts/bench_history.py --self-test``). Boundary semantics are exact:
+  signature of perf work not landing. Boundary semantics are exact:
   ``min_rounds - 1`` flat rounds stay quiet, ``min_rounds`` fire.
 * **regression** — a round-over-round move beyond tolerance in the *bad*
   direction for metrics whose direction is known (``step_ms`` up = bad,

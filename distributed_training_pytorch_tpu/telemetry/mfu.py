@@ -22,32 +22,40 @@ __all__ = [
     "window_report",
 ]
 
-# bf16 peak FLOP/s per chip, by PJRT device_kind substring (the table
-# bench.py's MFU headline has always used; "cpu" is a nominal stand-in so
-# smoke runs produce finite — clearly synthetic — utilization numbers).
+# Published bf16 peak FLOP/s of one chip, keyed by a substring of PJRT's
+# ``device_kind``. Source: Google Cloud TPU documentation, the per-generation
+# system-architecture pages ("TPU v5e": 197 TFLOP/s bf16, 16 GB HBM at
+# 819 GB/s — jax reports that chip as "TPU v5 lite"; "TPU v4": 275; "TPU v5p":
+# 459; "TPU v6e": 918). Only the v5e row has been exercised on a chip
+# (chip_smoke.py). A device that is not in the table has NO peak: there is no
+# CPU row and no default, so no utilisation is ever computed against a
+# made-up denominator.
 PEAK_FLOPS = {
-    "v5 lite": 197e12,  # v5e litepod chip (197 bf16 TFLOP/s)
+    "v5 lite": 197e12,
     "v5e": 197e12,
     "v4": 275e12,
     "v5p": 459e12,
     "v6": 918e12,
-    "cpu": 1e12,  # nominal, for smoke runs
 }
 
 
-def device_peak_flops(device) -> float:
-    """Peak bf16 FLOP/s of one device, by ``device_kind`` substring match
-    (1e12 nominal fallback for unknown kinds)."""
-    kind = getattr(device, "device_kind", "cpu").lower()
+def device_peak_flops(device) -> float | None:
+    """Published peak bf16 FLOP/s of one device, by ``device_kind`` substring
+    match; None for a kind that is not in :data:`PEAK_FLOPS`. Callers that
+    report utilisation treat None as "no utilisation field" (the Trainer) or
+    as an error (``bench.py``, ``chip_smoke.py``)."""
+    kind = str(getattr(device, "device_kind", "")).lower()
     for key, val in PEAK_FLOPS.items():
         if key in kind:
             return val
-    return 1e12
+    return None
 
 
-def mfu_value(flops_per_step: float, step_time_s: float, peak_flops: float) -> float | None:
+def mfu_value(
+    flops_per_step: float, step_time_s: float, peak_flops: float | None
+) -> float | None:
     """``flops / dt / peak`` with the degenerate cases mapped to None (no
-    FLOPs known / zero time / zero peak -> no utilization claim)."""
+    FLOPs known / zero time / no known peak -> no utilization claim)."""
     if not flops_per_step or not step_time_s or not peak_flops:
         return None
     return float(flops_per_step) / float(step_time_s) / float(peak_flops)
@@ -79,14 +87,14 @@ def window_report(
     window_time_s: float,
     *,
     flops_per_step: float | None,
-    peak_flops: float,
+    peak_flops: float | None,
 ) -> dict:
     """Per-window telemetry fields from measured wall time: ``steps``,
     ``step_ms``, and ``mfu`` when a FLOP count is known (the trainer's
     ``step_cost_analysis`` probe or an explicit ``Telemetry(flops_per_step=
-    ...)``). A "window" is whatever interval the caller timed — under
-    chained execution the trainer's sync points land on window boundaries,
-    so the report covers whole windows."""
+    ...)``) and the device has a published peak. A "window" is whatever
+    interval the caller timed — under chained execution the trainer's sync
+    points land on window boundaries, so the report covers whole windows."""
     steps = max(int(steps), 1)
     step_s = window_time_s / steps
     out = {"steps": steps, "step_ms": step_s * 1e3}

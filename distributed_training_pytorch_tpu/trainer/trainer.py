@@ -172,9 +172,8 @@ class Trainer:
         self.log_every = log_every
         # The reference saves `last` every epoch (``trainer/trainer.py:163``)
         # — the right default on local disk. When the checkpoint path is slow
-        # (multi-GB states, or a chip behind a thin link where the d2h
-        # snapshot dominates the epoch), raise this to save `last` every N
-        # epochs; preemption saves still fire regardless.
+        # (multi-GB states), raise this to save `last` every N epochs;
+        # preemption saves still fire regardless.
         self.last_save_period = max(1, int(last_save_period))
         self.cur_epoch = 0
         # Tracing knobs. `profile_dir`/`profile_steps` is the legacy surface
@@ -376,7 +375,7 @@ class Trainer:
         # Epoch this attempt began at (set after restore in train()):
         # compiles there are warmup, not the compile_bound retrace signal.
         self._start_epoch = 0
-        self._peak_flops = 0.0  # finalized after mesh selection below
+        self._peak_flops = None  # finalized after mesh selection below
         # Live-operations layer (ISSUE 15; docs/observability.md "Live
         # monitoring"): the heartbeat pulse + the optional in-process
         # status exporter. Heartbeats are emitted at the existing
@@ -486,9 +485,11 @@ class Trainer:
         # Telemetry's mesh-dependent piece (the subsystem itself was
         # constructed before mesh selection, for the elastic peek).
         if self.telemetry is not None:
+            # None for a device with no published peak (the CPU included):
+            # every mfu field is then simply absent (telemetry_mfu.mfu_value).
+            chip_peak = telemetry_mfu.device_peak_flops(self.mesh.devices.flat[0])
             self._peak_flops = (
-                telemetry_mfu.device_peak_flops(self.mesh.devices.flat[0])
-                * self.mesh.devices.size
+                None if chip_peak is None else chip_peak * self.mesh.devices.size
             )
         # Memory preflight (ISSUE 8; memory/preflight.py): predict the
         # configured program's peak HBM from an abstract lowering BEFORE the
@@ -536,7 +537,7 @@ class Trainer:
         self.train_dataloader = self.build_dataloader(self.train_dataset, phase="train")
         # Streaming data plane (ISSUE 19; docs/data.md): duck-typed on the
         # reader-state surface so any build_dataloader override returning a
-        # StreamingLoader gets checkpoint-carried reader state + the
+        # loader with ``reader_state`` gets checkpoint-carried reader state + the
         # shard_assignment/data_reader_state telemetry without trainer
         # subclassing. The loader feeds per-host row slices; telling it the
         # mesh's batch-shard extent pins its assignment version to the
@@ -1450,7 +1451,15 @@ class Trainer:
         dt = time.perf_counter() - t0
         if self.goodput is not None:
             self.goodput.tick("compile")  # the probe IS an XLA compile
-        self._flops_per_step = float(cost.get("flops", 0.0)) or None
+        # cost_analysis() of an SPMD-partitioned executable counts ONE
+        # device's program; the utilisation denominator (_peak_flops) is the
+        # whole mesh's peak, so the numerator must be the whole mesh's work
+        # too. (Under tensor parallelism the per-device programs duplicate a
+        # little work, so this slightly over-counts — it never under-counts
+        # by the device count, which is what the bare figure did.)
+        self._flops_per_step = (
+            float(cost.get("flops", 0.0)) * self.mesh.devices.size
+        ) or None
         self.events.emit(
             "compile",
             kind="mfu_probe",

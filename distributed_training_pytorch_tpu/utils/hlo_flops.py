@@ -33,13 +33,9 @@ __all__ = [
 
 
 def xla_cost_analysis(compiled) -> dict:
-    """``compiled.cost_analysis()`` normalized to one flat dict — old jaxlib
-    returns a single-element list of per-program dicts, new jaxlib the dict
-    itself."""
-    cost = compiled.cost_analysis() or {}
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return dict(cost)
+    """``compiled.cost_analysis()`` as a plain dict ({} when the backend
+    reports none)."""
+    return dict(compiled.cost_analysis() or {})
 
 
 def bytes_accessed(compiled) -> float | None:
@@ -48,7 +44,7 @@ def bytes_accessed(compiled) -> float | None:
     attributes to the program. The memory-side twin of the ``flops`` entry —
     together they place a program on the roofline. For a ``lax.scan``-chained
     program the body is counted once, matching the FLOP convention. None when
-    the backend reports no cost analysis (e.g. some relay/plugin paths)."""
+    the backend reports no cost analysis."""
     value = xla_cost_analysis(compiled).get("bytes accessed")
     return float(value) if value is not None else None
 
@@ -228,8 +224,7 @@ def executed_matmul_flops(compiled) -> float | None:
 
     Custom calls (Pallas kernels) are opaque to both this walk and to
     ``cost_analysis()`` — a flash-attention program's counted FLOPs exclude
-    the attention matmuls entirely (measured: BASELINE.md "LM FLOP-counter
-    reconciliation"); comparisons against nominal counts must add the
+    the attention matmuls entirely; comparisons against nominal counts must add the
     kernel's analytic FLOPs back."""
     total = sum(r["flops"] for r in itemize_hlo_matmul_flops(compiled.as_text()))
     cost = xla_cost_analysis(compiled)

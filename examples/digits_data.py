@@ -1,8 +1,8 @@
 """Materialize the sklearn `digits` corpus as an image-folder tree.
 
 The only *real* image-classification corpus reachable in this offline
-environment (network egress is blocked — CIFAR-10 cannot be downloaded; see
-BASELINE.md). 1,797 genuine 8x8 grayscale handwritten digits (UCI Optical
+environment (network egress is blocked — CIFAR-10 cannot be downloaded).
+1,797 genuine 8x8 grayscale handwritten digits (UCI Optical
 Recognition of Handwritten Digits) are upscaled to 32x32 RGB PNGs and laid out
 exactly like the reference's dataset tree (``dataset/example_dataset.py:24-30``:
 ``<root>/<split>/<label>/*.png``), so the full reference flow — ImageFolder

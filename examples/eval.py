@@ -36,6 +36,7 @@ from distributed_training_pytorch_tpu.models import VGG16
 from distributed_training_pytorch_tpu.ops import top_k_accuracy
 from distributed_training_pytorch_tpu.train import TrainEngine, make_supervised_loss
 from distributed_training_pytorch_tpu.parallel import mesh as mesh_lib
+from distributed_training_pytorch_tpu.utils import enable_compile_cache
 
 LABELS = ["cat", "dog", "snake"]
 HEIGHT = WIDTH = 224
@@ -96,6 +97,7 @@ def evaluate(
 
 
 if __name__ == "__main__":
+    enable_compile_cache()  # before the first compile (utils/compile_cache.py)
     import os
 
     checkpoint_dir = sys.argv[1] if len(sys.argv) > 1 else "./runs/weights/last"

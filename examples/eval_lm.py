@@ -30,6 +30,7 @@ from distributed_training_pytorch_tpu.checkpoint import CheckpointManager
 from distributed_training_pytorch_tpu.models import GPTSmall, LMTiny
 from distributed_training_pytorch_tpu.models.transformer_lm import generate
 from distributed_training_pytorch_tpu.train import TrainState
+from distributed_training_pytorch_tpu.utils import enable_compile_cache
 
 
 def build_model(size: str, seq_len: int, moe_every: int = 0):
@@ -104,10 +105,8 @@ def sample(checkpoint_dir: str, prompt_text: bytes, *, size="small", seq_len=256
     if timings is not None:
         import time as _time
 
-        # The np.asarray above already forced the warm-up to completion (the
-        # one reliable sync on relay-backed platforms, where
-        # block_until_ready can be a no-op), so the window below times only
-        # the second generate call.
+        # The np.asarray above already forced the warm-up to completion, so
+        # the window below times only the second generate call.
         t0 = _time.perf_counter()
         greedy = np.asarray(generate(model, variables, prompt, gen_steps, key0))
         dt = _time.perf_counter() - t0
@@ -127,7 +126,7 @@ def sample(checkpoint_dir: str, prompt_text: bytes, *, size="small", seq_len=256
 
 def decode_benchmark(model, params, *, prompt_len=32, gen_steps=128,
                      batches=(1, 8, 32, 128)) -> list[dict]:
-    """Batched KV-cache decode throughput (r4 VERDICT item 8): time greedy
+    """Batched KV-cache decode throughput: time greedy
     ``generate`` at several decode batch sizes and report aggregate tok/s and
     per-stream rate. One compile per batch size (shape change); the timed
     window is the second call. Single-token decode is HBM-bandwidth-bound
@@ -157,6 +156,7 @@ def decode_benchmark(model, params, *, prompt_len=32, gen_steps=128,
 
 
 if __name__ == "__main__":
+    enable_compile_cache()  # before the first compile (utils/compile_cache.py)
     ckpt = sys.argv[1] if len(sys.argv) > 1 else "./runs/lm/weights/last"
     corpus = sys.argv[2] if len(sys.argv) > 2 else os.environ.get("LM_CORPUS", "")
     size = os.environ.get("LM_SIZE", "small")
@@ -187,7 +187,7 @@ if __name__ == "__main__":
         print(f"DECODE: {timings['decode_tok_per_s']:.1f} tok/s "
               f"(greedy, batch 1, {timings['decode_steps']} single-token steps)")
     # DECODE_BATCHES="1,8,32,128": measure batched decode throughput instead
-    # of claiming it scales (BASELINE.md decode table). DECODE_GEN_STEPS sets
+    # of claiming it scales. DECODE_GEN_STEPS sets
     # the timing window independently of the sampling GEN_STEPS — the
     # per-step rate is window-length sensitive (dispatch amortization), so
     # table rows must come from a fixed window.

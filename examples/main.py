@@ -10,10 +10,11 @@ import sys
 
 sys.path.insert(0, ".")  # allow `python examples/main.py` from the repo root
 
-from distributed_training_pytorch_tpu.utils import Logger
+from distributed_training_pytorch_tpu.utils import Logger, enable_compile_cache
 from examples.example_trainer import ExampleTrainer
 
 if __name__ == "__main__":
+    enable_compile_cache()  # before the first compile (utils/compile_cache.py)
     logger = Logger("VGG16", "./runs/logfile.log")
 
     # Analog of ExampleTrainer.ddp_setup(backend="nccl") (``main.py:7``): a

@@ -1,7 +1,7 @@
 """Materialize a real byte-level LM corpus from in-env text.
 
-The environment is offline (BASELINE.md), so the LM train-to-accuracy proof
-(r3 VERDICT item 2) uses genuine text that ships with the image: the Python
+The environment is offline, so the LM train-to-accuracy proof
+uses genuine text that ships with the image: the Python
 standard library's source files plus installed-package documentation — real,
 human-written prose and code, ~tens of MB. Deterministic: files are collected
 in sorted order, so every run (and every host) builds the identical corpus.

@@ -2,7 +2,7 @@
 
 ``./run.sh`` runs this on TPU: VGG16 (bf16 activations) on CIFAR-10 with
 data-parallel sharding over every available chip, targeting GPU-DDP top-1
-parity at >= 60% MFU (BASELINE.md). Reads the standard ``cifar-10-batches-py``
+parity at >= 60% MFU (BASELINE.json). Reads the standard ``cifar-10-batches-py``
 pickle directory (pure numpy — no torchvision dependency); if absent, falls
 back to a synthetic CIFAR-shaped set so the pipeline is still exercisable.
 
@@ -10,9 +10,7 @@ Env knobs: ``CIFAR10_DIR`` (default ./data/cifar-10-batches-py), ``EPOCHS``
 (default 100), ``BATCH`` (global, default 1024), ``BASE_LR`` (default 0.1,
 linearly scaled by BATCH/256), ``SAVE_DIR`` (default ./runs/cifar10),
 ``DTYPE`` (fp32|bf16|fp16 mixed-precision policy — docs/mixed_precision.md),
-``PALLAS`` (1|0 kernel-policy knob, unset = per-model auto — ops/dispatch.py),
-``TUNED`` (1 adopts the committed TUNED.json winner's knobs as defaults —
-docs/performance.md "Autotuning").
+``PALLAS`` (1|0 kernel-policy knob, unset = per-model auto — ops/dispatch.py).
 """
 
 from __future__ import annotations
@@ -23,25 +21,16 @@ import sys
 
 sys.path.insert(0, ".")
 
-from distributed_training_pytorch_tpu.ops.dispatch import pallas_from_env
-from distributed_training_pytorch_tpu.train.autotune import tuned_defaults
-
-# TUNED=1 (mirrors DTYPE/CHAIN_STEPS; docs/performance.md "Autotuning"):
-# adopt the committed TUNED.json winner's knobs as DEFAULTS — resolved here,
-# before the first jax use, so a tuned xla_flags win installs into XLA_FLAGS
-# in time for backend init. Explicit env knobs still override; unset TUNED
-# (the default) changes nothing anywhere.
-TUNED = tuned_defaults()
-
 import jax.numpy as jnp
 import numpy as np
 import optax
 
 from distributed_training_pytorch_tpu.data import ArrayDataSource
 from distributed_training_pytorch_tpu.ops import accuracy, cross_entropy_loss, warmup_cosine_lr
+from distributed_training_pytorch_tpu.ops.dispatch import pallas_from_env
 from distributed_training_pytorch_tpu.parallel import mesh_from_env
 from distributed_training_pytorch_tpu.trainer import Trainer
-from distributed_training_pytorch_tpu.utils import Logger
+from distributed_training_pytorch_tpu.utils import Logger, enable_compile_cache
 
 CIFAR_MEAN = np.array([0.4914, 0.4822, 0.4465], np.float32)
 CIFAR_STD = np.array([0.2470, 0.2435, 0.2616], np.float32)
@@ -105,8 +94,8 @@ DTYPE = os.environ.get("DTYPE") or None
 # paths, 0 forces plain XLA, unset = per-model auto — for VGG16 every
 # resolution lands on plain (no fused-kernel coverage for 3x3 convs) and the
 # no-op is recorded as a kernel_dispatch event rather than ignored silently
-# (ops/dispatch.py). A kept TUNED.json pallas verdict is the auto default.
-PALLAS = pallas_from_env(default=TUNED.get("pallas"))
+# (ops/dispatch.py).
+PALLAS = pallas_from_env()
 
 
 class Cifar10Trainer(Trainer):
@@ -196,6 +185,7 @@ class Cifar10Trainer(Trainer):
 
 
 if __name__ == "__main__":
+    enable_compile_cache()  # before the first compile (utils/compile_cache.py)
     Trainer.distributed_setup()
     save_dir = os.environ.get("SAVE_DIR", "./runs/cifar10")
     trainer = Cifar10Trainer(
@@ -203,10 +193,7 @@ if __name__ == "__main__":
         base_lr=float(os.environ.get("BASE_LR", "0.1")),
         max_epoch=int(os.environ.get("EPOCHS", "100")),
         batch_size=int(os.environ.get("BATCH", "1024")),
-        # explicit CHAIN_STEPS wins; a kept TUNED.json chain_steps is the
-        # default under TUNED=1; otherwise the historical 1.
-        chain_steps=int(os.environ.get("CHAIN_STEPS")
-                        or TUNED.get("chain_steps") or 1),
+        chain_steps=int(os.environ.get("CHAIN_STEPS") or 1),
         # MESH (the CHAIN_STEPS/DTYPE convention): a mesh spec like
         # "fsdp4x2" or "dp2fsdp2tp2" trains sharded end to end
         # (docs/parallelism.md); unset = the historical pure-DP program.

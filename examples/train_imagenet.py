@@ -1,7 +1,7 @@
 """ImageNet-scale training entry — BASELINE.json configs 3-5.
 
 One entry for the three scale-out configs (the reference has a single config
-in ``main.py:9-22``; these extend its capability surface per BASELINE.md):
+in ``main.py:9-22``; these extend its capability surface per BASELINE.json):
 
 =============  ==============================  =========================================
 ``MODEL=``     BASELINE config                 recipe
@@ -47,7 +47,7 @@ from distributed_training_pytorch_tpu.ops import accuracy, cross_entropy_loss, w
 from distributed_training_pytorch_tpu.ops.dispatch import pallas_from_env
 from distributed_training_pytorch_tpu.parallel import mesh_from_env
 from distributed_training_pytorch_tpu.trainer import Trainer
-from distributed_training_pytorch_tpu.utils import Logger
+from distributed_training_pytorch_tpu.utils import Logger, enable_compile_cache
 from distributed_training_pytorch_tpu.utils.tpu import enable_fast_rng
 
 RECIPES = {
@@ -64,8 +64,8 @@ def _ship_uint8() -> bool:
     """SHIP_UINT8=1 (default): the host pipeline stays uint8 end-to-end and
     normalization runs on device (models.wrappers.InputNormalizer, fused by
     XLA into the first conv) — the host->device link carries 4x fewer bytes
-    than pre-normalized float32 and the host skips a float pass (measured
-    2.7x records-path E2E, BASELINE.md). Same math, same augmentation
+    than pre-normalized float32 and the host skips a float pass (effect not
+    measured on today's chip). Same math, same augmentation
     stream; SHIP_UINT8=0 restores host-side normalize.
 
     NOTE: the wrapper nests the model's params under an ``inner`` scope, so
@@ -240,6 +240,7 @@ class ImageNetTrainer(Trainer):
 
 
 if __name__ == "__main__":
+    enable_compile_cache()  # before the first compile (utils/compile_cache.py)
     enable_fast_rng()
     Trainer.distributed_setup()
     model_name = os.environ.get("MODEL", "resnet50").lower()

@@ -11,8 +11,7 @@ build a real one offline with ``examples/make_lm_corpus.py``), ``SEQ_LEN``
 (default 256), ``EPOCHS``, ``BATCH``, ``BASE_LR``, ``MOE_EVERY`` (0 = dense),
 ``SAVE_DIR``, ``SNAPSHOT``, ``PROFILE_DIR``, ``LM_SIZE`` (``tiny`` | ``small``
 = GPT-2-small shape), ``SAVE_PERIOD`` / ``LAST_SAVE_PERIOD`` (epochs between
-periodic / `last` saves — raise both when the checkpoint path is slow, e.g.
-a chip behind a relay where a GPT-small save costs minutes), ``DTYPE``
+periodic / `last` saves — raise both when the checkpoint path is slow), ``DTYPE``
 (fp32|bf16|fp16 mixed-precision policy — docs/mixed_precision.md),
 ``PALLAS`` (1|0 kernel-policy knob: forces the flash-attention path on/off;
 unset = the historical auto — ops/dispatch.py, docs/performance.md
@@ -37,7 +36,7 @@ from distributed_training_pytorch_tpu.ops import warmup_cosine_lr
 from distributed_training_pytorch_tpu.ops.dispatch import pallas_from_env
 from distributed_training_pytorch_tpu.parallel import mesh_from_env
 from distributed_training_pytorch_tpu.trainer import Trainer
-from distributed_training_pytorch_tpu.utils import Logger
+from distributed_training_pytorch_tpu.utils import Logger, enable_compile_cache
 from distributed_training_pytorch_tpu.utils.tpu import enable_fast_rng
 
 
@@ -167,6 +166,7 @@ class LMTrainer(Trainer):
 
 
 if __name__ == "__main__":
+    enable_compile_cache()  # before the first compile (utils/compile_cache.py)
     enable_fast_rng()
     Trainer.distributed_setup()
     save_dir = os.environ.get("SAVE_DIR", "./runs/lm")

@@ -1,11 +1,11 @@
 #!/usr/bin/env python
 """XLA-flag / schedule autotuner CLI (ISSUE 17) — sweep a declared candidate
-space on the bench workload and commit the winner as ``TUNED.json``.
+space on the bench workload and write the report as ``TUNED.json``.
 
-The flat r02->r05 bench streak showed the stack could *measure* but nothing
-*searched*: every knob with a measured win somewhere (latency-hiding
+The stack could *measure* but nothing *searched*: every knob (latency-hiding
 scheduler, scoped VMEM, chain length, Pallas hot paths) sat behind manual
-env flags. This CLI closes the loop:
+env flags. This CLI runs the search; none of its candidates has been
+measured on today's chip:
 
 * **Candidate space** — declared up front (``CANDIDATES`` below, or
   ``--candidates FILE.json``): XLA latency-hiding/async-collective flags
@@ -24,8 +24,12 @@ env flags. This CLI closes the loop:
   provenance differs from the baseline on an UNdeclared key is refused
   (PR 14 rule). A win inside the flat-streak noise band is reverted.
 * **Evidence** — ``--emit`` writes the full report (baseline, ranked
-  candidates with attribution, refusals, verdict) as TUNED.json; entries
-  opt in with ``TUNED=1`` (``train.autotune.tuned_defaults``).
+  candidates with attribution, refusals, verdict) as TUNED.json. Nothing
+  reads it back into a run: a winner becomes a default through a PR judged
+  on every benchmark cell, never through an exported ``XLA_FLAGS``.
+
+The sweep measures speed, so it refuses to run without a TPU; only
+``--self-test`` pins the CPU.
 
 ``--self-test`` (the scripts/verify.sh stage; CPU, ~seconds) runs a real
 tiny sweep with two teeth checks: a deliberately 3x de-tuned chain_steps=1
@@ -44,7 +48,6 @@ import os
 import sys
 import tempfile
 
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
 sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)), ".."))
 
 from distributed_training_pytorch_tpu.telemetry.provenance import provenance_fields
@@ -53,9 +56,8 @@ from distributed_training_pytorch_tpu.train import xla_flag_options
 from distributed_training_pytorch_tpu.train.autotune import Candidate
 
 # The declared bench-host candidate space (docs/performance.md "Autotuning").
-# Every knob here has a measured win SOMEWHERE in this repo's history
-# (BASELINE.md r3-r5, utils/tpu.py) — the sweep's job is to find which
-# combination wins on the CURRENT program, with evidence.
+# None of these is measured on today's chip — the sweep's job is to find
+# which combination wins on the CURRENT program, with evidence.
 CANDIDATES = [
     Candidate("latency-hiding",
               {"xla_flags": "--xla_tpu_enable_latency_hiding_scheduler=true"},
@@ -67,7 +69,7 @@ CANDIDATES = [
     Candidate("lhs+scoped-vmem",
               {"xla_flags": "--xla_tpu_enable_latency_hiding_scheduler=true"
                             " --xla_tpu_scoped_vmem_limit_kib=98304"},
-              "latency hiding + wider scoped VMEM (ConvNeXt-L's +6% value)"),
+              "latency hiding + wider scoped VMEM"),
     Candidate("chain-20", {"chain_steps": 20},
               "longer on-device window amortizes dispatch further"),
     Candidate("chain-40", {"chain_steps": 40}, ""),
@@ -224,18 +226,12 @@ def self_test(inject_slowdown: float) -> int:
     if len(report["ranked"]) < 3:
         failures.append(f"expected >= 3 ranked candidates, got {len(report['ranked'])}")
 
-    # TUNED.json round-trip: emit -> reload -> the entry-side opt-in returns
-    # the winner's knobs under TUNED=1 and NOTHING otherwise.
+    # TUNED.json round-trip: emit -> reload gives the report back.
     with tempfile.TemporaryDirectory(prefix="autotune_selftest_") as tmp:
         path = os.path.join(tmp, "TUNED.json")
         autotune_lib.emit_tuned(path, report)
-        knobs_on = autotune_lib.tuned_defaults(path, env={"TUNED": "1"})
-        knobs_off = autotune_lib.tuned_defaults(path, env={})
-        if report["kept"] and knobs_on != report["winner"]["knobs"]:
-            failures.append(f"tuned_defaults round-trip mismatch: {knobs_on}")
-        if knobs_off != {}:
-            failures.append("tuned_defaults must be empty with TUNED unset "
-                            f"(autotuner off = no behavior change), got {knobs_off}")
+        if autotune_lib.load_tuned(path) != json.loads(json.dumps(report)):
+            failures.append("TUNED.json emit/load round-trip mismatch")
 
     # The XLA_FLAGS bridge: parse + reject, both directions.
     opts = xla_flag_options("--xla_a=2 --xla_b")
@@ -260,6 +256,15 @@ def self_test(inject_slowdown: float) -> int:
 
 
 def run_sweep(args) -> int:
+    import jax
+
+    platform = jax.devices()[0].platform
+    if platform != "tpu":
+        print(f"autotune: the sweep ranks candidates by device speed and "
+              f"found platform {platform!r}, not a TPU — refusing "
+              "(--self-test is the CPU mode)", file=sys.stderr)
+        return 2
+
     import bench
 
     from distributed_training_pytorch_tpu.utils.tpu import enable_fast_rng
@@ -335,8 +340,7 @@ def run_sweep(args) -> int:
     _print_report(report)
     if args.emit:
         autotune_lib.emit_tuned(args.emit, report)
-        print(f"autotune: report written to {args.emit} — commit it with the "
-              "bench round it justifies (docs/performance.md)")
+        print(f"autotune: report written to {args.emit}")
     return 0
 
 
@@ -356,6 +360,9 @@ def main() -> int:
                         help="write the full report (TUNED.json) here")
     args = parser.parse_args()
     if args.self_test:
+        import jax
+
+        jax.config.update("jax_platforms", "cpu")  # explicit: a CPU harness
         return self_test(args.inject_slowdown)
     return run_sweep(args)
 

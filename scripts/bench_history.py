@@ -1,36 +1,23 @@
 #!/usr/bin/env python
-"""Bench-history ledger CLI (ISSUE 14) — read the committed rounds.
+"""Bench-history ledger CLI (ISSUE 14) — read a directory of bench rounds.
 
-Ingests the repo's committed ``BENCH_r*.json`` / ``MULTICHIP_r*.json``
-rounds into per-metric trajectories (``telemetry.history``) and prints the
-ledger with flat-streak and regression detections — the across-rounds
-instrument the per-run stack (goodput, StepProfile, doctor) never had:
-BENCH r02→r05 sat flat for four rounds and nothing noticed.
+Ingests ``BENCH_r*.json`` / ``MULTICHIP_r*.json`` round files under
+``--root`` into per-metric trajectories (``telemetry.history``) and prints
+the ledger with flat-streak and regression detections. The repo commits no
+round files of its own (the driver's ``PERF_LEDGER.jsonl`` is the record of
+measured performance); point ``--root`` at a directory that holds some.
 
 Usage::
 
-    python scripts/bench_history.py                 # ledger + detections
-    python scripts/bench_history.py --json          # machine-readable
-    python scripts/bench_history.py --events E      # + a `bench_history`
-                                                    #   JSONL record
-    python scripts/bench_history.py --self-test     # CI gate (verify.sh)
+    python scripts/bench_history.py --root DIR            # ledger + detections
+    python scripts/bench_history.py --root DIR --json     # machine-readable
+    python scripts/bench_history.py --root DIR --events E # + a `bench_history`
+                                                          #   JSONL record
 
-``--self-test`` asserts the detector's acceptance case on the committed
-files themselves, in BOTH directions (re-anchored for ISSUE 17):
+The detector's boundary cases are covered in ``tests/test_run_compare.py``
+on round files the tests write themselves.
 
-* the historical r02→r05 plateau (step_ms ~76 ms, value ~54k img/s/chip,
-  spread 1.4%) MUST still be reported as a >= 4-round flat streak on both
-  the ``step_ms`` and ``value`` series — ended streaks stay in the ledger;
-* that streak MUST have *ended*: BENCH_r06 (the first autotuned round,
-  ``TUNED.json``) sits outside the flat band, so no flat streak on the
-  headline series may extend to the newest committed round. A future
-  round sequence that re-flattens the line will fail this gate — by
-  design: the detector must never again sit quiet on a live plateau.
-
-The detector boundary cases stay covered in ``tests/test_run_compare.py``.
-
-Exit codes: 0 ok, 1 self-test failure (expected streak not detected, or a
-live flat streak at HEAD), 2 no round files found under ``--root``.
+Exit codes: 0 ok, 2 no round files found under ``--root``.
 """
 
 import argparse
@@ -44,57 +31,6 @@ sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)), ".."
 from distributed_training_pytorch_tpu.telemetry import history as history_lib  # noqa: E402
 
 REPO_ROOT = os.path.join(os.path.dirname(os.path.abspath(__file__)), "..")
-
-
-def self_test(report) -> int:
-    """The committed-rounds acceptance check: r02->r05 must read as a flat
-    streak that has ENDED — detected in the ledger, but not extending to
-    the newest committed round of the headline series (BENCH_r06, the
-    autotuned round, must sit outside the band)."""
-    failures = []
-    for field in ("step_ms", "value"):
-        hits = [
-            s for s in report.streaks
-            if s.series.endswith(f":: {field}")
-            and len(s.rounds) >= 4
-            and s.rounds[0] <= 2
-            and s.rounds[-1] >= 5
-        ]
-        if not hits:
-            failures.append(
-                f"{field}: no >=4-round flat streak covering r02->r05 "
-                f"(streaks: {[s.describe() for s in report.streaks]})"
-            )
-            continue
-        streak = hits[0]
-        last_round = max(r for r, _ in report.series[streak.series])
-        live = [
-            s for s in report.streaks
-            if s.series == streak.series and s.rounds[-1] >= last_round
-        ]
-        if last_round <= streak.rounds[-1]:
-            failures.append(
-                f"{field}: the plateau is the newest data — no round after "
-                f"r{streak.rounds[-1]:02d} on {streak.series} (the flat "
-                "streak was never ended)"
-            )
-        elif live:
-            failures.append(
-                f"{field}: a flat streak extends to the newest round "
-                f"r{last_round:02d} — the bench line is STILL flat at HEAD "
-                f"({live[0].describe()})"
-            )
-        else:
-            print(f"bench_history self-test [{field}]: {streak.describe()} — "
-                  f"detected, ended (r{last_round:02d} is outside the band)")
-    if failures:
-        print("BENCH HISTORY SELF-TEST FAILED:", file=sys.stderr)
-        for f in failures:
-            print(f"  - {f}", file=sys.stderr)
-        return 1
-    print("bench_history self-test OK: the r02->r05 plateau is detected on "
-          "both trajectories and ends before the newest committed round")
-    return 0
 
 
 def main() -> int:
@@ -115,9 +51,6 @@ def main() -> int:
                         help="print the full ledger as one JSON object")
     parser.add_argument("--events", default=None,
                         help="append a bench_history record to this JSONL event log")
-    parser.add_argument("--self-test", action="store_true",
-                        help="CI gate: the committed r02->r05 plateau must be "
-                             "detected (verify.sh)")
     args = parser.parse_args()
 
     report = history_lib.analyze_history(
@@ -147,8 +80,6 @@ def main() -> int:
             streaks=[s.to_dict() for s in report.streaks],
             regressions=[r.to_dict() for r in report.regressions],
         )
-    if args.self_test:
-        return self_test(report)
     return 0
 
 

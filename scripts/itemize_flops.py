@@ -1,4 +1,4 @@
-"""Itemize the analytic-vs-XLA FLOP gap on a bench step (r3 VERDICT item 4).
+"""Itemize the analytic-vs-XLA FLOP gap on a bench step.
 
 Compiles the exact ``bench.py`` executable and reconciles THREE counters:
 
@@ -14,13 +14,11 @@ Compiles the exact ``bench.py`` executable and reconciles THREE counters:
 and prints a per-instruction table with source-layer attribution
 (HLO ``op_name`` metadata), grouping by pass (fwd / dgrad / wgrad).
 
-r4 finding (VGG16/32x32, batch 4096): nominal 10.64 TF, executed 7.42 TF,
-cost_analysis 9.02 TF. The fwd/dgrad/wgrad conv FLOPs reconcile
-per-instruction; the whole nominal-vs-executed gap is the degenerate
-classifier — at 32x32 the 1x1 feature map is replicated to 7x7 by the
-adaptive pool, and XLA folds the replication out of the FC GEMMs (25088-wide
--> effective 512-wide). The r2/r3 "XLA undercounts conv backward" hypothesis
-is retired.
+Earlier finding (VGG16/32x32; a count from the compiled program, not a
+speed): the fwd/dgrad/wgrad conv FLOPs reconcile per-instruction, and the
+whole nominal-vs-executed gap is the degenerate classifier — at 32x32 the 1x1
+feature map is replicated to 7x7 by the adaptive pool, and XLA folds the
+replication out of the FC GEMMs (25088-wide -> effective 512-wide).
 
 Scope: the HLO recount is trustworthy for conv-stack models (vgg16,
 resnet50, convnext_l) where convolutions appear in canonical form. XLA:TPU

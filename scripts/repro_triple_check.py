@@ -1,6 +1,6 @@
 """Minimal repro of the upstream XLA SPMD-partitioner CHECK that blocks the
 GSPMD-constraint formulation of the data x expert x pipe composition
-(r4 VERDICT item 7; bisected on jax 0.9 / CPU).
+(bisected on jax 0.9 / CPU).
 
 An MoE stage whose expert parallelism is expressed as sharding CONSTRAINTS
 (parallel.moe.MoEMlp weight constraints) inside pipeline_apply's pipe-manual
@@ -79,7 +79,7 @@ def loss(stacked):
 
 
 print("compiling the GSPMD-constraint triple (crashes while the bug exists)...")
-with compat.set_mesh(mesh):
+with jax.sharding.set_mesh(mesh):
     l, _ = jax.jit(jax.value_and_grad(loss))(stacked)
 print(f"NO CRASH (loss {float(l):.3f}) — the upstream CHECK is fixed; the "
       "GSPMD formulation of data x expert x pipe can be re-evaluated.")

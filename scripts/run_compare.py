@@ -15,7 +15,7 @@ Inputs (both sides must be the same kind; ``--kind`` overrides detection)::
     python scripts/run_compare.py A.xplane.pb B.xplane.pb   # profile captures
     python scripts/run_compare.py tracedirA/ tracedirB/     #   (or trace dirs)
     python scripts/run_compare.py run_a/ run_b/             # Trainer run dirs
-    python scripts/run_compare.py BENCH_r02.json BENCH_r05.json  # bench entries
+    python scripts/run_compare.py before.json after.json    # bench entries
     python scripts/run_compare.py --kind hlo a.hlo b.hlo    # optimized-HLO texts
 
 * **profile vs profile** — ``profiling.diff.diff_profiles``: ranked

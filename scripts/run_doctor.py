@@ -81,7 +81,6 @@ def _self_test_trainer(tmp: str, **kw):
 
     load_delay_s = kw.pop("load_delay_s", 0.0)
     commit_delay_s = kw.pop("commit_delay_s", 0.0)
-    streaming = kw.pop("streaming", False)
 
     class DoctorNet(nn.Module):
         @nn.compact
@@ -118,26 +117,7 @@ def _self_test_trainer(tmp: str, **kw):
             return 0.1
 
         def build_dataloader(self, dataset, phase="train"):
-            if streaming and phase == "train":
-                # The streaming reader (ISSUE 19) honours the SAME
-                # load_delay_s seam, so the data-bound case and the perf
-                # gate's --inject-data-wait keep working unchanged.
-                from distributed_training_pytorch_tpu.data import (
-                    StreamingLoader,
-                    shard_array_source,
-                )
-
-                loader = StreamingLoader(
-                    shard_array_source(dataset, 4),
-                    self.batch_size,
-                    shuffle=True,
-                    seed=self.seed,
-                    num_workers=self.num_workers,
-                    prefetch_batches=self.prefetch_batches,
-                    drop_last=True,
-                )
-            else:
-                loader = super().build_dataloader(dataset, phase)
+            loader = super().build_dataloader(dataset, phase)
             if load_delay_s:
                 loader.load_delay_s = load_delay_s
             return loader

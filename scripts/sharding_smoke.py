@@ -8,8 +8,8 @@ make ``Trainer(mesh=MeshConfig(fsdp=..., tensor=...).build())`` trustworthy:
 1. **Mesh parity.** An ``fsdp=8`` engine run is BIT-EXACT with pure DP —
    per-step losses and final params identical (the batch stays 8-way
    sharded, so every cross-device reduction has the same participant set
-   and order; ``jax_threefry_partitionable`` was forced on in PR 1 for
-   exactly this). A ``data=2/fsdp=2/tensor=2`` mesh re-GROUPS those
+   and order; ``jax_threefry_partitionable``, jax 0.9's default, is what
+   makes this hold). A ``data=2/fsdp=2/tensor=2`` mesh re-GROUPS those
    reductions (4-way batch shards, TP contraction splits), which legally
    reorders float summation — its per-step losses must still match DP to
    float32-ULP tolerance, and its *initial* state must be bit-exact

@@ -99,7 +99,7 @@
 # goodput spans re-derive the meter's fractions within epsilon.
 #
 # Stage 13 is the live-monitor self-test (ISSUE 15; docs/observability.md
-# "Live monitoring"): run_monitor.py --self-test drives the streaming
+# "Live monitoring"): run_monitor.py --self-test drives the live
 # monitor against real background digits runs through the existing fault
 # seams — a clean run must read training/healthy live and match
 # run_doctor.py's post-hoc steady fractions to 1e-6 (byte-identical
@@ -114,12 +114,7 @@
 # must diff clean (no goodput bucket over the noise floor), and three
 # injected known-cause slowdowns (a synthetic 3x convolution, the loader
 # load_delay_s seam, the async committer commit_delay_s seam) must each be
-# attributed to the correct category/bucket with evidence refs — followed
-# by bench_history.py --self-test: the committed BENCH_r02->r05 plateau
-# (step_ms ~76 ms flat for four rounds) must be detected as a flat streak
-# on the committed files themselves — AND must have ended: BENCH_r06 (the
-# autotuned round, ISSUE 17) has to sit outside the flat band, so a future
-# re-flattened line fails this gate instead of sitting quiet.
+# attributed to the correct category/bucket with evidence refs.
 #
 # Stage 15 is the autotuner gate (ISSUE 17; docs/performance.md
 # "Autotuning"): autotune.py --self-test measures a deliberately 3x de-tuned
@@ -162,26 +157,12 @@
 # dead. A replica under SLO pressure must DECLINE (nothing drained), and a
 # handshake against an unreachable replica must revert cleanly and re-arm.
 #
-# Stage 19 is the streaming-data soak (ISSUE 19; docs/data.md): a real
-# digits run streaming DTPR1 record shards through the StreamingLoader's
-# decode pool, killed (SIGTERM + SIGKILL) at seeded offsets and resumed
-# from latest_valid — the consumed record-id sequence must be
-# byte-identical to an uninterrupted twin's (the loader's record_log audit
-# trail, compared with a stdlib JSONL parse) and final params bit-exact;
-# the resumed attempt's first consumed batch must equal the checkpoint's
-# data/ cursor (O(1) positioning, no replay). An elastic 8->4 leg asserts
-# the re-planned shard assignment changes per-host splits but NOT the
-# global sequence (params within the documented tolerance); a
-# decode-worker-crash leg must respawn and complete (never a hang); a
-# corrupt-record leg must skip-and-count under skip_corrupt; and the clean
-# streaming run must read 'healthy' from run_doctor (never data_bound).
-#
-# Stage 20 is the ROADMAP.md tier-1 command verbatim.
+# Stage 19 is the ROADMAP.md tier-1 command verbatim.
 set -o pipefail
 
 cd "$(dirname "$0")/.."
 
-echo "== stage 1/20: import health (pytest --collect-only) =="
+echo "== stage 1/19: import health (pytest --collect-only) =="
 if ! JAX_PLATFORMS=cpu python -m pytest tests/ -q -m 'not slow' --collect-only \
     -p no:cacheprovider > /tmp/_collect.log 2>&1; then
   echo "COLLECTION FAILED — import breakage (full log: /tmp/_collect.log):"
@@ -190,7 +171,7 @@ if ! JAX_PLATFORMS=cpu python -m pytest tests/ -q -m 'not slow' --collect-only \
 fi
 tail -1 /tmp/_collect.log
 
-echo "== stage 2/20: static audit (generic + jaxlint + HLO + comm) =="
+echo "== stage 2/19: static audit (generic + jaxlint + HLO + comm) =="
 if ! JAX_PLATFORMS=cpu python scripts/static_audit.py; then
   echo "STATIC AUDIT FAILED — fix the finding or waive it inline with a reason"
   echo "(# jaxlint: disable=<rule> -- <why>; catalog: docs/static_analysis.md;"
@@ -216,25 +197,25 @@ if JAX_PLATFORMS=cpu python scripts/static_audit.py --inject-violation comm --sk
 fi
 echo "static_audit self-tests OK: injected lint + donation + comm violations correctly failed"
 
-echo "== stage 3/20: chained-dispatch retrace guard =="
+echo "== stage 3/19: chained-dispatch retrace guard =="
 if ! JAX_PLATFORMS=cpu python scripts/retrace_guard.py; then
   echo "RETRACE GUARD FAILED — the chained executable recompiles per window"
   exit 4
 fi
 
-echo "== stage 4/20: mixed-precision smoke (bf16 digits) =="
+echo "== stage 4/19: mixed-precision smoke (bf16 digits) =="
 if ! JAX_PLATFORMS=cpu python scripts/precision_smoke.py; then
   echo "PRECISION SMOKE FAILED — bf16 training path regressed"
   exit 5
 fi
 
-echo "== stage 5/20: telemetry smoke (event log + goodput + stats) =="
+echo "== stage 5/19: telemetry smoke (event log + goodput + stats) =="
 if ! JAX_PLATFORMS=cpu python scripts/telemetry_smoke.py; then
   echo "TELEMETRY SMOKE FAILED — observability subsystem regressed"
   exit 6
 fi
 
-echo "== stage 6/20: memory-accounting gate (preflight parity + oversize self-test) =="
+echo "== stage 6/19: memory-accounting gate (preflight parity + oversize self-test) =="
 if ! JAX_PLATFORMS=cpu python scripts/memory_probe.py; then
   echo "MEMORY PROBE FAILED — preflight prediction drifted from compiled.memory_analysis()"
   exit 7
@@ -244,26 +225,26 @@ if ! JAX_PLATFORMS=cpu python scripts/memory_probe.py --inject-oversize; then
   exit 7
 fi
 
-echo "== stage 7/20: sharded-training smoke (FSDP/TP parity + resharding resume) =="
+echo "== stage 7/19: sharded-training smoke (FSDP/TP parity + resharding resume) =="
 if ! JAX_PLATFORMS=cpu python scripts/sharding_smoke.py; then
   echo "SHARDING SMOKE FAILED — FSDP/TP parity, sharded retrace guard, or the resharding restore path regressed"
   exit 8
 fi
 
-echo "== stage 8/20: chaos soak (kill/resume, async checkpointing) =="
+echo "== stage 8/19: chaos soak (kill/resume, async checkpointing) =="
 if ! JAX_PLATFORMS=cpu python scripts/chaos_soak.py --quick; then
   echo "CHAOS SOAK FAILED — recovery machinery regressed (reproduce: CHAOS_SEED)"
   exit 9
 fi
 
-echo "== stage 9/20: elastic chaos soak (kill on N devices, resume on M) =="
+echo "== stage 9/19: elastic chaos soak (kill on N devices, resume on M) =="
 if ! JAX_PLATFORMS=cpu python scripts/chaos_soak.py --elastic --quick; then
   echo "ELASTIC CHAOS SOAK FAILED — the N->M mesh re-plan / batch-equivalent"
   echo "restore regressed (reproduce: CHAOS_SEED; docs/fault_tolerance.md)"
   exit 11
 fi
 
-echo "== stage 10/20: perf-regression gate (clean + injected-slowdown self-test) =="
+echo "== stage 10/19: perf-regression gate (clean + injected-slowdown self-test) =="
 if ! JAX_PLATFORMS=cpu python scripts/perf_gate.py --quick; then
   echo "PERF GATE FAILED — step time regressed past tolerance vs PERF_BASELINE.json"
   echo "(legitimate perf change? re-record: scripts/perf_gate.py --quick --update)"
@@ -275,7 +256,7 @@ if JAX_PLATFORMS=cpu python scripts/perf_gate.py --quick --inject-slowdown 3; th
 fi
 echo "perf_gate self-test OK: injected 3x regression correctly failed"
 
-echo "== stage 11/20: data-wait gate (clean + injected-starvation self-test) =="
+echo "== stage 11/19: data-wait gate (clean + injected-starvation self-test) =="
 if ! JAX_PLATFORMS=cpu python scripts/perf_gate.py --data-wait; then
   echo "DATA-WAIT GATE FAILED — the input pipeline's steady-state data_wait"
   echo "fraction exceeds the PERF_BASELINE.json ceiling (ROADMAP item 5)"
@@ -289,7 +270,7 @@ if JAX_PLATFORMS=cpu python scripts/perf_gate.py --data-wait --inject-data-wait 
 fi
 echo "data-wait gate self-test OK: injected loader sleep correctly failed"
 
-echo "== stage 12/20: run-doctor self-test (injected-bottleneck diagnosis + timeline) =="
+echo "== stage 12/19: run-doctor self-test (injected-bottleneck diagnosis + timeline) =="
 if ! JAX_PLATFORMS=cpu python scripts/run_doctor.py --self-test; then
   echo "RUN DOCTOR SELF-TEST FAILED — an injected bottleneck was misdiagnosed,"
   echo "the clean twin was not healthy, or the exported timeline broke the"
@@ -297,7 +278,7 @@ if ! JAX_PLATFORMS=cpu python scripts/run_doctor.py --self-test; then
   exit 13
 fi
 
-echo "== stage 13/20: live-monitor self-test (heartbeat liveness + streaming doctor + alerts) =="
+echo "== stage 13/19: live-monitor self-test (heartbeat liveness + streaming doctor + alerts) =="
 if ! JAX_PLATFORMS=cpu python scripts/run_monitor.py --self-test; then
   echo "RUN MONITOR SELF-TEST FAILED — the liveness contract broke: a hang did"
   echo "not read stale_heartbeat, a SIGKILL did not read dead, the healthy twin"
@@ -306,22 +287,14 @@ if ! JAX_PLATFORMS=cpu python scripts/run_monitor.py --self-test; then
   exit 15
 fi
 
-echo "== stage 14/20: run-comparison gate (twin-diff + injected attribution + bench history) =="
+echo "== stage 14/19: run-comparison gate (twin-diff + injected attribution) =="
 if ! JAX_PLATFORMS=cpu python scripts/run_compare.py --self-test; then
   echo "RUN COMPARE SELF-TEST FAILED — identical twins did not diff clean, or"
   echo "an injected known-cause slowdown (3x conv / loader sleep / commit"
   echo "delay) was attributed to the wrong category/bucket (docs/profiling.md)"
   exit 14
 fi
-if ! JAX_PLATFORMS=cpu python scripts/bench_history.py --self-test; then
-  echo "BENCH HISTORY SELF-TEST FAILED — the committed r02->r05 flat streak"
-  echo "was not detected on the committed BENCH_r files, or a flat streak is"
-  echo "STILL live at the newest round (r06 must sit outside the band —"
-  echo "docs/profiling.md)"
-  exit 14
-fi
-
-echo "== stage 15/20: autotune gate (injected-win ranking + provenance refusal) + pallas parity =="
+echo "== stage 15/19: autotune gate (injected-win ranking + provenance refusal) + pallas parity =="
 if ! JAX_PLATFORMS=cpu python scripts/autotune.py --self-test; then
   echo "AUTOTUNE SELF-TEST FAILED — the injected known-win (3x de-tuned"
   echo "baseline) was not ranked first with per-category attribution, a"
@@ -338,7 +311,7 @@ if ! JAX_PLATFORMS=cpu python -m pytest tests/test_pallas.py tests/test_dispatch
 fi
 tail -1 /tmp/_pallas_parity.log
 
-echo "== stage 16/20: fleet-controller soak (closed-loop recovery + zero-budget refusal) =="
+echo "== stage 16/19: fleet-controller soak (closed-loop recovery + zero-budget refusal) =="
 if ! JAX_PLATFORMS=cpu python scripts/fleet_controller.py --soak --quick; then
   echo "FLEET SOAK FAILED — the closed-loop controller did not restore the"
   echo "diseased fleet to healthy (restart / restart_excluding / A/B tune),"
@@ -354,7 +327,7 @@ if JAX_PLATFORMS=cpu python scripts/fleet_controller.py --soak --quick --max-res
 fi
 echo "fleet soak self-test OK: zero-budget controller refused without acting"
 
-echo "== stage 17/20: serving soak (continuous-batching SLO + hot-swap + failover) =="
+echo "== stage 17/19: serving soak (continuous-batching SLO + hot-swap + failover) =="
 if ! JAX_PLATFORMS=cpu python scripts/serving_soak.py --quick; then
   echo "SERVING SOAK FAILED — the p99 SLO was breached, responses were not"
   echo "bit-identical across a checkpoint hot-swap, a SIGKILL'd replica was"
@@ -363,7 +336,7 @@ if ! JAX_PLATFORMS=cpu python scripts/serving_soak.py --quick; then
   exit 18
 fi
 
-echo "== stage 18/20: actuated-offer soak (drain + live re-plan + A/B keep) =="
+echo "== stage 18/19: actuated-offer soak (drain + live re-plan + A/B keep) =="
 if ! JAX_PLATFORMS=cpu python scripts/serving_soak.py --actuate --quick; then
   echo "ACTUATE SOAK FAILED — the actuated chip offer regressed: a request"
   echo "failed or hung across the drain window, response bytes changed across"
@@ -374,15 +347,7 @@ if ! JAX_PLATFORMS=cpu python scripts/serving_soak.py --actuate --quick; then
   exit 20
 fi
 
-echo "== stage 19/20: streaming-data soak (kill/resume determinism + elastic re-split) =="
-if ! JAX_PLATFORMS=cpu python scripts/data_soak.py --quick; then
-  echo "DATA SOAK FAILED — the streaming reader's deterministic-resume,"
-  echo "elastic re-split, worker-respawn, or corrupt-skip contract regressed"
-  echo "(reproduce: DATA_SOAK_SEED; docs/data.md)"
-  exit 19
-fi
-
-echo "== stage 20/20: tier-1 test suite =="
+echo "== stage 19/19: tier-1 test suite =="
 rm -f /tmp/_t1.log
 timeout -k 10 870 env JAX_PLATFORMS=cpu python -m pytest tests/ -q -m 'not slow' \
   --continue-on-collection-errors -p no:cacheprovider -p no:xdist -p no:randomly \

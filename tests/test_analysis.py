@@ -609,6 +609,7 @@ class TestSelfParity:
         assert report.ok, "\n".join(f.describe() for f in report.findings)
 
 
+@pytest.mark.slow  # subprocess runs of the CLI that verify.sh stage 2 already drives (PR 21)
 class TestStaticAuditCLI:
     def _run(self, *flags):
         return subprocess.run(

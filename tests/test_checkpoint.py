@@ -183,7 +183,7 @@ def test_params_only_restore_across_prng_impls(tmp_path, shared):
 
 
 # ---------------------------------------------------------------------------
-# Sharded-state checkpointing (r4 VERDICT item 4): FSDP+TP-sharded TrainState
+# Sharded-state checkpointing: FSDP+TP-sharded TrainState
 # round-trips, including onto a DIFFERENT mesh topology — the pod-scale resume
 # capability (ref trainer/trainer.py:96-101 once params are sharded).
 

@@ -105,7 +105,7 @@ def test_determinism_same_seed(devices):
 
 
 def test_state_sharding_rejects_foreign_state(devices):
-    """Regression (round-1 VERDICT): a reused engine applied the FIRST state's
+    """Regression: a reused engine applied the FIRST state's
     cached sharding tree to any later state; now a different tree structure
     raises instead of mis-sharding silently."""
     import pytest

@@ -107,6 +107,7 @@ def test_convnext_train_step_with_droppath(mesh):
     _smoke(ConvNeXtTiny(num_classes=10, drop_path_rate=0.2), mesh)
 
 
+@pytest.mark.slow  # moved out of tier-1 to keep it inside its cap (PR 21)
 def test_resnet_eval_deterministic(mesh):
     """Eval mode uses running stats — two eval calls agree, and differ from
     train-mode output."""

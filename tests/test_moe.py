@@ -6,7 +6,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from distributed_training_pytorch_tpu import compat
 from distributed_training_pytorch_tpu.parallel import mesh as mesh_lib
 from distributed_training_pytorch_tpu.parallel import moe as moe_lib
 from distributed_training_pytorch_tpu.parallel.moe import EXPERT_AXIS, MoEMlp
@@ -32,7 +31,7 @@ def dense_reference(variables, x, top_k):
     return out.reshape(x.shape)
 
 
-@pytest.mark.parametrize("top_k", [1, 2])
+@pytest.mark.parametrize("top_k", [pytest.param(1, marks=pytest.mark.slow), 2])  # [1] out of tier-1 (PR 21)
 def test_moe_matches_dense_reference(top_k):
     """With generous capacity nothing drops -> exact top-k mixture parity."""
     model = MoEMlp(num_experts=4, hidden_dim=16, top_k=top_k, capacity_factor=8.0)
@@ -44,6 +43,7 @@ def test_moe_matches_dense_reference(top_k):
     np.testing.assert_allclose(np.asarray(out), ref, atol=2e-4)
 
 
+@pytest.mark.slow  # moved out of tier-1 to keep it inside its cap (PR 21)
 def test_moe_capacity_drops_deterministically():
     """capacity 1 with many tokens: per expert only the first token (in order)
     is served per choice; output is finite and some tokens are zero."""
@@ -60,6 +60,7 @@ def test_moe_capacity_drops_deterministically():
     assert (np.abs(out).sum(-1) > 0).any(), "but serve at least one"
 
 
+@pytest.mark.slow  # moved out of tier-1 to keep it inside its cap (PR 21)
 def test_moe_aux_losses_sown():
     model = MoEMlp(num_experts=4, hidden_dim=8, top_k=2)
     x = jnp.ones((1, 8, 4))
@@ -72,6 +73,7 @@ def test_moe_aux_losses_sown():
     assert np.isfinite(float(zl))
 
 
+@pytest.mark.slow  # moved out of tier-1 to keep it inside its cap (PR 21)
 def test_moe_expert_sharded_under_jit(devices):
     """data x expert mesh: expert-stacked params and buffers shard over the
     expert axis; jitted output matches the single-device result."""
@@ -84,11 +86,12 @@ def test_moe_expert_sharded_under_jit(devices):
     variables = model.init(jax.random.key(0), x)
     expected = model.apply(variables, x)
 
-    with compat.set_mesh(mesh):
+    with jax.sharding.set_mesh(mesh):
         out = jax.jit(model.apply)(variables, x)
     np.testing.assert_allclose(np.asarray(out), np.asarray(expected), atol=2e-5)
 
 
+@pytest.mark.slow  # moved out of tier-1 to keep it inside its cap (PR 21)
 def test_moe_grouped_routing_matches_dense(devices):
     """num_groups > 1 (the at-scale layout): with generous per-group capacity
     nothing drops, so grouped routing still matches the dense mixture; and the
@@ -103,7 +106,7 @@ def test_moe_grouped_routing_matches_dense(devices):
     ref = dense_reference(variables, x, top_k=2)
     out = model.apply(variables, x)
     np.testing.assert_allclose(np.asarray(out), ref, atol=2e-4)
-    with compat.set_mesh(mesh):
+    with jax.sharding.set_mesh(mesh):
         out_sharded = jax.jit(model.apply)(variables, x)
     np.testing.assert_allclose(np.asarray(out_sharded), ref, atol=2e-4)
 
@@ -132,7 +135,7 @@ def test_engine_establishes_ambient_mesh(devices):
     class Probe(nn.Module):
         @nn.compact
         def __call__(self, x, *, train=False):
-            seen.append(compat.get_abstract_mesh().axis_names)
+            seen.append(jax.sharding.get_abstract_mesh().axis_names)
             return nn.Dense(3)(x.reshape(x.shape[0], -1))
 
     model = Probe()
@@ -184,6 +187,7 @@ def test_moe_sort_dispatch_matches_einsum(top_k, num_groups):
             np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=3e-4)
 
 
+@pytest.mark.slow  # moved out of tier-1 to keep it inside its cap (PR 21)
 def test_moe_sort_dispatch_sharded_under_jit(devices):
     mesh = mesh_lib.create_mesh(
         {mesh_lib.DATA_AXIS: 2, EXPERT_AXIS: 4}, devices=devices
@@ -196,7 +200,7 @@ def test_moe_sort_dispatch_sharded_under_jit(devices):
     x = jnp.asarray(rng.randn(4, 8, 8), jnp.float32)
     variables = model.init(jax.random.key(0), x)
     expected = dense_reference(variables, x, top_k=2)
-    with compat.set_mesh(mesh):
+    with jax.sharding.set_mesh(mesh):
         out = jax.jit(model.apply)(variables, x)
     np.testing.assert_allclose(np.asarray(out), expected, atol=2e-4)
 
@@ -221,6 +225,7 @@ def test_moe_decode_capacity_free_matches_dense():
     assert (np.abs(np.asarray(out).reshape(-1, 8)).sum(-1) > 0).all()
 
 
+@pytest.mark.slow  # moved out of tier-1 to keep it inside its cap (PR 21)
 @pytest.mark.parametrize(
     "tokens,expected_impl",
     [(16, "einsum"), (moe_lib.SORT_DISPATCH_MIN_GROUP, "sort")],
@@ -258,6 +263,7 @@ def test_moe_rejects_unknown_dispatch_impl():
         model.init(jax.random.key(0), jnp.ones((1, 4, 4)))
 
 
+@pytest.mark.slow  # soak-shaped: moved out of tier-1 to keep it inside its cap (PR 21)
 def test_manual_expert_mlp_matches_gspmd_path(devices):
     """manual_expert_mlp (nested-shard_map manual expert parallelism): both
     exchange formulations match the GSPMD-constraint MoEMlp forward AND
@@ -285,7 +291,7 @@ def test_manual_expert_mlp_matches_gspmd_path(devices):
                 num_groups=4, mesh=mesh, exchange=exchange,
             )
 
-        with compat.set_mesh(mesh):
+        with jax.sharding.set_mesh(mesh):
             got = jax.jit(fwd)(variables["params"], x)
             g_man = jax.jit(jax.grad(lambda p: jnp.sum(fwd(p, x) ** 2)))(
                 variables["params"]
@@ -295,17 +301,15 @@ def test_manual_expert_mlp_matches_gspmd_path(devices):
             np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-5)
 
 
-@pytest.mark.skipif(
-    not compat.HAS_PARTIAL_MANUAL,
-    reason="the enclosing region is itself partial-manual (pipe manual, expert auto)",
-)
+@pytest.mark.slow  # moved out of tier-1 to keep it inside its cap (PR 21)
 def test_manual_expert_mlp_rejects_nesting(devices):
     """Inside an enclosing manual region the GSPMD/nested paths are both
     unusable (Shardy rejections quoted in the docstring) — the error must
     point at the supported workaround, not die in the lowering."""
     from jax.sharding import PartitionSpec as P
 
-    from distributed_training_pytorch_tpu.compat import set_mesh, shard_map
+    from jax import shard_map
+    from jax.sharding import set_mesh
     from distributed_training_pytorch_tpu.parallel import mesh as mesh_lib
     from distributed_training_pytorch_tpu.parallel.moe import manual_expert_mlp
 
@@ -332,6 +336,7 @@ def test_manual_expert_mlp_rejects_nesting(devices):
             )(x)
 
 
+@pytest.mark.slow  # moved out of tier-1 to keep it inside its cap (PR 21)
 def test_manual_expert_mlp_degenerate_mesh(devices):
     """On a mesh without an expert axis the specs reference only present
     axes and the collectives compile out — exact parity with plain apply."""
@@ -344,7 +349,7 @@ def test_manual_expert_mlp_degenerate_mesh(devices):
     x = jnp.asarray(rng.randn(2, 4, 8), jnp.float32)
     v = moe.init(jax.random.key(0), x)
     mesh = mesh_lib.create_mesh({mesh_lib.DATA_AXIS: 2}, devices=devices[:2])
-    with compat.set_mesh(mesh):
+    with jax.sharding.set_mesh(mesh):
         got = jax.jit(
             lambda p, x: manual_expert_mlp(
                 p, x, num_experts=2, top_k=1, num_groups=2, mesh=mesh

@@ -151,7 +151,7 @@ def test_vit_pad_seq_to_exact_semantics():
 
 
 # --------------------------------------------------------------------------
-# Fused 1x1-conv + BN-apply + ReLU GEMM kernel (r4 VERDICT item 2)
+# Fused 1x1-conv + BN-apply + ReLU GEMM kernel
 
 
 def test_conv1x1_bn_act_matches_xla():
@@ -169,6 +169,38 @@ def test_conv1x1_bn_act_matches_xla():
     # relu=False epilogue
     got = conv1x1_bn_act(x, w, a, b, relu=False, interpret=True, block_rows=32)
     ref = ((x.reshape(-1, 24) @ w) * a + b).reshape(2, 7, 5, 16)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(ref), atol=1e-5)
+
+
+def test_conv1x1_bn_act_tiles_cout_when_the_weight_slab_would_not_fit(monkeypatch):
+    """Cout is tiled only where the double-buffered block buffers exceed
+    the VMEM budget (ConvNeXt-L's 1536 -> 6144 is refused whole on the chip);
+    the tiled grid computes the same thing, and callers whose buffers fit
+    keep Cout whole."""
+    from distributed_training_pytorch_tpu.ops import pallas as plmod
+
+    # The shapes chip_smoke.py compiles (bf16, block_rows 1024): only the two
+    # widest ConvNeXt-L expands are tiled.
+    tiles = {
+        (cin, cout): plmod._conv1x1_block_cols(1024, cin, cout, 2, 2, 2)
+        for cin, cout in ((64, 256), (256, 64), (192, 768), (384, 1536),
+                          (768, 3072), (1536, 6144))
+    }
+    assert tiles.pop((1536, 6144)) == 512
+    assert tiles.pop((768, 3072)) == 1024
+    assert all(bn == cout for (_, cout), bn in tiles.items())
+
+    rng = np.random.RandomState(3)
+    x = jnp.asarray(rng.randn(3, 5, 24), jnp.float32)
+    w = jnp.asarray(rng.randn(24, 384) * 0.2, jnp.float32)
+    a = jnp.asarray(rng.rand(384) + 0.5, jnp.float32)
+    b = jnp.asarray(rng.randn(384), jnp.float32)
+    # 2 * (x 1536 B + weight tile 24*bn*4 + out tile 16*bn*4): 131 kB whole,
+    # 44 kB at bn=128.
+    monkeypatch.setattr(plmod, "_CONV1X1_VMEM_BUDGET", 50_000)
+    assert plmod._conv1x1_block_cols(16, 24, 384, 4, 4, 4) == 128
+    got = plmod.conv1x1_bn_act(x, w, a, b, act="gelu", interpret=True, block_rows=16)
+    ref = jax.nn.gelu((x.reshape(-1, 24) @ w) * a + b, approximate=True).reshape(3, 5, 384)
     np.testing.assert_allclose(np.asarray(got), np.asarray(ref), atol=1e-5)
 
 

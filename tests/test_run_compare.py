@@ -449,18 +449,42 @@ class TestRoundIngestion:
             history_lib.load_round_file(path)
 
 
-def test_committed_rounds_flat_streak_self_parity():
-    """The acceptance case on the repo's own committed files: the r02->r05
-    plateau (spread 1.4%) must be detected on step_ms AND value."""
-    report = history_lib.analyze_history(REPO)
-    assert report.entries, "no committed BENCH_r files found"
+def _write_round(root, n: int, value: float, step_ms: float) -> str:
+    """One ``BENCH_rNN.json`` in the driver's round-file shape (``n``, the
+    stdout ``tail`` holding the bench's JSON line, and ``parsed``)."""
+    line = {"metric": "images/sec/chip (synthetic round)", "value": value,
+            "unit": "images/sec/chip", "step_ms": step_ms, "dtype": "bf16"}
+    path = os.path.join(str(root), f"BENCH_r{n:02d}.json")
+    with open(path, "w") as f:
+        json.dump({"n": n, "tail": "noise\n" + json.dumps(line),
+                   "parsed": line}, f)
+    return path
+
+
+@pytest.fixture
+def rounds_dir(tmp_path):
+    """Five synthetic rounds: r01 clearly lower, then a four-round plateau
+    r02->r05 inside the flat band (spread ~1.4%)."""
+    for n, value, step_ms in (
+        (1, 800.0, 12.5), (2, 1000.0, 10.0), (3, 998.0, 10.02),
+        (4, 1002.0, 9.98), (5, 988.0, 10.12),
+    ):
+        _write_round(tmp_path, n, value, step_ms)
+    return tmp_path
+
+
+def test_rounds_flat_streak_self_parity(rounds_dir):
+    """The acceptance case on round files: an r02->r05 plateau (spread
+    ~1.4%) must be detected on step_ms AND value."""
+    report = history_lib.analyze_history(str(rounds_dir))
+    assert report.entries, "no BENCH_r files found"
     for field in ("step_ms", "value"):
         hits = [s for s in report.streaks
                 if s.series.endswith(f":: {field}")
                 and s.rounds[0] <= 2 and s.rounds[-1] >= 5]
         assert hits, (field, [s.describe() for s in report.streaks])
         assert len(hits[0].rounds) >= 4
-    # r01 (45.8k img/s) must NOT be part of the value plateau
+    # r01 (20% lower) must NOT be part of the value plateau
     value_hit = [s for s in report.streaks if s.series.endswith(":: value")][0]
     assert 1 not in value_hit.rounds
 
@@ -499,7 +523,7 @@ class TestProvenance:
 
 
 # ---------------------------------------------------------------------------
-# The CLIs: one shared diff implementation + end-to-end on committed rounds
+# The CLIs: one shared diff implementation + end-to-end on round files
 # ---------------------------------------------------------------------------
 
 
@@ -534,13 +558,13 @@ def test_scripts_share_the_one_diff_implementation(script):
     )
 
 
-def test_run_compare_cli_on_committed_rounds():
-    """End to end on the repo's own committed bench record: r02 vs r05 must
-    produce a headline comparison (no provenance on the old rounds — a note,
-    not a refusal)."""
+@pytest.mark.slow  # moved out of tier-1 to keep it inside its cap (PR 21)
+def test_run_compare_cli_on_round_files(rounds_dir):
+    """End to end on two round files: r02 vs r05 must produce a headline
+    comparison (no provenance stamp on them — a note, not a refusal)."""
     proc = subprocess.run(
         [sys.executable, os.path.join(REPO, "scripts", "run_compare.py"),
-         os.path.join(REPO, "BENCH_r02.json"), os.path.join(REPO, "BENCH_r05.json")],
+         str(rounds_dir / "BENCH_r02.json"), str(rounds_dir / "BENCH_r05.json")],
         capture_output=True, text=True, timeout=180,
         env=dict(os.environ, JAX_PLATFORMS="cpu"),
     )
@@ -550,6 +574,7 @@ def test_run_compare_cli_on_committed_rounds():
     assert "value" in proc.stdout
 
 
+@pytest.mark.slow  # soak-shaped: moved out of tier-1 to keep it inside its cap (PR 21)
 def test_run_compare_provenance_refusal_and_force(tmp_path):
     """Two bench entries whose stamped configuration differs are refused
     (exit 2, keys named); --force compares them."""
@@ -572,15 +597,16 @@ def test_run_compare_provenance_refusal_and_force(tmp_path):
     assert "--force" in proc.stdout or "forced" in proc.stdout or "anyway" in proc.stdout
 
 
-def test_bench_history_events_record(tmp_path):
+@pytest.mark.slow  # moved out of tier-1 to keep it inside its cap (PR 21)
+def test_bench_history_events_record(rounds_dir):
     """--events appends a bench_history record (the vocabulary satellite —
     the doc-drift test in test_timeline covers the docs side)."""
     from distributed_training_pytorch_tpu.telemetry import read_events
 
-    events = str(tmp_path / "events.jsonl")
+    events = str(rounds_dir / "events.jsonl")
     proc = subprocess.run(
         [sys.executable, os.path.join(REPO, "scripts", "bench_history.py"),
-         "--events", events],
+         "--root", str(rounds_dir), "--events", events],
         capture_output=True, text=True, timeout=180,
         env=dict(os.environ, JAX_PLATFORMS="cpu"),
     )

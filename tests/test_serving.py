@@ -816,6 +816,7 @@ def test_fleet_controller_offers_freed_chip_to_serving_replica(tmp_path):
 # Import neutrality: serving pulls no jax at package import.
 
 
+@pytest.mark.slow  # moved out of tier-1 to keep it inside its cap (PR 21)
 def test_serving_package_import_is_neutral():
     """The acceptance neutrality pillar: a trainer that imports serving
     but never uses it cannot perturb training. The package import loads

@@ -8,8 +8,8 @@ kill/resume + full-trainer parity legs live in ``scripts/sharding_smoke.py``
 * **Mesh parity** — an ``fsdp=8`` engine run is BIT-EXACT with pure DP
   (losses and params; the batch stays 8-way sharded so every reduction has
   the same participant order), and a sharded INIT reproduces the
-  replicated init bit-for-bit (``jax_threefry_partitionable``, forced on
-  in PR 1 for exactly this).
+  replicated init bit-for-bit (``jax_threefry_partitionable``, jax 0.9's
+  default).
 * **Chained windows on sharded state** — bit-exact with sharded
   single-step execution, one compile per shape (the PR-2 invariants
   extended to SPMD).

@@ -437,6 +437,7 @@ def test_data_wait_gate_metric_selection_and_stale():
     assert res.metric == "step_per_calib"
 
 
+@pytest.mark.slow  # moved out of tier-1 to keep it inside its cap (PR 21)
 def test_perf_gate_refuses_conflicting_injection_flags():
     """Flag validation happens BEFORE any measurement (the PR 6 rule):
     --data-wait with --inject-slowdown must be an instant argparse error,

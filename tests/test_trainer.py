@@ -235,6 +235,7 @@ def test_preemption_saves_resumable_snapshot(tmp_path, mesh):
     assert resumed.cur_epoch == 0  # epoch 0 was interrupted -> retrain it
 
 
+@pytest.mark.slow  # moved out of tier-1 to keep it inside its cap (PR 21)
 def test_tensorboard_writer_emits_events(tmp_path, mesh):
     """tensorboard_dir writes BOTH train/ and val/ scalars (SURVEY §5.5)."""
     pytest.importorskip("tensorboardX")

@@ -29,6 +29,7 @@ import jax
 import numpy as np
 
 from distributed_training_pytorch_tpu.data import transforms
+from distributed_training_pytorch_tpu.profiling.trace import annotate
 
 
 class ShardedLoader:
@@ -202,20 +203,25 @@ class ShardedLoader:
 
             time.sleep(float(self.load_delay_s))  # injection seam (see ctor)
 
-    def _produce_batch(self, rows: np.ndarray, mask, epoch: int, fast: str | None) -> dict:
-        self._maybe_delay()
-        if fast == "source":
-            batch = dict(self.source.load_batch(rows, epoch))
-        elif fast == "arrays":
-            batch = {k: v[rows] for k, v in self.source.arrays.items()}
-            if "image" in batch:
-                batch["image"] = self.transform.batch_apply(batch["image"], rows, epoch)
-        else:
-            records = [self._load_one(i, epoch) for i in rows]
-            return self._collate(records, mask)
-        if mask is not None:
-            batch["mask"] = mask
-        return batch
+    def _produce_batch(
+        self, rows: np.ndarray, mask, epoch: int, fast: str | None, index: int
+    ) -> dict:
+        """Global batch ``index`` of ``epoch``, whole, on the calling thread
+        (a pool worker on the fast paths): one ``loader.batch`` span."""
+        with annotate("loader.batch", epoch=epoch, batch=index):
+            self._maybe_delay()
+            if fast == "source":
+                batch = dict(self.source.load_batch(rows, epoch))
+            elif fast == "arrays":
+                batch = {k: v[rows] for k, v in self.source.arrays.items()}
+                if "image" in batch:
+                    batch["image"] = self.transform.batch_apply(batch["image"], rows, epoch)
+            else:
+                records = [self._load_one(i, epoch) for i in rows]
+                return self._collate(records, mask)
+            if mask is not None:
+                batch["mask"] = mask
+            return batch
 
     def _collate(self, records: list[dict], mask: np.ndarray | None) -> dict:
         if self.collate_fn is not None:
@@ -276,7 +282,7 @@ class ShardedLoader:
         if self.num_workers <= 0:
             for b in range(start, num_batches):
                 rows, mask = batch_indices(b)
-                yield self._produce_batch(rows, mask, epoch, fast)
+                yield self._produce_batch(rows, mask, epoch, fast, b)
             return
 
         # Thread pool with a bounded in-flight window so decode/augment of
@@ -291,22 +297,26 @@ class ShardedLoader:
                 rows, mask = batch_indices(b)
                 if fast is not None:
                     window.put(
-                        (pool.submit(self._produce_batch, rows, mask, epoch, fast), None)
+                        (pool.submit(self._produce_batch, rows, mask, epoch, fast, b), None, b)
                     )
                 else:
                     futs = [pool.submit(self._load_one, i, epoch) for i in rows]
-                    window.put((futs, mask))
+                    window.put((futs, mask, b))
 
             upto = min(start + ahead, num_batches)
             for b in range(start, upto):
                 submit(b)
             for _ in range(num_batches - start):
-                item, mask = window.get()
+                item, mask, b = window.get()
                 if upto < num_batches:
                     submit(upto)
                     upto += 1
                 if fast is not None:
                     yield item.result()
                 else:
-                    self._maybe_delay()  # per-record path: delay at collate
-                    yield self._collate([f.result() for f in item], mask)
+                    # Per-record path: the workers decode, this thread waits
+                    # for them and collates (the delay seam sits here too).
+                    with annotate("loader.batch", epoch=epoch, batch=b):
+                        self._maybe_delay()
+                        batch = self._collate([f.result() for f in item], mask)
+                    yield batch

@@ -14,6 +14,13 @@ Two staging modes share the same producer/consumer machinery:
   array per window (``parallel.mesh.chain_batch_sharding`` layout), feeding
   the engine's chained train step. Still ``depth`` *windows* in flight, so
   on-device staging memory is bounded by ``depth x chain_steps`` batches.
+
+Spans and counters (profiling/trace.py): the producer stages each unit inside
+a ``prefetch.stage`` span (stacking a window and handing it to the device —
+where PJRT lays a batch out for the chip on the host). The span closes before
+the ``put``, so time blocked on a full ring (back-pressure) is in no span.
+The consumer counts ``prefetch.fetches`` and, when the ring held nothing as
+it asked, ``prefetch.fetches_empty``.
 """
 
 from __future__ import annotations
@@ -27,6 +34,7 @@ import jax
 import numpy as np
 
 from distributed_training_pytorch_tpu.parallel import mesh as mesh_lib
+from distributed_training_pytorch_tpu.profiling.trace import annotate, count
 
 
 def _prefetched(items: Iterable, depth: int) -> Iterator:
@@ -77,6 +85,9 @@ def _prefetched(items: Iterable, depth: int) -> Iterator:
     thread.start()
     try:
         while True:
+            count("prefetch.fetches")
+            if q.qsize() == 0:
+                count("prefetch.fetches_empty")
             item = q.get()
             if item is _SENTINEL:
                 if err:
@@ -98,15 +109,34 @@ def _prefetched(items: Iterable, depth: int) -> Iterator:
         drain()  # a put completed before the producer observed `cancelled`
 
 
+def _stage(put, host, mesh, ids: dict, steps: int):
+    """One unit onto the device inside its ``prefetch.stage`` span; ``ids``
+    (``epoch``, ``unit``, ``batch``: where this unit starts) then move on by
+    the unit's ``steps``."""
+    with annotate("prefetch.stage", steps=steps, **ids):
+        staged = put(host, mesh)
+    ids["unit"] += steps
+    ids["batch"] += steps
+    return staged
+
+
+def _stage_ids(ids: dict | None) -> dict:
+    return {"epoch": 0, "unit": 0, "batch": 0, **(ids or {})}
+
+
 def device_prefetch(
     batches: Iterable[dict],
     mesh: jax.sharding.Mesh,
     *,
     depth: int = 2,
+    ids: dict | None = None,
 ) -> Iterator[dict]:
-    """Yield global data-sharded ``jax.Array`` batches, ``depth`` in flight."""
+    """Yield global data-sharded ``jax.Array`` batches, ``depth`` in flight.
+    ``ids``: the ``epoch``, and the ``unit`` and ``batch`` of the first batch,
+    for the ``prefetch.stage`` spans (default: all 0)."""
+    ids = _stage_ids(ids)
     staged = (
-        mesh_lib.global_array_from_host_local(host_batch, mesh)
+        _stage(mesh_lib.global_array_from_host_local, host_batch, mesh, ids, 1)
         for host_batch in batches
     )
     return _prefetched(staged, depth)
@@ -119,6 +149,7 @@ def device_prefetch_chained(
     *,
     depth: int = 2,
     lead_singles: int = 0,
+    ids: dict | None = None,
 ) -> Iterator[tuple[int, dict]]:
     """Chain-major device staging: yield ``(n, batch)`` execution units.
 
@@ -127,34 +158,42 @@ def device_prefetch_chained(
     ``chain_batch_sharding``-laid-out transfer), ready for
     ``TrainEngine.train_steps_chained``. ``n == 1``: ``batch`` is a plain
     single-step global batch — emitted for the first ``lead_singles`` batches
-    (the trainer's window-boundary realignment after a mid-epoch resume, and
-    its profiled first-epoch prefix) and for the epoch tail shorter than a
+    (the trainer's window-boundary realignment after a mid-epoch resume) and
+    for the epoch tail shorter than a
     full window (compiling a fresh chain per tail length would cost a
     full-model retrace; the tail reuses the already-compiled single step).
 
     ``chain_steps == 1`` degenerates to :func:`device_prefetch` semantics
     (every unit a single), so one consumer loop serves both modes.
+    ``ids``: as :func:`device_prefetch`.
     """
     if chain_steps < 1:
         raise ValueError(f"chain_steps must be >= 1, got {chain_steps}")
+    ids = _stage_ids(ids)
+
+    def single(host_batch):
+        return 1, _stage(mesh_lib.global_array_from_host_local, host_batch, mesh, ids, 1)
+
+    def stack_and_put(window, mesh):
+        stacked = jax.tree.map(
+            lambda *xs: np.stack([np.asarray(x) for x in xs]), *window
+        )
+        return mesh_lib.global_chain_array_from_host_local(stacked, mesh)
 
     def staged():
         it = iter(batches)
         for host_batch in itertools.islice(it, max(0, int(lead_singles))):
-            yield 1, mesh_lib.global_array_from_host_local(host_batch, mesh)
+            yield single(host_batch)
         while True:
             window = list(itertools.islice(it, chain_steps))
             if not window:
                 return
             if len(window) < chain_steps or chain_steps == 1:
                 for host_batch in window:
-                    yield 1, mesh_lib.global_array_from_host_local(host_batch, mesh)
+                    yield single(host_batch)
                 if len(window) < chain_steps:
                     return
                 continue
-            stacked = jax.tree.map(
-                lambda *xs: np.stack([np.asarray(x) for x in xs]), *window
-            )
-            yield chain_steps, mesh_lib.global_chain_array_from_host_local(stacked, mesh)
+            yield chain_steps, _stage(stack_and_put, window, mesh, ids, chain_steps)
 
     return _prefetched(staged(), depth)

@@ -12,8 +12,11 @@ read with its own caveat comments. One implementation, one contract:
   ``None``/raise; every helper here **degrades to absent fields** rather
   than fabricating numbers (the events/bench consumers simply omit the
   keys — test-enforced);
-* ``peak_bytes`` is the allocator's **process-lifetime high-water mark**
-  with no reset: in a sweep, only the first run's peak describes that run —
+* ``peak_bytes`` is the larger of the allocator's ``peak_bytes_in_use`` and
+  in-use + reserved (:func:`peak_bytes`: this runtime keeps a program's
+  temporaries in ``bytes_reserved``, outside its own peak), a
+  **process-lifetime high-water mark** with no reset: in a sweep, only the
+  first run's peak describes that run —
   later (smaller) configs would silently report the earlier run's peak,
   which is why ``live_memory_fields(include_peak=False)`` exists and why
   the trainer's window records keep ``live_bytes`` as the per-window
@@ -30,6 +33,7 @@ __all__ = [
     "is_oom_error",
     "live_memory_fields",
     "memory_skew",
+    "peak_bytes",
     "window_memory_fields",
 ]
 
@@ -57,6 +61,23 @@ def device_capacity_bytes(device=None) -> int | None:
     return int(limit) if limit else None
 
 
+def peak_bytes(stats: dict) -> int | None:
+    """Peak device bytes from one ``memory_stats()`` dict, or None when it
+    carries no peak. On today's TPU runtime a running program's temporaries
+    sit in ``bytes_reserved`` and never reach ``peak_bytes_in_use`` (PERF.md
+    section 3), so where a reserved figure is reported the peak is the larger
+    of the allocator's own peak and in-use + reserved — the reckoning of
+    ``benchmarks/lib/harness.py:memory_peak_bytes``."""
+    if "peak_bytes_in_use" not in stats:
+        return None
+    in_use = stats.get("bytes_in_use", 0)
+    return int(max(
+        stats["peak_bytes_in_use"],
+        in_use + stats.get("peak_bytes_reserved", 0),
+        in_use + stats.get("bytes_reserved", 0),
+    ))
+
+
 def live_memory_fields(device=None, *, include_peak: bool = True) -> dict:
     """``{"live_bytes": ..., "peak_bytes": ...}`` from the allocator, or
     ``{}`` on statless backends. ``include_peak=False`` drops the
@@ -68,8 +89,9 @@ def live_memory_fields(device=None, *, include_peak: bool = True) -> dict:
     out = {}
     if "bytes_in_use" in stats:
         out["live_bytes"] = int(stats["bytes_in_use"])
-    if include_peak and "peak_bytes_in_use" in stats:
-        out["peak_bytes"] = int(stats["peak_bytes_in_use"])
+    peak = peak_bytes(stats) if include_peak else None
+    if peak is not None:
+        out["peak_bytes"] = peak
     return out
 
 
@@ -88,8 +110,9 @@ def window_memory_fields(devices=None, *, include_peak: bool = True) -> dict:
     if first:
         if "bytes_in_use" in first:
             out["live_bytes"] = int(first["bytes_in_use"])
-        if include_peak and "peak_bytes_in_use" in first:
-            out["peak_bytes"] = int(first["peak_bytes_in_use"])
+        peak = peak_bytes(first) if include_peak else None
+        if peak is not None:
+            out["peak_bytes"] = peak
     if len(per_device) >= 2 and all(
         s and "bytes_in_use" in s for s in per_device
     ):

@@ -60,6 +60,7 @@ def weighted_mean(values: jax.Array, weights: jax.Array | None = None) -> jax.Ar
     return jnp.where(total > 0, (values * weights).sum() / jnp.maximum(total, 1e-8), 0.0)
 
 
+@jax.named_scope("loss_head")
 def tied_cross_entropy(
     hidden: jax.Array,
     embedding: jax.Array,
@@ -83,6 +84,11 @@ def tied_cross_entropy(
     logsumexp, so peak memory is O(N * chunk_size); each chunk is wrapped in
     ``jax.checkpoint`` so the backward pass recomputes its logits instead of
     storing them.
+
+    Every op of the head carries the ``loss_head`` scope in its name (HLO
+    metadata only): the scan sits under ``jax.checkpoint``, so autodiff
+    carries the scope into the backward's and the recomputation's op names
+    too. The benchmark's ``loss_head_time_share`` matches it.
     """
     lead_shape = hidden.shape[:-1]
     d = hidden.shape[-1]

@@ -253,13 +253,18 @@ def _from_bhtd(x):
 def _fwd_call(qt, kt, vt, t_k, causal, bq, bk, interpret):
     """Forward pallas call on padded [B, H, T*, D] operands -> (o, lse) in the
     padded layout. Shared by flash_attention (square T) and the ring block
-    path (Tq from the resident shard, Tk from the visiting block)."""
+    path (Tq from the resident shard, Tk from the visiting block).
+
+    Each kernel of this file is called under a stable ``name=`` inside a
+    ``jax.named_scope`` of the same name (``flash_fwd``, ``flash_dq``,
+    ``flash_dkv``, ``conv1x1_bn_act``): the kernel's Python name reaches no
+    device trace on today's runtime, and a reader of one matches these."""
     b, h, tq_pad, d = qt.shape
     tk_pad = kt.shape[2]
     kernel = functools.partial(
         _fwd_kernel, scale=d**-0.5, block_k=bk, seq_len=t_k, causal=causal
     )
-    return pl.pallas_call(
+    call = pl.pallas_call(
         kernel,
         grid=(b, h, tq_pad // bq),
         in_specs=[
@@ -276,7 +281,10 @@ def _fwd_call(qt, kt, vt, t_k, causal, bq, bk, interpret):
             jax.ShapeDtypeStruct((b, h, 1, tq_pad), jnp.float32),
         ],
         interpret=interpret,
-    )(qt, kt, vt)
+        name="flash_fwd",
+    )
+    with jax.named_scope("flash_fwd"):
+        return call(qt, kt, vt)
 
 
 def _fwd_impl(q, k, v, causal, block_q, block_k, interpret, valid_len=None):
@@ -296,7 +304,7 @@ def _dq_call(qt, kt, vt, do, lse_p, delta, t_q, t_k, causal, bq, bk, interpret):
     dq_kernel = functools.partial(
         _bwd_dq_kernel, scale=d**-0.5, block_k=bk, seq_len=t_k, causal=causal
     )
-    return pl.pallas_call(
+    call = pl.pallas_call(
         dq_kernel,
         grid=(b, h, tq_pad // bq),
         in_specs=[
@@ -310,7 +318,10 @@ def _dq_call(qt, kt, vt, do, lse_p, delta, t_q, t_k, causal, bq, bk, interpret):
         out_specs=pl.BlockSpec((1, 1, bq, d), lambda bi, hi, qi: (bi, hi, qi, 0)),
         out_shape=jax.ShapeDtypeStruct((b, h, tq_pad, d), qt.dtype),
         interpret=interpret,
-    )(qt, kt, vt, do, lse_p, delta)
+        name="flash_dq",
+    )
+    with jax.named_scope("flash_dq"):
+        return call(qt, kt, vt, do, lse_p, delta)
 
 
 def _dkv_call(qt, kt, vt, do, lse_p, delta, t_q, t_k, causal, bq, bk, interpret):
@@ -322,7 +333,7 @@ def _dkv_call(qt, kt, vt, do, lse_p, delta, t_q, t_k, causal, bq, bk, interpret)
     dkv_kernel = functools.partial(
         _bwd_dkv_kernel, scale=d**-0.5, block_q=bq, seq_len=t_k, causal=causal
     )
-    return pl.pallas_call(
+    call = pl.pallas_call(
         dkv_kernel,
         grid=(b, h, tk_pad // bk),
         in_specs=[
@@ -342,7 +353,10 @@ def _dkv_call(qt, kt, vt, do, lse_p, delta, t_q, t_k, causal, bq, bk, interpret)
             jax.ShapeDtypeStruct((b, h, tk_pad, d), vt.dtype),
         ],
         interpret=interpret,
-    )(qt, kt, vt, do, lse_p, delta)
+        name="flash_dkv",
+    )
+    with jax.named_scope("flash_dkv"):
+        return call(qt, kt, vt, do, lse_p, delta)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
@@ -685,7 +699,7 @@ def conv1x1_bn_act(
     )
     # Cout tiles innermost: the x tile's block index does not change across
     # them, so it is fetched once per row block.
-    out = pl.pallas_call(
+    call = pl.pallas_call(
         functools.partial(_conv1x1_kernel, act=act),
         grid=(n_pad // block_rows, cout // bn),
         in_specs=[
@@ -697,7 +711,10 @@ def conv1x1_bn_act(
         out_specs=pl.BlockSpec((block_rows, bn), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((n_pad, cout), out_dtype),
         interpret=interpret,
-    )(x2, w, a2, b2)
+        name="conv1x1_bn_act",
+    )
+    with jax.named_scope("conv1x1_bn_act"):
+        out = call(x2, w, a2, b2)
     return out[:n].reshape(*lead, cout)
 
 

@@ -5,8 +5,10 @@ Telemetry (``telemetry/``, docs/observability.md) answers *how much* of a
 run's wall time was productive; this package answers *where the rest went* —
 and keeps it from regressing silently:
 
-* :mod:`~.trace`      — ``trace``/``annotate`` capture context managers +
-  headless ``top_ops`` summaries (no TensorBoard server needed);
+* :mod:`~.trace`      — the ``trace`` capture context manager, headless
+  ``top_ops`` summaries (no TensorBoard server needed), and the program's
+  one span-and-counter primitive: ``annotate`` / ``count``, kept in memory
+  while a recorder is installed (``recorded`` / ``counters``);
 * :mod:`~.xplane`     — minimal ``*.xplane.pb`` wire codec (offsets AND
   durations, so traces support interval analysis);
 * :mod:`~.categories` — the ONE HLO-op categorizer (shared by the report,
@@ -65,10 +67,17 @@ from distributed_training_pytorch_tpu.profiling.report import (  # noqa: F401
     flops_index,
 )
 from distributed_training_pytorch_tpu.profiling.trace import (  # noqa: F401
+    Span,
     annotate,
+    count,
+    counters,
+    install_recorder,
     latest_trace_file,
+    recorded,
+    session_start_ns,
     top_ops,
     trace,
+    uninstall_recorder,
 )
 
 __all__ = [
@@ -81,6 +90,7 @@ __all__ = [
     "ProfileConfig",
     "ProfileDiff",
     "REPORT_FIELDS",
+    "Span",
     "StepProfile",
     "StepTraceCapture",
     "analyze_trace",
@@ -88,13 +98,19 @@ __all__ = [
     "attribute_delta",
     "attribute_entry_delta",
     "categorize",
+    "count",
+    "counters",
     "describe_rows",
     "diff_profiles",
     "flops_index",
+    "install_recorder",
     "latest_trace_file",
     "load_baseline",
+    "recorded",
     "resolve_profile",
+    "session_start_ns",
     "top_ops",
     "trace",
+    "uninstall_recorder",
     "update_baseline",
 ]

@@ -11,9 +11,8 @@ boundaries (a unit = one single step or one chained window), so it is
   epoch-local step index is large, and a ``skip_steps`` longer than an epoch
   simply starts tracing in a later epoch instead of never firing;
 * **chained-window aware** — start/stop land on window boundaries, tracing
-  whole windows of the REAL chained program. The legacy ``profile_dir`` knob
-  forced the profiled prefix onto the single-step path; this capture traces
-  the exact execution the run would perform anyway, which is why a
+  whole windows of the REAL chained program: this capture traces the exact
+  execution the run would perform anyway, which is why a
   ``profile=``-on run keeps ``TrainEngine.trace_counts`` and final params
   bit-identical to a ``profile=None`` run (test-enforced);
 * **rank-0 owned** — only process 0 captures and writes, the logger/event-log

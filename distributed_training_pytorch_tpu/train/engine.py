@@ -388,8 +388,11 @@ class TrainEngine:
     def _train_step_impl(self, state: TrainState, batch):
         step_rng = jax.random.fold_in(state.rng, state.step)
         grads, loss, metrics, new_ms = self._grads_and_metrics(state, batch, step_rng)
-        updates, new_opt_state = self.optimizer.update(grads, state.opt_state, state.params)
-        new_params = optax.apply_updates(state.params, updates)
+        # HLO metadata only: splits a device trace of the step into forward +
+        # backward, the loss head (ops/losses.py) and the optimizer.
+        with jax.named_scope("optimizer"):
+            updates, new_opt_state = self.optimizer.update(grads, state.opt_state, state.params)
+            new_params = optax.apply_updates(state.params, updates)
         metrics = dict(metrics)
         if self.stats:
             from distributed_training_pytorch_tpu.telemetry.stats import (

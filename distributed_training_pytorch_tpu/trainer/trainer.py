@@ -44,6 +44,7 @@ Hook mapping (reference -> here):
 from __future__ import annotations
 
 import dataclasses
+import functools
 import os
 import signal
 import threading
@@ -82,6 +83,8 @@ from distributed_training_pytorch_tpu.precision import (
 )
 from distributed_training_pytorch_tpu.profiling import (
     StepTraceCapture,
+    annotate,
+    install_recorder,
     resolve_profile,
 )
 from distributed_training_pytorch_tpu.resilience import AsyncCheckpointSaver
@@ -102,6 +105,22 @@ from distributed_training_pytorch_tpu.train import (
 from distributed_training_pytorch_tpu.utils.tensorboard import MetricsWriter
 
 
+def _init_span(init):
+    """``Trainer.__init__`` as one ``trainer.init`` span. The span recorder
+    (profiling/trace.py) is installed first, exactly when telemetry is on: it
+    is process-wide, because the loader workers and the prefetch thread are
+    not handed the trainer."""
+
+    @functools.wraps(init)
+    def wrapped(self, *args, telemetry=None, **kwargs):
+        if resolve_telemetry(telemetry) is not None:
+            install_recorder()
+        with annotate("trainer.init"):
+            init(self, *args, telemetry=telemetry, **kwargs)
+
+    return wrapped
+
+
 class Trainer:
     """Subclass, implement the hooks, call :meth:`train`.
 
@@ -110,6 +129,7 @@ class Trainer:
     via the prefetcher — there is no pageable/pinned distinction to manage).
     """
 
+    @_init_span
     def __init__(
         self,
         max_epoch: int,
@@ -133,8 +153,6 @@ class Trainer:
         chain_steps: int = 1,
         last_save_period: int = 1,
         async_checkpoint: bool = True,
-        profile_dir: str | None = None,
-        profile_steps: int = 5,
         progress: bool = True,
         save_on_preemption: bool = True,
         preemption_check_every: int = 20,
@@ -176,32 +194,18 @@ class Trainer:
         # preemption saves still fire regardless.
         self.last_save_period = max(1, int(last_save_period))
         self.cur_epoch = 0
-        # Tracing knobs. `profile_dir`/`profile_steps` is the legacy surface
-        # (SURVEY.md §5; analog of the reference's NCCL flight-recorder
-        # buffer, run.sh:8): a raw jax.profiler trace of the first epoch's
-        # steady-state steps, forced onto the single-step path. `profile=`
-        # (a profiling.ProfileConfig, or a trace-dir string; ISSUE 6,
-        # docs/profiling.md) is the first-class capture: it traces a window
-        # of the REAL execution (chained windows included), analyzes it into
-        # a StepProfile (device-time attribution + dispatch-gap audit), and
+        # Tracing knob: `profile=` (a profiling.ProfileConfig, or a trace-dir
+        # string; ISSUE 6, docs/profiling.md) traces a window of the REAL
+        # execution (chained windows included), analyzes it into a
+        # StepProfile (device-time attribution + dispatch-gap audit), and
         # emits a `profile_capture` telemetry event — while keeping the run
         # bit-exact and trace-count-identical with profile=None
-        # (test-enforced). The two knobs are mutually exclusive: both would
-        # race one global jax.profiler session.
+        # (test-enforced).
         self.profile = resolve_profile(profile)
-        if self.profile is not None and profile_dir is not None:
-            raise ValueError(
-                "pass either profile= (ProfileConfig; analyzed capture) or "
-                "profile_dir= (legacy raw trace), not both — they would race "
-                "the one jax.profiler session"
-            )
         if self.profile is not None and self.profile.dir is None:
             self.profile = dataclasses.replace(
                 self.profile, dir=os.path.join(save_folder, "profile")
             )
-        self.profile_dir = profile_dir
-        self.profile_steps = profile_steps
-        self._profiled = False
         self.progress = progress
         # Preemption-aware checkpointing (SURVEY.md §5.3's named upgrade over
         # the reference's manual-restart-only recovery): SIGTERM — what cloud
@@ -294,9 +298,9 @@ class Trainer:
         # the bench's chained mode measures, now in real training. Per-step
         # metrics come back as scan outputs so loss logging and nonfinite
         # accounting stay exact; the epoch tail, the resume-realignment
-        # prefix, the profiled first-epoch prefix, and any window with
-        # pending fault injections automatically fall back to single-step
-        # execution (bit-exact either way — test-enforced).
+        # prefix, and any window with pending fault injections automatically
+        # fall back to single-step execution (bit-exact either way —
+        # test-enforced).
         self.chain_steps = int(chain_steps)
         self._validate_chain_config()
         # Mid-epoch resume position (set when restoring a preemption save's
@@ -533,8 +537,9 @@ class Trainer:
         self.criterion = self.build_criterion()
 
         # Datasets + loaders (``:56-71``).
-        self.train_dataset = self.build_train_dataset()
-        self.train_dataloader = self.build_dataloader(self.train_dataset, phase="train")
+        with annotate("trainer.build_loaders"):
+            self.train_dataset = self.build_train_dataset()
+            self.train_dataloader = self.build_dataloader(self.train_dataset, phase="train")
         # Streaming data plane (ISSUE 19; docs/data.md): duck-typed on the
         # reader-state surface so any build_dataloader override returning a
         # loader with ``reader_state`` gets checkpoint-carried reader state + the
@@ -548,8 +553,9 @@ class Trainer:
             self.train_dataloader.batch_extent = self.batch_replicas
         self.val_dataloader = None
         if have_validate:
-            self.val_dataset = self.build_val_dataset()
-            self.val_dataloader = self.build_dataloader(self.val_dataset, phase="val")
+            with annotate("trainer.build_loaders"):
+                self.val_dataset = self.build_val_dataset()
+                self.val_dataloader = self.build_dataloader(self.val_dataset, phase="val")
 
         schedule = self.build_scheduler()
         if schedule is None:
@@ -587,10 +593,11 @@ class Trainer:
         # materialize directly into their shards — a model too big for one
         # chip's HBM never exists replicated anywhere.
         example = self.build_example_input()
-        self.state = self.engine.init_state(
-            jax.random.key(seed),
-            lambda rng: self.model.init(rng, example),
-        )
+        with annotate("engine.init_state"):
+            self.state = self.engine.init_state(
+                jax.random.key(seed),
+                lambda rng: self.model.init(rng, example),
+            )
         self._log_sharded_layout()
 
         # Snapshot resume (``:44-45,96-101``). The peek above already
@@ -714,7 +721,12 @@ class Trainer:
     # ------------------------------------------------------------------
 
     def train(self) -> None:
-        """The epoch loop — structural twin of ``trainer/trainer.py:104-181``."""
+        """The epoch loop — structural twin of ``trainer/trainer.py:104-181``.
+        One call is one ``trainer.train`` root span."""
+        with annotate("trainer.train", epoch=self.cur_epoch):
+            self._train()
+
+    def _train(self) -> None:
         self._install_sigterm()
         self.metrics_writer.reopen()  # symmetric with the close() below
         self._start_epoch = self.cur_epoch  # warmup epoch for late-compile
@@ -864,94 +876,97 @@ class Trainer:
         for epoch in range(self.cur_epoch, self.max_epoch):
             self.cur_epoch = epoch
 
-            # Periodic validation + best-model tracking at the top of the
-            # epoch (``:114-135`` — validates *before* this epoch's training;
-            # best stores label `epoch`, deliberate parity with §2e).
-            if self.have_validate and self.save_period and epoch % self.save_period == 0:
-                metrics = self.validate()
-                if self._save_checkpoint(
-                    BEST, epoch, reason="best", metrics=metrics, best=True
-                ):
-                    best_banner = {"epoch": epoch, "metrics": dict(metrics)}
-                if best_banner is not None:
-                    self.log(100 * "=")
-                    msg = f"The BEST model is at EPOCH {best_banner['epoch']} and has "
-                    for k, v in best_banner["metrics"].items():
-                        msg += f" | {k.upper()} = {v} | "
-                    self.log(msg)
+            with annotate("trainer.epoch", epoch=epoch):
+                # Periodic validation + best-model tracking at the top of the
+                # epoch (``:114-135`` — validates *before* this epoch's training;
+                # best stores label `epoch`, deliberate parity with §2e).
+                if self.have_validate and self.save_period and epoch % self.save_period == 0:
+                    with annotate("trainer.validate", epoch=epoch):
+                        metrics = self.validate()
+                    if self._save_checkpoint(
+                        BEST, epoch, reason="best", metrics=metrics, best=True
+                    ):
+                        best_banner = {"epoch": epoch, "metrics": dict(metrics)}
+                    if best_banner is not None:
+                        self.log(100 * "=")
+                        msg = f"The BEST model is at EPOCH {best_banner['epoch']} and has "
+                        for k, v in best_banner["metrics"].items():
+                            msg += f" | {k.upper()} = {v} | "
+                        self.log(msg)
 
-            # Train one epoch (``:138-156``).
-            self.train_dataloader.set_epoch(epoch)
-            self.log(100 * "=")
-            self.log(
-                f"[process {jax.process_index()}] Epoch {epoch + 1}/{self.max_epoch}"
-            )
-            epoch_metrics = self.train_epoch(epoch)
-
-            # Preemption: save a resumable snapshot and stop. An interrupted
-            # epoch is labeled `epoch` (resume retrains it); a completed one
-            # `epoch + 1` — same labeling rule as the normal saves below.
-            # The decision is collective: a host whose signal arrived after
-            # the last in-epoch poll must not diverge from its peers here.
-            if self._collective_preempt_flag():
-                self._preempted = True
-                resume_epoch = epoch if self._epoch_interrupted else epoch + 1
-                # A mid-epoch interruption records its position so the resume
-                # skips the already-trained batches (bit-exact continuation);
-                # an epoch-boundary save restarts the next epoch at step 0.
-                loop_state = (
-                    {"step_in_epoch": self._interrupted_at_step}
-                    if self._epoch_interrupted
-                    else None
-                )
-                self.events.emit(
-                    "preemption",
-                    epoch=epoch,
-                    resume_epoch=resume_epoch,
-                    step_in_epoch=self._interrupted_at_step
-                    if self._epoch_interrupted
-                    else 0,
-                )
-                self._save_checkpoint(
-                    LAST, resume_epoch, loop_state=loop_state, wait=True,
-                    reason="preemption",
-                )
+                # Train one epoch (``:138-156``).
+                self.train_dataloader.set_epoch(epoch)
+                self.log(100 * "=")
                 self.log(
-                    f"SIGTERM received — saved resumable snapshot (epoch "
-                    f"{resume_epoch}"
-                    + (
-                        f", step {self._interrupted_at_step}"
-                        if self._epoch_interrupted
-                        else ""
-                    )
-                    + f") to {self.checkpoints.path(LAST)}; exiting",
-                    "warning",
+                    f"[process {jax.process_index()}] Epoch {epoch + 1}/{self.max_epoch}"
                 )
-                return
+                epoch_metrics = self.train_epoch(epoch)
 
-            # Next-LR report (``:159-160``) — optax schedules are per-step.
-            next_lr = float(self.schedule(self.state.step))
-            self.log(f"THE NEXT LEARNING RATE VALUE IS {next_lr}")
+                with annotate("trainer.epoch_end", epoch=epoch):
+                    # Preemption: save a resumable snapshot and stop. An interrupted
+                    # epoch is labeled `epoch` (resume retrains it); a completed one
+                    # `epoch + 1` — same labeling rule as the normal saves below.
+                    # The decision is collective: a host whose signal arrived after
+                    # the last in-epoch poll must not diverge from its peers here.
+                    if self._collective_preempt_flag():
+                        self._preempted = True
+                        resume_epoch = epoch if self._epoch_interrupted else epoch + 1
+                        # A mid-epoch interruption records its position so the resume
+                        # skips the already-trained batches (bit-exact continuation);
+                        # an epoch-boundary save restarts the next epoch at step 0.
+                        loop_state = (
+                            {"step_in_epoch": self._interrupted_at_step}
+                            if self._epoch_interrupted
+                            else None
+                        )
+                        self.events.emit(
+                            "preemption",
+                            epoch=epoch,
+                            resume_epoch=resume_epoch,
+                            step_in_epoch=self._interrupted_at_step
+                            if self._epoch_interrupted
+                            else 0,
+                        )
+                        self._save_checkpoint(
+                            LAST, resume_epoch, loop_state=loop_state, wait=True,
+                            reason="preemption",
+                        )
+                        self.log(
+                            f"SIGTERM received — saved resumable snapshot (epoch "
+                            f"{resume_epoch}"
+                            + (
+                                f", step {self._interrupted_at_step}"
+                                if self._epoch_interrupted
+                                else ""
+                            )
+                            + f") to {self.checkpoints.path(LAST)}; exiting",
+                            "warning",
+                        )
+                        return
 
-            # last / periodic checkpoint (``:163-172``): saved epoch is
-            # epoch+1 = the next epoch to train on resume (``:165-167``).
-            if self.have_validate:
-                if (epoch + 1) % self.last_save_period == 0 or epoch + 1 == self.max_epoch:
-                    self._save_checkpoint(LAST, epoch + 1)
-                    self.log(f"Saved model at epoch {epoch + 1}!")
-            elif self.save_period and epoch % self.save_period == 0:
-                self._save_checkpoint(epoch_checkpoint_name(epoch + 1), epoch + 1)
-                self.log(f"Saved model at epoch {epoch + 1}!")
+                    # Next-LR report (``:159-160``) — optax schedules are per-step.
+                    next_lr = float(self.schedule(self.state.step))
+                    self.log(f"THE NEXT LEARNING RATE VALUE IS {next_lr}")
 
-            # Epoch loss report — *global* means (pmean'd inside the step),
-            # upgrading the reference's local-only report (``:175-178``).
-            msg = "TOTAL GLOBAL TRAINING LOSS: "
-            for k, v in epoch_metrics.items():
-                msg += f" | {k} = {v} | "
-            self.log(msg)
-            self.metrics_writer.write(int(self.state.step), epoch_metrics, prefix="train")
-            self._write_precision_scalars()
-            self._write_telemetry_scalars()
+                    # last / periodic checkpoint (``:163-172``): saved epoch is
+                    # epoch+1 = the next epoch to train on resume (``:165-167``).
+                    if self.have_validate:
+                        if (epoch + 1) % self.last_save_period == 0 or epoch + 1 == self.max_epoch:
+                            self._save_checkpoint(LAST, epoch + 1)
+                            self.log(f"Saved model at epoch {epoch + 1}!")
+                    elif self.save_period and epoch % self.save_period == 0:
+                        self._save_checkpoint(epoch_checkpoint_name(epoch + 1), epoch + 1)
+                        self.log(f"Saved model at epoch {epoch + 1}!")
+
+                    # Epoch loss report — *global* means (pmean'd inside the step),
+                    # upgrading the reference's local-only report (``:175-178``).
+                    msg = "TOTAL GLOBAL TRAINING LOSS: "
+                    for k, v in epoch_metrics.items():
+                        msg += f" | {k} = {v} | "
+                    self.log(msg)
+                    self.metrics_writer.write(int(self.state.step), epoch_metrics, prefix="train")
+                    self._write_precision_scalars()
+                    self._write_telemetry_scalars()
 
         # Barrier: every queued background commit fully on disk (and any
         # commit error surfaced) before the run declares itself finished.
@@ -962,7 +977,8 @@ class Trainer:
         # verdict looks, not vanish into epoch glue.
         if self.goodput is not None:
             self.goodput.tick("other")
-        self.saver.flush()
+        with annotate("trainer.checkpoint", reason="flush"):
+            self.saver.flush()
         if self.goodput is not None:
             self.goodput.tick("checkpoint")
         self.log("Finished!")
@@ -1201,53 +1217,54 @@ class Trainer:
         returns whether a checkpoint was written."""
         if self.goodput is not None:
             self.goodput.tick("other")  # close the epoch-glue interval
-        mode = "async" if (self._async_saves and not wait) else "sync"
-        telemetry_meta = self._telemetry_meta()
-        # Streaming reader state rides EVERY save (sync/async/emergency/best
-        # — this is the one save site): epoch is the resume epoch the caller
-        # passed, cursor the global records already consumed in it (0 for an
-        # end-of-epoch save; step_in_epoch * G for a preemption save).
-        data_state = None
-        if self._streaming_train:
-            data_state = self.train_dataloader.reader_state(
-                epoch=epoch,
-                batches_consumed=int((loop_state or {}).get("step_in_epoch", 0)),
-            )
-        snapshot_s = None
-        save_s = None  # full synchronous-save stall (the sync-mode twin of
-        #                snapshot_s) — the timeline's `save:` span duration
-        if best:
-            if mode == "async":
-                saved, snapshot_s = self.saver.maybe_save_best(
-                    metrics, self.state, epoch, telemetry=telemetry_meta,
-                    data_state=data_state,
+        with annotate("trainer.checkpoint", epoch=epoch, reason=reason):
+            mode = "async" if (self._async_saves and not wait) else "sync"
+            telemetry_meta = self._telemetry_meta()
+            # Streaming reader state rides EVERY save (sync/async/emergency/best
+            # — this is the one save site): epoch is the resume epoch the caller
+            # passed, cursor the global records already consumed in it (0 for an
+            # end-of-epoch save; step_in_epoch * G for a preemption save).
+            data_state = None
+            if self._streaming_train:
+                data_state = self.train_dataloader.reader_state(
+                    epoch=epoch,
+                    batches_consumed=int((loop_state or {}).get("step_in_epoch", 0)),
                 )
+            snapshot_s = None
+            save_s = None  # full synchronous-save stall (the sync-mode twin of
+            #                snapshot_s) — the timeline's `save:` span duration
+            if best:
+                if mode == "async":
+                    saved, snapshot_s = self.saver.maybe_save_best(
+                        metrics, self.state, epoch, telemetry=telemetry_meta,
+                        data_state=data_state,
+                    )
+                else:
+                    t_save = time.perf_counter()
+                    saved = self.checkpoints.maybe_save_best(
+                        metrics, self.state, epoch, telemetry=telemetry_meta,
+                        data_state=data_state,
+                    )
+                    save_s = time.perf_counter() - t_save
             else:
-                t_save = time.perf_counter()
-                saved = self.checkpoints.maybe_save_best(
-                    metrics, self.state, epoch, telemetry=telemetry_meta,
-                    data_state=data_state,
-                )
-                save_s = time.perf_counter() - t_save
-        else:
-            if mode == "async":
-                snapshot_s = self.saver.save_async(
-                    name, self.state, epoch, metrics=metrics,
-                    loop_state=loop_state, telemetry=telemetry_meta,
-                    data_state=data_state,
-                )
-            else:
-                save_s = self.saver.save_sync(
-                    name, self.state, epoch, metrics=metrics,
-                    loop_state=loop_state, telemetry=telemetry_meta,
-                    data_state=data_state,
-                )
-            saved = True
-        if wait:
-            # The emergency save above is already durable; a PRIOR background
-            # commit's failure (re-stashed by save_sync) must be reported,
-            # not abort the grace-window exit this save exists to protect.
-            self._flush_saver_logged()
+                if mode == "async":
+                    snapshot_s = self.saver.save_async(
+                        name, self.state, epoch, metrics=metrics,
+                        loop_state=loop_state, telemetry=telemetry_meta,
+                        data_state=data_state,
+                    )
+                else:
+                    save_s = self.saver.save_sync(
+                        name, self.state, epoch, metrics=metrics,
+                        loop_state=loop_state, telemetry=telemetry_meta,
+                        data_state=data_state,
+                    )
+                saved = True
+            if wait:
+                # The emergency save above is already durable; a PRIOR background
+                # commit's failure (re-stashed by save_sync) must be reported,
+                # not abort the grace-window exit this save exists to protect.
+                self._flush_saver_logged()
         if self.goodput is not None:
             self.goodput.tick("checkpoint" if saved else "other")
         if saved:
@@ -1612,18 +1629,8 @@ class Trainer:
         """Single steps to run before the first chained window of an epoch:
         realigns a mid-epoch resume offset to a window boundary (windows sit
         at absolute step_in_epoch multiples of chain_steps, so chained and
-        resumed runs execute identical window shapes), and keeps the profiled
-        prefix of the first epoch on the per-step path (the profiler brackets
-        individual steps; its stop check fires at step 1 + profile_steps)."""
-        first_window_step = skip_steps
-        # skip_steps <= 1: _maybe_profile only ever STARTS a trace at
-        # step_in_epoch == 1, so a deeper mid-epoch resume cannot profile
-        # this epoch — extending its single-step prefix would waste
-        # dispatches without a trace to show for it.
-        if self.profile_dir is not None and not self._profiled and skip_steps <= 1:
-            first_window_step = max(first_window_step, 2 + self.profile_steps)
-        aligned = -(-first_window_step // self.chain_steps) * self.chain_steps
-        return aligned - skip_steps
+        resumed runs execute identical window shapes)."""
+        return -skip_steps % self.chain_steps
 
     def _fault_active_in_window(self, epoch: int, start: int, stop: int) -> bool:
         return self.fault_plan is not None and self.fault_plan.active_in_window(
@@ -1672,81 +1679,92 @@ class Trainer:
         with an uninterrupted one. Under chaining the first (-k mod
         chain_steps) resumed steps run single-step so window boundaries
         realign to the uninterrupted run's."""
-        # Metric records: (k, tree) where k == 1 holds one step's scalar
-        # metrics and k > 1 a whole window's stacked scan outputs. Kept
-        # UNsliced on purpose: per-step slicing here would issue k x num_keys
-        # tiny device ops right after the one chained dispatch — paying back
-        # the very dispatch overhead chaining removes. Slicing happens where
-        # a host sync exists anyway (log points, epoch end).
-        collected: list[tuple[int, Any]] = []
-        skip_steps = self._resume_step_in_epoch
-        self._resume_step_in_epoch = 0  # consumed by the first trained epoch
-        step_in_epoch = skip_steps
-        executed = 0
-        synced_entries = 0  # index into `collected` of the last nan-policy sync
-        synced_steps = 0  # the same sync position, in steps
-        t0 = time.perf_counter()
-        # Telemetry (no-ops when off): goodput attributes the epoch's wall
-        # time to buckets at the loop's existing boundaries — no added device
-        # syncs anywhere in this method; tele_sync anchors per-window step
-        # timing at the log_every host syncs.
-        tm = self.goodput
-        if tm is not None:
-            tm.tick("other")  # close the epoch preamble (validation/log glue)
-        # The first fetch after a mid-epoch resume replays the loader past
-        # the already-trained batches — restart-rollback cost, not data_wait.
-        rollback_fetch = skip_steps > 0
-        tele_sync = [t0, 0]  # (perf_counter, executed) at the last sync point
-        trace_base = [0]  # trace_counts total before the in-flight unit
-        # Trace totals at the last sync point / epoch start: a window (or
-        # epoch) that paid XLA compile has a known-skewed wall, so its
-        # step_time is withheld from the anomaly detector's EWMA — the
-        # compile-polluted first windows would otherwise seed the baseline
-        # minutes high and mask real regressions for the rest of the run
-        # (warmup alone only delays firing; it does not keep the poison
-        # out of the baseline).
-        sync_trace = [sum(self.engine.trace_counts.values())]
-        epoch_trace_start = sync_trace[0]
+        # `unit` ids (profiling/trace.py spans): the global step of a unit's
+        # first step, reckoned on the host as epoch x steps an epoch + step in
+        # the epoch (reading state.step would be a device sync).
         num_batches = len(self.train_dataloader)
-        chain = self.chain_steps
-        # Resume skip happens at the loader's INDEX level when it can
-        # (iter_batches: none of the skipped batches are read or decoded);
-        # generic iterables fall back to drain-and-discard.
-        if skip_steps and hasattr(self.train_dataloader, "iter_batches"):
-            source_iter = self.train_dataloader.iter_batches(skip_steps)
-        elif skip_steps:
-            import itertools
+        first_unit = epoch * num_batches
+        with annotate("trainer.epoch_start", epoch=epoch):
+            # Metric records: (k, tree) where k == 1 holds one step's scalar
+            # metrics and k > 1 a whole window's stacked scan outputs. Kept
+            # UNsliced on purpose: per-step slicing here would issue k x num_keys
+            # tiny device ops right after the one chained dispatch — paying back
+            # the very dispatch overhead chaining removes. Slicing happens where
+            # a host sync exists anyway (log points, epoch end).
+            collected: list[tuple[int, Any]] = []
+            skip_steps = self._resume_step_in_epoch
+            self._resume_step_in_epoch = 0  # consumed by the first trained epoch
+            step_in_epoch = skip_steps
+            executed = 0
+            synced_entries = 0  # index into `collected` of the last nan-policy sync
+            synced_steps = 0  # the same sync position, in steps
+            t0 = time.perf_counter()
+            # Telemetry (no-ops when off): goodput attributes the epoch's wall
+            # time to buckets at the loop's existing boundaries — no added device
+            # syncs anywhere in this method; tele_sync anchors per-window step
+            # timing at the log_every host syncs.
+            tm = self.goodput
+            if tm is not None:
+                tm.tick("other")  # close the epoch preamble (validation/log glue)
+            # The first fetch after a mid-epoch resume replays the loader past
+            # the already-trained batches — restart-rollback cost, not data_wait.
+            rollback_fetch = skip_steps > 0
+            tele_sync = [t0, 0]  # (perf_counter, executed) at the last sync point
+            trace_base = [0]  # trace_counts total before the in-flight unit
+            # Trace totals at the last sync point / epoch start: a window (or
+            # epoch) that paid XLA compile has a known-skewed wall, so its
+            # step_time is withheld from the anomaly detector's EWMA — the
+            # compile-polluted first windows would otherwise seed the baseline
+            # minutes high and mask real regressions for the rest of the run
+            # (warmup alone only delays firing; it does not keep the poison
+            # out of the baseline).
+            sync_trace = [sum(self.engine.trace_counts.values())]
+            epoch_trace_start = sync_trace[0]
+            chain = self.chain_steps
+            # Resume skip happens at the loader's INDEX level when it can
+            # (iter_batches: none of the skipped batches are read or decoded);
+            # generic iterables fall back to drain-and-discard.
+            if skip_steps and hasattr(self.train_dataloader, "iter_batches"):
+                source_iter = self.train_dataloader.iter_batches(skip_steps)
+            elif skip_steps:
+                import itertools
 
-            source_iter = itertools.islice(iter(self.train_dataloader), skip_steps, None)
-        else:
-            source_iter = iter(self.train_dataloader)
-        host_batches = (
-            self._check_image_range(self.preprocess_batch(b)) for b in source_iter
-        )
-        # Execution units (n, batch): n == chain -> a chain-stacked window,
-        # n == 1 -> a plain single-step batch (lead realignment + epoch tail).
-        if chain > 1:
-            units = device_prefetch_chained(
-                host_batches,
-                self.mesh,
-                chain,
-                lead_singles=self._chain_lead_singles(skip_steps),
+                source_iter = itertools.islice(iter(self.train_dataloader), skip_steps, None)
+            else:
+                source_iter = iter(self.train_dataloader)
+            host_batches = (
+                self._check_image_range(self.preprocess_batch(b)) for b in source_iter
             )
-        else:
-            units = ((1, b) for b in device_prefetch(host_batches, self.mesh))
-        bar = self._progress_bar(num_batches, f"epoch {epoch + 1}")
-        self._epoch_interrupted = False
-        # Profiling capture (ProfileConfig): a no-op object reference when
-        # off; when on, start/stop transitions fire at unit boundaries so
-        # chained windows are traced whole — execution itself is untouched
-        # (trace_counts + params bit-identical with capture off).
-        cap = self._profile_capture
-        watchdog = None
-        # The watchdog pats once per executed unit; under chaining a window
-        # legitimately takes ~chain step-times, so the timeout scales with it
-        # (single-step fallback units then just run with extra slack).
-        watchdog_timeout = self.step_timeout * chain if self.step_timeout else None
-        self._watchdog_timeout = watchdog_timeout
+            # Execution units (n, batch): n == chain -> a chain-stacked window,
+            # n == 1 -> a plain single-step batch (lead realignment + epoch tail).
+            # stage_ids: where the producer's `prefetch.stage` spans start counting.
+            stage_ids = {"epoch": epoch, "unit": first_unit + skip_steps, "batch": skip_steps}
+            if chain > 1:
+                units = device_prefetch_chained(
+                    host_batches,
+                    self.mesh,
+                    chain,
+                    lead_singles=self._chain_lead_singles(skip_steps),
+                    ids=stage_ids,
+                )
+            else:
+                units = (
+                    (1, b)
+                    for b in device_prefetch(host_batches, self.mesh, ids=stage_ids)
+                )
+            bar = self._progress_bar(num_batches, f"epoch {epoch + 1}")
+            self._epoch_interrupted = False
+            # Profiling capture (ProfileConfig): a no-op object reference when
+            # off; when on, start/stop transitions fire at unit boundaries so
+            # chained windows are traced whole — execution itself is untouched
+            # (trace_counts + params bit-identical with capture off).
+            cap = self._profile_capture
+            watchdog = None
+            # The watchdog pats once per executed unit; under chaining a window
+            # legitimately takes ~chain step-times, so the timeout scales with it
+            # (single-step fallback units then just run with extra slack).
+            watchdog_timeout = self.step_timeout * chain if self.step_timeout else None
+            self._watchdog_timeout = watchdog_timeout
 
         def sync_log_point():
             # Intra-epoch host syncs: this (every log_every steps — always a
@@ -1938,15 +1956,35 @@ class Trainer:
                     executables=traced,
                 )
 
+        def dispatch(step_fn, *args, steps):
+            # One call into the engine. `traced`: the call raised trace_counts,
+            # i.e. it traced and compiled (or loaded from the compile cache)
+            # a program — jit does that synchronously inside the call.
+            with annotate(
+                "engine.dispatch", epoch=epoch, unit=first_unit + step_in_epoch, steps=steps
+            ) as span:
+                if self.telemetry is None:
+                    return step_fn(*args)
+                before = sum(self.engine.trace_counts.values())
+                out = step_fn(*args)
+                span.set(traced=sum(self.engine.trace_counts.values()) > before)
+            return out
+
         try:
             interrupted = False
-            for n, batch in units:
-                # First tick of the body: everything since the previous
-                # unit's tick is the for statement's implicit next() — the
-                # input pipeline wait.
+            units = iter(units)
+            while True:
+                with annotate("trainer.fetch", epoch=epoch, unit=first_unit + step_in_epoch):
+                    unit = next(units, None)
+                # Everything since the previous unit's tick is the fetch above
+                # — the input pipeline wait (the last one finds the ring
+                # finished).
                 if tm is not None:
                     tm.tick("restart_rollback" if rollback_fetch else "data_wait")
                 rollback_fetch = False
+                if unit is None:
+                    break
+                n, batch = unit
                 if self.preflight is not None and not self._preflight_done:
                     # Before the first dispatch (nothing compiled yet): the
                     # unit's shapes are exact, the fit verdict covers the
@@ -1998,8 +2036,8 @@ class Trainer:
                         break
                     if cap is not None:
                         cap.maybe_start(step_in_epoch, self.state.params)
-                    self.state, window_metrics = self.engine.train_steps_chained(
-                        self.state, batch, n
+                    self.state, window_metrics = dispatch(
+                        self.engine.train_steps_chained, self.state, batch, n, steps=n
                     )
                     collected.append((n, window_metrics))
                     step_in_epoch += n
@@ -2010,7 +2048,8 @@ class Trainer:
                     if bar is not None:
                         bar.update(n)
                     if self.log_every and step_in_epoch % self.log_every == 0:
-                        sync_log_point()
+                        with annotate("trainer.sync", epoch=epoch, reason="log_every"):
+                            sync_log_point()
                     tick_unit()
                     continue
                 # -- single-step path: lead/tail units, chain_steps == 1, and
@@ -2029,10 +2068,9 @@ class Trainer:
                         self._preempted = True  # collective (multi-host OR)
                         interrupted = True
                         break
-                    self._maybe_profile(step_in_epoch)
                     if cap is not None:
                         cap.maybe_start(step_in_epoch, self.state.params)
-                    self.state, metrics = self.train_step(self.state, b)
+                    self.state, metrics = dispatch(self.train_step, self.state, b, steps=1)
                     collected.append((1, metrics))
                     step_in_epoch += 1
                     executed += 1
@@ -2046,7 +2084,8 @@ class Trainer:
                         # loss.item() sync back in).
                         bar.update(1)
                     if self.log_every and step_in_epoch % self.log_every == 0:
-                        sync_log_point()
+                        with annotate("trainer.sync", epoch=epoch, reason="log_every"):
+                            sync_log_point()
                 tick_unit()
                 if interrupted:
                     break
@@ -2060,21 +2099,13 @@ class Trainer:
             # later start_trace in this process fail. sync=None: never block
             # teardown on (possibly hung) device work; abort=True: never pay
             # trace analysis or the roofline probe compile ahead of the
-            # emergency-save path. The legacy profile_dir bracket holds the
-            # same process-global session and needs the same teardown.
+            # emergency-save path.
             if cap is not None and cap.state == "tracing":
                 cap.maybe_stop(step_in_epoch, None, force=True, abort=True)
-            if self._profiled == "tracing":
-                try:
-                    jax.profiler.stop_trace()
-                except (OSError, RuntimeError):
-                    pass  # teardown: the original exception must propagate
-                self._profiled = True
             raise
         finally:
             if watchdog is not None:
                 watchdog.stop()
-        self._maybe_profile(step_in_epoch, end_of_epoch=True)
         if cap is not None:  # close a still-open capture window (short epoch)
             # A preemption-interrupted epoch is on the emergency-save clock:
             # abort=True skips trace analysis and the roofline probe compile
@@ -2093,7 +2124,9 @@ class Trainer:
         # ONE host transfer for the whole epoch, then expand window records
         # to per-step dicts host-side (free: numpy indexing, no device ops).
         host: list[dict] = []
-        for k, tree in jax.device_get(collected):
+        with annotate("trainer.sync", epoch=epoch, reason="epoch_drain"):
+            drained = jax.device_get(collected)
+        for k, tree in drained:
             if k == 1:
                 host.append(tree)
             else:
@@ -2104,78 +2137,79 @@ class Trainer:
             # The device_get above drained every in-flight step — that wait
             # is device execution, i.e. productive time.
             tm.tick("productive_step")
-        # Epoch wall time is closed BEFORE the MFU probe: the probe's one-time
-        # XLA compile (seconds to minutes on a real model) must not inflate
-        # this epoch's step_ms/MFU report — a first-epoch step-time figure
-        # 2.5x the window baseline would fire a spurious step_time_regression.
-        epoch_wall = time.perf_counter() - t0
-        self._maybe_probe_mfu()  # one-time; attributes itself to `compile`
-        out = self._aggregate_epoch_metrics(host, synced_steps)
-        if self.telemetry is not None and executed:
-            report = telemetry_mfu.window_report(
-                executed,
-                epoch_wall,
-                flops_per_step=self._flops_per_step,
-                peak_flops=self._peak_flops,
-            )
-            self._last_step_ms = report["step_ms"]
-            health = {
-                k: out[k]
-                for k in ("loss", "ce_loss", "grad_norm", "update_ratio", "nonfinite")
-                if k in out
-            }
-            mem_fields = self._live_memory_fields()
-            epoch_fields = {}
-            if self.goodput is not None:
-                # Cumulative goodput snapshot per epoch: the timeline
-                # exporter turns consecutive snapshots into per-bucket
-                # spans, and the offline doctor reads the last one.
-                epoch_fields["goodput_seconds"] = self.goodput.to_state()
-            if self._last_straggler:
-                epoch_fields["chip_skew_ms"] = self._last_straggler["chip_skew_ms"]
-                epoch_fields["straggler_ratio"] = self._last_straggler[
-                    "straggler_ratio"
-                ]
-            self.events.emit(
-                "epoch_end",
-                epoch=epoch,
-                wall_s=epoch_wall,
-                interrupted=self._epoch_interrupted,
-                **report,
-                **health,
-                **mem_fields,
-                **epoch_fields,
-            )
-            self._attempt_units = getattr(self, "_attempt_units", 0) + executed
-            self._note_heartbeat_progress(
-                epoch=epoch, step_in_epoch=step_in_epoch,
-                units=self._attempt_units, step_ms=report["step_ms"],
-            )
-            self._emit_heartbeat("loop")
-            self._update_status(
-                step_in_epoch=step_in_epoch, units=self._attempt_units,
-                **mem_fields,
-            )
-            if self.anomaly_detector is not None:
-                epoch_compiled = (
-                    sum(self.engine.trace_counts.values()) > epoch_trace_start
+        with annotate("trainer.epoch_end", epoch=epoch):
+            # Epoch wall time is closed BEFORE the MFU probe: the probe's one-time
+            # XLA compile (seconds to minutes on a real model) must not inflate
+            # this epoch's step_ms/MFU report — a first-epoch step-time figure
+            # 2.5x the window baseline would fire a spurious step_time_regression.
+            epoch_wall = time.perf_counter() - t0
+            self._maybe_probe_mfu()  # one-time; attributes itself to `compile`
+            out = self._aggregate_epoch_metrics(host, synced_steps)
+            if self.telemetry is not None and executed:
+                report = telemetry_mfu.window_report(
+                    executed,
+                    epoch_wall,
+                    flops_per_step=self._flops_per_step,
+                    peak_flops=self._peak_flops,
                 )
-                self._report_anomalies(
-                    self.anomaly_detector.observe(
-                        step_in_epoch,
-                        loss=out.get("loss", out.get("ce_loss")),
-                        grad_norm=out.get("grad_norm"),
-                        # An epoch that paid compile (epoch 0, or a resume
-                        # retrace) reports a compile-diluted mean step
-                        # time: withheld, like the per-window rule above.
-                        step_time=None
-                        if epoch_compiled
-                        else report["step_ms"] / 1e3,
-                        live_bytes=mem_fields.get("live_bytes"),
-                    ),
+                self._last_step_ms = report["step_ms"]
+                health = {
+                    k: out[k]
+                    for k in ("loss", "ce_loss", "grad_norm", "update_ratio", "nonfinite")
+                    if k in out
+                }
+                mem_fields = self._live_memory_fields()
+                epoch_fields = {}
+                if self.goodput is not None:
+                    # Cumulative goodput snapshot per epoch: the timeline
+                    # exporter turns consecutive snapshots into per-bucket
+                    # spans, and the offline doctor reads the last one.
+                    epoch_fields["goodput_seconds"] = self.goodput.to_state()
+                if self._last_straggler:
+                    epoch_fields["chip_skew_ms"] = self._last_straggler["chip_skew_ms"]
+                    epoch_fields["straggler_ratio"] = self._last_straggler[
+                        "straggler_ratio"
+                    ]
+                self.events.emit(
+                    "epoch_end",
                     epoch=epoch,
-                    step_in_epoch=step_in_epoch,
+                    wall_s=epoch_wall,
+                    interrupted=self._epoch_interrupted,
+                    **report,
+                    **health,
+                    **mem_fields,
+                    **epoch_fields,
                 )
+                self._attempt_units = getattr(self, "_attempt_units", 0) + executed
+                self._note_heartbeat_progress(
+                    epoch=epoch, step_in_epoch=step_in_epoch,
+                    units=self._attempt_units, step_ms=report["step_ms"],
+                )
+                self._emit_heartbeat("loop")
+                self._update_status(
+                    step_in_epoch=step_in_epoch, units=self._attempt_units,
+                    **mem_fields,
+                )
+                if self.anomaly_detector is not None:
+                    epoch_compiled = (
+                        sum(self.engine.trace_counts.values()) > epoch_trace_start
+                    )
+                    self._report_anomalies(
+                        self.anomaly_detector.observe(
+                            step_in_epoch,
+                            loss=out.get("loss", out.get("ce_loss")),
+                            grad_norm=out.get("grad_norm"),
+                            # An epoch that paid compile (epoch 0, or a resume
+                            # retrace) reports a compile-diluted mean step
+                            # time: withheld, like the per-window rule above.
+                            step_time=None
+                            if epoch_compiled
+                            else report["step_ms"] / 1e3,
+                            live_bytes=mem_fields.get("live_bytes"),
+                        ),
+                        epoch=epoch,
+                        step_in_epoch=step_in_epoch,
+                    )
         return out
 
     def _aggregate_epoch_metrics(self, host: list[dict], synced: int = 0) -> dict:
@@ -2362,24 +2396,6 @@ class Trainer:
         except ImportError:
             return None
         return tqdm(total=total, desc=desc, dynamic_ncols=True, leave=False)
-
-    def _maybe_profile(self, step_in_epoch: int, end_of_epoch: bool = False) -> None:
-        """Trace steps [1, 1+profile_steps) of the first trained epoch —
-        step 0 is excluded so compile time never pollutes the trace."""
-        if self.profile_dir is None or self._profiled is True:
-            return
-        if self._profiled == "tracing" and (
-            end_of_epoch or step_in_epoch >= 1 + self.profile_steps
-        ):
-            jax.block_until_ready(self.state.params)
-            jax.profiler.stop_trace()
-            self._profiled = True
-            self.log(f"Profiler trace written to {self.profile_dir}")
-        elif self._profiled is False and not end_of_epoch and step_in_epoch == 1:
-            jax.block_until_ready(self.state.params)
-            os.makedirs(self.profile_dir, exist_ok=True)
-            jax.profiler.start_trace(self.profile_dir)
-            self._profiled = "tracing"
 
     def validate(self) -> dict:
         """Collective validation over the val loader; returns weighted-mean

@@ -192,7 +192,7 @@ if __name__ == "__main__":
         save_folder=save_dir,
         snapshot_path=os.environ.get("SNAPSHOT") or None,
         logger=Logger("lm", os.path.join(save_dir, "logfile.log")),
-        profile_dir=os.environ.get("PROFILE_DIR") or None,
+        profile=os.environ.get("PROFILE_DIR") or None,
     )
     trainer.train()
     Trainer.destroy_process()

@@ -554,6 +554,35 @@ def test_window_memory_fields_single_pass_consistency():
     assert solo == {"live_bytes": 42}  # no skew fields on single-chip
 
 
+@pytest.mark.parametrize("stats,want", [
+    # no reserved figure reported: the allocator's own peak
+    ({"bytes_in_use": 100, "peak_bytes_in_use": 250}, 250),
+    # today's TPU runtime: a program's temporaries sit in bytes_reserved
+    ({"bytes_in_use": 100, "peak_bytes_in_use": 120, "bytes_reserved": 400}, 500),
+    ({"bytes_in_use": 100, "peak_bytes_in_use": 120, "bytes_reserved": 10,
+      "peak_bytes_reserved": 700}, 800),
+    # the allocator's peak still wins where it is the larger
+    ({"bytes_in_use": 100, "peak_bytes_in_use": 900, "bytes_reserved": 400}, 900),
+])
+def test_peak_bytes_counts_reserved_temporaries(stats, want):
+    """`peak_bytes` is the larger of peak_bytes_in_use and in-use + reserved
+    (benchmarks/lib/harness.py:memory_peak_bytes's reckoning), through both
+    field builders; a stats dict with no peak yields no field."""
+    from distributed_training_pytorch_tpu.memory import window_memory_fields
+
+    class FakeDevice:
+        def __init__(self, stats):
+            self._stats = stats
+
+        def memory_stats(self):
+            return dict(self._stats)
+
+    assert live_memory_fields(FakeDevice(stats))["peak_bytes"] == want
+    assert window_memory_fields([FakeDevice(stats)])["peak_bytes"] == want
+    assert "peak_bytes" not in live_memory_fields(FakeDevice(stats), include_peak=False)
+    assert "peak_bytes" not in live_memory_fields(FakeDevice({"bytes_in_use": 7}))
+
+
 def test_is_oom_error_classification():
     from jax.errors import JaxRuntimeError
 

@@ -758,15 +758,13 @@ def test_capture_flops_source_failure_degrades_to_warning(tmp_path):
 # Trainer integration: the acceptance pillars.
 
 
-def test_trainer_rejects_profile_with_legacy_profile_dir(tmp_path, mesh):
-    with pytest.raises(ValueError, match="not both"):
-        make_tiny(tmp_path, mesh, profile="x", profile_dir=str(tmp_path / "y"))
-    # profile=False means OFF — it composes with the legacy knob
-    trainer = make_tiny(tmp_path, mesh, profile=False, profile_dir=str(tmp_path / "y"))
-    assert trainer._profile_capture is None
-
-
-def test_trainer_abort_mid_capture_stops_profiler_session(tmp_path, mesh):
+@pytest.mark.parametrize("profile", [
+    lambda tmp: profiling.ProfileConfig(steps=100),  # analyze=True: the default
+    # a bare trace dir, as the examples pass PROFILE_DIR (5 steps from step 1:
+    # still open at the poisoned step 3)
+    lambda tmp: str(tmp / "prof"),
+], ids=["config", "dir_string"])
+def test_trainer_abort_mid_capture_stops_profiler_session(tmp_path, mesh, profile):
     """An exception with the capture window open (anomaly raise, watchdog)
     must still stop the process-global jax.profiler session — a leaked
     session would fail every later start_trace in this process."""
@@ -776,7 +774,7 @@ def test_trainer_abort_mid_capture_stops_profiler_session(tmp_path, mesh):
     trainer = make_tiny(
         tmp_path,
         mesh,
-        profile=profiling.ProfileConfig(steps=100),  # analyze=True: the default
+        profile=profile(tmp_path),
         chain_steps=1,
         fault_plan=plan,
         nan_policy="raise",
@@ -786,30 +784,6 @@ def test_trainer_abort_mid_capture_stops_profiler_session(tmp_path, mesh):
     assert trainer._profile_capture.state == "done"  # closed, not leaked
     # abort teardown skipped analysis: no report, no probe compile paid
     assert trainer._profile_capture.report is None
-    # the proof: a fresh trace session starts cleanly afterwards
-    with legacy_profiling.trace(str(tmp_path / "after")):
-        jax.block_until_ready(jnp.ones((8, 8)) @ jnp.ones((8, 8)))
-
-
-def test_trainer_abort_with_legacy_profile_dir_stops_session(tmp_path, mesh):
-    """The legacy profile_dir bracket holds the same process-global
-    jax.profiler session as the ProfileConfig capture: an abort while it is
-    tracing must stop it too, or every later start_trace in this process
-    fails."""
-    from distributed_training_pytorch_tpu.fault import FaultPlan
-
-    plan = FaultPlan().add("nan_loss", epoch=0, step=3)
-    trainer = make_tiny(
-        tmp_path,
-        mesh,
-        profile_dir=str(tmp_path / "prof"),
-        chain_steps=1,
-        fault_plan=plan,
-        nan_policy="raise",
-    )
-    with pytest.raises(Exception, match="[Nn]on-finite|nan"):
-        trainer.train()
-    assert trainer._profiled is True  # closed, not leaked
     # the proof: a fresh trace session starts cleanly afterwards
     with legacy_profiling.trace(str(tmp_path / "after")):
         jax.block_until_ready(jnp.ones((8, 8)) @ jnp.ones((8, 8)))

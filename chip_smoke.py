@@ -284,7 +284,7 @@ def phase_kernels(smoke: Smoke, devices) -> None:
     # (name, B, T, H, D, causal, valid_len): [0] the chip's shapes, [1] the
     # rehearsal's. ViT-B/16: T=197 padded to 256 by ViT.pad_seq_to, 12 heads
     # of 64. The LM's default path (T=1024) and the long-context shape
-    # (T=8192, block 1024): heads cut to 2 at 8192 so the float32
+    # (T=8192, at the blocks ops.pallas._flash_blocks gives it): heads cut to 2 at 8192 so the float32
     # reference's [B,H,T,T] scores (0.5 GB) fit beside the kernel's operands.
     for chip, toy in (
         (("flash_vit_valid_len", 8, 256, 12, 64, False, 197),

@@ -202,7 +202,7 @@ def attention_fn(
         )
         return None
 
-    from .pallas import FLASH_MIN_SEQ_LEN, make_attention_fn
+    from .pallas import FLASH_MIN_SEQ_LEN, flash_block_plan, make_attention_fn
 
     min_seq_len = 1 if use_flash is True else FLASH_MIN_SEQ_LEN
     inner = make_attention_fn(causal=causal, min_seq_len=min_seq_len, **kwargs)
@@ -226,6 +226,11 @@ def attention_fn(
                 "flash",
                 reason="pallas=True (forced)" if use_flash is True else f"auto: T={seq} >= {min_seq_len}",
                 seq_len=seq,
+                # Static at trace time: the forward's block shape and how many
+                # of a sequence's block pairs it visits (causal skips the rest).
+                **flash_block_plan(
+                    seq, k.shape[1], causal, kwargs.get("block_q"), kwargs.get("block_k")
+                ),
             )
         if valid_len is None:
             return inner(q, k, v)

@@ -19,13 +19,27 @@ Public surface:
 
 Kernel design (see /opt/skills/guides/pallas_guide.md): grid over
 ``(batch, head, q-block)``; K/V live in VMEM as whole ``[T, D]`` slabs per
-(batch, head) — fine through ~32k tokens at D=64/128; beyond that, sequence
+(batch, head) — compile-checked for the v5e through T = 8192 at D = 64/128
+(at 16,384 the slabs alone are refused); beyond that, sequence
 parallelism (``parallel.ring_attention``) shards T across chips and each shard
 re-enters this kernel. Softmax statistics are carried in float32; matmuls run
 on the MXU with ``preferred_element_type=float32``. The backward pass is the
 standard flash decomposition: a delta precompute (``rowsum(dO * O)``), a
 dq kernel gridded over q-blocks, and a dk/dv kernel gridded over k-blocks —
 so the [T, T] score matrix is never materialized in either direction.
+
+Inside a kernel the loop over the other side's blocks follows the causal
+mask: a q-block stops at the last k-block the diagonal reaches
+(:func:`_k_blocks_end`), a k-block starts at the first q-block that can see it
+(:func:`_q_blocks_start`), so a block whose every weight would be exactly 0 is
+never computed; non-causal calls visit every block. Every visited block still
+builds the iota / compare / select mask: a second, mask-free loop for the
+blocks wholly below the diagonal measured 2-7% *slower* on the v5e (the
+mask's vector work hides under the exponentials and matmuls; a second loop's
+carried accumulators do not — PERF.md §6, PR 26). The block shape comes from
+``(T_q, T_k, causal)``, one shape per kernel (:func:`_flash_blocks`);
+:func:`flash_block_plan` reports the forward's, with the number of block pairs
+it visits, for the ``kernel_dispatch`` record.
 """
 
 from __future__ import annotations
@@ -56,12 +70,30 @@ def resolve_interpret(interpret: Optional[bool] = None) -> bool:
     return interpret
 
 
-# 1024-blocks: bigger q-tiles amortize the K/V streaming loop and fill the
-# MXU (block sizes not swept on today's chip). Blocks auto-clamp to T
-# (rounded up to the 128-lane tile, _block_size), so short sequences are
-# unaffected.
-_DEFAULT_BLOCK_Q = 1024
-_DEFAULT_BLOCK_K = 1024
+def _flash_blocks(kernel: str, t_q: int, t_k: int, causal: bool) -> tuple[int, int]:
+    """``(block_q, block_k)`` for one of the kernels ``"fwd"``, ``"dq"``,
+    ``"dkv"``, from the shapes alone; :func:`_block_size` then clamps to T.
+
+    Measured on the TPU v5e (PR 26, ``scripts/flash_block_sweep.py``: bf16,
+    head dim 64, causal, each kernel alone over ``{256, 512, 1024}^2``; PERF.md
+    §6 has the table). At ``[8, 12, 4096, 64]`` the forward and dq are fastest
+    at 512 x 512 (5.05 / 5.55 ms a call; 5.27 / 5.69 at 1024 x 1024), dkv at
+    1024 x 1024 (7.92; 8.79 at 512 x 512): smaller blocks skip more of the
+    masked half (44% of block pairs at 512, 37.5% at 1024) but every loop trip
+    pays for its carried accumulators, and a 256-wide k-block makes the
+    forward's per-row rescale a quarter of its work. At ``[32, 12, 1024, 64]``
+    nothing beats one 1024 x 1024 block pair, which lowers to straight-line
+    code (1.98 / 2.76 / 3.56 ms; the best split, 512 x 512, 2.62 / 2.85 /
+    3.95). Non-causal calls have nothing to skip and keep 1024 x 1024. Past
+    T = 4096 the whole-T slabs a kernel holds in VMEM leave no room for
+    1024 x 1024 float32 tiles (refused by Mosaic at 8192; the shapes below
+    compile there, unmeasured), and at 16,384 the slabs alone are refused."""
+    t = max(t_q, t_k)
+    if t > 4096:
+        return (1024, 512) if kernel == "dkv" else (512, 512)
+    if causal and t > 1024 and kernel != "dkv":
+        return 512, 512
+    return 1024, 1024
 
 
 def _block_size(block: int, t: int) -> int:
@@ -78,6 +110,21 @@ def _block_size(block: int, t: int) -> int:
     return min(block, ((max(t, 1) + 127) // 128) * 128)
 
 
+def _resolve_blocks(kernel, block_q, block_k, t_q, t_k, causal) -> tuple[int, int]:
+    """The ``(bq, bk)`` one kernel (``"fwd"``, ``"dq"``, ``"dkv"``) runs at: a
+    caller's explicit size wins, else the shape rule's; both clamped to T."""
+    rule_q, rule_k = _flash_blocks(kernel, t_q, t_k, causal)
+    return _block_size(block_q or rule_q, t_q), _block_size(block_k or rule_k, t_k)
+
+
+def flash_block_plan(t_q, t_k, causal, block_q=None, block_k=None) -> dict:
+    """What the flash path does at these shapes, for the ``kernel_dispatch``
+    record: the forward's block shape and :func:`flash_block_counts` at it."""
+    bq, bk = _resolve_blocks("fwd", block_q, block_k, t_q, t_k, causal)
+    total, computed = flash_block_counts(t_q, t_k, bq, bk, causal)
+    return {"block_q": bq, "block_k": bk, "blocks_total": total, "blocks_computed": computed}
+
+
 def _pad_to(x: jax.Array, size: int, axis: int) -> jax.Array:
     pad = size - x.shape[axis]
     if pad == 0:
@@ -87,24 +134,78 @@ def _pad_to(x: jax.Array, size: int, axis: int) -> jax.Array:
     return jnp.pad(x, widths)
 
 
+def _fit(x: jax.Array, size: int, axis: int) -> jax.Array:
+    """Zero-pad ``x`` to ``size`` along ``axis``, or drop zero padding down to it."""
+    if x.shape[axis] > size:
+        return jax.lax.slice_in_dim(x, 0, size, axis=axis)
+    return _pad_to(x, size, axis)
+
+
+# ---------------------------------------------------------------------------
+# Which blocks a kernel visits
+# ---------------------------------------------------------------------------
+#
+# Positions count from 0 on both sides inside a call (row r sees column c iff
+# r >= c), also where Tq != Tk (flash_block_fwd/bwd). q-block i holds rows
+# [i*bq, (i+1)*bq), k-block j columns [j*bk, (j+1)*bk): the pair (i, j) has an
+# unmasked element iff j*bk < (i+1)*bq. The two bounds below say that from
+# either side; they take Python ints (flash_block_counts) and traced int32
+# scalars (the kernels, from pl.program_id) alike, so the record and the
+# kernels share one formula.
+
+
+def _k_blocks_end(qi, bq: int, bk: int, n_k: int):
+    """q-block ``qi`` sees k-blocks ``[0, end)`` under the causal mask."""
+    end = ((qi + 1) * bq + bk - 1) // bk
+    return min(n_k, end) if isinstance(end, int) else jnp.minimum(n_k, end)
+
+
+def _q_blocks_start(ki, bq: int, bk: int):
+    """k-block ``ki`` is seen by q-blocks ``[start, n_q)`` under the causal
+    mask (``start`` may lie past ``n_q`` where Tq < Tk: seen by none)."""
+    return (ki * bk) // bq
+
+
+def flash_block_counts(t_q: int, t_k: int, bq: int, bk: int, causal: bool) -> tuple[int, int]:
+    """``(blocks_total, blocks_computed)``: the ``[bq, bk]`` block pairs of one
+    (batch, head)'s ``T_q x T_k`` score square, and how many of them the
+    forward kernel visits (the backward kernels visit the same pairs at their
+    own block shape)."""
+    n_q, n_k = pl.cdiv(t_q, bq), pl.cdiv(t_k, bk)
+    total = n_q * n_k
+    if not causal:
+        return total, total
+    return total, sum(_k_blocks_end(qi, bq, bk, n_k) for qi in range(n_q))
+
+
+def _grid_index(axis: int, extent: int):
+    """``pl.program_id(axis)``, or a plain 0 on an axis of one block: the
+    bounds above then fold to Python ints and the kernels' loops to static
+    ones, so a call whose T fits one block (ViT's 197; T=1024 at 1024) lowers
+    to the straight-line program it was before the loops followed the mask
+    (a dynamic trip count of 1 measured +35% on the forward at T=1024)."""
+    return pl.program_id(axis) if extent > 1 else 0
+
+
 # ---------------------------------------------------------------------------
 # Forward kernel
 # ---------------------------------------------------------------------------
 
 
-def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, block_k, seq_len, causal):
-    """One q-block against all k-blocks, online softmax. Refs are
+def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, block_k, seq_len, causal, n_q):
+    """One q-block against the k-blocks it can see, online softmax. Refs are
     (1, 1, bq, D) / (1, 1, Tp, D) blocks; statistics in f32."""
     bq = q_ref.shape[2]
     d = q_ref.shape[3]
     t_pad = k_ref.shape[2]
     n_k = t_pad // block_k
+    qi = _grid_index(2, n_q)
 
     # Matmuls run in the input dtype (bf16 in production — one MXU pass; an
     # f32 cast would force the 3x-slower f32 path) with f32 accumulation;
     # softmax statistics and the scale multiply stay f32.
     q = q_ref[0, 0]  # [bq, D]
-    q_idx = pl.program_id(2) * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, block_k), 0)
+    q_idx = qi * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, block_k), 0)
 
     def body(j, carry):
         acc, m, l = carry
@@ -130,7 +231,12 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, block_k, seq_len,
     acc0 = jnp.zeros((bq, d), jnp.float32)
     m0 = jnp.full((bq, 1), NEG_INF, jnp.float32)
     l0 = jnp.zeros((bq, 1), jnp.float32)
-    acc, m, l = jax.lax.fori_loop(0, n_k, body, (acc0, m0, l0))
+    # Causal: stop at the last k-block the diagonal reaches — every weight
+    # past it is exactly 0. Ascending j, so the first block visited holds
+    # column 0, which every row sees: m is a real logit before a wholly
+    # masked row of a tile meets it.
+    end = _k_blocks_end(qi, bq, block_k, n_k) if causal else n_k
+    acc, m, l = jax.lax.fori_loop(0, end, body, (acc0, m0, l0))
     # Padded q rows (and fully-masked causal rows cannot occur: row i always
     # sees k=i) have l=0 only when the whole row was padding; guard the divide.
     l_safe = jnp.where(l == 0.0, 1.0, l)
@@ -147,19 +253,22 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, block_k, seq_len,
 
 
 def _bwd_dq_kernel(
-    q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, *, scale, block_k, seq_len, causal
+    q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
+    *, scale, block_k, seq_len, causal, n_q,
 ):
-    """dq for one q-block: dq_i = scale * sum_j (p_ij * (dp_ij - delta_i)) k_j."""
+    """dq for one q-block: dq_i = scale * sum_j (p_ij * (dp_ij - delta_i)) k_j,
+    over the k-blocks the q-block can see."""
     bq = q_ref.shape[2]
     d = q_ref.shape[3]
     t_pad = k_ref.shape[2]
     n_k = t_pad // block_k
+    qi = _grid_index(2, n_q)
 
     q = q_ref[0, 0]
     do = do_ref[0, 0]  # [bq, D]
     lse = jnp.transpose(lse_ref[0, 0], (1, 0))  # [1, bq] -> [bq, 1]
     delta = jnp.transpose(delta_ref[0, 0], (1, 0))
-    q_idx = pl.program_id(2) * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, block_k), 0)
+    q_idx = qi * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, block_k), 0)
 
     def body(j, dq):
         k = k_ref[0, 0, pl.ds(j * block_k, block_k), :]
@@ -181,23 +290,26 @@ def _bwd_dq_kernel(
             ds, k, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
         )
 
-    dq = jax.lax.fori_loop(0, n_k, body, jnp.zeros((bq, d), jnp.float32))
+    end = _k_blocks_end(qi, bq, block_k, n_k) if causal else n_k
+    dq = jax.lax.fori_loop(0, end, body, jnp.zeros((bq, d), jnp.float32))
     dq_ref[0, 0] = (dq * scale).astype(dq_ref.dtype)
 
 
 def _bwd_dkv_kernel(
-    q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_ref, *, scale, block_q, seq_len, causal
+    q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_ref,
+    *, scale, block_q, seq_len, causal, n_k,
 ):
-    """dk/dv for one k-block, looping over q-blocks:
+    """dk/dv for one k-block, looping over the q-blocks that can see it:
     dv_j = sum_i p_ij^T do_i ; dk_j = scale * sum_i (p_ij * (dp_ij - delta_i))^T q_i."""
     bk = k_ref.shape[2]
     d = k_ref.shape[3]
     t_pad = q_ref.shape[2]
     n_q = t_pad // block_q
+    ki = _grid_index(2, n_k)
 
     k = k_ref[0, 0]  # [bk, D]
     v = v_ref[0, 0]
-    k_idx = pl.program_id(2) * bk + jax.lax.broadcasted_iota(jnp.int32, (block_q, bk), 1)
+    k_idx = ki * bk + jax.lax.broadcasted_iota(jnp.int32, (block_q, bk), 1)
 
     def body(i, carry):
         dk, dv = carry
@@ -232,7 +344,9 @@ def _bwd_dkv_kernel(
 
     dk0 = jnp.zeros((bk, d), jnp.float32)
     dv0 = jnp.zeros((bk, d), jnp.float32)
-    dk, dv = jax.lax.fori_loop(0, n_q, body, (dk0, dv0))
+    # Causal: start at the first q-block the diagonal lets see this k-block.
+    start = _q_blocks_start(ki, block_q, bk) if causal else 0
+    dk, dv = jax.lax.fori_loop(start, n_q, body, (dk0, dv0))
     dk_ref[0, 0] = (dk * scale).astype(dk_ref.dtype)
     dv_ref[0, 0] = dv.astype(dv_ref.dtype)
 
@@ -262,7 +376,7 @@ def _fwd_call(qt, kt, vt, t_k, causal, bq, bk, interpret):
     b, h, tq_pad, d = qt.shape
     tk_pad = kt.shape[2]
     kernel = functools.partial(
-        _fwd_kernel, scale=d**-0.5, block_k=bk, seq_len=t_k, causal=causal
+        _fwd_kernel, scale=d**-0.5, block_k=bk, seq_len=t_k, causal=causal, n_q=tq_pad // bq
     )
     call = pl.pallas_call(
         kernel,
@@ -290,7 +404,8 @@ def _fwd_call(qt, kt, vt, t_k, causal, bq, bk, interpret):
 def _fwd_impl(q, k, v, causal, block_q, block_k, interpret, valid_len=None):
     b, t, h, d = q.shape
     t_k = t if valid_len is None else valid_len  # kernels mask keys >= t_k
-    qt, kt, vt, bq, bk = _ring_pad(q, k, v, block_q, block_k)
+    bq, bk = _resolve_blocks("fwd", block_q, block_k, t, t, causal)
+    qt, kt, vt = _pad_bhtd(q, k, v, bq, bk)
     o, lse = _fwd_call(qt, kt, vt, t_k, causal, bq, bk, interpret)
     return o[:, :, :t, :], lse[:, :, :, :t], (qt, kt, vt)
 
@@ -302,7 +417,7 @@ def _dq_call(qt, kt, vt, do, lse_p, delta, t_q, t_k, causal, bq, bk, interpret):
     b, h, tq_pad, d = qt.shape
     tk_pad = kt.shape[2]
     dq_kernel = functools.partial(
-        _bwd_dq_kernel, scale=d**-0.5, block_k=bk, seq_len=t_k, causal=causal
+        _bwd_dq_kernel, scale=d**-0.5, block_k=bk, seq_len=t_k, causal=causal, n_q=tq_pad // bq
     )
     call = pl.pallas_call(
         dq_kernel,
@@ -331,7 +446,7 @@ def _dkv_call(qt, kt, vt, do, lse_p, delta, t_q, t_k, causal, bq, bk, interpret)
     b, h, tq_pad, d = qt.shape
     tk_pad = kt.shape[2]
     dkv_kernel = functools.partial(
-        _bwd_dkv_kernel, scale=d**-0.5, block_q=bq, seq_len=t_k, causal=causal
+        _bwd_dkv_kernel, scale=d**-0.5, block_q=bq, seq_len=t_k, causal=causal, n_k=tk_pad // bk
     )
     call = pl.pallas_call(
         dkv_kernel,
@@ -359,6 +474,21 @@ def _dkv_call(qt, kt, vt, do, lse_p, delta, t_q, t_k, causal, bq, bk, interpret)
         return call(qt, kt, vt, do, lse_p, delta)
 
 
+def _bwd_call(kernel, operands, t_q, t_k, seq_len, causal, block_q, block_k, interpret):
+    """One backward kernel (``"dq"`` or ``"dkv"``) at its own block shape:
+    the ``[B, H, T*, D]`` q/k/v/dO and ``[B, H, 1, T*]`` lse/delta, zero-padded
+    or not, are fitted to whole blocks of it (zeros on dO / delta, as
+    _bwd_dkv_kernel needs)."""
+    bq, bk = _resolve_blocks(kernel, block_q, block_k, t_q, t_k, causal)
+    tq_pad, tk_pad = pl.cdiv(t_q, bq) * bq, pl.cdiv(t_k, bk) * bk
+    qt, kt, vt, do, lse, delta = operands
+    return {"dq": _dq_call, "dkv": _dkv_call}[kernel](
+        _fit(qt, tq_pad, 2), _fit(kt, tk_pad, 2), _fit(vt, tk_pad, 2),
+        _fit(do, tq_pad, 2), _fit(lse, tq_pad, 3), _fit(delta, tq_pad, 3),
+        t_q, seq_len, causal, bq, bk, interpret,
+    )
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
 def _flash(q, k, v, causal, block_q, block_k, interpret, valid_len):
     o, _, _ = _fwd_impl(q, k, v, causal, block_q, block_k, interpret, valid_len)
@@ -376,20 +506,14 @@ def _flash_bwd(causal, block_q, block_k, interpret, valid_len, res, g):
     qt, kt, vt, o, lse, q_shape = res
     b, t, h, d = q_shape
     t_k = t if valid_len is None else valid_len
-    bq = _block_size(block_q, t)
-    bk = _block_size(block_k, t)
-    tq_pad = qt.shape[2]
 
-    do = _pad_to(_to_bhtd(g), tq_pad, 2)
+    do = _to_bhtd(g)
     # delta_i = rowsum(dO_i * O_i) — tiny elementwise precompute, plain XLA.
-    delta = jnp.sum(
-        do.astype(jnp.float32) * _pad_to(o, tq_pad, 2).astype(jnp.float32),
-        axis=-1,
-    )[:, :, None, :]  # [B, H, 1, Tq_pad]
-    lse_p = _pad_to(lse, tq_pad, 3)
-
-    dq = _dq_call(qt, kt, vt, do, lse_p, delta, t, t_k, causal, bq, bk, interpret)
-    dk, dv = _dkv_call(qt, kt, vt, do, lse_p, delta, t, t_k, causal, bq, bk, interpret)
+    delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)[:, :, None, :]
+    # The residuals are padded to the forward's blocks; _bwd_call refits them.
+    operands = (qt, kt, vt, do, lse, delta)
+    dq = _bwd_call("dq", operands, t, t, t_k, causal, block_q, block_k, interpret)
+    dk, dv = _bwd_call("dkv", operands, t, t, t_k, causal, block_q, block_k, interpret)
 
     return (
         _from_bhtd(dq[:, :, :t, :]),
@@ -414,19 +538,17 @@ _flash.defvjp(_flash_fwd, _flash_bwd)
 # ``[B, T, H, D]`` (lse/delta ``[B, H, T]``).
 
 
-def _ring_pad(q, k, v, block_q, block_k):
+def _pad_bhtd(q, k, v, bq, bk):
+    """``[B, T, H, D]`` q/k/v -> ``[B, H, T*, D]``, zero-padded to whole blocks."""
     tq, tk = q.shape[1], k.shape[1]
-    bq = _block_size(block_q, tq)
-    bk = _block_size(block_k, tk)
     qt = _pad_to(_to_bhtd(q), pl.cdiv(tq, bq) * bq, 2)
     kt = _pad_to(_to_bhtd(k), pl.cdiv(tk, bk) * bk, 2)
     vt = _pad_to(_to_bhtd(v), pl.cdiv(tk, bk) * bk, 2)
-    return qt, kt, vt, bq, bk
+    return qt, kt, vt
 
 
 def flash_block_fwd(
-    q, k, v, *, causal=False,
-    block_q=_DEFAULT_BLOCK_Q, block_k=_DEFAULT_BLOCK_K, interpret=None,
+    q, k, v, *, causal=False, block_q=None, block_k=None, interpret=None,
 ):
     """One (q-shard x k/v-block) flash pass -> ``(o, lse)``; o is
     block-normalized, lse = log-sum-exp of this block's logits per q row
@@ -434,27 +556,22 @@ def flash_block_fwd(
     owns the VJP."""
     interpret = resolve_interpret(interpret)
     tq, tk = q.shape[1], k.shape[1]
-    qt, kt, vt, bq, bk = _ring_pad(q, k, v, block_q, block_k)
+    bq, bk = _resolve_blocks("fwd", block_q, block_k, tq, tk, causal)
+    qt, kt, vt = _pad_bhtd(q, k, v, bq, bk)
     o, lse = _fwd_call(qt, kt, vt, tk, causal, bq, bk, interpret)
     return _from_bhtd(o[:, :, :tq, :]), lse[:, :, 0, :tq]
 
 
 def flash_block_bwd(
-    q, k, v, do, lse, delta, *, causal=False,
-    block_q=_DEFAULT_BLOCK_Q, block_k=_DEFAULT_BLOCK_K, interpret=None,
+    q, k, v, do, lse, delta, *, causal=False, block_q=None, block_k=None, interpret=None,
 ):
     """One block's backward contributions ``(dq, dk, dv)`` given the global
     ``lse``/``delta`` ``[B, H, Tq]`` of the resident q shard."""
     interpret = resolve_interpret(interpret)
-    b, tq, h, d = q.shape
-    tk = k.shape[1]
-    qt, kt, vt, bq, bk = _ring_pad(q, k, v, block_q, block_k)
-    tq_pad = qt.shape[2]
-    dot = _pad_to(_to_bhtd(do), tq_pad, 2)
-    lse_p = _pad_to(lse[:, :, None, :], tq_pad, 3)
-    delta_p = _pad_to(delta[:, :, None, :], tq_pad, 3)
-    dq = _dq_call(qt, kt, vt, dot, lse_p, delta_p, tq, tk, causal, bq, bk, interpret)
-    dk, dv = _dkv_call(qt, kt, vt, dot, lse_p, delta_p, tq, tk, causal, bq, bk, interpret)
+    tq, tk = q.shape[1], k.shape[1]
+    operands = (*map(_to_bhtd, (q, k, v, do)), lse[:, :, None, :], delta[:, :, None, :])
+    dq = _bwd_call("dq", operands, tq, tk, tk, causal, block_q, block_k, interpret)
+    dk, dv = _bwd_call("dkv", operands, tq, tk, tk, causal, block_q, block_k, interpret)
     return (
         _from_bhtd(dq[:, :, :tq, :]),
         _from_bhtd(dk[:, :, :tk, :]),
@@ -468,8 +585,8 @@ def flash_attention(
     v: jax.Array,
     *,
     causal: bool = False,
-    block_q: int = _DEFAULT_BLOCK_Q,
-    block_k: int = _DEFAULT_BLOCK_K,
+    block_q: Optional[int] = None,
+    block_k: Optional[int] = None,
     interpret: Optional[bool] = None,
     valid_len: Optional[int] = None,
 ) -> jax.Array:
@@ -477,7 +594,9 @@ def flash_attention(
 
     Numerics match ``models.vit.dot_product_attention`` (softmax statistics in
     float32, scale ``D**-0.5``); memory is O(T) per (batch, head) instead of
-    the O(T^2) score tensor. ``interpret=None`` auto-selects
+    the O(T^2) score tensor. ``block_q`` / ``block_k`` set one block shape for
+    all three kernels; left ``None``, each kernel takes the shape
+    :func:`_flash_blocks` gives it. ``interpret=None`` auto-selects
     (:func:`resolve_interpret`). ``valid_len`` masks key
     positions >= it — for caller-padded sequences (``ViT.pad_seq_to``); the
     kernels' own seq_len masking does the work, no score tensor or bias mask

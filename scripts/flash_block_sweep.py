@@ -1,0 +1,133 @@
+#!/usr/bin/env python
+"""Block-shape sweep of the three flash kernels, each alone (ISSUE 26).
+
+Times ``flash_fwd`` / ``flash_dq`` / ``flash_dkv`` (``ops/pallas.py``) at the
+LM cells' shapes for every ``(block_q, block_k)`` of a small grid and prints
+one JSON line per measurement; the table in PERF.md §6 (PR 26) and the rule in
+``ops.pallas._flash_blocks`` come from it. TPU only::
+
+    chiprun --chips 1 -- python scripts/flash_block_sweep.py [--parent build/parent]
+
+``--parent DIR`` also times the kernels of the checkout unpacked at DIR (the
+parent commit) at its own 1024 x 1024 blocks, for the before / after columns.
+``--compile-only`` compiles every point for a described v5e and times nothing
+(runs without a chip: what Mosaic refuses there it refuses on the chip).
+"""
+
+from __future__ import annotations
+
+import argparse
+import importlib.util
+import json
+import os
+import sys
+import time
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+import jax
+import jax.numpy as jnp
+
+SHAPES = {"t4096_b8": (8, 12, 4096, 64), "t1024_b32": (32, 12, 1024, 64)}
+BLOCKS = (256, 512, 1024)
+
+
+def load_pallas(root):
+    """``ops/pallas.py`` of the checkout at ``root`` as a module of its own."""
+    path = os.path.join(root, "distributed_training_pytorch_tpu", "ops", "pallas.py")
+    spec = importlib.util.spec_from_file_location(f"pallas_{abs(hash(root))}", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def kernel_fns(mod, shape, causal, bq, bk):
+    """``{kernel: (fn, args)}`` on padded ``[B, H, T, D]`` bf16 operands."""
+    b, h, t, d = shape
+    q = k = v = do = jax.ShapeDtypeStruct(shape, jnp.bfloat16)
+    stat = jax.ShapeDtypeStruct((b, h, 1, t), jnp.float32)
+
+    def fwd(q, k, v):
+        return mod._fwd_call(q, k, v, t, causal, bq, bk, False)
+
+    def dq(q, k, v, do, lse, delta):
+        return mod._dq_call(q, k, v, do, lse, delta, t, t, causal, bq, bk, False)
+
+    def dkv(q, k, v, do, lse, delta):
+        return mod._dkv_call(q, k, v, do, lse, delta, t, t, causal, bq, bk, False)
+
+    return {"fwd": (fwd, (q, k, v)), "dq": (dq, (q, k, v, do, stat, stat)),
+            "dkv": (dkv, (q, k, v, do, stat, stat))}
+
+
+def materialize(args):
+    out = []
+    for i, a in enumerate(args):
+        x = jax.random.normal(jax.random.key(i), a.shape, jnp.float32)
+        # lse of unit-normal logits sits near log(T); any finite value times the same.
+        out.append((x + 5.0 if a.dtype == jnp.float32 else x).astype(a.dtype))
+    return out
+
+
+def time_ms(compiled, args, iters):
+    jax.block_until_ready(compiled(*args))
+    t0 = time.perf_counter()
+    for _ in range(iters):
+        out = compiled(*args)
+    jax.block_until_ready(out)
+    return (time.perf_counter() - t0) / iters * 1e3
+
+
+def main():
+    ap = argparse.ArgumentParser()
+    ap.add_argument("--parent", default=None)
+    ap.add_argument("--compile-only", action="store_true")
+    ap.add_argument("--iters", type=int, default=20)
+    ap.add_argument("--shapes", default=",".join(SHAPES))
+    ap.add_argument("--out", default="chiprun_out/flash_block_sweep.jsonl")
+    args = ap.parse_args()
+
+    sharding = None
+    if args.compile_only:
+        from jax.experimental import topologies
+        from jax.sharding import SingleDeviceSharding
+
+        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+        sharding = SingleDeviceSharding(topo.devices[0])
+    elif jax.default_backend() != "tpu":
+        print(f"flash_block_sweep: needs a TPU, found {jax.default_backend()}", file=sys.stderr)
+        return 2
+
+    sides = [("change", load_pallas(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))))]
+    if args.parent:
+        sides.append(("parent", load_pallas(args.parent)))
+    os.makedirs(os.path.dirname(args.out) or ".", exist_ok=True)
+    with open(args.out, "a") as sink:
+        for name in args.shapes.split(","):
+            shape = SHAPES[name]
+            for side, mod in sides:
+                grid = [(1024, 1024)] if side == "parent" else [(bq, bk) for bq in BLOCKS for bk in BLOCKS]
+                for bq, bk in grid:
+                    if bq > shape[2] or bk > shape[2]:
+                        continue
+                    for kernel, (fn, specs) in kernel_fns(mod, shape, True, bq, bk).items():
+                        row = {"shape": name, "side": side, "kernel": kernel, "block_q": bq, "block_k": bk}
+                        try:
+                            if sharding is not None:
+                                specs = [jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sharding) for s in specs]
+                            compiled = jax.jit(fn).lower(*specs).compile()
+                            if args.compile_only:
+                                row["compiled"] = True
+                            else:
+                                row["ms"] = round(time_ms(compiled, materialize(specs), args.iters), 4)
+                                row["device_kind"] = jax.devices()[0].device_kind
+                        except Exception as e:  # a refused point is a row of the table, not the end of it
+                            row["error"] = f"{type(e).__name__}: {str(e)[:300]}"
+                        print(json.dumps(row), flush=True)
+                        sink.write(json.dumps(row) + "\n")
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

@@ -6,6 +6,8 @@ platform (strict float32 tolerances; on TPU the MXU's bf16 multiply path adds
 the plain O(T^2) softmax attention in ``models/vit.py``.
 """
 
+import itertools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -19,41 +21,53 @@ from distributed_training_pytorch_tpu.models.vit import (
 
 
 def reference_attention(q, k, v, causal):
+    """Plain softmax attention; ``Tq != Tk`` allowed (row r sees column c iff
+    r >= c, both from 0 — the kernels' convention)."""
     scale = q.shape[-1] ** -0.5
     logits = jnp.einsum("bqhd,bkhd->bhqk", q, k).astype(jnp.float32) * scale
     if causal:
-        t = q.shape[1]
-        mask = np.tril(np.ones((t, t), bool))
+        mask = np.arange(q.shape[1])[:, None] >= np.arange(k.shape[1])[None, :]
         logits = jnp.where(mask[None, None], logits, -1e30)
     weights = jax.nn.softmax(logits, axis=-1)
     return jnp.einsum("bhqk,bkhd->bqhd", weights, v)
 
 
+# (b, t, h, d, causal, block_q, block_k); None = the shape rule's blocks,
+# which clamp to a single block at these T.
 CASES = [
-    (2, 197, 3, 64, False),  # ViT-B/16 sequence length (197 = 14^2 + cls)
-    (1, 256, 2, 32, False),  # block-aligned
-    (2, 100, 2, 16, True),  # causal, unaligned T
-    (1, 130, 4, 64, True),  # causal, crosses one block boundary
+    (2, 197, 3, 64, False, None, None),  # ViT-B/16 sequence length (197 = 14^2 + cls)
+    (1, 256, 2, 32, False, None, None),  # block-aligned
+    (2, 100, 2, 16, True, None, None),  # causal, unaligned T
+    (1, 130, 4, 64, True, None, None),  # causal, crosses one block boundary
+    # Several blocks on each side: the loop bounds and the masked / unmasked
+    # split are exercised (ISSUE 26).
+    (1, 512, 2, 32, True, 128, 128),  # 4 x 4, 6 pairs skipped
+    (1, 384, 2, 32, True, 256, 128),  # bq > bk: the k-loop's upper bound
+    (1, 384, 2, 32, True, 128, 256),  # bq < bk: the q-loop's lower bound
+    (1, 300, 2, 32, True, 128, 128),  # padding and diagonal in the same last block
+    (1, 300, 2, 32, False, 128, 128),  # non-causal: every block, the last one padded
 ]
+GRAD_CASES = CASES[:1] + CASES[2:3] + CASES[4:]
 
 
-@pytest.mark.parametrize("b,t,h,d,causal", CASES)
-def test_forward_parity(b, t, h, d, causal):
+@pytest.mark.parametrize("b,t,h,d,causal,block_q,block_k", CASES)
+def test_forward_parity(b, t, h, d, causal, block_q, block_k):
     rng = np.random.RandomState(0)
     q, k, v = (jnp.asarray(rng.randn(b, t, h, d), jnp.float32) for _ in range(3))
-    out = flash_attention(q, k, v, causal=causal)
+    out = flash_attention(q, k, v, causal=causal, block_q=block_q, block_k=block_k)
     ref = reference_attention(q, k, v, causal)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5)
 
 
-@pytest.mark.parametrize("b,t,h,d,causal", CASES[:1] + CASES[2:3])
-def test_gradient_parity(b, t, h, d, causal):
+@pytest.mark.parametrize("b,t,h,d,causal,block_q,block_k", GRAD_CASES)
+def test_gradient_parity(b, t, h, d, causal, block_q, block_k):
     rng = np.random.RandomState(1)
     q, k, v = (jnp.asarray(rng.randn(b, t, h, d), jnp.float32) for _ in range(3))
     cotangent = jnp.cos(jnp.arange(b * t * h * d, dtype=jnp.float32)).reshape(b, t, h, d) * 0.1
 
     def loss_flash(q, k, v):
-        return jnp.sum(flash_attention(q, k, v, causal=causal) * cotangent)
+        out = flash_attention(q, k, v, causal=causal, block_q=block_q, block_k=block_k)
+        return jnp.sum(out * cotangent)
 
     def loss_ref(q, k, v):
         return jnp.sum(reference_attention(q, k, v, causal) * cotangent)
@@ -64,6 +78,96 @@ def test_gradient_parity(b, t, h, d, causal):
         np.testing.assert_allclose(
             np.asarray(gf), np.asarray(gr), atol=2e-4, err_msg=f"d{name}"
         )
+
+
+def test_causal_skip_never_reads_future_blocks():
+    """The test that the skip engages: with the last k-block's k and v rows
+    NaN, a kernel that computes the blocks above the diagonal and masks them
+    afterwards poisons every row (0 x NaN in p @ v, NaN in q @ k^T); one that
+    never visits them leaves all earlier q-blocks exactly as if the sequence
+    ended before the NaNs."""
+    t, blk = 512, 128
+    keep = t - blk
+    rng = np.random.RandomState(7)
+    q, k, v, g = (jnp.asarray(rng.randn(1, t, 2, 32), jnp.float32) for _ in range(4))
+    k_nan = k.at[:, keep:].set(jnp.nan)
+    v_nan = v.at[:, keep:].set(jnp.nan)
+    g = g.at[:, keep:].set(0.0)
+
+    def flash(q, k, v):
+        return flash_attention(q, k, v, causal=True, block_q=blk, block_k=blk)
+
+    out, vjp = jax.vjp(flash, q, k_nan, v_nan)
+    dq, _, _ = vjp(g)
+    ref, ref_vjp = jax.vjp(
+        lambda q, k, v: reference_attention(q, k, v, True), q[:, :keep], k[:, :keep], v[:, :keep]
+    )
+    ref_dq, _, _ = ref_vjp(g[:, :keep])
+    assert np.isfinite(np.asarray(out[:, :keep])).all()
+    assert np.isfinite(np.asarray(dq[:, :keep])).all()
+    np.testing.assert_allclose(np.asarray(out[:, :keep]), np.asarray(ref), atol=2e-5)
+    np.testing.assert_allclose(np.asarray(dq[:, :keep]), np.asarray(ref_dq), atol=2e-4)
+
+
+@pytest.mark.parametrize("tq,tk", [(384, 384), (256, 384)])
+def test_flash_block_entry_points_causal_multi_block(tq, tk):
+    """``flash_block_fwd`` / ``flash_block_bwd`` — the ring path's per-block
+    passes — against the reference with no mesh: causal, several blocks a
+    side, square and ``Tq != Tk`` (where the last k-block is seen by no row:
+    its dk / dv are exactly 0)."""
+    from distributed_training_pytorch_tpu.ops.pallas import flash_block_bwd, flash_block_fwd
+
+    rng = np.random.RandomState(8)
+    q, g = (jnp.asarray(rng.randn(1, tq, 2, 32), jnp.float32) for _ in range(2))
+    k, v = (jnp.asarray(rng.randn(1, tk, 2, 32), jnp.float32) for _ in range(2))
+    blocks = dict(causal=True, block_q=128, block_k=128, interpret=True)
+
+    o, lse = flash_block_fwd(q, k, v, **blocks)
+    ref, ref_vjp = jax.vjp(lambda q, k, v: reference_attention(q, k, v, True), q, k, v)
+    np.testing.assert_allclose(np.asarray(o), np.asarray(ref), atol=2e-5)
+    logits = jnp.einsum("bqhd,bkhd->bhqk", q, k) * q.shape[-1] ** -0.5
+    mask = np.arange(tq)[:, None] >= np.arange(tk)[None, :]
+    ref_lse = jax.nn.logsumexp(jnp.where(mask, logits, -jnp.inf), axis=-1)
+    np.testing.assert_allclose(np.asarray(lse), np.asarray(ref_lse), atol=2e-5)
+
+    delta = jnp.einsum("bqhd,bqhd->bhq", g, o)
+    grads = flash_block_bwd(q, k, v, g, lse, delta, **blocks)
+    for got, want, name in zip(grads, ref_vjp(g), "qkv", strict=True):
+        np.testing.assert_allclose(
+            np.asarray(got), np.asarray(want), atol=2e-4, err_msg=f"d{name}"
+        )
+    if tq < tk:
+        assert not np.asarray(grads[1][:, tq:]).any() and not np.asarray(grads[2][:, tq:]).any()
+
+
+def test_block_counts_match_a_brute_force_count_and_reach_the_record():
+    """``flash_block_counts`` (which shares its bound with the kernels' loops)
+    == the number of block pairs holding at least one unmasked element, and
+    the flash ``kernel_dispatch`` record carries the plan."""
+    from distributed_training_pytorch_tpu.ops import dispatch
+    from distributed_training_pytorch_tpu.ops.pallas import flash_block_counts, flash_block_plan
+
+    sizes, blocks = (128, 300, 384, 512, 1024), (128, 256, 512)
+    for t_q, t_k, bq, bk in itertools.product(sizes, sizes, blocks, blocks):
+        n_q, n_k = -(-t_q // bq), -(-t_k // bk)
+        r, c = np.arange(n_q * bq)[:, None], np.arange(n_k * bk)[None, :]
+        seen = (r >= c).reshape(n_q, bq, n_k, bk).any(axis=(1, 3))
+        assert flash_block_counts(t_q, t_k, bq, bk, True) == (n_q * n_k, seen.sum())
+        assert flash_block_counts(t_q, t_k, bq, bk, False) == (n_q * n_k, n_q * n_k)
+    assert flash_block_counts(4096, 4096, 1024, 1024, True) == (16, 10)
+    assert flash_block_counts(4096, 4096, 256, 256, True) == (256, 136)
+
+    dispatch.reset()
+    try:
+        fn = dispatch.attention_fn("transformer_lm", True, causal=True, block_q=128, block_k=128)
+        x = jnp.zeros((1, 512, 1, 8), jnp.float32)
+        jax.eval_shape(fn, x, x, x)
+        (rec,) = [r for r in dispatch.records() if r["path"] == "flash"]
+    finally:
+        dispatch.reset()
+    plan = flash_block_plan(512, 512, True, 128, 128)
+    assert plan == {"block_q": 128, "block_k": 128, "blocks_total": 16, "blocks_computed": 10}
+    assert {k: rec[k] for k in plan} == plan
 
 
 def test_default_attention_fn_selects_by_backend():

@@ -41,6 +41,26 @@ def check_devices(cell, require_tpu: bool):
     return devices[: cell.chips]
 
 
+def place_compile_cache() -> str:
+    """``<checkout>/.jax_cache`` unless ``JAX_COMPILATION_CACHE_DIR`` places it. The
+    checkout's own directory is never capped: it holds what this checkout's
+    cells compile and nothing else, and a cell's next run has to find every
+    program of the last one there. A cap set from outside for some other
+    directory (``JAX_COMPILATION_CACHE_MAX_SIZE``; the chip tool's machines
+    bring 192 MiB) makes jax evict the least recently used entry, which is the
+    one the next run asks for first: the four-chip LM cell's programs weigh
+    148 MB a run, 228 MB with a stand-in's, and the calibration compiled every
+    seed anew under it (PERF.md section 6, PR 27). A directory placed from
+    outside keeps whatever cap came with it."""
+    import jax
+    from distributed_training_pytorch_tpu.utils import enable_compile_cache
+
+    path = enable_compile_cache()
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        jax.config.update("jax_compilation_cache_max_size", -1)
+    return path
+
+
 def build_mesh(traffic: dict, devices):
     from distributed_training_pytorch_tpu.parallel.mesh import MeshConfig, create_mesh
 
@@ -101,14 +121,12 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *, require_t
     """Returns the result object. ``fault`` (tests) is called with the built
     trainer before warm-up and may break the timed path. ``stand_in`` (tests,
     calibration) puts the reference, in the configuration's control precision
-    ("control") or with a planted fault ("half_batch"), in the program's place."""
+    ("control") or with a planted fault ("half_batch", "no_exchange"), in the program's place."""
     t_start = time.perf_counter() if t_start is None else t_start
     cell = cells.load_cell(workload, bench_file, data_dirs)
     cfg, traffic = cell.config, cell.traffic
     devices = check_devices(cell, require_tpu)
-    from distributed_training_pytorch_tpu.utils import enable_compile_cache
-
-    enable_compile_cache()  # <checkout>/.jax_cache unless JAX_COMPILATION_CACHE_DIR places it
+    place_compile_cache()
     system, ref = importlib.import_module(cfg["system"]), importlib.import_module(cfg["reference"])
     system.prepare()
     data_seed, weight_seed, trainer_seed = traffic_lib.sub_seeds(seed)
@@ -229,13 +247,13 @@ def _run(cell, devices, system, ref, data, weight_seed, trainer_seed, workdir, t
 
     t_ref = time.perf_counter()
     params0 = jax.jit(lambda key: ref.init_params(cfg, traffic, key))(jax.random.key(weight_seed))
-    reference = refrun.run_reference(ref, cfg, traffic, params0, prog["batches"])
+    reference = refrun.run_reference(ref, cfg, traffic, params0, prog["batches"], devices=devices)
     numbers = refrun.gaps(prog, reference)
     readings = {"program": numbers}
     for name in ([stand_in] if stand_in else []) + (sorted(STAND_INS) if extra_readings else []):
-        if name not in readings:
-            kwargs = STAND_INS[name](len(prog["batches"][0]["label"]), cfg)
-            placed = refrun.run_reference(ref, cfg, traffic, params0, prog["batches"], **kwargs)
+        kwargs = STAND_INS[name](len(prog["batches"][0]["label"]), cfg, len(devices))
+        if name not in readings and kwargs is not None:
+            placed = refrun.run_reference(ref, cfg, traffic, params0, prog["batches"], devices=devices, **kwargs)
             readings[name] = refrun.gaps(placed, reference)
     if stand_in:
         numbers = readings[stand_in]
@@ -284,10 +302,13 @@ def _run(cell, devices, system, ref, data, weight_seed, trainer_seed, workdir, t
 
 # The reference put in the program's place: the control (the nearest precision
 # below the one the configuration states: fp8 under bfloat16, bfloat16 under
-# float32) and the planted fault that needs a reading.
+# float32) and the planted faults that need a reading. The exchange between
+# chips left out is what one chip's copy holds when it steps on the gradient
+# of its own rows alone; a one-chip cell cannot have it (None).
 STAND_INS = {
-    "control": lambda rows, cfg: {"control": cfg["precision"]["control"]},
-    "half_batch": lambda rows, cfg: {"keep_rows": rows // 2},
+    "control": lambda rows, cfg, chips: {"control": cfg["precision"]["control"]},
+    "half_batch": lambda rows, cfg, chips: {"keep_rows": rows // 2},
+    "no_exchange": lambda rows, cfg, chips: {"keep_rows": rows // chips} if chips > 1 else None,
 }
 
 

@@ -17,16 +17,26 @@ def _leaf_norms(tree: dict) -> dict:
 
 
 def run_reference(ref, cfg: dict, traffic: dict, params: dict, batches: list, *,
-                  control=None, keep_rows: int | None = None) -> dict:
+                  control=None, keep_rows: int | None = None, devices=None) -> dict:
     """``batches``: one ``{"image", "label"}`` of host arrays per step, as the
     device got them. ``control``: a lower precision for every matmul operand.
     ``keep_rows``: the planted fault "half of the batch left out, the mean
-    taken over the rest". Returns per-step losses and, per compared leaf, the
-    norms of the first gradient, of the optimizer's first moment after the
-    last step and of the parameters' change over the steps."""
+    taken over the rest". ``devices``: the cell's chips; over more than one, a
+    call takes ``reference_block_rows`` rows a chip, split by row over them
+    (the weights on each), so that a four-chip cell's four times larger batch
+    takes the reference no longer than a one-chip cell's. Returns per-step
+    losses and, per compared leaf, the norms of the first gradient, of the
+    optimizer's first moment after the last step and of the parameters'
+    change over the steps."""
     opt = cfg["optimizer"]
     block_rows = traffic["reference_block_rows"]
     leaves = common.leaves_view(ref)
+    by_row = None
+    if devices is not None and len(devices) > 1:
+        mesh = jax.sharding.Mesh(np.asarray(devices), ("rows",))
+        by_row = jax.sharding.NamedSharding(mesh, jax.sharding.PartitionSpec("rows"))
+        params = jax.device_put(params, jax.sharding.NamedSharding(mesh, jax.sharding.PartitionSpec()))
+        block_rows *= len(devices)
 
     @functools.partial(jax.jit, donate_argnums=(1, 2))
     def accumulate(p, gsum, lsum, block):
@@ -45,7 +55,8 @@ def run_reference(ref, cfg: dict, traffic: dict, params: dict, batches: list, *,
         gsum = jax.tree.map(jnp.zeros_like, params)
         lsum = jnp.zeros((), jnp.float32)
         for lo in range(0, rows, block_rows):
-            block = {k: jnp.asarray(v[lo:min(lo + block_rows, rows)]) for k, v in batch.items()}
+            block = {k: v[lo:min(lo + block_rows, rows)] for k, v in batch.items()}
+            block = {k: jnp.asarray(v) if by_row is None else jax.device_put(v, by_row) for k, v in block.items()}
             gsum, lsum = accumulate(params, gsum, lsum, block)
         grads = jax.tree.map(lambda g: g / rows, gsum)
         losses.append(float(lsum) / rows)

@@ -6,6 +6,7 @@ arithmetic is tested on a synthesised trace; ``load`` alone touches jax."""
 from __future__ import annotations
 
 import dataclasses
+import functools
 import glob
 import os
 import re
@@ -18,6 +19,10 @@ MOSAIC_CALL = ("tpu_custom_call",)
 
 
 CONTAINERS = ("while", "conditional", "call")  # their time is their children's, which the line also holds
+# Operations that move data between chips. The runtime may split one into a `-start` and a `-done`
+# half (or wrap it in `async-start` / `async-done` and keep the kind in the instruction's name).
+COLLECTIVES = ("all-reduce", "all-gather", "reduce-scatter", "all-to-all", "collective-permute")
+_COLLECTIVE = re.compile(r"^%?(" + "|".join(COLLECTIVES) + r")(?![a-z])")
 
 
 def short_name(hlo_text: str) -> tuple:
@@ -32,6 +37,20 @@ def short_name(hlo_text: str) -> tuple:
     return f"{head} {opcode}".strip()[:80], opcode
 
 
+def collective_half(hlo_text: str) -> str | None:
+    """Which part of a collective an operation is: "whole" (synchronous),
+    "start", "done", or None for anything else. Read from the opcode; under
+    the generic async wrapper from the instruction's name."""
+    return _half(*short_name(hlo_text))
+
+
+def _half(name: str, opcode: str) -> str | None:
+    if opcode in ("async-start", "async-done"):
+        return opcode[len("async-"):] if _COLLECTIVE.match(name) else None
+    m = _COLLECTIVE.match(opcode)
+    return {"": "whole", "-start": "start", "-done": "done"}.get(opcode[m.end():]) if m else None
+
+
 @dataclasses.dataclass
 class Op:
     name: str  # the event's name as the profiler gives it
@@ -40,20 +59,21 @@ class Op:
     dur_ns: float
 
 
-def union_ns(intervals) -> float:
-    """Total length of the union of (start, duration) intervals."""
-    total, cur_lo, cur_hi = 0.0, None, None
+def merged(intervals) -> list:
+    """The union of (start, duration) intervals as disjoint (lo, hi) stretches in time order."""
+    out = []
     for lo, dur in sorted(intervals):
         hi = lo + dur
-        if cur_hi is None or lo > cur_hi:
-            if cur_hi is not None:
-                total += cur_hi - cur_lo
-            cur_lo, cur_hi = lo, hi
+        if out and lo <= out[-1][1]:
+            out[-1][1] = max(out[-1][1], hi)
         else:
-            cur_hi = max(cur_hi, hi)
-    if cur_hi is not None:
-        total += cur_hi - cur_lo
-    return total
+            out.append([lo, hi])
+    return out
+
+
+def union_ns(intervals) -> float:
+    """Total length of the union of (start, duration) intervals."""
+    return float(sum(hi - lo for lo, hi in merged(intervals)))
 
 
 def gaps_ns(intervals, lo: float, hi: float) -> list:
@@ -68,6 +88,51 @@ def gaps_ns(intervals, lo: float, hi: float) -> list:
     if edge < hi:
         out.append((edge, hi - edge))
     return [g for g in out if g[1] > 0]
+
+
+def collective_intervals(ops) -> tuple:
+    """((start, duration) of every collective of one device line, the same of
+    every other operation). A split collective lasts from its start's
+    beginning to its done's end; a done names its start as its operand, and
+    the instruction names repeat from step to step, so a done closes the
+    latest open start of that name. A half without its partner (cut off by
+    the trace's edge) counts for its own length. Containers are in neither
+    list: their children are on the same line."""
+    collectives, others, open_starts = [], [], {}
+    for o in sorted(ops, key=lambda o: o.start_ns):
+        name, opcode = short_name(o.name)
+        half = _half(name, opcode)
+        if half is None:
+            if opcode not in CONTAINERS:
+                others.append((o.start_ns, o.dur_ns))
+        elif half == "start":
+            head = o.name.partition(" = ")[0].strip()
+            if head in open_starts:  # never closed: its own length
+                collectives.append(open_starts[head])
+            open_starts[head] = (o.start_ns, o.dur_ns)
+        elif half == "done":
+            m = re.search(r"-done\(.*?(%[\w.\-]+)", o.name)  # the operand, after its shape
+            begun = open_starts.pop(m.group(1), None) if m else None
+            lo = begun[0] if begun else o.start_ns
+            collectives.append((lo, o.start_ns + o.dur_ns - lo))
+        else:
+            collectives.append((o.start_ns, o.dur_ns))
+    collectives.extend(open_starts.values())
+    return collectives, others
+
+
+def uncovered_ns(intervals, others) -> float:
+    """Length of the union of ``intervals`` that no interval of ``others`` covers."""
+    cover, total, j = merged(others), 0.0, 0
+    for lo, hi in merged(intervals):
+        total += hi - lo
+        while j < len(cover) and cover[j][1] <= lo:
+            j += 1
+        k = j
+        while k < len(cover) and cover[k][0] < hi:
+            total -= min(hi, cover[k][1]) - max(lo, cover[k][0])
+            k += 1
+    return total
 
 
 def innermost_span(spans, t_ns: float) -> str:
@@ -102,6 +167,20 @@ class TraceSummary:
             hit = hit or t > 0
             per.append(t)
         return sum(per) / len(per) / 1e9 if hit else None
+
+    @functools.cached_property
+    def collective_seconds(self) -> tuple | None:
+        """(seconds a collective was in flight, seconds of that in which the
+        line ran nothing else), each the union on a device plane, averaged
+        over the planes; None where no plane holds a collective."""
+        per = []
+        for ops in self.devices.values():
+            collectives, others = collective_intervals(ops)
+            per.append((union_ns(collectives), uncovered_ns(collectives, others)) if collectives else None)
+        if not per or all(p is None for p in per):
+            return None
+        n = len(per)
+        return sum(p[0] for p in per if p) / n / 1e9, sum(p[1] for p in per if p) / n / 1e9
 
     def top_ops(self, n: int = 10) -> list:
         first = next(iter(self.devices.values()), [])
@@ -189,3 +268,11 @@ def describe(logdir: str, out_path: str, limit: int = 60) -> None:
                 f.write(f"    {len(calls)} distinct custom-call names; whole text of the first two:\n")
                 for name in calls[:2]:
                     f.write(f"      {name}\n")
+                # Around the first collective, in time order: what runs while one is in flight.
+                events.sort(key=lambda ev: ev.start_ns)
+                first = next((i for i, ev in enumerate(events) if collective_half(ev.name)), None)
+                if first is not None:
+                    f.write("    in time order from 20 events before the first collective (start us, us, name):\n")
+                    for ev in events[max(0, first - 20):first + 180]:
+                        f.write(f"      {ev.start_ns / 1e3:12.1f} {ev.duration_ns / 1e3:9.1f} {short_name(ev.name)[0]}\n")
+                    f.write(f"      the first collective's whole text: {events[first].name}\n")

@@ -12,15 +12,16 @@ DECLARATION = {"name": "flash_roofline", "unit": "%", "better": "higher", "sourc
                "layer": "pallas flash attention", "moves": "step_ms"}
 
 
-
 def read(ctx):
     # ops/pallas.py's flash forward, dq and dkv kernels are the only Mosaic calls on the LM's path.
-    if ctx["peaks"] is None or ctx["cfg"]["family"] != "lm":
+    if ctx["peaks"] is None:
         return None
     spent = ctx["trace"].op_seconds(MOSAIC_CALL)
     if not spent:
         return None
-    need = flops.flash_required_per_step(ctx["cfg"], ctx["traffic"]["seq_len"], ctx["traffic"]["global_batch"])
+    need = flops.flash_required_per_step(ctx["cfg"], ctx["traffic"])
+    if need is None:  # the configuration's FLOPs module reckons no flash call
+        return None
     peaks = ctx["peaks"]
     least = max(need["flops"] / peaks["flops_per_s_bf16"], need["bytes"] / peaks["hbm_bytes_per_s"])
     return 100.0 * least * ctx["trace_steps"] / ctx["chips"] / spent

@@ -17,6 +17,7 @@ DATA = os.path.join(os.path.dirname(__file__), "data")
 ZERO = 1_700_000_000_000_000_000  # the profiler session's start, realtime ns
 NEW = ["idle_in_fetch_share", "idle_in_dispatch_share", "idle_in_glue_share", "stage_ms_per_step",
        "produce_ms_per_step", "ring_empty_share", "loss_head_time_share", "flash_bwd_time_share", "program_load_s"]
+COLLECTIVE = ["collective_share", "collective_exposed_share"]  # the four-chip cell's, appended after them
 
 
 def span(name, lo, hi, thread="MainThread", parent=None, **ids):
@@ -239,15 +240,17 @@ def test_every_new_per_layer_entry_has_its_reader_and_says_the_same():
     with open(os.path.join(cells.ROOT, "BENCHMARK.json")) as f:
         bench = json.load(f)
     entries = {m["name"]: m for m in bench["per_layer"]}
-    assert [m["name"] for m in bench["per_layer"]][-len(NEW):] == NEW  # appended, in this order
+    assert [m["name"] for m in bench["per_layer"]][-len(NEW + COLLECTIVE):] == NEW + COLLECTIVE  # appended, in this order
     cell_names = [w["name"] for w in bench["workloads"]]
-    for name in NEW:
+    for name in NEW + COLLECTIVE:
         entry = dict(entries[name])
         workloads = entry.pop("workloads", None)
         assert reader(name).DECLARATION == entry, name
         assert entry["moves"] in {m["name"] for m in bench["end_to_end"]}
         if name in ("loss_head_time_share", "flash_bwd_time_share"):
-            assert workloads == ["gpt2s_t1024", "gpt2s_t4096"] and set(workloads) <= set(cell_names)
+            assert workloads == ["gpt2s_t1024", "gpt2s_t4096", "gpt2s_t1024_dp4"] and set(workloads) <= set(cell_names)
+        elif name in COLLECTIVE:
+            assert workloads == ["gpt2s_t1024_dp4"]
         else:
             assert workloads is None
 

@@ -29,7 +29,7 @@ def main(path: str) -> None:
         print(name, "|", " | ".join(out), f"| second/first median {medians[1] / medians[0]:.5f}")
     bad = [(r["set"], r["seed"]) for r in rows if not r["correct"]]
     print("runs:", len(rows), "not correct:", bad)
-    for key in ("loss_gap", "grad_gap", "delta_gap"):
+    for key in rows[0]["checks"]:  # a cell compares the numbers its traffic file gives limits for
         values = [r["checks"][key]["value"] for r in rows]
         print(f"{key}: max {max(values):.3g} over {len(values)} runs, limit {rows[0]['checks'][key]['limit']}")
     traced = [r for r in rows if r["set"] == 0]

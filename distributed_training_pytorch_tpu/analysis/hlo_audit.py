@@ -71,6 +71,40 @@ _CALLBACK_TARGET_RE = re.compile(
 )
 
 
+_COMPUTATION_RE = re.compile(r"^(?:ENTRY )?%?([\w.\-]+) (?:\(.*\) -> .* )?\{\s*$")
+_CALLEE_RE = re.compile(r"\b(?:calls|body|condition|to_apply)=%?([\w.\-]+)")
+
+
+def computations(hlo_text: str) -> dict[str, list[str]]:
+    """Instruction lines of an HLO module's text by the computation that holds
+    them (the lowered module's ``name {`` headers and the compiled one's
+    ``%name (params) -> type {`` alike)."""
+    comps: dict[str, list[str]] = {}
+    lines = None
+    for line in hlo_text.splitlines():
+        m = _COMPUTATION_RE.match(line)
+        if m:
+            lines = comps.setdefault(m.group(1), [])
+        elif lines is not None and line.startswith(" "):
+            lines.append(line)
+    return comps
+
+
+def called_from(comps: dict[str, list[str]], is_root: Callable[[str], bool]) -> set[str]:
+    """Computations an instruction picked by ``is_root`` calls (a fusion, a
+    ``while``'s body and condition, a ``call``'s target), and everything
+    those call in turn."""
+    reached = {c for lines in comps.values() for ln in lines if is_root(ln) for c in _CALLEE_RE.findall(ln)}
+    frontier = list(reached)
+    while frontier:
+        for ln in comps.get(frontier.pop(), ()):
+            for c in _CALLEE_RE.findall(ln):
+                if c not in reached:
+                    reached.add(c)
+                    frontier.append(c)
+    return reached
+
+
 def parse_input_output_aliases(hlo_text: str) -> set[int]:
     """Parameter numbers that are input-output aliased (donated) in a
     compiled module's header. Empty set when the header carries no

@@ -194,7 +194,7 @@ class TransformerLM(nn.Module):
     ) -> jax.Array:
         """``return_hidden=True`` skips the vocab projection and returns the
         final-LN hidden states ``[B, T, d]`` — pair with
-        ``ops.losses.tied_cross_entropy`` (and the ``embed`` param) so training
+        ``ops.losses.tied_cross_entropy_loss`` (and the ``embed`` param) so training
         never materializes the [B, T, V] float32 logits."""
         b, t = tokens.shape
         if t > self.max_len:
@@ -261,8 +261,10 @@ def make_fused_lm_loss(
     z_loss_coef: float = 1e-3,
 ):
     """Engine LossFn for next-token training through the fused tied-embedding
-    CE (``ops.losses.tied_cross_entropy``) — the [B, T, V] float32 logits
-    never materialize. Batch contract: ``image`` = input tokens, ``label`` =
+    CE (``ops.losses.tied_cross_entropy_loss``): the head scans the sequence
+    a slice at a time and takes its gradients in the forward pass, so the
+    [B, T, V] float32 logits never materialize and no logit is computed
+    twice. Batch contract: ``image`` = input tokens, ``label`` =
     next tokens, optional ``mask`` [B] pad weights. ONE implementation shared
     by the training entry and the benchmark so they measure the same
     computation.
@@ -271,10 +273,7 @@ def make_fused_lm_loss(
     objective: Switch load-balance * ``aux_loss_coef`` + router-z *
     ``z_loss_coef`` (standard coefficients; without them routing collapses
     onto a few experts)."""
-    from distributed_training_pytorch_tpu.ops.losses import (
-        tied_cross_entropy,
-        weighted_mean,
-    )
+    from distributed_training_pytorch_tpu.ops.losses import tied_cross_entropy_loss
 
     has_moe = model.moe_every > 0
 
@@ -293,10 +292,9 @@ def make_fused_lm_loss(
             hidden = model.apply(
                 {"params": params}, batch["image"], train=train, return_hidden=True, **kwargs
             )
-        nll = tied_cross_entropy(
-            hidden, params["embed"]["embedding"], batch["label"]
-        ).mean(axis=-1)  # [B]
-        loss = weighted_mean(nll, batch.get("mask"))
+        loss = tied_cross_entropy_loss(
+            hidden, params["embed"]["embedding"], batch["label"], batch.get("mask")
+        )
         metrics = {"loss": loss, "nll": loss, "ppl": jnp.exp(loss)}
         if has_moe:
             # mean of each sown metric across the MoE blocks, selected by name

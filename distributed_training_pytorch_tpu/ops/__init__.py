@@ -1,7 +1,7 @@
 from distributed_training_pytorch_tpu.ops.losses import (  # noqa: F401
     cross_entropy_loss,
     softmax_cross_entropy_with_integer_labels,
-    tied_cross_entropy,
+    tied_cross_entropy_loss,
     weighted_mean,
 )
 from distributed_training_pytorch_tpu.ops.metrics import accuracy, top_k_accuracy  # noqa: F401

@@ -45,7 +45,6 @@ it visits, for the ``kernel_dispatch`` record.
 from __future__ import annotations
 
 import functools
-import math
 from typing import Optional
 
 import jax
@@ -641,18 +640,11 @@ def _ambient_shard_spec(shape):
     mesh = jax.sharding.get_abstract_mesh()
     if not mesh.axis_names or mesh.manual_axes:
         return None
-    from distributed_training_pytorch_tpu.parallel.mesh import (
-        DATA_AXIS,
-        FSDP_AXIS,
-        TENSOR_AXIS,
-    )
+    from distributed_training_pytorch_tpu.parallel.mesh import TENSOR_AXIS, ambient_batch_axes
 
     b, _, h, _ = shape
-    sizes = mesh.shape
-    batch_axes = tuple(a for a in (DATA_AXIS, FSDP_AXIS) if sizes.get(a, 1) > 1)
-    if b % math.prod(sizes[a] for a in batch_axes):
-        batch_axes = ()
-    heads = sizes.get(TENSOR_AXIS, 1)
+    batch_axes, _ = ambient_batch_axes(b)
+    heads = mesh.shape.get(TENSOR_AXIS, 1)
     head_axis = TENSOR_AXIS if heads > 1 and h % heads == 0 else None
     if not batch_axes and head_axis is None:
         return None

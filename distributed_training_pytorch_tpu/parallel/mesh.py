@@ -23,6 +23,7 @@ Mesh axes used throughout the framework:
 from __future__ import annotations
 
 import dataclasses
+import math
 import os
 import re
 from typing import Mapping, Sequence
@@ -162,6 +163,21 @@ def batch_sharding(mesh: Mesh, batch_axes: Sequence[str] | None = None) -> Named
         batch_axes = [a for a in (DATA_AXIS, FSDP_AXIS) if a in mesh.axis_names]
     spec = P(tuple(batch_axes)) if batch_axes else P()
     return NamedSharding(mesh, spec)
+
+
+def ambient_batch_axes(rows: int) -> tuple[tuple[str, ...], int]:
+    """The batch axes (``data`` x ``fsdp``) of the ambient mesh (the one
+    ``TrainEngine`` / ``InferEngine`` set around their jits) that split a
+    batch of ``rows``, and how many chips they make. None, and 1, where there
+    is no ambient mesh, where they do not divide ``rows`` (the batch-1 example
+    input of ``model.init`` stays whole), or inside a manual region, whose
+    shapes are a chip's already."""
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.manual_axes:
+        return (), 1
+    axes = tuple(a for a in (DATA_AXIS, FSDP_AXIS) if mesh.shape.get(a, 1) > 1)
+    chips = math.prod(mesh.shape[a] for a in axes)
+    return (axes, chips) if rows % chips == 0 else ((), 1)
 
 
 def replicated_sharding(mesh: Mesh) -> NamedSharding:

@@ -2496,7 +2496,7 @@ class Trainer:
         """Advanced hook (beyond the reference's nine): the full functional
         LossFn handed to the engine. The default composes ``build_model`` +
         ``build_criterion`` the standard way; override when the loss needs
-        direct access to params (e.g. ``ops.losses.tied_cross_entropy`` fusing
+        direct access to params (e.g. ``ops.losses.tied_cross_entropy_loss`` fusing
         a tied LM head so the [B, T, V] logits never materialize)."""
         return make_supervised_loss(self.model, self.criterion)
 

@@ -134,9 +134,10 @@ class LMTrainer(Trainer):
 
     def build_loss_fn(self):
         """Fused tied-head CE by default (FUSED_CE=0 for the naive path): the
-        model returns final hidden states and ``tied_cross_entropy`` streams
-        the vocab in chunks — the [B, T, 256]/[B, T, 50257] float32 logits
-        never materialize (doubles the trainable batch for GPT-small on v5e:
+        model returns final hidden states and ``tied_cross_entropy_loss``
+        scans them a slice of the sequence at a time, taking the head's
+        gradients in the forward pass — the [B, T, 256]/[B, T, 50257] float32
+        logits never materialize (doubles the trainable batch for GPT-small on v5e:
         B=32 -> 64 at T=1024, same tok/s)."""
         if os.environ.get("FUSED_CE", "1") == "0":
             if self.moe_every > 0:

@@ -342,29 +342,48 @@ def test_telemetry_on_is_bit_identical_to_off(tmp_path, mesh, no_recorder):
 # -- on the device: scopes and kernel names -------------------------------------------------
 
 
-def test_the_lowered_step_names_the_loss_head_and_the_optimizer(devices):
-    """HLO metadata only: both `while` loops of the fused tied-CE head (the
-    forward's and the backward's) carry `loss_head`, the update `optimizer`."""
+def test_the_lowered_step_names_the_loss_head_and_the_optimizer(devices, monkeypatch):
+    """HLO metadata only: every matmul of the fused tied-CE head, the `while`
+    that holds them and the backward half's scaling (the `custom_vjp`'s bwd
+    rule, which no autodiff names) carry `loss_head`; the update `optimizer`."""
     import optax
 
-    from distributed_training_pytorch_tpu.ops.losses import tied_cross_entropy
+    from distributed_training_pytorch_tpu.analysis import hlo_audit
+    from distributed_training_pytorch_tpu.ops import losses
+    from distributed_training_pytorch_tpu.precision import DynamicScale
     from distributed_training_pytorch_tpu.train import TrainEngine
+
+    monkeypatch.setattr(losses, "_SLICE_LOGITS_BYTES", 4 * 2 * 300 * 4)  # 2 rows a chip x 4 tokens: two slices
 
     def loss_fn(params, model_state, batch, rng, train):
         hidden = jnp.tanh(batch["image"] @ params["w"])
-        loss = tied_cross_entropy(hidden, params["emb"], batch["label"], chunk_size=128).mean()
+        loss = losses.tied_cross_entropy_loss(hidden, params["emb"], batch["label"], batch["mask"])
         return loss, ({"loss": loss}, model_state)
 
-    engine = TrainEngine(loss_fn, optax.adamw(1e-3), mesh_lib.create_mesh())
+    # a dynamic loss scale: the head's cotangent is a traced scalar, so the backward's scaling is in the program
+    engine = TrainEngine(loss_fn, optax.adamw(1e-3), mesh_lib.create_mesh(), precision="fp16",
+                         loss_scale=DynamicScale.create())
     state = engine.init_state(jax.random.key(0), lambda rng: {"params": {
         "w": jnp.ones((16, 32)) * 0.1, "emb": jax.random.normal(rng, (300, 32))}})
-    batch = {"image": np.ones((2, 16, 16), np.float32), "label": np.zeros((2, 16), np.int32)}
+    batch = {"image": np.ones((2, 16, 8, 16), np.float32), "label": np.zeros((2, 16, 8), np.int32),
+             "mask": np.ones((2, 16), np.float32)}  # 2 steps of 16 rows (2 a chip) x 8 tokens
     batch = jax.tree.map(lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), batch)
-    text = engine._chained_step_fn(2, state).lower(state, batch).compile().as_text()
+    with engine._ambient_mesh():  # as a dispatch sets it: the head sizes its slices by a chip's rows
+        lowered = engine._chained_step_fn(2, state).lower(state, batch)
+    # the lowered module names an op relative to its computation: one is the head's if the
+    # instruction that calls it is
+    comps = hlo_audit.computations(lowered.as_text(dialect="hlo", debug_info=True))
+    heads = hlo_audit.called_from(comps, lambda ln: "loss_head" in ln)
+    matmuls = [(re.search(r'op_name="([^"]*)"', ln).group(1), name in heads or "loss_head" in ln)
+               for name, lines in comps.items() for ln in lines if " dot(" in ln]
+    in_head = sorted(op.split("/")[0] for op, scoped in matmuls if scoped)
+    assert in_head == ["crsd,vd->crsv", "crsv,crsd->cvd", "crsv,vd->crsd"], matmuls  # three, none twice
+    assert [op for op, scoped in matmuls if not scoped and "->" in op] == [], matmuls  # the model's own only
+    text = lowered.compile().as_text()
     names = re.findall(r'op_name="([^"]*)"', text)
     whiles = [re.search(r'op_name="([^"]*)"', ln).group(1) for ln in text.splitlines() if " while(" in ln]
-    assert len(whiles) == 4 and all("loss_head" in w for w in whiles), whiles  # 2 steps x (forward, backward)
-    assert any("transpose(jvp(loss_head))" in w for w in whiles)
+    assert len(whiles) == 2 and all("loss_head" in w for w in whiles), whiles  # one loop a step
+    assert any("transpose(jvp(" in n and "loss_head" in n for n in names)  # the bwd rule's scaling
     assert any("/optimizer/" in n for n in names)
     assert not any("optimizer" in n and "loss_head" in n for n in names)
 

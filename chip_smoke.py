@@ -17,9 +17,10 @@ Phases
            path reaches, COMPILED (never interpreted) at the shapes its
            callers use and compared with its plain float32 reference: flash
            non-causal with ``valid_len`` (the ViT ``pad_seq_to`` path), flash
-           causal at T=1024 and T=8192, ``flash_block_fwd/bwd`` (the ring
-           path's blocks), ``conv1x1_bn_act`` relu/identity at ResNet stage-1
-           shapes and gelu at ConvNeXt-L's expand shapes. Plus two device
+           causal at T=1024, T=4096 and T=8192 (the backward one Mosaic call
+           at each), ``flash_block_fwd/bwd`` (the ring path's blocks),
+           ``conv1x1_bn_act`` relu/identity at ResNet stage-1 shapes and gelu
+           at ConvNeXt-L's expand shapes. Plus two device
            checks: ``tpu_compiler_options()`` is accepted by the installed
            libtpu, and ``jax.block_until_ready`` really blocks.
   leg_a    ``Cifar10Trainer`` (examples/train_cifar10.py): VGG16 at full
@@ -280,17 +281,26 @@ def phase_kernels(smoke: Smoke, devices) -> None:
         ok = finite and errs["o"] <= TOL_FWD and max(errs["dq"], errs["dk"], errs["dv"]) <= TOL_GRAD
         smoke.check(name, ok, f"shape {(b, t, h, d)} rel err {errs}")
         mosaic_check(name, fwd_bwd)
+        if on_tpu:  # the backward is ONE Mosaic call (dq, dk, dv together), whatever T
+            calls = re.findall(r"%(flash_\w+)\.\d+ = ", fwd_bwd.as_text())
+            smoke.check(f"{name}.one_backward_kernel",
+                        sorted(set(calls)) == ["flash_dqkv", "flash_fwd"] and calls.count("flash_dqkv") == 1,
+                        f"Mosaic calls {calls}")
 
     # (name, B, T, H, D, causal, valid_len): [0] the chip's shapes, [1] the
     # rehearsal's. ViT-B/16: T=197 padded to 256 by ViT.pad_seq_to, 12 heads
-    # of 64. The LM's default path (T=1024) and the long-context shape
-    # (T=8192, at the blocks ops.pallas._flash_blocks gives it): heads cut to 2 at 8192 so the float32
+    # of 64. The LM's default path (T=1024: one block pair), the benchmark's
+    # long cell (T=4096: several block pairs, dq summed over the k-block grid
+    # axis) and the long-context shape (T=8192), each at the blocks
+    # ops.pallas._flash_blocks gives it: heads cut to 4 and 2 so the float32
     # reference's [B,H,T,T] scores (0.5 GB) fit beside the kernel's operands.
     for chip, toy in (
         (("flash_vit_valid_len", 8, 256, 12, 64, False, 197),
          ("flash_vit_valid_len", 2, 32, 2, 16, False, 25)),
         (("flash_causal_1024", 2, 1024, 12, 64, True, None),
          ("flash_causal_1024", 1, 64, 2, 16, True, None)),
+        (("flash_causal_4096", 2, 4096, 4, 64, True, None),
+         ("flash_causal_4096", 1, 128, 2, 16, True, None)),
         (("flash_causal_8192", 1, 8192, 2, 64, True, None),
          ("flash_causal_8192", 1, 256, 2, 16, True, None)),
     ):
@@ -692,7 +702,7 @@ def phase_leg_b(smoke: Smoke, devices, sizes, workdir: str) -> None:
         if on_tpu:
             smoke.check(f"{tag}.dispatch_record_says_flash",
                         bool(recs) and all(r["path"] == "flash" and r["reason"].startswith("auto")
-                                           for r in recs), f"{recs}")
+                                           and r["backward"] == "fused" for r in recs), f"{recs}")
             # The compiled train step the MFU probe already built (memoized:
             # no extra compile). Its per-device HLO must hold the Mosaic call
             # on the per-device batch (and, under `tensor`, per-device heads).

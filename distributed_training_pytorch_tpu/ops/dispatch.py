@@ -226,8 +226,9 @@ def attention_fn(
                 "flash",
                 reason="pallas=True (forced)" if use_flash is True else f"auto: T={seq} >= {min_seq_len}",
                 seq_len=seq,
-                # Static at trace time: the forward's block shape and how many
-                # of a sequence's block pairs it visits (causal skips the rest).
+                # Static at trace time: the forward's block shape, how many of
+                # a sequence's block pairs it visits (causal skips the rest),
+                # and which backward these shapes get at which block shape.
                 **flash_block_plan(
                     seq, k.shape[1], causal, kwargs.get("block_q"), kwargs.get("block_k")
                 ),

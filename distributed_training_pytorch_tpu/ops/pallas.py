@@ -12,7 +12,7 @@ Public surface:
 
 * :func:`flash_attention` — ``[B, T, H, D]`` q/k/v -> ``[B, T, H, D]``, same
   contract as ``models.vit.dot_product_attention`` (scale = D**-0.5, optional
-  causal mask), differentiable (custom VJP, flash backward kernels).
+  causal mask), differentiable (custom VJP, one flash backward kernel).
 * :func:`make_attention_fn` — adapter for ``models.vit.MultiHeadAttention``'s
   ``attention_fn`` hook; picks the kernel on TPU and the plain XLA path
   elsewhere.
@@ -24,9 +24,14 @@ Kernel design (see /opt/skills/guides/pallas_guide.md): grid over
 parallelism (``parallel.ring_attention``) shards T across chips and each shard
 re-enters this kernel. Softmax statistics are carried in float32; matmuls run
 on the MXU with ``preferred_element_type=float32``. The backward pass is the
-standard flash decomposition: a delta precompute (``rowsum(dO * O)``), a
-dq kernel gridded over q-blocks, and a dk/dv kernel gridded over k-blocks —
-so the [T, T] score matrix is never materialized in either direction.
+standard flash decomposition in one kernel: a delta precompute
+(``rowsum(dO * O)``, plain XLA), then a grid over ``(batch, head, k-block)``
+with q / dO / lse / delta resident as whole-T slabs and a loop over q-blocks
+that recomputes the scores of a block pair once and takes dv, dk and the
+pair's share of dq from them — five matmuls a pair; dk / dv are carried in
+float32 through the loop, dq is summed in a float32 VMEM slab across the
+(sequential) k-block grid axis — so the [T, T] score matrix is never
+materialized in either direction.
 
 Inside a kernel the loop over the other side's blocks follows the causal
 mask: a q-block stops at the last k-block the diagonal reaches
@@ -38,8 +43,8 @@ blocks wholly below the diagonal measured 2-7% *slower* on the v5e (the
 mask's vector work hides under the exponentials and matmuls; a second loop's
 carried accumulators do not — PERF.md §6, PR 26). The block shape comes from
 ``(T_q, T_k, causal)``, one shape per kernel (:func:`_flash_blocks`);
-:func:`flash_block_plan` reports the forward's, with the number of block pairs
-it visits, for the ``kernel_dispatch`` record.
+:func:`flash_block_plan` reports both kernels', with the number of block pairs
+the forward visits, for the ``kernel_dispatch`` record.
 """
 
 from __future__ import annotations
@@ -50,6 +55,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import PartitionSpec as P
 
 NEG_INF = -1e30  # large-negative logit for masked positions (f32-safe)
@@ -70,27 +76,39 @@ def resolve_interpret(interpret: Optional[bool] = None) -> bool:
 
 
 def _flash_blocks(kernel: str, t_q: int, t_k: int, causal: bool) -> tuple[int, int]:
-    """``(block_q, block_k)`` for one of the kernels ``"fwd"``, ``"dq"``,
-    ``"dkv"``, from the shapes alone; :func:`_block_size` then clamps to T.
+    """``(block_q, block_k)`` for one of the kernels ``"fwd"``, ``"bwd"``, from
+    the shapes alone; :func:`_block_size` then clamps to T.
 
-    Measured on the TPU v5e (PR 26, ``scripts/flash_block_sweep.py``: bf16,
-    head dim 64, causal, each kernel alone over ``{256, 512, 1024}^2``; PERF.md
-    §6 has the table). At ``[8, 12, 4096, 64]`` the forward and dq are fastest
-    at 512 x 512 (5.05 / 5.55 ms a call; 5.27 / 5.69 at 1024 x 1024), dkv at
-    1024 x 1024 (7.92; 8.79 at 512 x 512): smaller blocks skip more of the
-    masked half (44% of block pairs at 512, 37.5% at 1024) but every loop trip
-    pays for its carried accumulators, and a 256-wide k-block makes the
-    forward's per-row rescale a quarter of its work. At ``[32, 12, 1024, 64]``
-    nothing beats one 1024 x 1024 block pair, which lowers to straight-line
-    code (1.98 / 2.76 / 3.56 ms; the best split, 512 x 512, 2.62 / 2.85 /
-    3.95). Non-causal calls have nothing to skip and keep 1024 x 1024. Past
-    T = 4096 the whole-T slabs a kernel holds in VMEM leave no room for
-    1024 x 1024 float32 tiles (refused by Mosaic at 8192; the shapes below
-    compile there, unmeasured), and at 16,384 the slabs alone are refused."""
+    Measured on the TPU v5e (``scripts/flash_block_sweep.py``: bf16, head dim
+    64, causal, each kernel alone over ``{256, 512, 1024}^2``, ms a call;
+    forward PR 26, backward PR 30; PERF.md §6 has the tables):
+
+    ==================  ===========  ===========  ===========  =================
+    shape               512 x 512    1024 x 512   1024 x 1024  dq + dkv (PR 29)
+    ==================  ===========  ===========  ===========  =================
+    fwd [8,12,4096,64]  **5.06**     5.57         5.26         —
+    bwd [8,12,4096,64]  **8.90**     9.00         9.13         5.56 + 7.93
+    fwd [32,12,1024,64] 2.62         2.75         **1.98**     —
+    bwd [32,12,1024,64] 4.24         4.78         **4.21**     2.75 + 3.56
+    fwd [4,12,8192,64]  **8.30**     8.87         refused      —
+    bwd [4,12,8192,64]  15.07        14.77        **14.71**    9.15 + 13.84
+    ==================  ===========  ===========  ===========  =================
+
+    Smaller blocks skip more of the masked half (44% of block pairs at 512,
+    37.5% at 1024, of T=4096) but every loop trip pays for its carried
+    accumulators, and a 256-wide block on either side loses everywhere (the
+    forward's per-row rescale becomes a quarter of its work; the backward
+    reads 9.3-14.0 at T=4096). At T=1024 nothing beats one 1024 x 1024 block
+    pair, which lowers to straight-line code. Non-causal calls have nothing
+    to skip and keep 1024 x 1024. Past T = 4096 the whole-T K/V slabs leave
+    the forward no room for 1024 x 1024 float32 tiles under the compiler's
+    default limit (refused by Mosaic at 8192); the backward is compiled under
+    a limit of its own (:func:`_bwd_vmem_bytes`) and is fastest there at
+    1024 x 1024. At 16,384 the forward's slabs alone are refused."""
     t = max(t_q, t_k)
     if t > 4096:
-        return (1024, 512) if kernel == "dkv" else (512, 512)
-    if causal and t > 1024 and kernel != "dkv":
+        return (1024, 1024) if kernel == "bwd" else (512, 512)
+    if causal and t > 1024:
         return 512, 512
     return 1024, 1024
 
@@ -110,7 +128,7 @@ def _block_size(block: int, t: int) -> int:
 
 
 def _resolve_blocks(kernel, block_q, block_k, t_q, t_k, causal) -> tuple[int, int]:
-    """The ``(bq, bk)`` one kernel (``"fwd"``, ``"dq"``, ``"dkv"``) runs at: a
+    """The ``(bq, bk)`` one kernel (``"fwd"``, ``"bwd"``) runs at: a
     caller's explicit size wins, else the shape rule's; both clamped to T."""
     rule_q, rule_k = _flash_blocks(kernel, t_q, t_k, causal)
     return _block_size(block_q or rule_q, t_q), _block_size(block_k or rule_k, t_k)
@@ -118,10 +136,16 @@ def _resolve_blocks(kernel, block_q, block_k, t_q, t_k, causal) -> tuple[int, in
 
 def flash_block_plan(t_q, t_k, causal, block_q=None, block_k=None) -> dict:
     """What the flash path does at these shapes, for the ``kernel_dispatch``
-    record: the forward's block shape and :func:`flash_block_counts` at it."""
+    record: the forward's block shape and :func:`flash_block_counts` at it,
+    and which backward the custom VJP takes (``"fused"``: dq, dk and dv from
+    one kernel, the only one there is since ISSUE 30) at which block shape."""
     bq, bk = _resolve_blocks("fwd", block_q, block_k, t_q, t_k, causal)
     total, computed = flash_block_counts(t_q, t_k, bq, bk, causal)
-    return {"block_q": bq, "block_k": bk, "blocks_total": total, "blocks_computed": computed}
+    bwd_q, bwd_k = _resolve_blocks("bwd", block_q, block_k, t_q, t_k, causal)
+    return {
+        "block_q": bq, "block_k": bk, "blocks_total": total, "blocks_computed": computed,
+        "backward": "fused", "bwd_block_q": bwd_q, "bwd_block_k": bwd_k,
+    }
 
 
 def _pad_to(x: jax.Array, size: int, axis: int) -> jax.Array:
@@ -168,7 +192,7 @@ def _q_blocks_start(ki, bq: int, bk: int):
 def flash_block_counts(t_q: int, t_k: int, bq: int, bk: int, causal: bool) -> tuple[int, int]:
     """``(blocks_total, blocks_computed)``: the ``[bq, bk]`` block pairs of one
     (batch, head)'s ``T_q x T_k`` score square, and how many of them the
-    forward kernel visits (the backward kernels visit the same pairs at their
+    forward kernel visits (the backward kernel visits the same pairs at its
     own block shape)."""
     n_q, n_k = pl.cdiv(t_q, bq), pl.cdiv(t_k, bk)
     total = n_q * n_k
@@ -251,55 +275,28 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, block_k, seq_len,
 # ---------------------------------------------------------------------------
 
 
-def _bwd_dq_kernel(
-    q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
-    *, scale, block_k, seq_len, causal, n_q,
+def _bwd_kernel(
+    q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, dk_ref, dv_ref, *scratch,
+    scale, block_q, seq_len, causal, n_k,
 ):
-    """dq for one q-block: dq_i = scale * sum_j (p_ij * (dp_ij - delta_i)) k_j,
-    over the k-blocks the q-block can see."""
-    bq = q_ref.shape[2]
-    d = q_ref.shape[3]
-    t_pad = k_ref.shape[2]
-    n_k = t_pad // block_k
-    qi = _grid_index(2, n_q)
+    """dq, dk and dv for one k-block, looping over the q-blocks that can see it;
+    s, the mask, p and dp are computed once a block pair and feed all three:
+    dv_j = sum_i p_ij^T do_i ; dk_j = scale * sum_i ds_ij^T q_i ;
+    dq_i = scale * sum_j ds_ij k_j, with ds_ij = p_ij * (dp_ij - delta_i).
 
-    q = q_ref[0, 0]
-    do = do_ref[0, 0]  # [bq, D]
-    lse = jnp.transpose(lse_ref[0, 0], (1, 0))  # [1, bq] -> [bq, 1]
-    delta = jnp.transpose(delta_ref[0, 0], (1, 0))
-    q_idx = qi * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, block_k), 0)
+    The tiles are held transposed, ``[bk, bq]`` (s^T = k q^T, dp^T = v dO^T):
+    the statistics are then the ``[1, bq]`` lane-major rows they are stored
+    as, dv and dk are plain products, and only dq's contracts a tile over its
+    rows. (The other way up — ``[bq, bk]`` tiles, as the two kernels this one
+    replaced held them — transposes lse and delta every pair and a tile in two
+    of the three products: 6-13% slower at T=4096, PERF.md §6, PR 30.)
 
-    def body(j, dq):
-        k = k_ref[0, 0, pl.ds(j * block_k, block_k), :]
-        v = v_ref[0, 0, pl.ds(j * block_k, block_k), :]
-        s = scale * jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )
-        k_idx = j * block_k + jax.lax.broadcasted_iota(jnp.int32, (bq, block_k), 1)
-        mask = k_idx < seq_len
-        if causal:
-            mask = jnp.logical_and(mask, q_idx >= k_idx)
-        s = jnp.where(mask, s, NEG_INF)
-        p = jnp.exp(s - lse)  # [bq, bk]
-        dp = jax.lax.dot_general(
-            do, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )
-        ds = (p * (dp - delta)).astype(k.dtype)
-        return dq + jax.lax.dot_general(
-            ds, k, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-        )
-
-    end = _k_blocks_end(qi, bq, block_k, n_k) if causal else n_k
-    dq = jax.lax.fori_loop(0, end, body, jnp.zeros((bq, d), jnp.float32))
-    dq_ref[0, 0] = (dq * scale).astype(dq_ref.dtype)
-
-
-def _bwd_dkv_kernel(
-    q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_ref,
-    *, scale, block_q, seq_len, causal, n_k,
-):
-    """dk/dv for one k-block, looping over the q-blocks that can see it:
-    dv_j = sum_i p_ij^T do_i ; dk_j = scale * sum_i (p_ij * (dp_ij - delta_i))^T q_i."""
+    dq's sum runs over the grid's k-block axis, which is therefore sequential:
+    a float32 ``[T_q, D]`` scratch slab is zeroed at k-block 0, added to by
+    rows inside the loop, and scaled, cast and written to the resident dq
+    block once, after the last k-block (ascending j, every addition float32).
+    Where the axis has one block there is nothing to sum and no scratch: the
+    rows go straight to ``dq_ref``."""
     bk = k_ref.shape[2]
     d = k_ref.shape[3]
     t_pad = q_ref.shape[2]
@@ -308,37 +305,51 @@ def _bwd_dkv_kernel(
 
     k = k_ref[0, 0]  # [bk, D]
     v = v_ref[0, 0]
-    k_idx = ki * bk + jax.lax.broadcasted_iota(jnp.int32, (block_q, bk), 1)
+    k_idx = ki * bk + jax.lax.broadcasted_iota(jnp.int32, (bk, block_q), 0)
+    if n_k > 1:
+        (dq_acc,) = scratch
+
+        @pl.when(ki == 0)
+        def _():
+            dq_acc[...] = jnp.zeros(dq_acc.shape, jnp.float32)
 
     def body(i, carry):
         dk, dv = carry
-        q = q_ref[0, 0, pl.ds(i * block_q, block_q), :]
-        do = do_ref[0, 0, pl.ds(i * block_q, block_q), :]
-        lse = jnp.transpose(lse_ref[0, 0, :, pl.ds(i * block_q, block_q)], (1, 0))
-        delta = jnp.transpose(delta_ref[0, 0, :, pl.ds(i * block_q, block_q)], (1, 0))
-        s = scale * jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )  # [bq, bk] f32
-        q_idx = i * block_q + jax.lax.broadcasted_iota(jnp.int32, (block_q, bk), 0)
+        rows = pl.ds(i * block_q, block_q)
+        q = q_ref[0, 0, rows, :]  # [bq, D]
+        do = do_ref[0, 0, rows, :]
+        lse = lse_ref[0, 0, :, rows]  # [1, bq]
+        delta = delta_ref[0, 0, :, rows]
+        st = scale * jax.lax.dot_general(
+            k, q, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        )  # [bk, bq] f32
+        q_idx = i * block_q + jax.lax.broadcasted_iota(jnp.int32, (bk, block_q), 1)
         mask = k_idx < seq_len
         if causal:
             mask = jnp.logical_and(mask, q_idx >= k_idx)
-        s = jnp.where(mask, s, NEG_INF)
-        # [bq, bk]. Padded q rows (zero q, zero-padded lse) give s=0, lse=0,
-        # p=1 — NOT p=0. Their dv/dk contributions still vanish only because
-        # dO and delta are zero-padded (dv += p^T·dO = 0; ds = p*(dp-delta)
-        # has dp = dO·v^T = 0 and delta = 0). Keep the dO/delta zero-padding.
-        p = jnp.exp(s - lse)
+        st = jnp.where(mask, st, NEG_INF)
+        # [bk, bq]. Padded q rows (zero q, zero-padded lse) give s=0, lse=0,
+        # p=1 — NOT p=0. Their contributions still vanish only because dO and
+        # delta are zero-padded (dv += p^T·dO = 0; ds = p*(dp-delta) has
+        # dp = dO·v^T = 0 and delta = 0). Keep the dO/delta zero-padding.
+        pt = jnp.exp(st - lse)
         dv_new = dv + jax.lax.dot_general(
-            p.astype(do.dtype), do, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
+            pt.astype(do.dtype), do, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
         )  # [bk, D]
-        dp = jax.lax.dot_general(
-            do, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )  # [bq, bk]
-        ds = (p * (dp - delta)).astype(q.dtype)
+        dpt = jax.lax.dot_general(
+            v, do, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        )  # [bk, bq]
+        dst = (pt * (dpt - delta)).astype(q.dtype)
         dk_new = dk + jax.lax.dot_general(
-            ds, q, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
+            dst, q, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
         )  # [bk, D]
+        dq = jax.lax.dot_general(
+            dst, k, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
+        )  # [bq, D]: this pair's share of dq's rows
+        if n_k > 1:
+            dq_acc[rows, :] += dq
+        else:
+            dq_ref[0, 0, rows, :] = (dq * scale).astype(dq_ref.dtype)
         return dk_new, dv_new
 
     dk0 = jnp.zeros((bk, d), jnp.float32)
@@ -348,6 +359,11 @@ def _bwd_dkv_kernel(
     dk, dv = jax.lax.fori_loop(start, n_q, body, (dk0, dv0))
     dk_ref[0, 0] = (dk * scale).astype(dk_ref.dtype)
     dv_ref[0, 0] = dv.astype(dv_ref.dtype)
+    if n_k > 1:
+
+        @pl.when(ki == n_k - 1)
+        def _():
+            dq_ref[0, 0] = (dq_acc[...] * scale).astype(dq_ref.dtype)
 
 
 # ---------------------------------------------------------------------------
@@ -369,9 +385,10 @@ def _fwd_call(qt, kt, vt, t_k, causal, bq, bk, interpret):
     path (Tq from the resident shard, Tk from the visiting block).
 
     Each kernel of this file is called under a stable ``name=`` inside a
-    ``jax.named_scope`` of the same name (``flash_fwd``, ``flash_dq``,
-    ``flash_dkv``, ``conv1x1_bn_act``): the kernel's Python name reaches no
-    device trace on today's runtime, and a reader of one matches these."""
+    ``jax.named_scope`` of the same name (``flash_fwd``, ``flash_dqkv``,
+    ``conv1x1_bn_act``): the kernel's Python name reaches no device trace on
+    today's runtime, and a reader of one matches these (the backward's holds
+    ``flash_dq``, which the benchmark's ``flash_bwd_time_share`` looks for)."""
     b, h, tq_pad, d = qt.shape
     tk_pad = kt.shape[2]
     kernel = functools.partial(
@@ -409,82 +426,73 @@ def _fwd_impl(q, k, v, causal, block_q, block_k, interpret, valid_len=None):
     return o[:, :, :t, :], lse[:, :, :, :t], (qt, kt, vt)
 
 
-def _dq_call(qt, kt, vt, do, lse_p, delta, t_q, t_k, causal, bq, bk, interpret):
-    """dq pallas call on padded [B, H, T*, D] operands. ``t_k`` masks padded
-    K rows; ``t_q`` is unused by the kernel (padded q rows produce garbage dq
-    rows that callers slice off) but kept for call-site clarity."""
-    b, h, tq_pad, d = qt.shape
-    tk_pad = kt.shape[2]
-    dq_kernel = functools.partial(
-        _bwd_dq_kernel, scale=d**-0.5, block_k=bk, seq_len=t_k, causal=causal, n_q=tq_pad // bq
-    )
-    call = pl.pallas_call(
-        dq_kernel,
-        grid=(b, h, tq_pad // bq),
-        in_specs=[
-            pl.BlockSpec((1, 1, bq, d), lambda bi, hi, qi: (bi, hi, qi, 0)),
-            pl.BlockSpec((1, 1, tk_pad, d), lambda bi, hi, qi: (bi, hi, 0, 0)),
-            pl.BlockSpec((1, 1, tk_pad, d), lambda bi, hi, qi: (bi, hi, 0, 0)),
-            pl.BlockSpec((1, 1, bq, d), lambda bi, hi, qi: (bi, hi, qi, 0)),
-            pl.BlockSpec((1, 1, 1, bq), lambda bi, hi, qi: (bi, hi, 0, qi)),
-            pl.BlockSpec((1, 1, 1, bq), lambda bi, hi, qi: (bi, hi, 0, qi)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, bq, d), lambda bi, hi, qi: (bi, hi, qi, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, h, tq_pad, d), qt.dtype),
-        interpret=interpret,
-        name="flash_dq",
-    )
-    with jax.named_scope("flash_dq"):
-        return call(qt, kt, vt, do, lse_p, delta)
+def _bwd_vmem_bytes(tq_pad: int, bq: int, bk: int, d: int, itemsize: int) -> int:
+    """The scoped-VMEM limit the fused backward is compiled under, reckoned
+    from its shapes as an upper bound on what it holds: every pipelined block
+    double-buffered and padded to the 128-lane tile (the q / dO / dq whole-T
+    slabs, the two statistics rows on 8 sublanes, the k / v / dk / dv
+    blocks), dq's float32 scratch slab, and six float32 ``[bq, bk]`` tiles
+    for s, p, dp, ds and their cast and transposed copies. Mosaic's own count
+    is a half to a third of this (binary search on the limit, compiled for a
+    described v5e: 10.2 MiB at T=4096 and 12.2 at 8192 with 1024 x 1024
+    tiles and D=64, 18.4 at T=4096 and D=128, where the reckoning gives
+    34.5 / 43 / 34.5), so the compiler's 16 MiB default would refuse D=128
+    at that tile and nothing else a caller has today. Never under that
+    default: the limit is a ceiling, not a reservation."""
+    lanes = pl.cdiv(d, 128) * 128
+    slabs = 3 * 2 * tq_pad * lanes * itemsize + tq_pad * lanes * 4 + 2 * 2 * 8 * tq_pad * 4
+    blocks = 4 * 2 * bk * lanes * itemsize
+    tiles = 6 * bq * bk * 4
+    return max(16 * 2**20, slabs + blocks + tiles)
 
 
-def _dkv_call(qt, kt, vt, do, lse_p, delta, t_q, t_k, causal, bq, bk, interpret):
-    """dk/dv pallas call on padded [B, H, T*, D] operands. Padded q rows are
-    harmless because ``do``/``delta`` are zero-padded (see _bwd_dkv_kernel);
-    ``t_k`` masks padded K rows."""
+def _bwd_call(qt, kt, vt, do, lse_p, delta, t_k, causal, bq, bk, interpret):
+    """Backward pallas call on padded [B, H, T*, D] operands -> (dq, dk, dv).
+    Padded q rows are harmless because ``do``/``delta`` are zero-padded (see
+    _bwd_kernel); ``t_k`` masks padded K rows."""
     b, h, tq_pad, d = qt.shape
     tk_pad = kt.shape[2]
-    dkv_kernel = functools.partial(
-        _bwd_dkv_kernel, scale=d**-0.5, block_q=bq, seq_len=t_k, causal=causal, n_k=tk_pad // bk
+    n_k = tk_pad // bk
+    kernel = functools.partial(
+        _bwd_kernel, scale=d**-0.5, block_q=bq, seq_len=t_k, causal=causal, n_k=n_k
     )
+    slab = pl.BlockSpec((1, 1, tq_pad, d), lambda bi, hi, ki: (bi, hi, 0, 0))
+    stat = pl.BlockSpec((1, 1, 1, tq_pad), lambda bi, hi, ki: (bi, hi, 0, 0))
+    block = pl.BlockSpec((1, 1, bk, d), lambda bi, hi, ki: (bi, hi, ki, 0))
     call = pl.pallas_call(
-        dkv_kernel,
-        grid=(b, h, tk_pad // bk),
-        in_specs=[
-            pl.BlockSpec((1, 1, tq_pad, d), lambda bi, hi, ki: (bi, hi, 0, 0)),
-            pl.BlockSpec((1, 1, bk, d), lambda bi, hi, ki: (bi, hi, ki, 0)),
-            pl.BlockSpec((1, 1, bk, d), lambda bi, hi, ki: (bi, hi, ki, 0)),
-            pl.BlockSpec((1, 1, tq_pad, d), lambda bi, hi, ki: (bi, hi, 0, 0)),
-            pl.BlockSpec((1, 1, 1, tq_pad), lambda bi, hi, ki: (bi, hi, 0, 0)),
-            pl.BlockSpec((1, 1, 1, tq_pad), lambda bi, hi, ki: (bi, hi, 0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, 1, bk, d), lambda bi, hi, ki: (bi, hi, ki, 0)),
-            pl.BlockSpec((1, 1, bk, d), lambda bi, hi, ki: (bi, hi, ki, 0)),
-        ],
+        kernel,
+        grid=(b, h, n_k),
+        in_specs=[slab, block, block, slab, stat, stat],
+        out_specs=[slab, block, block],
         out_shape=[
+            jax.ShapeDtypeStruct((b, h, tq_pad, d), qt.dtype),
             jax.ShapeDtypeStruct((b, h, tk_pad, d), kt.dtype),
             jax.ShapeDtypeStruct((b, h, tk_pad, d), vt.dtype),
         ],
+        scratch_shapes=[pltpu.VMEM((tq_pad, d), jnp.float32)] if n_k > 1 else [],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=_bwd_vmem_bytes(tq_pad, bq, bk, d, qt.dtype.itemsize),
+        ),
         interpret=interpret,
-        name="flash_dkv",
+        name="flash_dqkv",
     )
-    with jax.named_scope("flash_dkv"):
+    with jax.named_scope("flash_dqkv"):
         return call(qt, kt, vt, do, lse_p, delta)
 
 
-def _bwd_call(kernel, operands, t_q, t_k, seq_len, causal, block_q, block_k, interpret):
-    """One backward kernel (``"dq"`` or ``"dkv"``) at its own block shape:
-    the ``[B, H, T*, D]`` q/k/v/dO and ``[B, H, 1, T*]`` lse/delta, zero-padded
-    or not, are fitted to whole blocks of it (zeros on dO / delta, as
-    _bwd_dkv_kernel needs)."""
-    bq, bk = _resolve_blocks(kernel, block_q, block_k, t_q, t_k, causal)
+def _bwd_impl(operands, t_q, t_k, seq_len, causal, block_q, block_k, interpret):
+    """The backward kernel at its own block shape: the ``[B, H, T*, D]``
+    q/k/v/dO and ``[B, H, 1, T*]`` lse/delta, zero-padded or not, are fitted
+    to whole blocks of it (zeros on dO / delta, as _bwd_kernel needs)
+    -> padded (dq, dk, dv)."""
+    bq, bk = _resolve_blocks("bwd", block_q, block_k, t_q, t_k, causal)
     tq_pad, tk_pad = pl.cdiv(t_q, bq) * bq, pl.cdiv(t_k, bk) * bk
     qt, kt, vt, do, lse, delta = operands
-    return {"dq": _dq_call, "dkv": _dkv_call}[kernel](
+    return _bwd_call(
         _fit(qt, tq_pad, 2), _fit(kt, tk_pad, 2), _fit(vt, tk_pad, 2),
         _fit(do, tq_pad, 2), _fit(lse, tq_pad, 3), _fit(delta, tq_pad, 3),
-        t_q, seq_len, causal, bq, bk, interpret,
+        seq_len, causal, bq, bk, interpret,
     )
 
 
@@ -509,10 +517,9 @@ def _flash_bwd(causal, block_q, block_k, interpret, valid_len, res, g):
     do = _to_bhtd(g)
     # delta_i = rowsum(dO_i * O_i) — tiny elementwise precompute, plain XLA.
     delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)[:, :, None, :]
-    # The residuals are padded to the forward's blocks; _bwd_call refits them.
+    # The residuals are padded to the forward's blocks; _bwd_impl refits them.
     operands = (qt, kt, vt, do, lse, delta)
-    dq = _bwd_call("dq", operands, t, t, t_k, causal, block_q, block_k, interpret)
-    dk, dv = _bwd_call("dkv", operands, t, t, t_k, causal, block_q, block_k, interpret)
+    dq, dk, dv = _bwd_impl(operands, t, t, t_k, causal, block_q, block_k, interpret)
 
     return (
         _from_bhtd(dq[:, :, :t, :]),
@@ -531,7 +538,7 @@ _flash.defvjp(_flash_fwd, _flash_bwd)
 # The ring path differentiates at the RING level (one custom VJP around the
 # whole rotation schedule), so these wrappers are plain functions: the forward
 # returns the per-block (normalized o, lse) the online merge consumes, and the
-# backward wrappers compute one block's dq / dk/dv contributions given the
+# backward wrapper computes one block's dq / dk / dv contributions given the
 # *global* lse/delta of the resident q shard — exactly the flash
 # decomposition, applied blockwise across devices. All take/return
 # ``[B, T, H, D]`` (lse/delta ``[B, H, T]``).
@@ -569,8 +576,7 @@ def flash_block_bwd(
     interpret = resolve_interpret(interpret)
     tq, tk = q.shape[1], k.shape[1]
     operands = (*map(_to_bhtd, (q, k, v, do)), lse[:, :, None, :], delta[:, :, None, :])
-    dq = _bwd_call("dq", operands, tq, tk, tk, causal, block_q, block_k, interpret)
-    dk, dv = _bwd_call("dkv", operands, tq, tk, tk, causal, block_q, block_k, interpret)
+    dq, dk, dv = _bwd_impl(operands, tq, tk, tk, causal, block_q, block_k, interpret)
     return (
         _from_bhtd(dq[:, :, :tq, :]),
         _from_bhtd(dk[:, :, :tk, :]),
@@ -594,7 +600,7 @@ def flash_attention(
     Numerics match ``models.vit.dot_product_attention`` (softmax statistics in
     float32, scale ``D**-0.5``); memory is O(T) per (batch, head) instead of
     the O(T^2) score tensor. ``block_q`` / ``block_k`` set one block shape for
-    all three kernels; left ``None``, each kernel takes the shape
+    both kernels; left ``None``, each kernel takes the shape
     :func:`_flash_blocks` gives it. ``interpret=None`` auto-selects
     (:func:`resolve_interpret`). ``valid_len`` masks key
     positions >= it — for caller-padded sequences (``ViT.pad_seq_to``); the

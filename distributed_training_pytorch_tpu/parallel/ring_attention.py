@@ -153,7 +153,7 @@ def _ring_attention_flash(q, k, v, mesh, *, axis, causal):
     Backward: the standard blockwise flash decomposition, run as a second
     ring. ``delta = rowsum(dO * O)`` and the final LSE are global per-q-row
     statistics, so each visiting K/V block's (dq, dk, dv) contributions are
-    computable locally by the flash backward kernels; dq accumulates in place
+    computable locally by the flash backward kernel; dq accumulates in place
     while dk/dv accumulate on buffers that rotate *with* their K/V blocks and
     arrive home after a full loop. A custom VJP around the two shard_maps
     owns the schedule (autodiff never sees the kernel internals).

@@ -1,15 +1,17 @@
 #!/usr/bin/env python
-"""Block-shape sweep of the three flash kernels, each alone (ISSUE 26).
+"""Block-shape sweep of the flash kernels, each alone (ISSUE 26, ISSUE 30).
 
-Times ``flash_fwd`` / ``flash_dq`` / ``flash_dkv`` (``ops/pallas.py``) at the
-LM cells' shapes for every ``(block_q, block_k)`` of a small grid and prints
-one JSON line per measurement; the table in PERF.md §6 (PR 26) and the rule in
-``ops.pallas._flash_blocks`` come from it. TPU only::
+Times ``flash_fwd`` and the fused backward ``flash_dqkv`` (``ops/pallas.py``)
+at the LM cells' shapes for every ``(block_q, block_k)`` of a small grid and
+prints one JSON line per measurement; the tables in PERF.md §6 (PR 26, PR 30)
+and the rule in ``ops.pallas._flash_blocks`` come from it. TPU only::
 
     chiprun --chips 1 -- python scripts/flash_block_sweep.py [--parent build/parent]
 
 ``--parent DIR`` also times the kernels of the checkout unpacked at DIR (the
-parent commit) at its own 1024 x 1024 blocks, for the before / after columns.
+parent commit), each at the block shape that checkout's own rule gives it, for
+the before / after columns: a checkout from before ISSUE 30 has two backward
+kernels, ``dq`` and ``dkv``, whose sum is what ``bwd`` replaced.
 ``--compile-only`` compiles every point for a described v5e and times nothing
 (runs without a chip: what Mosaic refuses there it refuses on the chip).
 """
@@ -29,7 +31,8 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 import jax
 import jax.numpy as jnp
 
-SHAPES = {"t4096_b8": (8, 12, 4096, 64), "t1024_b32": (32, 12, 1024, 64)}
+SHAPES = {"t4096_b8": (8, 12, 4096, 64), "t1024_b32": (32, 12, 1024, 64), "t8192_b4": (4, 12, 8192, 64)}
+CELL_SHAPES = "t4096_b8,t1024_b32"
 BLOCKS = (256, 512, 1024)
 
 
@@ -42,23 +45,30 @@ def load_pallas(root):
     return mod
 
 
-def kernel_fns(mod, shape, causal, bq, bk):
-    """``{kernel: (fn, args)}`` on padded ``[B, H, T, D]`` bf16 operands."""
+def kernel_fns(mod, shape, causal, blocks):
+    """``{kernel: (fn, args, (bq, bk))}`` on padded ``[B, H, T, D]`` bf16
+    operands: the kernels the checkout behind ``mod`` has, all at ``blocks``
+    or, where that is None, each at the shape the checkout's own rule gives."""
     b, h, t, d = shape
     q = k = v = do = jax.ShapeDtypeStruct(shape, jnp.bfloat16)
     stat = jax.ShapeDtypeStruct((b, h, 1, t), jnp.float32)
+    grads = (q, k, v, do, stat, stat)
 
-    def fwd(q, k, v):
-        return mod._fwd_call(q, k, v, t, causal, bq, bk, False)
+    def at(kernel):
+        return blocks or mod._flash_blocks(kernel, t, t, causal)
 
-    def dq(q, k, v, do, lse, delta):
-        return mod._dq_call(q, k, v, do, lse, delta, t, t, causal, bq, bk, False)
+    # kernel -> (the checkout's call, its operands, what it takes between them and the blocks)
+    calls = {"fwd": ("_fwd_call", (q, k, v), (t, causal))}
+    if hasattr(mod, "_dq_call"):  # a checkout from before ISSUE 30
+        calls.update(dq=("_dq_call", grads, (t, t, causal)), dkv=("_dkv_call", grads, (t, t, causal)))
+    else:
+        calls["bwd"] = ("_bwd_call", grads, (t, causal))
 
-    def dkv(q, k, v, do, lse, delta):
-        return mod._dkv_call(q, k, v, do, lse, delta, t, t, causal, bq, bk, False)
+    def bind(kernel, call, between):
+        return lambda *operands: getattr(mod, call)(*operands, *between, *at(kernel), False)
 
-    return {"fwd": (fwd, (q, k, v)), "dq": (dq, (q, k, v, do, stat, stat)),
-            "dkv": (dkv, (q, k, v, do, stat, stat))}
+    return {kernel: (bind(kernel, call, between), operands, at(kernel))
+            for kernel, (call, operands, between) in calls.items()}
 
 
 def materialize(args):
@@ -84,7 +94,7 @@ def main():
     ap.add_argument("--parent", default=None)
     ap.add_argument("--compile-only", action="store_true")
     ap.add_argument("--iters", type=int, default=20)
-    ap.add_argument("--shapes", default=",".join(SHAPES))
+    ap.add_argument("--shapes", default=CELL_SHAPES, help=f"of {','.join(SHAPES)}")
     ap.add_argument("--out", default="chiprun_out/flash_block_sweep.jsonl")
     args = ap.parse_args()
 
@@ -107,11 +117,11 @@ def main():
         for name in args.shapes.split(","):
             shape = SHAPES[name]
             for side, mod in sides:
-                grid = [(1024, 1024)] if side == "parent" else [(bq, bk) for bq in BLOCKS for bk in BLOCKS]
-                for bq, bk in grid:
-                    if bq > shape[2] or bk > shape[2]:
-                        continue
-                    for kernel, (fn, specs) in kernel_fns(mod, shape, True, bq, bk).items():
+                grid = [None] if side == "parent" else [(bq, bk) for bq in BLOCKS for bk in BLOCKS]
+                for blocks in grid:
+                    for kernel, (fn, specs, (bq, bk)) in kernel_fns(mod, shape, True, blocks).items():
+                        if bq > shape[2] or bk > shape[2]:
+                            continue
                         row = {"shape": name, "side": side, "kernel": kernel, "block_q": bq, "block_k": bk}
                         try:
                             if sharding is not None:

@@ -47,7 +47,16 @@ CASES = [
     (1, 300, 2, 32, True, 128, 128),  # padding and diagonal in the same last block
     (1, 300, 2, 32, False, 128, 128),  # non-causal: every block, the last one padded
 ]
-GRAD_CASES = CASES[:1] + CASES[2:3] + CASES[4:]
+# The fused backward (ISSUE 30): dq is summed over the grid's k-block axis in
+# a scratch slab where that axis has several blocks, written straight out
+# where it has one.
+BWD_CASES = [
+    (1, 520, 2, 32, True, 256, 128),  # bq != bk, T a multiple of neither, 5 k-blocks
+    (2, 384, 2, 32, False, 256, 128),  # non-causal, bq != bk: every row of the slab from every k-block
+    (1, 512, 2, 64, True, 128, 512),  # one k-block, four q-blocks: no scratch, rows stored from the loop
+    (1, 512, 2, 64, True, 512, 128),  # one q-block, four k-blocks: the whole slab added to each grid step
+]
+GRAD_CASES = CASES[:1] + CASES[2:3] + CASES[4:] + BWD_CASES
 
 
 @pytest.mark.parametrize("b,t,h,d,causal,block_q,block_k", CASES)
@@ -109,24 +118,35 @@ def test_causal_skip_never_reads_future_blocks():
     np.testing.assert_allclose(np.asarray(dq[:, :keep]), np.asarray(ref_dq), atol=2e-4)
 
 
-@pytest.mark.parametrize("tq,tk", [(384, 384), (256, 384)])
-def test_flash_block_entry_points_causal_multi_block(tq, tk):
+@pytest.mark.parametrize(
+    "tq,tk,causal,block_q,block_k",
+    [
+        (384, 384, True, 128, 128),
+        (256, 384, True, 128, 128),
+        # The fused backward (ISSUE 30) under the ring's shapes:
+        (384, 256, True, 256, 128),  # more rows than columns, bq != bk
+        (256, 384, True, 128, 256),  # a k-block seen by no row still closes dq's sum
+        (256, 384, False, 128, 128),  # a visible block: non-causal, Tq != Tk
+        (200, 300, False, 128, 128),  # neither side a multiple of its block
+    ],
+)
+def test_flash_block_entry_points_causal_multi_block(tq, tk, causal, block_q, block_k):
     """``flash_block_fwd`` / ``flash_block_bwd`` — the ring path's per-block
-    passes — against the reference with no mesh: causal, several blocks a
-    side, square and ``Tq != Tk`` (where the last k-block is seen by no row:
-    its dk / dv are exactly 0)."""
+    passes — against the reference with no mesh: causal (the diagonal block)
+    and not (a visible one), several blocks a side, square and ``Tq != Tk``
+    (where the last k-block is seen by no row: its dk / dv are exactly 0)."""
     from distributed_training_pytorch_tpu.ops.pallas import flash_block_bwd, flash_block_fwd
 
     rng = np.random.RandomState(8)
     q, g = (jnp.asarray(rng.randn(1, tq, 2, 32), jnp.float32) for _ in range(2))
     k, v = (jnp.asarray(rng.randn(1, tk, 2, 32), jnp.float32) for _ in range(2))
-    blocks = dict(causal=True, block_q=128, block_k=128, interpret=True)
+    blocks = dict(causal=causal, block_q=block_q, block_k=block_k, interpret=True)
 
     o, lse = flash_block_fwd(q, k, v, **blocks)
-    ref, ref_vjp = jax.vjp(lambda q, k, v: reference_attention(q, k, v, True), q, k, v)
+    ref, ref_vjp = jax.vjp(lambda q, k, v: reference_attention(q, k, v, causal), q, k, v)
     np.testing.assert_allclose(np.asarray(o), np.asarray(ref), atol=2e-5)
     logits = jnp.einsum("bqhd,bkhd->bhqk", q, k) * q.shape[-1] ** -0.5
-    mask = np.arange(tq)[:, None] >= np.arange(tk)[None, :]
+    mask = np.arange(tq)[:, None] >= np.arange(tk)[None, :] if causal else np.ones((tq, tk), bool)
     ref_lse = jax.nn.logsumexp(jnp.where(mask, logits, -jnp.inf), axis=-1)
     np.testing.assert_allclose(np.asarray(lse), np.asarray(ref_lse), atol=2e-5)
 
@@ -136,7 +156,7 @@ def test_flash_block_entry_points_causal_multi_block(tq, tk):
         np.testing.assert_allclose(
             np.asarray(got), np.asarray(want), atol=2e-4, err_msg=f"d{name}"
         )
-    if tq < tk:
+    if causal and tq < tk:
         assert not np.asarray(grads[1][:, tq:]).any() and not np.asarray(grads[2][:, tq:]).any()
 
 
@@ -166,8 +186,66 @@ def test_block_counts_match_a_brute_force_count_and_reach_the_record():
     finally:
         dispatch.reset()
     plan = flash_block_plan(512, 512, True, 128, 128)
-    assert plan == {"block_q": 128, "block_k": 128, "blocks_total": 16, "blocks_computed": 10}
+    assert plan == {"block_q": 128, "block_k": 128, "blocks_total": 16, "blocks_computed": 10,
+                    "backward": "fused", "bwd_block_q": 128, "bwd_block_k": 128}
     assert {k: rec[k] for k in plan} == plan
+
+
+def _pallas_calls(jaxpr):
+    """Names of every ``pallas_call`` in a jaxpr, sub-jaxprs included."""
+    names = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            names.append(eqn.params["name"])
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            names += _pallas_calls(sub)
+    return names
+
+
+@pytest.mark.parametrize(
+    "t,causal,valid_len",
+    [
+        (1024, True, None),  # gpt2s_t1024: one block pair
+        (4096, True, None),  # gpt2s_t4096: the shape the claim rests on
+        (8192, True, None),  # the longest T the forward compiles at
+        (256, False, 197),  # ViT-B's 197 in 256
+    ],
+)
+def test_backward_is_one_pallas_call(t, causal, valid_len):
+    """Traced, not run: the custom VJP's backward holds one ``pallas_call``
+    (``flash_dqkv``: dq, dk and dv from one pass over the block pairs) at
+    every T the forward compiles at, head dim 64; its name holds ``flash_dq``,
+    which ``benchmarks/metrics/flash_bwd_time_share.py`` matches."""
+    x = jax.ShapeDtypeStruct((1, t, 2, 64), jnp.bfloat16)
+
+    def bwd(q, k, v, g):
+        flash = lambda q, k, v: flash_attention(q, k, v, causal=causal, valid_len=valid_len)  # noqa: E731
+        return jax.vjp(flash, q, k, v)[1](g)
+
+    calls = _pallas_calls(jax.make_jaxpr(bwd)(x, x, x, x).jaxpr)
+    (backward,) = [name for name in calls if name != "flash_fwd"]  # besides the vjp's forward
+    assert calls.count("flash_fwd") == 1 and backward == "flash_dqkv", calls
+    assert any(pattern in backward for pattern in ("flash_dq", "flash_dkv"))  # the benchmark reader's two
+
+
+@pytest.mark.parametrize(
+    "t_q,t_k,causal,want",
+    [
+        (1024, 1024, True, (1024, 1024)),
+        (4096, 4096, True, (512, 512)),  # the benchmark's long cell
+        (8192, 8192, True, (1024, 1024)),  # the longest T the forward compiles at
+        (256, 256, False, (256, 256)),  # clamped to T
+        (2048, 512, False, (1024, 512)),  # a ring block: the shard's rows against a visiting block
+    ],
+)
+def test_block_plan_carries_the_backward(t_q, t_k, causal, want):
+    """``flash_block_plan`` (spread into the ``kernel_dispatch`` record) says
+    which backward the shapes get and at which block shape."""
+    from distributed_training_pytorch_tpu.ops.pallas import flash_block_plan
+
+    plan = flash_block_plan(t_q, t_k, causal)
+    assert plan["backward"] == "fused"
+    assert (plan["bwd_block_q"], plan["bwd_block_k"]) == want
 
 
 def test_default_attention_fn_selects_by_backend():
@@ -194,20 +272,29 @@ def test_mha_with_flash_kernel_matches_plain():
     np.testing.assert_allclose(np.asarray(out_flash), np.asarray(out_plain), atol=2e-5)
 
 
-def test_flash_valid_len_matches_masked_plain():
+@pytest.mark.parametrize(
+    "t,valid,block",
+    [
+        (24, 17, None),
+        (256, 197, None),  # ViT-B's 197 in pad_seq_to=256: one block pair
+        (300, 197, 128),  # several blocks: valid_len ends inside the second k-block, the third is all padding
+    ],
+)
+def test_flash_valid_len_matches_masked_plain(t, valid, block):
     """valid_len (caller-padded sequences) masks exactly like the plain
     path's key mask — outputs AND gradients, through the custom VJP."""
     from distributed_training_pytorch_tpu.models.vit import dot_product_attention
     from distributed_training_pytorch_tpu.ops.pallas import flash_attention
 
     rng = np.random.RandomState(5)
-    t, valid = 24, 17
     q = jnp.asarray(rng.randn(2, t, 4, 8), jnp.float32)
     k = jnp.asarray(rng.randn(2, t, 4, 8), jnp.float32)
     v = jnp.asarray(rng.randn(2, t, 4, 8), jnp.float32)
 
     def f_flash(q, k, v):
-        return flash_attention(q, k, v, valid_len=valid, interpret=True)
+        return flash_attention(
+            q, k, v, valid_len=valid, block_q=block, block_k=block, interpret=True
+        )
 
     def f_plain(q, k, v):
         return dot_product_attention(q, k, v, valid_len=valid)

@@ -388,7 +388,7 @@ def test_the_lowered_step_names_the_loss_head_and_the_optimizer(devices, monkeyp
     assert not any("optimizer" in n and "loss_head" in n for n in names)
 
 
-@pytest.mark.parametrize("kernel", ["flash_fwd", "flash_dq", "flash_dkv", "conv1x1_bn_act"])
+@pytest.mark.parametrize("kernel", ["flash_fwd", "flash_dqkv", "conv1x1_bn_act"])
 def test_each_kernel_is_lowered_under_its_name(kernel):
     """Lowered for the TPU (nothing compiles or runs): the Mosaic call carries
     `kernel_name`, and the op's location the scope of the same name."""

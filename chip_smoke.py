@@ -456,7 +456,7 @@ def _common_trainer_checks(smoke: Smoke, tag: str, trainer, events, devices, *,
     late = [e for e in events if e["event"] == "compile" and e.get("kind") != "mfu_probe"
             and e.get("epoch", start) != start]
     smoke.check(f"{tag}.nothing_compiles_after_warmup",
-                not late and trainer._late_compiles == 0, f"late compile events: {late}")
+                not late and trainer.run_telemetry.late_compiles == 0, f"late compile events: {late}")
     ends = [e for e in events if e["event"] == "epoch_end"]
     smoke.check(f"{tag}.losses_finite",
                 bool(ends) and all(e.get("loss") is not None and e["loss"] == e["loss"]
@@ -464,7 +464,7 @@ def _common_trainer_checks(smoke: Smoke, tag: str, trainer, events, devices, *,
                 f"epoch losses {[e.get('loss') for e in ends]}")
     smoke.check(f"{tag}.no_nonfinite_steps", trainer.nonfinite_steps == 0,
                 f"nonfinite_steps={trainer.nonfinite_steps}")
-    # The MFU probe (trainer.py:_maybe_probe_mfu) nets every exception into
+    # The MFU probe (telemetry/run.py:RunTelemetry.probe_flops) nets every exception into
     # a warning; the smoke turns that into a failure.
     probes = [e for e in events if e["event"] == "compile" and e.get("kind") == "mfu_probe"]
     smoke.check(f"{tag}.mfu_probe_produced_flops",

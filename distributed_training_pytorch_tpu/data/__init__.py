@@ -16,6 +16,7 @@ from distributed_training_pytorch_tpu.data.records import (  # noqa: F401
 from distributed_training_pytorch_tpu.data.prefetch import (  # noqa: F401
     device_prefetch,
     device_prefetch_chained,
+    epoch_units,
 )
 from distributed_training_pytorch_tpu.data.transforms import (  # noqa: F401
     IMAGENET_MEAN,

@@ -6,7 +6,9 @@ SURVEY.md §2e). Here transfers are issued from a background thread ``depth``
 batches ahead: ``jax.make_array_from_process_local_data`` starts the async
 H2D copy and XLA's scheduler overlaps it with the running step.
 
-Two staging modes share the same producer/consumer machinery:
+The trainer asks for an epoch's input through :func:`epoch_units` and sees
+nothing else of this module. Two staging modes share the same
+producer/consumer machinery:
 
 * :func:`device_prefetch` — one global batch per item (the single-step loop);
 * :func:`device_prefetch_chained` — chain-major: ``chain_steps`` consecutive
@@ -28,7 +30,7 @@ from __future__ import annotations
 import itertools
 import queue
 import threading
-from typing import Iterable, Iterator
+from typing import Callable, Iterable, Iterator
 
 import jax
 import numpy as np
@@ -197,3 +199,42 @@ def device_prefetch_chained(
             yield chain_steps, _stage(stack_and_put, window, mesh, ids, chain_steps)
 
     return _prefetched(staged(), depth)
+
+
+def epoch_units(
+    loader,
+    mesh: jax.sharding.Mesh,
+    *,
+    chain_steps: int,
+    skip_steps: int = 0,
+    preprocess: Callable[[dict], dict] | None = None,
+    epoch: int = 0,
+    first_unit: int = 0,
+) -> Iterator[tuple[int, dict]]:
+    """One epoch of ``loader`` as device-resident execution units ``(n,
+    batch)``: ``n == chain_steps`` a chain-stacked window, ``n == 1`` a plain
+    global batch (every unit when ``chain_steps == 1``; else the lead and the
+    tail, :func:`device_prefetch_chained`). The ring is built here, per call.
+
+    ``skip_steps``: batches a mid-epoch resume has already trained. They are
+    skipped at the loader's index level where it can (``iter_batches``: none
+    is read or decoded), else drained and dropped; under chaining the first
+    ``-skip_steps % chain_steps`` units are then singles, so that windows sit
+    at the same multiples of ``chain_steps`` as in an uninterrupted epoch.
+    ``preprocess`` runs on each host batch, on the producer's thread.
+    ``epoch`` and ``first_unit`` (the global step of the epoch's first batch)
+    are where the ``prefetch.stage`` spans' ids start counting."""
+    if skip_steps and hasattr(loader, "iter_batches"):
+        batches = loader.iter_batches(skip_steps)
+    elif skip_steps:
+        batches = itertools.islice(iter(loader), skip_steps, None)
+    else:
+        batches = iter(loader)
+    if preprocess is not None:
+        batches = (preprocess(b) for b in batches)
+    ids = {"epoch": epoch, "unit": first_unit + skip_steps, "batch": skip_steps}
+    if chain_steps > 1:
+        return device_prefetch_chained(
+            batches, mesh, chain_steps, lead_singles=-skip_steps % chain_steps, ids=ids
+        )
+    return ((1, b) for b in device_prefetch(batches, mesh, ids=ids))

@@ -533,11 +533,13 @@ class TrainEngine:
                 # into the per-step metrics. unroll=length: a rolled While
                 # body reads its per-step batch through a dynamic-slice whose
                 # layout can differ from the standalone step's input, and the
-                # conv wgrad reduction order shifts by 1 ULP with it
-                # (measured on CPU: 1 element of a VGG conv kernel after 4
-                # steps) — unrolled windows reproduce the single-step program
-                # bit-for-bit. Cost: compile time linear in `length`, the
-                # right trade at the 4-32 window sizes chaining targets.
+                # conv wgrad reduction order shifts with it. An unrolled
+                # window holds the single step's arithmetic, not its bits: on
+                # the CPU backend the two still differ by a few ULPs of a
+                # leaf's largest value (tests/test_engine.py:
+                # CHAINED_VS_SINGLE_ULPS; not checked on the TPU). Cost:
+                # compile time linear in `length`, the right trade at the
+                # 4-32 window sizes chaining targets.
                 return jax.lax.scan(
                     self._train_step_impl, st, sbatch, unroll=length
                 )

@@ -47,7 +47,6 @@ import dataclasses
 import functools
 import os
 import signal
-import threading
 import time
 from typing import Any, Mapping
 
@@ -63,17 +62,9 @@ from distributed_training_pytorch_tpu.checkpoint import (
     CheckpointManager,
     epoch_checkpoint_name,
 )
-from distributed_training_pytorch_tpu.data import (
-    ShardedLoader,
-    device_prefetch,
-    device_prefetch_chained,
-)
+from distributed_training_pytorch_tpu.data import ShardedLoader, epoch_units
 from distributed_training_pytorch_tpu.fault.watchdog import StepWatchdog
-from distributed_training_pytorch_tpu.memory import (
-    resolve_preflight,
-    run_preflight,
-    window_memory_fields,
-)
+from distributed_training_pytorch_tpu.memory import resolve_preflight, run_preflight
 from distributed_training_pytorch_tpu.parallel import elastic as elastic_lib
 from distributed_training_pytorch_tpu.parallel import mesh as mesh_lib
 from distributed_training_pytorch_tpu.precision import (
@@ -88,15 +79,8 @@ from distributed_training_pytorch_tpu.profiling import (
     resolve_profile,
 )
 from distributed_training_pytorch_tpu.resilience import AsyncCheckpointSaver
-from distributed_training_pytorch_tpu.telemetry import (
-    EventLog,
-    GoodputMeter,
-    resolve_telemetry,
-)
-from distributed_training_pytorch_tpu.telemetry.events import claim_attempt
-from distributed_training_pytorch_tpu.telemetry import doctor as telemetry_doctor
-from distributed_training_pytorch_tpu.telemetry import mfu as telemetry_mfu
-from distributed_training_pytorch_tpu.telemetry import straggler as straggler_lib
+from distributed_training_pytorch_tpu.telemetry import resolve_telemetry
+from distributed_training_pytorch_tpu.telemetry.run import RunTelemetry
 from distributed_training_pytorch_tpu.train import (
     NonFiniteLossError,
     TrainEngine,
@@ -183,9 +167,8 @@ class Trainer:
         self.seed = seed
         self.accum_steps = accum_steps
         self.num_workers = num_workers
-        # Host-side batch look-ahead (ShardedLoader window). Composes with
-        # the device-side device_prefetch(depth=2) ring in train_epoch: this
-        # bounds host decode-ahead, that bounds on-device staging.
+        # Host-side batch look-ahead (ShardedLoader window); the device-side
+        # ring (data.epoch_units, depth 2) bounds on-device staging.
         self.prefetch_batches = prefetch_batches
         self.log_every = log_every
         # The reference saves `last` every epoch (``trainer/trainer.py:163``)
@@ -194,26 +177,20 @@ class Trainer:
         # preemption saves still fire regardless.
         self.last_save_period = max(1, int(last_save_period))
         self.cur_epoch = 0
-        # Tracing knob: `profile=` (a profiling.ProfileConfig, or a trace-dir
-        # string; ISSUE 6, docs/profiling.md) traces a window of the REAL
-        # execution (chained windows included), analyzes it into a
-        # StepProfile (device-time attribution + dispatch-gap audit), and
-        # emits a `profile_capture` telemetry event — while keeping the run
-        # bit-exact and trace-count-identical with profile=None
-        # (test-enforced).
+        # `profile=` (a profiling.ProfileConfig or a trace-dir string;
+        # docs/profiling.md) traces a window of the REAL execution, chained
+        # windows included, and analyzes it into a StepProfile; the run's
+        # numbers and trace counts are those of profile=None.
         self.profile = resolve_profile(profile)
         if self.profile is not None and self.profile.dir is None:
             self.profile = dataclasses.replace(
                 self.profile, dir=os.path.join(save_folder, "profile")
             )
         self.progress = progress
-        # Preemption-aware checkpointing (SURVEY.md §5.3's named upgrade over
-        # the reference's manual-restart-only recovery): SIGTERM — what cloud
-        # schedulers send ahead of eviction, delivered to every host of the
-        # job — sets a flag the epoch loop polls; the loop then saves a
-        # resumable snapshot and returns cleanly. The handler itself only
-        # flips the flag (checkpoint saves are collective and must not run in
-        # signal context).
+        # SIGTERM (what cloud schedulers send ahead of eviction, to every
+        # host of the job) sets a flag the step loop polls; the loop then
+        # saves a resumable snapshot and returns. The handler only flips the
+        # flag: saves are collective and must not run in signal context.
         self._preempted = False
         self._epoch_interrupted = False
         self._prev_sigterm = None
@@ -223,12 +200,11 @@ class Trainer:
         # every` steps all hosts vote (one tiny allgather — the only intra-
         # epoch host sync besides log_every). 0 = epoch boundaries only.
         self.preemption_check_every = preemption_check_every
-        # Optional TensorBoard scalars (SURVEY §5.5 upgrade; process 0 only).
+        # Optional TensorBoard scalars (process 0 only).
         self.metrics_writer = MetricsWriter(tensorboard_dir)
 
-        # Graceful degradation (fault/ subsystem). nan_policy governs steps
-        # whose loss/grads go non-finite:
-        #   None                 — legacy behavior: train on, no guard;
+        # nan_policy governs steps whose loss/grads go non-finite:
+        #   None                 — train on, no guard;
         #   "raise"              — NonFiniteLossError at the next host sync
         #                          point (log_every / epoch end);
         #   "skip"               — the engine guard drops the update (params
@@ -244,17 +220,15 @@ class Trainer:
         self.nan_policy = nan_policy
         self.nonfinite_steps = 0
         self.nonfinite_rollbacks = 0
-        # Mixed precision (precision/ subsystem; docs/mixed_precision.md).
-        # `precision` names a dtype policy ("fp32" default — bit-exact with
-        # pre-precision behavior, test-enforced; "bf16" = fp32 master params
-        # + bf16 compute; "fp16" adds dynamic loss scaling automatically).
-        # `loss_scale` overrides the scaling choice ("dynamic" | "none" | a
-        # precision.DynamicScale/NoOpScale instance; None = policy default).
-        # Resolved BEFORE the build hooks so build_model can read
-        # self.model_dtype and match its activation dtype to the policy.
-        # precision_requested distinguishes an explicit precision="fp32" from
-        # an unset knob (the resolved Policy is identical) — entries with a
-        # legacy non-fp32 model default honor the explicit request.
+        # `precision` names a dtype policy (docs/mixed_precision.md: "fp32"
+        # default; "bf16" = fp32 master params + bf16 compute; "fp16" adds
+        # dynamic loss scaling). `loss_scale` overrides the scaling choice
+        # ("dynamic" | "none" | a precision.DynamicScale/NoOpScale instance;
+        # None = policy default). Resolved BEFORE the build hooks so that
+        # build_model can read self.model_dtype. precision_requested tells an
+        # explicit precision="fp32" from an unset knob (the resolved Policy is
+        # the same): entries whose model defaults to another dtype honor the
+        # explicit request.
         self.precision_requested = precision is not None
         self.precision = get_policy(precision)
         self._initial_loss_scale = resolve_loss_scale(loss_scale, self.precision)
@@ -292,15 +266,12 @@ class Trainer:
         self._watchdog_timeout = step_timeout
         # Deterministic fault injection (tests; None in production).
         self.fault_plan = fault_plan
-        # On-device chained execution (perf): windows of `chain_steps` train
-        # steps dispatch as ONE compiled program (engine.train_steps_chained),
-        # eliminating per-step host dispatch from the hot loop — the regime
-        # the bench's chained mode measures, now in real training. Per-step
-        # metrics come back as scan outputs so loss logging and nonfinite
-        # accounting stay exact; the epoch tail, the resume-realignment
-        # prefix, and any window with pending fault injections automatically
-        # fall back to single-step execution (bit-exact either way —
-        # test-enforced).
+        # Windows of `chain_steps` train steps dispatch as ONE compiled
+        # program (engine.train_steps_chained): no per-step host dispatch.
+        # Per-step metrics come back stacked, so loss logging and nonfinite
+        # accounting stay exact; the epoch tail, the realignment prefix after
+        # a resume, and any window with a fault pending run as single steps
+        # (the same arithmetic: tests/test_chained.py states how closely).
         self.chain_steps = int(chain_steps)
         self._validate_chain_config()
         # Mid-epoch resume position (set when restoring a preemption save's
@@ -308,14 +279,12 @@ class Trainer:
         self._resume_step_in_epoch = 0
         self._interrupted_at_step = 0
 
-        # Save folder layout: <save_folder>/weights/<name> (``:29-32``).
-        # Asynchrony lives in the resilience layer now (ISSUE 5), not in the
-        # manager: the manager commits synchronously (each save it runs is
-        # fully durable when the call returns), and `async_checkpoint=True`
-        # routes periodic/best saves through AsyncCheckpointSaver — a fast
-        # device->host snapshot on this thread, the staging+manifest+rename
-        # commit on a background thread. Preemption/watchdog saves always
-        # commit synchronously (emergency path) regardless of this knob.
+        # Save folder layout: <save_folder>/weights/<name> (``:29-32``). The
+        # manager commits synchronously (a save it runs is durable when the
+        # call returns); `async_checkpoint=True` routes periodic/best saves
+        # through AsyncCheckpointSaver: a device->host snapshot on this
+        # thread, the staging+manifest+rename commit on a background thread.
+        # Preemption/watchdog saves always commit synchronously.
         self.save_folder = save_folder
         self.save_weight_folder = os.path.join(save_folder, "weights")
         self._async_saves = bool(async_checkpoint)
@@ -326,113 +295,38 @@ class Trainer:
             max_to_keep=max_checkpoints_to_keep,
             fault_plan=fault_plan,
         )
-        self.saver = AsyncCheckpointSaver(
-            self.checkpoints, on_commit=self._on_async_commit
-        )
+        self.saver = AsyncCheckpointSaver(self.checkpoints, on_commit=self._on_async_commit)
 
-        # Telemetry subsystem (ISSUE 4; docs/observability.md): structured
-        # JSONL event log, goodput wall-time buckets, on-device train-health
-        # stats (threaded into the engine below), per-window MFU, and anomaly
-        # detectors. telemetry=None (default) is the historical program —
-        # self.events is a disabled no-op, self.goodput stays None, and the
-        # engine traces the exact pre-telemetry step. Constructed BEFORE the
-        # mesh so the elastic-resume peek below (which may re-plan the mesh)
-        # reports through the event log; the mesh-dependent peak-FLOPs figure
-        # is finalized right after mesh selection.
-        self.telemetry = resolve_telemetry(telemetry)
-        if self.telemetry is not None:
-            self.events = EventLog(
-                self.telemetry.events_path
-                or os.path.join(save_folder, "telemetry", "events.jsonl")
-            )
-            self.goodput = GoodputMeter() if self.telemetry.goodput else None
-            self.anomaly_detector = self.telemetry.resolve_anomaly()
-            self._flops_per_step = self.telemetry.flops_per_step
-        else:
-            self.events = EventLog(None)
-            self.goodput = None
-            self.anomaly_detector = None
-            self._flops_per_step = None
-        # Straggler attribution (ISSUE 13; telemetry/straggler.py): per-chip
-        # arrival-skew fields sampled at the log_every syncs, the live
-        # inputs to the doctor's `straggler` verdict. Off (or telemetry
-        # off) keeps the sync path byte-identical to the historical one.
-        self._straggler_on = self.telemetry is not None and getattr(
-            self.telemetry, "straggler", False
+        # Everything the run tells telemetry goes through this one object
+        # (telemetry/run.py). With telemetry=None it is built disabled: the
+        # log is a no-op, the meter None, and the engine traces the step
+        # without the health stats. Built BEFORE the mesh so that the resume
+        # peek below, which may re-plan the mesh, reports through the log.
+        self.run_telemetry = RunTelemetry(
+            telemetry, save_folder=save_folder, log=self.log, metrics_writer=self.metrics_writer
         )
-        # Attempt id (ISSUE 16): the monotonic per-run-dir restart
-        # generation, claimed in train() (rank 0, telemetry on) and stamped
-        # on run_start/heartbeat records + checkpoint meta so one appended
-        # events.jsonl attributes every record to the attempt that wrote
-        # it. 0 = unclaimed (telemetry off / non-zero rank).
-        self._attempt = 0
-        self._last_straggler: dict | None = None
-        self._max_straggler_ratio: float | None = None
-        # Live doctor signals (telemetry/doctor.py): per-kind anomaly
-        # counts, hung steps, and steady-state retraces, accumulated where
-        # the trainer already observes each fact — the epoch-end `doctor/*`
-        # TensorBoard scalars project them through the same rules the
-        # offline run doctor applies to the event log.
-        self._anomaly_counts: dict[str, int] = {}
-        self._hung_steps = 0
-        self._late_compiles = 0
-        # Epoch this attempt began at (set after restore in train()):
-        # compiles there are warmup, not the compile_bound retrace signal.
-        self._start_epoch = 0
-        self._peak_flops = None  # finalized after mesh selection below
-        # Live-operations layer (ISSUE 15; docs/observability.md "Live
-        # monitoring"): the heartbeat pulse + the optional in-process
-        # status exporter. Heartbeats are emitted at the existing
-        # log_every syncs (source="loop") and, when the step_timeout
-        # watchdog is armed, from its patrol thread between syncs
-        # (source="watchdog" + since_progress_s) — both debounced to
-        # heartbeat_every_s through ONE lock-guarded gate (the patrol
-        # thread and the loop race the debounce state, nothing else).
-        self._heartbeat_every_s = (
-            float(getattr(self.telemetry, "heartbeat_every_s", 0.0) or 0.0)
-            if self.telemetry is not None
-            else 0.0
-        )
-        self._hb_lock = threading.Lock()
-        self._hb_last_emit = 0.0
-        # The last sync point's progress fields, swapped wholesale under
-        # the lock so a patrol-thread heartbeat reads one coherent dict
-        # (its step fields may lag the hang by up to log_every steps; its
-        # since_progress_s figure is exact — the watchdog measures it).
-        self._hb_fields: dict = {}
-        # Status exporter (telemetry/exporter.py): constructed in train()
-        # on process 0 when Telemetry(export_port=...) asks for it. The
-        # trainer BUILDS a fresh snapshot dict at its sync points and
-        # swaps the reference; the exporter's HTTP threads only read
-        # whichever complete dict the reference points at — the hot loop
-        # is never blocked and never shares mutable state with a scrape.
-        self.exporter = None
-        self._status: dict = {}
+        self.events = self.run_telemetry.events
+        self.goodput = self.run_telemetry.goodput
+        self.anomaly_detector = self.run_telemetry.anomaly_detector
         # Recovery skips (restore_latest_valid / the resume peek walking past
-        # a corrupt checkpoint) land in the event log as `checkpoint_rejected`
-        # records.
+        # a corrupt checkpoint) land in the log as `checkpoint_rejected`.
         self.checkpoints.event_log = self.events
 
-        # Elastic resume (ISSUE 12; docs/fault_tolerance.md): resolve the
-        # resume checkpoint BEFORE choosing the mesh. A sharded checkpoint
-        # written on a different device count than this backend re-plans the
-        # mesh axes + grad-accumulation for the current topology
-        # (parallel.elastic) when mesh=None — a run killed at fsdp=8 resumes
-        # on 4 or 16 devices without user intervention. Same-topology resumes
-        # (and cold starts) are untouched: the peek is host-side metadata
-        # reading only, and the historical program stays byte-identical.
+        # Elastic resume (docs/fault_tolerance.md): the resume checkpoint is
+        # resolved BEFORE the mesh is chosen. A sharded checkpoint written on
+        # another device count re-plans the mesh axes + grad accumulation for
+        # this topology (parallel.elastic) when mesh=None: a run killed at
+        # fsdp=8 resumes on 4 or 16 devices. The peek reads host-side
+        # metadata only; same-topology resumes and cold starts set nothing.
         snapshot_path = self._peek_resume_checkpoint(snapshot_path, mesh, batch_size)
         if self._elastic_plan is not None:
             mesh = self._elastic_plan.mesh_config.build()
 
         # Mesh — the distributed world (replaces LOCAL_RANK/RANK/WORLD_SIZE
-        # env reads + DDP wrap, ``:48-52``). mesh=None is the historical
-        # pure-DP program (1-D data mesh over every device, replicated
-        # params — trace_counts + params parity test-enforced); any
-        # MeshConfig(...).build() mesh trains sharded end to end
-        # (docs/parallelism.md): state initializes directly into the
-        # fsdp/tensor layout, chained windows / checkpoints / preflight all
-        # operate on the sharded arrays.
+        # env reads + DDP wrap, ``:48-52``). mesh=None is pure data
+        # parallelism (1-D data mesh over every device, replicated params);
+        # any MeshConfig(...).build() mesh trains sharded end to end
+        # (docs/parallelism.md).
         self.mesh = mesh if mesh is not None else mesh_lib.create_mesh()
         self.world_size = self.mesh.devices.size
         # Batch-dim divisibility is against the BATCH-SHARDED axes product
@@ -449,11 +343,10 @@ class Trainer:
                 "of the data and fsdp axes): every batch shard must hold the "
                 "same number of rows. Round batch_size or re-plan the mesh."
             )
-        # Elastic re-validation (ISSUE 12 satellite): a resumed run on a
-        # re-planned (or hand-picked) mesh can land on a global batch the new
-        # data x fsdp extent x accumulation does not tile — the engine's
-        # microbatch reshape would then fail deep in jax array assembly. Fail
-        # fast here with the ctor-style message instead.
+        # A resumed run on a re-planned (or hand-picked) mesh can land on a
+        # global batch the new data x fsdp extent x accumulation does not
+        # tile; the engine's microbatch reshape would then fail deep in jax
+        # array assembly, so fail here with names attached.
         if self._topology_changed and batch_size % (
             self.batch_replicas * self.accum_steps
         ):
@@ -471,8 +364,7 @@ class Trainer:
         self.local_batch_size = batch_size // jax.process_count()
         # Parameter-sharding rules (parallel.sharding): "auto" resolves via
         # the build_sharding_rules hook AFTER build_model runs (the hook may
-        # inspect self.model); an explicit list/None passes through. None on
-        # a pure-DP mesh is the historical replicated program. Any OTHER
+        # inspect self.model); an explicit list/None passes through. Any OTHER
         # string is rejected here — forwarded to the engine it would crash
         # deep inside state_shardings as a bogus (regex, spec) iterable with
         # no mention of this knob.
@@ -483,32 +375,20 @@ class Trainer:
                 "replicated/FSDP-fallback default, or an explicit list of "
                 "(path_regex, PartitionSpec) rules."
             )
-        self._sharding_rules_requested = sharding_rules
         self.fsdp_min_size = int(fsdp_min_size)
 
-        # Telemetry's mesh-dependent piece (the subsystem itself was
-        # constructed before mesh selection, for the elastic peek).
-        if self.telemetry is not None:
-            # None for a device with no published peak (the CPU included):
-            # every mfu field is then simply absent (telemetry_mfu.mfu_value).
-            chip_peak = telemetry_mfu.device_peak_flops(self.mesh.devices.flat[0])
-            self._peak_flops = (
-                None if chip_peak is None else chip_peak * self.mesh.devices.size
-            )
-        # Memory preflight (ISSUE 8; memory/preflight.py): predict the
-        # configured program's peak HBM from an abstract lowering BEFORE the
-        # first real compile, fail fast on predicted OOM with a batch/
-        # microbatch recommendation. preflight=None (default) reproduces the
-        # historical program exactly — no lowering, no probe, trace_counts +
-        # params parity test-enforced (the telemetry/profile convention).
+        self.run_telemetry.set_mesh(self.mesh)
+        # Memory preflight (memory/preflight.py): predict the program's peak
+        # HBM from an abstract lowering BEFORE the first real compile and fail
+        # fast on predicted OOM with a batch / microbatch recommendation.
+        # preflight=None (default): no lowering, no probe.
         self.preflight = resolve_preflight(preflight)
         self._preflight_done = False
         # The last PreflightReport (fit verdict, per-class attribution,
         # recommendations) — operator-inspectable after train().
         self.memory_report = None
-        # Hot-path profiling capture (profiling/capture.py): one traced
-        # window of real steps, driven at unit boundaries in train_epoch.
-        # Rank-0 owned; events no-op when telemetry is off.
+        # profiling/capture.py: one traced window of real steps, driven at
+        # unit boundaries in train_epoch (process 0).
         self._profile_capture = (
             StepTraceCapture(
                 self.profile,
@@ -519,15 +399,10 @@ class Trainer:
             if self.profile is not None
             else None
         )
-        # MFU probe bookkeeping: the first executed batch's abstract shapes
-        # (ShapeDtypeStructs only — no device ops) feed the one-time
-        # engine.step_cost_analysis probe at the end of the first epoch.
-        self._mfu_probed = False
+        # The first executed batch's per-step shapes (ShapeDtypeStructs): what
+        # the preflight, the one-time FLOP probe and the capture's roofline
+        # join lower against.
         self._abstract_batch = None
-        self._last_step_ms = None
-        # Loss-scale backoff detection reads the per-step `loss_scale` metric
-        # at sync points (already host-fetched there — zero extra syncs).
-        self._last_scale_seen = None
 
         # Build hooks (``:38-41``) — model/criterion first, then datasets
         # (so ``build_scheduler`` can size per-epoch schedules from
@@ -540,14 +415,12 @@ class Trainer:
         with annotate("trainer.build_loaders"):
             self.train_dataset = self.build_train_dataset()
             self.train_dataloader = self.build_dataloader(self.train_dataset, phase="train")
-        # Streaming data plane (ISSUE 19; docs/data.md): duck-typed on the
-        # reader-state surface so any build_dataloader override returning a
-        # loader with ``reader_state`` gets checkpoint-carried reader state + the
-        # shard_assignment/data_reader_state telemetry without trainer
-        # subclassing. The loader feeds per-host row slices; telling it the
+        # Streaming data plane (docs/data.md), duck-typed: a loader with
+        # ``reader_state`` gets its reader state carried by checkpoints and
+        # the shard_assignment / data_reader_state records. Telling it the
         # mesh's batch-shard extent pins its assignment version to the
-        # data x fsdp split it actually feeds (PR 9) — which is what makes
-        # an elastic N→M resume visible as a version change.
+        # data x fsdp split it feeds, which is what makes an elastic N→M
+        # resume visible as a version change.
         self._streaming_train = hasattr(self.train_dataloader, "reader_state")
         if self._streaming_train and hasattr(self.train_dataloader, "batch_extent"):
             self.train_dataloader.batch_extent = self.batch_replicas
@@ -566,10 +439,7 @@ class Trainer:
         self.optimizer = self.build_optimizer(self.schedule)
 
         self.sharding_rules = (
-            self.build_sharding_rules()
-            if isinstance(self._sharding_rules_requested, str)
-            and self._sharding_rules_requested == "auto"
-            else self._sharding_rules_requested
+            self.build_sharding_rules() if isinstance(sharding_rules, str) else sharding_rules
         )
         self.engine = TrainEngine(
             self.build_loss_fn(),
@@ -582,16 +452,15 @@ class Trainer:
             nan_guard=self.nan_policy in ("skip", "restore_last_good"),
             precision=self.precision,
             loss_scale=self._initial_loss_scale,
-            stats=self.telemetry.stats if self.telemetry is not None else False,
+            stats=self.run_telemetry.stats,
             sharding_rules=self.sharding_rules,
             fsdp_min_size=self.fsdp_min_size,
         )
 
-        # State init (replaces model.to(device) + DDP param broadcast).
-        # Sharded init: init_state jits the model init with the engine's
-        # state sharding as OUTPUT sharding, so fsdp/tensor-sharded params
-        # materialize directly into their shards — a model too big for one
-        # chip's HBM never exists replicated anywhere.
+        # State init (replaces model.to(device) + DDP param broadcast):
+        # init_state jits the model init with the engine's state sharding as
+        # OUTPUT sharding, so a model too big for one chip's HBM never exists
+        # replicated anywhere.
         example = self.build_example_input()
         with annotate("engine.init_state"):
             self.state = self.engine.init_state(
@@ -600,11 +469,9 @@ class Trainer:
             )
         self._log_sharded_layout()
 
-        # Snapshot resume (``:44-45,96-101``). The peek above already
-        # resolved "latest_valid" to the newest checkpoint passing integrity
-        # validation (falling back past a torn last save, emitting
-        # `checkpoint_rejected` for each reject) — or to None on a cold
-        # start — and read its meta.
+        # Snapshot resume (``:44-45,96-101``). The peek above resolved
+        # "latest_valid" to the newest checkpoint passing integrity
+        # validation (or to None on a cold start) and read its meta.
         if snapshot_path is not None:
             t_restore = time.perf_counter()
             self.state, self.cur_epoch = self.checkpoints.restore(
@@ -624,22 +491,16 @@ class Trainer:
                 if self._resume_meta is not None
                 else self.checkpoints.read_meta(snapshot_path)
             )
-            self._resume_step_in_epoch = int(
-                (meta.get("loop") or {}).get("step_in_epoch", 0)
-            )
-            # Streaming reader state (ISSUE 19): the checkpoint's data/ item
-            # positions the data plane. Missing item = fresh cursor (a
-            # pre-streaming checkpoint or a non-streaming run — the
-            # loss-scale item rule); present = validate it speaks this
-            # stream and position at cursor // G, O(1). The data cursor is
-            # authoritative for the reader; it cross-checks the loop's
-            # step_in_epoch (same quantity, saved atomically together).
+            self._resume_step_in_epoch = int((meta.get("loop") or {}).get("step_in_epoch", 0))
+            # The checkpoint's data/ item positions a streaming reader: a
+            # missing item means a fresh cursor; a present one is validated
+            # against this stream and positions at cursor // G. The data
+            # cursor is authoritative for the reader; the loop's
+            # step_in_epoch (saved atomically with it) cross-checks it.
             if self._streaming_train:
                 data_state = self.checkpoints.read_data_state(snapshot_path)
                 if data_state:
-                    resume_batch = self.train_dataloader.apply_reader_state(
-                        data_state
-                    )
+                    resume_batch = self.train_dataloader.apply_reader_state(data_state)
                     if resume_batch != self._resume_step_in_epoch:
                         self.log(
                             "checkpoint data cursor (batch "
@@ -654,18 +515,7 @@ class Trainer:
                         "checkpoint has no data/ item (pre-streaming): "
                         "streaming reader resumes with a fresh cursor"
                     )
-            if self.goodput is not None:
-                # Cumulative goodput counters ride checkpoint meta (the way
-                # loss_scale state rides its checkpoint item): a resumed run
-                # continues the interrupted run's accounting bit-identically
-                # (json round-trips floats exactly — test-enforced), then
-                # books the restore itself as restart-rollback overhead.
-                saved = (meta.get("telemetry") or {}).get("goodput")
-                if saved:
-                    self.goodput.load_state(saved)
-                self.goodput.account(
-                    "restart_rollback", time.perf_counter() - t_restore
-                )
+            self.run_telemetry.restored(meta, time.perf_counter() - t_restore)
             self.events.emit(
                 "checkpoint_restore",
                 name=os.path.basename(str(snapshot_path)),
@@ -729,146 +579,51 @@ class Trainer:
     def _train(self) -> None:
         self._install_sigterm()
         self.metrics_writer.reopen()  # symmetric with the close() below
-        self._start_epoch = self.cur_epoch  # warmup epoch for late-compile
-        if self.goodput is not None:
-            self.goodput.start()
-        if self.events.enabled:
-            # guarded like run_end: the field build includes an
-            # int(self.state.step) device fetch the telemetry-off
-            # (historical) path must not pay
-            self._attempt = claim_attempt(self.save_folder)
-            fields = dict(
-                attempt=self._attempt,
-                epoch=self.cur_epoch,
-                max_epoch=self.max_epoch,
-                step=int(self.state.step),
-                resumed_step_in_epoch=self._resume_step_in_epoch,
-                processes=jax.process_count(),
-                devices=self.world_size,
-                mesh={str(k): int(v) for k, v in self.mesh.shape.items()},
-                batch_replicas=self.batch_replicas,
-                chain_steps=self.chain_steps,
-                compute_dtype=str(jnp.dtype(self.precision.compute_dtype)),
+        assignment = None
+        if self._streaming_train and hasattr(self.train_dataloader, "assignment"):
+            # One record per attempt: after an elastic resume the loader's
+            # extent was re-planned, so this IS the re-split assignment.
+            assignment = dict(
+                elastic=self._topology_changed,
+                **self.train_dataloader.assignment(
+                    cursor=self._resume_step_in_epoch
+                    * self.train_dataloader.global_batch_size
+                ),
             )
-            if self.goodput is not None:
-                # Cumulative-counter snapshot (zero on a cold start, the
-                # carried totals on a resume): the timeline exporter
-                # anchors its goodput-span chain here, so the spans cover
-                # exactly THIS attempt's wall.
-                fields["goodput_seconds"] = self.goodput.to_state()
-            # Provenance stamp (ISSUE 14): git SHA + jax/jaxlib + effective
-            # XLA_FLAGS + the program identity, so run_compare can refuse
-            # to diff runs that measured different programs. Inside the
-            # events.enabled guard like the rest of the field build.
-            from distributed_training_pytorch_tpu.telemetry.provenance import (
-                provenance_fields,
-            )
-
-            fields["provenance"] = provenance_fields(
-                mesh=fields["mesh"],
-                dtype=fields["compute_dtype"],
-                chain_steps=self.chain_steps,
-                batch=self.batch_size,
-            )
-            self.events.emit("run_start", **fields)
-            # Streaming shard assignment (ISSUE 19): one record per attempt
-            # — on an elastic resume the loader's extent was re-planned
-            # above, so the version/extent here IS the re-split assignment
-            # (docs/data.md "elastic re-split ritual").
-            if self._streaming_train and hasattr(self.train_dataloader, "assignment"):
-                self.events.emit(
-                    "shard_assignment",
-                    elastic=self._topology_changed,
-                    **self.train_dataloader.assignment(
-                        cursor=self._resume_step_in_epoch
-                        * self.train_dataloader.global_batch_size
-                    ),
-                )
-            # Kernel-policy visibility (ISSUE 17): route ops/dispatch.py's
-            # one-time kernel_dispatch decisions into this run's event log.
-            # Decisions already made while building the model were buffered
-            # by the dispatcher and flush here; uninstalled in the finally.
-            from distributed_training_pytorch_tpu.ops import dispatch as _dispatch
-
-            _dispatch.set_event_sink(self.events.emit)
-        # Status exporter (ISSUE 15): rank-0 only, constructed per train()
-        # attempt and torn down in the finally below. A taken port warns
-        # and disables (never a reason training dies); the run itself is
-        # bit-exact with export_port=None (the exporter only READS
-        # host-side snapshots — test-enforced).
-        if (
-            self.telemetry is not None
-            and self.telemetry.export_port is not None
-            and jax.process_index() == 0
-        ):
-            from distributed_training_pytorch_tpu.telemetry.exporter import (
-                StatusExporter,
-            )
-
-            self.exporter = StatusExporter(
-                lambda: self._status,
-                self.telemetry.export_port,
-                log=lambda msg: self.log(msg, "warning"),
-            )
-        # Seed the liveness pulse: a monitor attaching before the first
-        # log_every sync still sees a heartbeat (and the exporter serves a
-        # pre-first-sync snapshot instead of an empty dict). `units` on
-        # heartbeats counts executed units cumulatively across THIS
-        # attempt (epochs reset `executed`; a liveness progress marker
-        # must be monotone).
-        self._attempt_units = 0
-        self._note_heartbeat_progress(
+        self.run_telemetry.run_start(
             epoch=self.cur_epoch,
-            step_in_epoch=self._resume_step_in_epoch,
-            units=0,
+            max_epoch=self.max_epoch,
+            step=self.state.step,
+            resumed_step_in_epoch=self._resume_step_in_epoch,
+            batch_size=self.batch_size,
+            batch_replicas=self.batch_replicas,
+            chain_steps=self.chain_steps,
+            compute_dtype=str(jnp.dtype(self.precision.compute_dtype)),
+            nonfinite_steps=self.nonfinite_steps,
+            shard_assignment=assignment,
         )
-        self._emit_heartbeat("loop")
-        self._update_status(step_in_epoch=self._resume_step_in_epoch, units=0)
         try:
             self._train_loop()
         finally:
             # Stop owning the process SIGTERM once training is over (or died):
             # a lingering handler would silently swallow later terminations.
-            # Symmetric with the install above, so a re-entered train() is
-            # protected again. The metrics writer closes here too so the
-            # preemption early-return and error paths flush it.
+            # The metrics writer closes here too, so that the preemption
+            # early-return and the error paths flush it.
             self._restore_sigterm()
-            # Error/preemption paths must not leave a background commit in
-            # flight into interpreter teardown; a commit error here must not
-            # mask the original exception (logged, not raised). close() also
-            # stops the commit worker — a process constructing many Trainers
-            # must not accumulate parked daemon threads (a re-entered
-            # train()'s next save restarts the worker transparently).
+            # No background commit may be left in flight into interpreter
+            # teardown, and a commit error here must not mask the original
+            # exception (logged, not raised). close() also stops the commit
+            # worker: a process constructing many Trainers must not collect
+            # parked daemon threads (a re-entered train()'s next save restarts
+            # it).
             self._flush_saver_logged()
             self.saver.close()
-            if self.goodput is not None:
-                self.goodput.stop()
-            if self.events.enabled:
-                from distributed_training_pytorch_tpu.ops import dispatch as _dispatch
-
-                _dispatch.clear_event_sink()
-                fields = {
-                    "step": int(self.state.step),
-                    "epoch": self.cur_epoch,
-                    "preempted": self._preempted,
-                    "nonfinite_steps": self.nonfinite_steps,
-                }
-                if self.goodput is not None:
-                    fields["goodput"] = self.goodput.goodput
-                    fields["goodput_seconds"] = self.goodput.to_state()
-                    fields["goodput_fractions"] = self.goodput.fractions()
-                if self.anomaly_detector is not None:
-                    fields["anomalies"] = self.anomaly_detector.total_fired
-                self.events.emit("run_end", **fields)
-            # Final exporter snapshot (phase "finished"), then release the
-            # port — a scraper that races the teardown gets either the
-            # terminal snapshot or a connection refusal, never a hang. A
-            # re-entered train() constructs a fresh exporter.
-            self._update_status(phase="finished")
-            if self.exporter is not None:
-                self.exporter.close()
-                self.exporter = None
-            self.events.close()  # a re-entered train() lazily reopens (append)
+            self.run_telemetry.run_end(
+                step=self.state.step,
+                epoch=self.cur_epoch,
+                preempted=self._preempted,
+                nonfinite_steps=self.nonfinite_steps,
+            )
             self.metrics_writer.close()
 
     def _train_loop(self) -> None:
@@ -912,7 +667,7 @@ class Trainer:
                         self._preempted = True
                         resume_epoch = epoch if self._epoch_interrupted else epoch + 1
                         # A mid-epoch interruption records its position so the resume
-                        # skips the already-trained batches (bit-exact continuation);
+                        # skips the already-trained batches (the same stream continues);
                         # an epoch-boundary save restarts the next epoch at step 0.
                         loop_state = (
                             {"step_in_epoch": self._interrupted_at_step}
@@ -966,7 +721,7 @@ class Trainer:
                     self.log(msg)
                     self.metrics_writer.write(int(self.state.step), epoch_metrics, prefix="train")
                     self._write_precision_scalars()
-                    self._write_telemetry_scalars()
+                    self.run_telemetry.write_scalars(self.state.step)
 
         # Barrier: every queued background commit fully on disk (and any
         # commit error surfaced) before the run declares itself finished.
@@ -987,8 +742,7 @@ class Trainer:
         """One construction-time line saying what the mesh actually did to
         the state: how many leaves landed sharded, and the per-device vs
         global param bytes (the measurable ZeRO-3 win). Silent on a pure-DP
-        mesh — the historical console transcript is part of the historical
-        program."""
+        mesh."""
         from distributed_training_pytorch_tpu.parallel import sharding as sharding_lib
 
         record = sharding_lib.sharding_record(self.state)
@@ -1010,7 +764,7 @@ class Trainer:
         )
 
     # ------------------------------------------------------------------
-    # Elastic resume (ISSUE 12; docs/fault_tolerance.md "Elastic training")
+    # Elastic resume (docs/fault_tolerance.md "Elastic training")
     # ------------------------------------------------------------------
 
     def _peek_resume_checkpoint(self, snapshot_path, mesh, batch_size):
@@ -1031,8 +785,8 @@ class Trainer:
           current-backend layout); only the topology-change flag is set so
           the manager's :class:`TopologyMismatchError` seam stands down.
 
-        Same-topology resumes and cold starts set nothing — the historical
-        program is untouched (host-side metadata reads only).
+        Same-topology resumes and cold starts set nothing (host-side metadata
+        reads only).
         """
         self._elastic_plan = None
         self._resume_meta = None
@@ -1123,6 +877,20 @@ class Trainer:
         )
 
     @property
+    def exporter(self):
+        """The status exporter while ``train()`` runs with
+        ``Telemetry(export_port=...)``, else None."""
+        return self.run_telemetry.exporter
+
+    @property
+    def _flops_per_step(self):
+        return self.run_telemetry.flops_per_step
+
+    @property
+    def _peak_flops(self):
+        return self.run_telemetry.peak_flops
+
+    @property
     def model_dtype(self):
         """The activation dtype matching this trainer's precision policy —
         pass as ``dtype=`` when constructing models in ``build_model`` so
@@ -1146,24 +914,6 @@ class Trainer:
             },
             prefix="precision",
         )
-
-    # ------------------------------------------------------------------
-    # Telemetry (ISSUE 4; docs/observability.md). Everything here is a
-    # no-op / zero-overhead path when telemetry is off, and never a reason
-    # training dies (the MFU probe degrades to a warning on failure).
-    # ------------------------------------------------------------------
-
-    def _telemetry_meta(self) -> dict | None:
-        """Cumulative telemetry counters for checkpoint meta — the goodput
-        buckets (so goodput accounting survives kill/resume) plus the
-        attempt id that wrote the checkpoint (ISSUE 16 provenance; the
-        manager hoists it to a first-class ``meta["attempt"]``)."""
-        meta = {}
-        if self.goodput is not None:
-            meta["goodput"] = self.goodput.to_state()
-        if self._attempt:
-            meta["attempt"] = self._attempt
-        return meta or None
 
     def _flush_saver_logged(self) -> None:
         """Flush the async saver, reporting — never raising — a background
@@ -1219,7 +969,7 @@ class Trainer:
             self.goodput.tick("other")  # close the epoch-glue interval
         with annotate("trainer.checkpoint", epoch=epoch, reason=reason):
             mode = "async" if (self._async_saves and not wait) else "sync"
-            telemetry_meta = self._telemetry_meta()
+            telemetry_meta = self.run_telemetry.checkpoint_meta()
             # Streaming reader state rides EVERY save (sync/async/emergency/best
             # — this is the one save site): epoch is the resume epoch the caller
             # passed, cursor the global records already consumed in it (0 for an
@@ -1277,7 +1027,7 @@ class Trainer:
                 fields["step_in_epoch"] = int(loop_state.get("step_in_epoch", 0))
             self.events.emit("checkpoint_save", **fields)
             if data_state is not None:
-                # The data plane's save record (ISSUE 19): which records a
+                # The data plane's save record: which records a
                 # resume from this checkpoint will consume next.
                 self.events.emit(
                     "data_reader_state",
@@ -1291,213 +1041,18 @@ class Trainer:
                 )
         return saved
 
-    def _write_telemetry_scalars(self) -> None:
-        """TensorBoard: goodput fractions + per-step wall time / MFU next to
-        the train scalars (process 0 only; no-op without tensorboardX —
-        the MetricsWriter contract). The on-device health stats need no
-        writer of their own: they are ordinary train metrics."""
-        if self.telemetry is None:
-            return
-        step = int(self.state.step)
-        if self.goodput is not None:
-            self.metrics_writer.write(step, self.goodput.fractions(), prefix="goodput")
-        if self._last_step_ms is not None:
-            scalars = {"step_ms": self._last_step_ms}
-            mfu = telemetry_mfu.mfu_value(
-                self._flops_per_step or 0.0, self._last_step_ms / 1e3, self._peak_flops
-            )
-            if mfu is not None:
-                scalars["mfu"] = mfu
-            self.metrics_writer.write(step, scalars, prefix="telemetry")
-        if self._last_straggler:
-            self.metrics_writer.write(
-                step,
-                {
-                    "skew_ms": self._last_straggler["chip_skew_ms"],
-                    "ratio": self._last_straggler["straggler_ratio"],
-                },
-                prefix="straggler",
-            )
-        # The live doctor (ISSUE 13): the same verdict rules the offline
-        # run doctor applies to the event log, projected from this run's
-        # in-memory counters — dashboards see per-verdict severity scores
-        # (>= 1.0 = over the line) without waiting for the offline pass.
-        self.metrics_writer.write(
-            step, telemetry_doctor.scalar_fields(self._doctor_signals()), prefix="doctor"
-        )
-
-    def _doctor_signals(self) -> "telemetry_doctor.Signals":
-        """The live-path :class:`telemetry.doctor.Signals` bundle — the same
-        facts :func:`telemetry.doctor.extract_signals` would distill from
-        this run's event log, read off the trainer's own counters instead
-        (no file round trip at epoch end)."""
-        return telemetry_doctor.Signals(
-            goodput_seconds=self.goodput.to_state() if self.goodput else None,
-            anomaly_counts=dict(self._anomaly_counts),
-            hung_steps=self._hung_steps,
-            max_straggler_ratio=self._max_straggler_ratio,
-            late_compiles=self._late_compiles,
-        )
-
-    def _emit_heartbeat(self, source: str, **extra) -> None:
-        """The liveness pulse (ISSUE 15): one cheap ``heartbeat`` record,
-        debounced to ``heartbeat_every_s`` across BOTH sources (the
-        log_every sync and the watchdog patrol thread share one gate —
-        the contract is "the log pulses at least this often while the
-        process lives", not one pulse per source). Carries the last sync
-        point's progress fields plus the cumulative goodput snapshot;
-        zero device syncs (host counters and an allocator-free dict
-        build only)."""
-        if not self._heartbeat_every_s or not self.events.enabled:
-            return
-        now = time.monotonic()
-        with self._hb_lock:
-            if now - self._hb_last_emit < self._heartbeat_every_s:
-                return
-            self._hb_last_emit = now
-            fields = dict(self._hb_fields)
-        fields.update(extra)
-        if self._attempt:
-            fields["attempt"] = self._attempt
-        if self.goodput is not None:
-            # GoodputMeter's bucket keys are fixed at construction, so a
-            # patrol-thread read races only float value updates — safe.
-            fields["goodput_seconds"] = self.goodput.to_state()
-        self.events.emit("heartbeat", source=source, **fields)
-
-    def _note_heartbeat_progress(self, **fields) -> None:
-        """Refresh the progress fields patrol-thread heartbeats report
-        (one dict swap under the heartbeat lock)."""
-        with self._hb_lock:
-            self._hb_fields = dict(fields)
-
-    def _heartbeat_patrol(self, since_progress_s: float) -> None:
-        """Watchdog patrol-thread hook (``StepWatchdog(on_patrol=...)``):
-        keep the event log pulsing while the main thread is stuck inside
-        a step — ``since_progress_s`` (seconds since the last completed
-        unit) is exactly what lets the monitor call the run *hung* rather
-        than merely slow, and the record's continued arrival is what
-        distinguishes hung from *dead*."""
-        self._emit_heartbeat("watchdog", since_progress_s=since_progress_s)
-
-    def _update_status(self, **extra) -> None:
-        """Rebuild the exporter's status snapshot from the live counters
-        (called at the existing sync points only — never the hot path).
-        One reference assignment publishes it; HTTP threads read the
-        complete dict it points at (``telemetry/exporter.py``)."""
-        if self.exporter is None or not self.exporter.enabled:
-            return
-        sig = self._doctor_signals()
-        scores = telemetry_doctor.scalar_fields(sig)
-        verdict, worst = "healthy", 0.0
-        for kind, score in scores.items():
-            if kind != "healthy" and score >= 1.0 and score > worst:
-                verdict, worst = kind, score
-        snap = {
-            "run_dir": self.save_folder,
-            "pid": os.getpid(),
-            "t_wall": time.time(),
-            "phase": "training",
-            "epoch": self.cur_epoch,
-            "nonfinite_steps": self.nonfinite_steps,
-            "hung_steps": self._hung_steps,
-            "late_compiles": self._late_compiles,
-            "anomaly_counts": dict(self._anomaly_counts),
-            "doctor_scores": scores,
-            "verdict": verdict,
-        }
-        if self.goodput is not None:
-            snap["goodput_seconds"] = self.goodput.to_state()
-            snap["goodput_fractions"] = self.goodput.fractions()
-            snap["steady_fractions"] = telemetry_doctor.steady_fractions(
-                snap["goodput_seconds"]
-            )
-        if self._last_step_ms is not None:
-            snap["step_ms"] = self._last_step_ms
-            mfu = telemetry_mfu.mfu_value(
-                self._flops_per_step or 0.0,
-                self._last_step_ms / 1e3,
-                self._peak_flops,
-            )
-            if mfu is not None:
-                snap["mfu"] = mfu
-        snap.update(extra)
-        self._status = snap
-
-    def _maybe_probe_mfu(self) -> None:
-        """One-time XLA cost-analysis probe for the per-step FLOP count
-        (``TrainEngine.step_cost_analysis``): one extra off-hot-path compile
-        that never touches the dispatch executables or ``trace_counts``.
-        Runs at the end of the first trained epoch (shapes known by then);
-        skipped when an analytic ``Telemetry(flops_per_step=...)`` was given,
-        when MFU is off, or when a custom ``train_step`` override means the
-        engine's step is not the one actually running."""
-        if (
-            self.telemetry is None
-            or not self.telemetry.mfu
-            or self._mfu_probed
-            or self._flops_per_step is not None
-            or self._abstract_batch is None
-            or type(self).train_step is not Trainer.train_step
-        ):
-            return
-        self._mfu_probed = True
-        if self.engine.accum_steps > 1:
-            # XLA's cost_analysis may count the grad-accumulation scan BODY
-            # once (~accum x undercount — bench.py rescales against its
-            # analytic anchor; the trainer has none, and a silently-wrong
-            # MFU is worse than no MFU). Probe disabled: pass the analytic
-            # count via Telemetry(flops_per_step=...) instead.
-            self.log(
-                "telemetry: MFU probe skipped under grad accumulation "
-                f"(accum_steps={self.engine.accum_steps}) — XLA may count the "
-                "microbatch scan body once; pass Telemetry(flops_per_step=...) "
-                "for MFU reporting",
-                "warning",
-            )
-            return
-        t0 = time.perf_counter()
-        try:
-            cost = self.engine.step_cost_analysis(self.state, self._abstract_batch)
-        except Exception as e:  # noqa: BLE001 — telemetry must never kill a run
-            self.log(
-                f"telemetry: MFU probe failed ({e}) — per-window MFU disabled",
-                "warning",
-            )
-            return
-        dt = time.perf_counter() - t0
-        if self.goodput is not None:
-            self.goodput.tick("compile")  # the probe IS an XLA compile
-        # cost_analysis() of an SPMD-partitioned executable counts ONE
-        # device's program; the utilisation denominator (_peak_flops) is the
-        # whole mesh's peak, so the numerator must be the whole mesh's work
-        # too. (Under tensor parallelism the per-device programs duplicate a
-        # little work, so this slightly over-counts — it never under-counts
-        # by the device count, which is what the bare figure did.)
-        self._flops_per_step = (
-            float(cost.get("flops", 0.0)) * self.mesh.devices.size
-        ) or None
-        self.events.emit(
-            "compile",
-            kind="mfu_probe",
-            seconds=dt,
-            flops_per_step=self._flops_per_step,
-        )
-
-    def _run_memory_preflight(self, n: int, batch, *, can_chain: bool) -> None:
-        """One-shot OOM preflight on the first execution unit's abstract
-        shapes (``memory.preflight.run_preflight``): predicted peak vs
-        per-device capacity, a ``memory_preflight`` event, and on predicted
-        OOM a fail-fast :class:`~memory.PreflightOOMError` carrying the
-        max-batch / microbatch recommendations. ``can_chain`` gates the
-        chained-window prediction (the caller knows whether a full window
-        can still occur this epoch — conservative at window granularity:
-        lead-single realignment may rarely leave the last possible window
-        unformed, in which case the verdict covers a slightly larger
-        program than dispatches). Skipped (with a warning) under a custom
-        ``train_step`` override — the engine's program is not the one
-        dispatched, so its prediction would be for the wrong program (the
-        MFU-probe rule)."""
+    def _run_memory_preflight(self, *, can_chain: bool) -> None:
+        """One-shot OOM preflight on the first unit's abstract shapes
+        (``memory.preflight.run_preflight``): predicted peak vs per-device
+        capacity, a ``memory_preflight`` event, and on predicted OOM a
+        fail-fast :class:`~memory.PreflightOOMError` carrying the max-batch /
+        microbatch recommendations. ``can_chain`` gates the chained-window
+        prediction (the caller knows whether a full window can still occur
+        this epoch — conservative at window granularity: lead-single
+        realignment may rarely leave the last possible window unformed, in
+        which case the verdict covers a slightly larger program than
+        dispatches). Skipped (with a warning) under a custom ``train_step``
+        override: the engine's program is then not the one dispatched."""
         self._preflight_done = True
         if type(self).train_step is not Trainer.train_step:
             self.log(
@@ -1507,34 +1062,15 @@ class Trainer:
                 "warning",
             )
             return
-        per_step = jax.tree.map(
-            lambda x: jax.ShapeDtypeStruct(
-                x.shape if n == 1 else x.shape[1:], x.dtype
-            ),
-            batch,
-        )
         self.memory_report = run_preflight(
             self.engine,
             self.state,
-            per_step,
+            self._abstract_batch,
             self.preflight,
             chain_length=self.chain_steps if can_chain else None,
             log=self.log,
             events=self.events,
         )
-
-    def _live_memory_fields(self) -> dict:
-        """Per-window live device memory (``memory.live`` — the one
-        memory_stats read): ``live_bytes``/``peak_bytes`` plus per-chip
-        skew on multi-chip hosts. Read only at existing host sync points
-        (an allocator query, zero device syncs); ``{}`` on statless
-        backends — the records simply omit the fields. ``peak_bytes`` is
-        the allocator's process-lifetime high-water mark (documented
-        caveat): the per-window signal — and the growth detector's input —
-        is ``live_bytes``."""
-        if self.telemetry is None or not getattr(self.telemetry, "memory", True):
-            return {}
-        return window_memory_fields()
 
     def _profile_flops_index(self):
         """Per-op roofline join table for the profile capture's top-op rows
@@ -1560,28 +1096,6 @@ class Trainer:
         return flops_index(
             self.engine.compile_step_probe(self.state, self._abstract_batch)
         )
-
-    def _report_anomalies(self, anomalies, *, epoch=None, step_in_epoch=None) -> None:
-        """Emit + log each finding; raise when the detector was built with
-        ``action="raise"`` (the observability analog of nan_policy='raise')."""
-        if not anomalies:
-            return
-        for a in anomalies:
-            self._anomaly_counts[a.kind] = self._anomaly_counts.get(a.kind, 0) + 1
-            self.events.emit(
-                "anomaly",
-                kind=a.kind,
-                value=a.value,
-                baseline=a.baseline,
-                factor=a.factor,
-                epoch=epoch,
-                step_in_epoch=step_in_epoch,
-            )
-            self.log(f"telemetry anomaly: {a.describe()}", "warning")
-        if self.anomaly_detector.action == "raise":
-            from distributed_training_pytorch_tpu.telemetry import AnomalyError
-
-            raise AnomalyError("; ".join(a.describe() for a in anomalies))
 
     def _validate_chain_config(self) -> None:
         """Reject/round knob combinations that would silently misalign with
@@ -1625,12 +1139,8 @@ class Trainer:
                 f"step_timeout x chain_steps = {self.step_timeout * self.chain_steps}s."
             )
 
-    def _chain_lead_singles(self, skip_steps: int) -> int:
-        """Single steps to run before the first chained window of an epoch:
-        realigns a mid-epoch resume offset to a window boundary (windows sit
-        at absolute step_in_epoch multiples of chain_steps, so chained and
-        resumed runs execute identical window shapes)."""
-        return -skip_steps % self.chain_steps
+    def _trace_total(self) -> int:
+        return sum(self.engine.trace_counts.values())
 
     def _fault_active_in_window(self, epoch: int, start: int, stop: int) -> bool:
         return self.fault_plan is not None and self.fault_plan.active_in_window(
@@ -1646,51 +1156,40 @@ class Trainer:
             return watchdog
         if watchdog is None:
             # max_fires=2: fire 1 = graceful SIGTERM save; fire 2 = the
-            # thread is wedged, hard-exit (_on_hung_step). The patrol hook
-            # keeps heartbeats flowing from the watchdog thread while the
-            # main thread is stuck (ISSUE 15 liveness contract).
+            # thread is wedged, hard-exit (_on_hung_step).
             watchdog = StepWatchdog(
                 timeout,
                 self._on_hung_step,
                 max_fires=2,
-                on_patrol=(
-                    self._heartbeat_patrol
-                    if self._heartbeat_every_s and self.events.enabled
-                    else None
-                ),
+                on_patrol=self.run_telemetry.patrol_hook,
             ).start()
         watchdog.pat()
         return watchdog
 
     def train_epoch(self, epoch: int) -> dict:
-        """Inner hot loop: compiled step per global batch — or, with
-        ``chain_steps > 1``, ONE compiled program per window of chain_steps
-        batches (``engine.train_steps_chained``), removing per-step host
-        dispatch entirely. Metrics stay device-resident either way (no
-        per-step host sync — the reference pays a ``loss.item()`` sync every
-        step, ``example_trainer.py:89``); chained windows return per-step
-        metrics as scan outputs, so the accounting below is identical.
+        """One epoch of the step loop: fetch a unit from the input path
+        (``data.epoch_units``), dispatch it, repeat. A unit is one compiled
+        step on one global batch or, with ``chain_steps > 1``, ONE compiled
+        program over a window of ``chain_steps`` batches
+        (``engine.train_steps_chained``). Metrics stay on the device either
+        way (a window's come back stacked, one row a step) and reach the host
+        at the ``log_every`` syncs and in one ``device_get`` at the end.
 
-        Mid-epoch resume: when this epoch was interrupted by a preemption
-        save at step k, the first k batches are skipped (the loader's
-        permutation and the per-(epoch, index) augmentation keys are
-        deterministic, so the surviving stream is identical to the one the
-        interrupted run would have seen) — the resumed run stays bit-exact
-        with an uninterrupted one. Under chaining the first (-k mod
-        chain_steps) resumed steps run single-step so window boundaries
-        realign to the uninterrupted run's."""
-        # `unit` ids (profiling/trace.py spans): the global step of a unit's
-        # first step, reckoned on the host as epoch x steps an epoch + step in
-        # the epoch (reading state.step would be a device sync).
+        Mid-epoch resume: an epoch interrupted by a preemption save at step k
+        skips its first k batches (the loader's permutation and the
+        per-(epoch, index) augmentation keys are deterministic, so the rest of
+        the stream is the one the interrupted run would have seen) and, under
+        chaining, runs single steps up to the next window boundary, so that a
+        resumed run executes the same windows as an uninterrupted one."""
+        tel = self.run_telemetry
+        # `unit` ids of the spans: the global step of a unit's first step,
+        # reckoned on the host (reading state.step would be a device sync).
         num_batches = len(self.train_dataloader)
         first_unit = epoch * num_batches
         with annotate("trainer.epoch_start", epoch=epoch):
-            # Metric records: (k, tree) where k == 1 holds one step's scalar
-            # metrics and k > 1 a whole window's stacked scan outputs. Kept
-            # UNsliced on purpose: per-step slicing here would issue k x num_keys
-            # tiny device ops right after the one chained dispatch — paying back
-            # the very dispatch overhead chaining removes. Slicing happens where
-            # a host sync exists anyway (log points, epoch end).
+            # (k, tree) records: k == 1 one step's scalar metrics, k > 1 a
+            # window's stacked outputs, kept UNsliced: slicing per step here
+            # would issue k x num_keys tiny device ops after every dispatch.
             collected: list[tuple[int, Any]] = []
             skip_steps = self._resume_step_in_epoch
             self._resume_step_in_epoch = 0  # consumed by the first trained epoch
@@ -1699,131 +1198,44 @@ class Trainer:
             synced_entries = 0  # index into `collected` of the last nan-policy sync
             synced_steps = 0  # the same sync position, in steps
             t0 = time.perf_counter()
-            # Telemetry (no-ops when off): goodput attributes the epoch's wall
-            # time to buckets at the loop's existing boundaries — no added device
-            # syncs anywhere in this method; tele_sync anchors per-window step
-            # timing at the log_every host syncs.
-            tm = self.goodput
-            if tm is not None:
-                tm.tick("other")  # close the epoch preamble (validation/log glue)
-            # The first fetch after a mid-epoch resume replays the loader past
-            # the already-trained batches — restart-rollback cost, not data_wait.
-            rollback_fetch = skip_steps > 0
-            tele_sync = [t0, 0]  # (perf_counter, executed) at the last sync point
-            trace_base = [0]  # trace_counts total before the in-flight unit
-            # Trace totals at the last sync point / epoch start: a window (or
-            # epoch) that paid XLA compile has a known-skewed wall, so its
-            # step_time is withheld from the anomaly detector's EWMA — the
-            # compile-polluted first windows would otherwise seed the baseline
-            # minutes high and mask real regressions for the rest of the run
-            # (warmup alone only delays firing; it does not keep the poison
-            # out of the baseline).
-            sync_trace = [sum(self.engine.trace_counts.values())]
-            epoch_trace_start = sync_trace[0]
+            tel.epoch_start(resumed=skip_steps > 0, traces=self._trace_total())
             chain = self.chain_steps
-            # Resume skip happens at the loader's INDEX level when it can
-            # (iter_batches: none of the skipped batches are read or decoded);
-            # generic iterables fall back to drain-and-discard.
-            if skip_steps and hasattr(self.train_dataloader, "iter_batches"):
-                source_iter = self.train_dataloader.iter_batches(skip_steps)
-            elif skip_steps:
-                import itertools
-
-                source_iter = itertools.islice(iter(self.train_dataloader), skip_steps, None)
-            else:
-                source_iter = iter(self.train_dataloader)
-            host_batches = (
-                self._check_image_range(self.preprocess_batch(b)) for b in source_iter
+            units = epoch_units(
+                self.train_dataloader, self.mesh, chain_steps=chain, skip_steps=skip_steps,
+                preprocess=lambda b: self._check_image_range(self.preprocess_batch(b)),
+                epoch=epoch, first_unit=first_unit,
             )
-            # Execution units (n, batch): n == chain -> a chain-stacked window,
-            # n == 1 -> a plain single-step batch (lead realignment + epoch tail).
-            # stage_ids: where the producer's `prefetch.stage` spans start counting.
-            stage_ids = {"epoch": epoch, "unit": first_unit + skip_steps, "batch": skip_steps}
-            if chain > 1:
-                units = device_prefetch_chained(
-                    host_batches,
-                    self.mesh,
-                    chain,
-                    lead_singles=self._chain_lead_singles(skip_steps),
-                    ids=stage_ids,
-                )
-            else:
-                units = (
-                    (1, b)
-                    for b in device_prefetch(host_batches, self.mesh, ids=stage_ids)
-                )
             bar = self._progress_bar(num_batches, f"epoch {epoch + 1}")
             self._epoch_interrupted = False
-            # Profiling capture (ProfileConfig): a no-op object reference when
-            # off; when on, start/stop transitions fire at unit boundaries so
-            # chained windows are traced whole — execution itself is untouched
-            # (trace_counts + params bit-identical with capture off).
+            # Capture transitions fire at unit boundaries: a window is traced whole.
             cap = self._profile_capture
             watchdog = None
-            # The watchdog pats once per executed unit; under chaining a window
-            # legitimately takes ~chain step-times, so the timeout scales with it
-            # (single-step fallback units then just run with extra slack).
+            # The watchdog is patted once a unit; a window takes ~`chain` step times.
             watchdog_timeout = self.step_timeout * chain if self.step_timeout else None
             self._watchdog_timeout = watchdog_timeout
 
         def sync_log_point():
-            # Intra-epoch host syncs: this (every log_every steps — always a
-            # window boundary, log_every % chain_steps == 0 is ctor-enforced)
-            # and, multi-host only, the preemption vote (_preemption_requested).
+            # The intra-epoch host syncs are this one (always at a unit
+            # boundary: log_every % chain_steps == 0 is ctor-enforced) and,
+            # multi-host only, the preemption vote.
             nonlocal synced_entries, synced_steps
             n_last, last = collected[-1]
-            # Straggler sample FIRST (ISSUE 13): the float() fetches below
-            # are about to block this host on every chip's window results —
-            # sampling per-shard arrival order now observes WHICH chip the
-            # sync is waiting on, at zero extra device syncs (the total
-            # blocking time is the same either way).
-            slow = None
-            if self._straggler_on and self.fault_plan is not None:
-                # Degraded-chip seam (ISSUE 16): a scheduled `slow_chip`
-                # fault delays the named local device's shard arrival
-                # inside the sample below — timing-only, numbers untouched.
-                # Queried here (a sync point), NOT in the step loop: it
-                # must never force chained windows into single-step mode.
-                slow = self.fault_plan.slow_chip(
-                    (d.id for d in jax.local_devices()), epoch=epoch
-                )
-                if slow is not None:
-                    self.events.emit(
-                        "fault_injection",
-                        kind="slow_chip",
-                        epoch=epoch,
-                        step_in_epoch=step_in_epoch,
-                        device=slow[0],
-                        delay_ms=slow[1] * 1e3,
-                    )
-            strag = (
-                straggler_lib.sample_arrivals(last, slow_chip=slow)
-                if self._straggler_on
-                else {}
+            arrivals = tel.sample_arrivals(
+                last, fault_plan=self.fault_plan, epoch=epoch, step_in_epoch=step_in_epoch
             )
-            m = {
-                k: float(v[-1]) if n_last > 1 else float(v) for k, v in last.items()
-            }
+            m = {k: float(v[-1]) if n_last > 1 else float(v) for k, v in last.items()}
+            m_check = m
             if "nonfinite" in m:
-                # The policy check must see every step since the last sync,
-                # not just the latest — a guarded poison at step k<now has
-                # nonfinite=1 only in ITS metrics. Chained windows report
-                # per-step nonfinite flags (scan outputs), so the sum below
-                # counts poisoned steps exactly as the single-step loop does.
+                # A guarded poison at an earlier step has nonfinite=1 only in
+                # ITS metrics, so the policy sees every step since the last
+                # sync (windows report the flag per step).
                 m_check = dict(m)
                 m_check["nonfinite"] = float(
-                    np.sum(
-                        [
-                            np.sum(np.asarray(x["nonfinite"]))
-                            for _, x in collected[synced_entries:]
-                        ]
-                    )
+                    sum(np.sum(np.asarray(x["nonfinite"])) for _, x in collected[synced_entries:])
                 )
                 synced_entries = len(collected)
                 synced_steps = executed
-                self._apply_nan_policy(m_check)
-            else:
-                self._apply_nan_policy(m)
+            self._apply_nan_policy(m_check)
             rate = executed * self.batch_size / (time.perf_counter() - t0)
             if bar is not None:
                 bar.set_postfix(m, refresh=False)
@@ -1831,298 +1243,122 @@ class Trainer:
             self.log(f"  step {step_in_epoch}/{num_batches} {m} ({rate:.1f} img/s)")
             if bar is not None:
                 bar.refresh()
-            if self.telemetry is not None:
-                # Per-window telemetry on the back of this host sync (the
-                # float() fetches above) — step timing/MFU event, loss-scale
-                # backoff detection, anomaly detectors. Zero extra syncs.
-                now = time.perf_counter()
-                window_steps = executed - tele_sync[1]
-                window_s = now - tele_sync[0]
-                tele_sync[0], tele_sync[1] = now, executed
-                if window_steps > 0:
-                    report = telemetry_mfu.window_report(
-                        window_steps,
-                        window_s,
-                        flops_per_step=self._flops_per_step,
-                        peak_flops=self._peak_flops,
-                    )
-                    self._last_step_ms = report["step_ms"]
-                    mem_fields = self._live_memory_fields()
-                    if strag:
-                        # Normalize skew by this window's step wall — the
-                        # floor-baselined anomaly signal and the doctor's
-                        # attribution input.
-                        strag["straggler_ratio"] = straggler_lib.ratio(
-                            strag["chip_skew_ms"], report["step_ms"]
-                        )
-                        self._last_straggler = strag
-                        if (
-                            self._max_straggler_ratio is None
-                            or strag["straggler_ratio"] > self._max_straggler_ratio
-                        ):
-                            self._max_straggler_ratio = strag["straggler_ratio"]
-                    self.events.emit(
-                        "window",
-                        epoch=epoch,
-                        step_in_epoch=step_in_epoch,
-                        **report,
-                        **mem_fields,
-                        **strag,
-                    )
-                    # Liveness pulse + exporter snapshot (ISSUE 15): both
-                    # ride this host sync — host counters already in hand,
-                    # zero extra device syncs. The progress-field refresh
-                    # is unconditional (patrol heartbeats must report the
-                    # newest step even when the pulse itself debounces).
-                    hb_fields = {
-                        "epoch": epoch,
-                        "step_in_epoch": step_in_epoch,
-                        "units": getattr(self, "_attempt_units", 0) + executed,
-                        "step_ms": report["step_ms"],
-                    }
-                    if mem_fields.get("live_bytes") is not None:
-                        hb_fields["live_bytes"] = mem_fields["live_bytes"]
-                    self._note_heartbeat_progress(**hb_fields)
-                    self._emit_heartbeat("loop")
-                    status_extra = dict(
-                        step_in_epoch=step_in_epoch,
-                        units=hb_fields["units"],
-                        **mem_fields,
-                    )
-                    if strag.get("straggler_ratio") is not None:
-                        status_extra["straggler_ratio"] = strag["straggler_ratio"]
-                    if m.get("loss_scale") is not None:
-                        status_extra["loss_scale"] = m["loss_scale"]
-                    if m.get("loss") is not None:
-                        status_extra["loss"] = m["loss"]
-                    self._update_status(**status_extra)
-                    scale = m.get("loss_scale")
-                    if scale is not None:
-                        if (
-                            self._last_scale_seen is not None
-                            and scale < self._last_scale_seen
-                        ):
-                            self.events.emit(
-                                "loss_scale_backoff",
-                                epoch=epoch,
-                                step_in_epoch=step_in_epoch,
-                                from_scale=self._last_scale_seen,
-                                to_scale=scale,
-                            )
-                        self._last_scale_seen = scale
-                    if self.anomaly_detector is not None:
-                        now_traced = sum(self.engine.trace_counts.values())
-                        window_compiled = now_traced > sync_trace[0]
-                        sync_trace[0] = now_traced
-                        self._report_anomalies(
-                            self.anomaly_detector.observe(
-                                step_in_epoch,
-                                loss=m.get("loss", m.get("ce_loss")),
-                                grad_norm=m.get("grad_norm"),
-                                # None (absent) when this window paid
-                                # compile: never fires, never feeds the
-                                # baseline (see sync_trace above).
-                                step_time=None
-                                if window_compiled
-                                else report["step_ms"] / 1e3,
-                                live_bytes=mem_fields.get("live_bytes"),
-                                straggler_ratio=strag.get("straggler_ratio"),
-                            ),
-                            epoch=epoch,
-                            step_in_epoch=step_in_epoch,
-                        )
+            tel.log_sync(
+                m, arrivals, epoch=epoch, step_in_epoch=step_in_epoch,
+                executed=executed, traces=self._trace_total(),
+            )
 
-        def tick_unit():
-            # Attribute the just-executed unit's wall time: a unit whose
-            # dispatch traced a new executable paid XLA compile (jit compiles
-            # synchronously inside the call) — the compile bucket; every
-            # cache-hit unit is productive step time.
-            if self.telemetry is None:
-                return
-            traced = sum(self.engine.trace_counts.values()) - trace_base[0]
-            if tm is not None:
-                tm.tick("compile" if traced else "productive_step")
-            if traced:
-                if epoch > self._start_epoch:
-                    # Compiles in the attempt's starting epoch (0 cold, the
-                    # resume epoch after a restart) are warmup; a compile in
-                    # the steady state is the retrace signature the doctor's
-                    # compile_bound verdict keys on.
-                    self._late_compiles += 1
-                self.events.emit(
-                    "compile",
-                    epoch=epoch,
-                    step_in_epoch=step_in_epoch,
-                    executables=traced,
-                )
-
-        def dispatch(step_fn, *args, steps):
-            # One call into the engine. `traced`: the call raised trace_counts,
-            # i.e. it traced and compiled (or loaded from the compile cache)
-            # a program — jit does that synchronously inside the call.
+        def run_unit(step_fn, batch, n) -> bool:
+            """The one dispatch sequence: ``n`` steps in one call into the
+            engine. False when a preemption was agreed on instead; it is
+            polled at unit boundaries only (a device program has no mid-window
+            host hook), so saves land on them."""
+            nonlocal step_in_epoch, executed, watchdog
+            if self._preemption_requested(step_in_epoch):
+                self._preempted = True  # collective (multi-host OR)
+                return False
+            if cap is not None:
+                cap.maybe_start(step_in_epoch, self.state.params)
             with annotate(
-                "engine.dispatch", epoch=epoch, unit=first_unit + step_in_epoch, steps=steps
+                "engine.dispatch", epoch=epoch, unit=first_unit + step_in_epoch, steps=n
             ) as span:
-                if self.telemetry is None:
-                    return step_fn(*args)
-                before = sum(self.engine.trace_counts.values())
-                out = step_fn(*args)
-                span.set(traced=sum(self.engine.trace_counts.values()) > before)
-            return out
+                # `traced`: the call traced and compiled (or loaded from the
+                # compile cache) a program; jit does that inside the call.
+                before = self._trace_total()
+                args = (self.state, batch, n) if n > 1 else (self.state, batch)
+                self.state, metrics = step_fn(*args)
+                span.set(traced=self._trace_total() > before)
+            collected.append((n, metrics))
+            step_in_epoch += n
+            executed += n
+            if cap is not None:
+                cap.maybe_stop(step_in_epoch, self.state.params)
+            watchdog = self._pat_watchdog(watchdog, watchdog_timeout)
+            if bar is not None:
+                # Host-only; the postfix refreshes at the log_every syncs (a
+                # live per-step loss would be a device sync every step).
+                bar.update(n)
+            if self.log_every and step_in_epoch % self.log_every == 0:
+                with annotate("trainer.sync", epoch=epoch, reason="log_every"):
+                    sync_log_point()
+            return True
 
         try:
             interrupted = False
-            units = iter(units)
-            while True:
+            while not interrupted:
                 with annotate("trainer.fetch", epoch=epoch, unit=first_unit + step_in_epoch):
-                    unit = next(units, None)
-                # Everything since the previous unit's tick is the fetch above
-                # — the input pipeline wait (the last one finds the ring
-                # finished).
-                if tm is not None:
-                    tm.tick("restart_rollback" if rollback_fetch else "data_wait")
-                rollback_fetch = False
+                    unit = next(units, None)  # the epoch's last finds the ring finished
+                tel.fetched()
                 if unit is None:
                     break
                 n, batch = unit
-                if self.preflight is not None and not self._preflight_done:
-                    # Before the first dispatch (nothing compiled yet): the
-                    # unit's shapes are exact, the fit verdict covers the
-                    # REAL program — the chained window when one can still
-                    # occur this epoch (remaining steps >= chain_steps;
-                    # an epoch shorter than one window only ever dispatches
-                    # singles, and a verdict on the never-dispatched window
-                    # program could fail a run whose real program fits).
-                    # Predicted OOM raises out of the loop — failing fast
-                    # host-side is the whole point. The abstract lowerings
-                    # are one-time XLA compile work: booked to the `compile`
-                    # bucket so goodput stays honest about the new startup
-                    # cost.
-                    self._run_memory_preflight(
-                        n,
-                        batch,
-                        can_chain=chain > 1
-                        and num_batches - step_in_epoch >= chain,
-                    )
-                    if tm is not None:
-                        tm.tick("compile")
-                if self.telemetry is not None:
-                    trace_base[0] = sum(self.engine.trace_counts.values())
-                if (
-                    self._abstract_batch is None
-                    and (self.telemetry is not None or cap is not None)
-                ):
-                    # Shapes only (ShapeDtypeStructs, no device ops): feeds
-                    # the one-time MFU probe at epoch end and the profile
-                    # capture's roofline join. A window leaf [n, B, ...]
-                    # strips its leading step axis.
+                if self._abstract_batch is None:
+                    # Shapes only, no device ops; a window leaf [n, B, ...]
+                    # loses its leading step axis.
                     self._abstract_batch = jax.tree.map(
-                        lambda x: jax.ShapeDtypeStruct(
-                            x.shape if n == 1 else x.shape[1:], x.dtype
-                        ),
+                        lambda x: jax.ShapeDtypeStruct(x.shape if n == 1 else x.shape[1:], x.dtype),
                         batch,
                     )
+                if self.preflight is not None and not self._preflight_done:
+                    # Before anything compiles. The verdict covers the chained
+                    # window only when one can still occur this epoch: an
+                    # epoch shorter than a window dispatches singles only, and
+                    # a verdict on a program that never runs could fail a run
+                    # whose real program fits.
+                    self._run_memory_preflight(
+                        can_chain=chain > 1 and num_batches - step_in_epoch >= chain
+                    )
+                    tel.preflight_ran()
+                unit_traces = self._trace_total()
                 if n > 1 and not self._fault_active_in_window(
                     epoch, step_in_epoch, step_in_epoch + n
                 ):
-                    # -- chained window: one dispatch runs n steps on device.
-                    # Preemption is polled at window boundaries only (the
-                    # device program has no mid-window host hook), so saves
-                    # land on boundaries and the watchdog/vote cadences above
-                    # are scaled/rounded to match.
-                    if self._preemption_requested(step_in_epoch):
-                        self._preempted = True  # collective (multi-host OR)
-                        interrupted = True
-                        break
-                    if cap is not None:
-                        cap.maybe_start(step_in_epoch, self.state.params)
-                    self.state, window_metrics = dispatch(
-                        self.engine.train_steps_chained, self.state, batch, n, steps=n
+                    interrupted = not run_unit(self.engine.train_steps_chained, batch, n)
+                else:
+                    # Lead and tail units, chain_steps == 1, and windows with a
+                    # fault pending: those are unstacked so that the per-step
+                    # injection points and preemption polls run.
+                    singles = (
+                        (batch,)
+                        if n == 1
+                        else (self.engine.unstack_window(batch, i) for i in range(n))
                     )
-                    collected.append((n, window_metrics))
-                    step_in_epoch += n
-                    executed += n
-                    if cap is not None:
-                        cap.maybe_stop(step_in_epoch, self.state.params)
-                    watchdog = self._pat_watchdog(watchdog, watchdog_timeout)
-                    if bar is not None:
-                        bar.update(n)
-                    if self.log_every and step_in_epoch % self.log_every == 0:
-                        with annotate("trainer.sync", epoch=epoch, reason="log_every"):
-                            sync_log_point()
-                    tick_unit()
-                    continue
-                # -- single-step path: lead/tail units, chain_steps == 1, and
-                # windows with pending fault injections (unstacked so the
-                # per-step injection points and preemption checks actually
-                # run — semantics identical to the unchained loop).
-                singles = (
-                    (batch,)
-                    if n == 1
-                    else (self.engine.unstack_window(batch, i) for i in range(n))
+                    for b in singles:
+                        if self.fault_plan is not None:
+                            b = self._inject_step_faults(b, epoch, step_in_epoch)
+                        if not run_unit(self.train_step, b, 1):
+                            interrupted = True
+                            break
+                tel.unit_done(
+                    self._trace_total() - unit_traces, epoch=epoch, step_in_epoch=step_in_epoch
                 )
-                for b in singles:
-                    if self.fault_plan is not None:
-                        b = self._inject_step_faults(b, epoch, step_in_epoch)
-                    if self._preemption_requested(step_in_epoch):
-                        self._preempted = True  # collective (multi-host OR)
-                        interrupted = True
-                        break
-                    if cap is not None:
-                        cap.maybe_start(step_in_epoch, self.state.params)
-                    self.state, metrics = dispatch(self.train_step, self.state, b, steps=1)
-                    collected.append((1, metrics))
-                    step_in_epoch += 1
-                    executed += 1
-                    if cap is not None:
-                        cap.maybe_stop(step_in_epoch, self.state.params)
-                    watchdog = self._pat_watchdog(watchdog, watchdog_timeout)
-                    if bar is not None:
-                        # Advancing the bar is host-only; the postfix refreshes
-                        # at the log_every sync points (a true per-step live
-                        # loss would force the reference's per-step
-                        # loss.item() sync back in).
-                        bar.update(1)
-                    if self.log_every and step_in_epoch % self.log_every == 0:
-                        with annotate("trainer.sync", epoch=epoch, reason="log_every"):
-                            sync_log_point()
-                tick_unit()
-                if interrupted:
-                    break
             if interrupted:
                 self._epoch_interrupted = True
                 self._interrupted_at_step = step_in_epoch
         except BaseException:
-            # An abort with a capture window open (anomaly raise, watchdog
-            # hung-step, nan_policy raise) must still stop the PROCESS-GLOBAL
-            # jax.profiler session — leaving it running would make every
-            # later start_trace in this process fail. sync=None: never block
-            # teardown on (possibly hung) device work; abort=True: never pay
-            # trace analysis or the roofline probe compile ahead of the
-            # emergency-save path.
+            # An abort with a capture window open must still stop the
+            # PROCESS-GLOBAL jax.profiler session, or every later start_trace
+            # in this process fails. sync=None: never block teardown on
+            # (possibly hung) device work; abort=True: no trace analysis or
+            # roofline compile ahead of the emergency-save path.
             if cap is not None and cap.state == "tracing":
                 cap.maybe_stop(step_in_epoch, None, force=True, abort=True)
             raise
         finally:
             if watchdog is not None:
                 watchdog.stop()
-        if cap is not None:  # close a still-open capture window (short epoch)
-            # A preemption-interrupted epoch is on the emergency-save clock:
-            # abort=True skips trace analysis and the roofline probe compile
-            # (same contract as the exception teardown above) — the grace
-            # window is for the checkpoint, not a report.
+        if cap is not None:
+            # A window still open (short epoch). An interrupted epoch is on
+            # the emergency-save clock: abort, as above.
             cap.maybe_stop(
-                step_in_epoch,
-                self.state.params,
-                force=True,
-                abort=self._epoch_interrupted,
+                step_in_epoch, self.state.params, force=True, abort=self._epoch_interrupted
             )
         if bar is not None:
             bar.close()
         if not collected:
             return {}
-        # ONE host transfer for the whole epoch, then expand window records
-        # to per-step dicts host-side (free: numpy indexing, no device ops).
+        # ONE host transfer for the whole epoch; window records are expanded
+        # to per-step dicts on the host (numpy indexing, no device ops).
         host: list[dict] = []
         with annotate("trainer.sync", epoch=epoch, reason="epoch_drain"):
             drained = jax.device_get(collected)
@@ -2130,86 +1366,23 @@ class Trainer:
             if k == 1:
                 host.append(tree)
             else:
-                host.extend(
-                    {key: v[i] for key, v in tree.items()} for i in range(k)
-                )
-        if tm is not None:
-            # The device_get above drained every in-flight step — that wait
-            # is device execution, i.e. productive time.
-            tm.tick("productive_step")
+                host.extend({key: v[i] for key, v in tree.items()} for i in range(k))
+        tel.drained()
         with annotate("trainer.epoch_end", epoch=epoch):
-            # Epoch wall time is closed BEFORE the MFU probe: the probe's one-time
-            # XLA compile (seconds to minutes on a real model) must not inflate
-            # this epoch's step_ms/MFU report — a first-epoch step-time figure
-            # 2.5x the window baseline would fire a spurious step_time_regression.
+            # The wall is closed BEFORE the one-time FLOP probe: its compile
+            # would inflate this epoch's step time into a spurious
+            # step_time_regression.
             epoch_wall = time.perf_counter() - t0
-            self._maybe_probe_mfu()  # one-time; attributes itself to `compile`
+            tel.probe_flops(
+                self.engine, self.state, self._abstract_batch,
+                engine_step_runs=type(self).train_step is Trainer.train_step,
+            )
             out = self._aggregate_epoch_metrics(host, synced_steps)
-            if self.telemetry is not None and executed:
-                report = telemetry_mfu.window_report(
-                    executed,
-                    epoch_wall,
-                    flops_per_step=self._flops_per_step,
-                    peak_flops=self._peak_flops,
-                )
-                self._last_step_ms = report["step_ms"]
-                health = {
-                    k: out[k]
-                    for k in ("loss", "ce_loss", "grad_norm", "update_ratio", "nonfinite")
-                    if k in out
-                }
-                mem_fields = self._live_memory_fields()
-                epoch_fields = {}
-                if self.goodput is not None:
-                    # Cumulative goodput snapshot per epoch: the timeline
-                    # exporter turns consecutive snapshots into per-bucket
-                    # spans, and the offline doctor reads the last one.
-                    epoch_fields["goodput_seconds"] = self.goodput.to_state()
-                if self._last_straggler:
-                    epoch_fields["chip_skew_ms"] = self._last_straggler["chip_skew_ms"]
-                    epoch_fields["straggler_ratio"] = self._last_straggler[
-                        "straggler_ratio"
-                    ]
-                self.events.emit(
-                    "epoch_end",
-                    epoch=epoch,
-                    wall_s=epoch_wall,
-                    interrupted=self._epoch_interrupted,
-                    **report,
-                    **health,
-                    **mem_fields,
-                    **epoch_fields,
-                )
-                self._attempt_units = getattr(self, "_attempt_units", 0) + executed
-                self._note_heartbeat_progress(
-                    epoch=epoch, step_in_epoch=step_in_epoch,
-                    units=self._attempt_units, step_ms=report["step_ms"],
-                )
-                self._emit_heartbeat("loop")
-                self._update_status(
-                    step_in_epoch=step_in_epoch, units=self._attempt_units,
-                    **mem_fields,
-                )
-                if self.anomaly_detector is not None:
-                    epoch_compiled = (
-                        sum(self.engine.trace_counts.values()) > epoch_trace_start
-                    )
-                    self._report_anomalies(
-                        self.anomaly_detector.observe(
-                            step_in_epoch,
-                            loss=out.get("loss", out.get("ce_loss")),
-                            grad_norm=out.get("grad_norm"),
-                            # An epoch that paid compile (epoch 0, or a resume
-                            # retrace) reports a compile-diluted mean step
-                            # time: withheld, like the per-window rule above.
-                            step_time=None
-                            if epoch_compiled
-                            else report["step_ms"] / 1e3,
-                            live_bytes=mem_fields.get("live_bytes"),
-                        ),
-                        epoch=epoch,
-                        step_in_epoch=step_in_epoch,
-                    )
+            tel.epoch_end(
+                out, epoch=epoch, step_in_epoch=step_in_epoch, executed=executed,
+                wall_s=epoch_wall, interrupted=self._epoch_interrupted,
+                traces=self._trace_total(), nonfinite_steps=self.nonfinite_steps,
+            )
         return out
 
     def _aggregate_epoch_metrics(self, host: list[dict], synced: int = 0) -> dict:
@@ -2323,13 +1496,12 @@ class Trainer:
             )
             os._exit(75)  # EX_TEMPFAIL
         self._hung_once = True
-        self._hung_steps += 1
         self.log(
             f"watchdog: no step completed in {timeout}s — forcing a "
             "preemption-style resumable save",
             "warning",
         )
-        self.events.emit("hung_step", timeout_s=timeout)
+        self.run_telemetry.hung_step(timeout)
         os.kill(os.getpid(), signal.SIGTERM)
 
     def _on_preemption_signal(self, signum, frame) -> None:
@@ -2483,9 +1655,8 @@ class Trainer:
         (Megatron-style TP for the ViT/LM transformer blocks — conv models
         match none of its patterns and fall through to the FSDP/replicated
         fallback), any other mesh gets None (pure FSDP via ``spec_for_leaf``
-        / ``_fsdp_spec``, or fully replicated on a pure-data mesh — the
-        historical program). Override to hand-place specs for a custom
-        model."""
+        / ``_fsdp_spec``, or fully replicated on a pure-data mesh). Override
+        to hand-place specs for a custom model."""
         from distributed_training_pytorch_tpu.parallel import (
             default_sharding_rules,
         )
@@ -2509,16 +1680,16 @@ class Trainer:
     _image_range_checked = False
 
     def _check_image_range(self, batch: Mapping) -> Mapping:
-        """One-time foot-gun guard (first train batch only): a FLOAT image
-        batch whose values span raw-pixel range almost certainly missed its
-        normalize — ``models.InputNormalizer`` passes floats through as
-        already normalized, so the model would train on ~100x-misscaled
-        input with no error anywhere else."""
+        """One-time foot-gun guard (the whole first train batch, once a
+        trainer): a FLOAT image batch whose values span raw-pixel range almost
+        certainly missed its normalize — ``models.InputNormalizer`` passes
+        floats through as already normalized, so the model would train on
+        ~100x-misscaled input with no error anywhere else."""
         if not self._image_range_checked:
             self._image_range_checked = True
             img = batch.get("image") if hasattr(batch, "get") else None
             if img is not None and np.issubdtype(np.asarray(img).dtype, np.floating):
-                hi = float(np.max(np.abs(np.asarray(img[:1]))))
+                hi = float(np.max(np.abs(np.asarray(img))))
                 if hi > 16.0:  # normalized images sit within a few sigma of 0
                     self.log(
                         f"float image batch spans |x| up to {hi:.0f} — looks like "

@@ -38,6 +38,7 @@ from distributed_training_pytorch_tpu.fault import (
 from distributed_training_pytorch_tpu.parallel import mesh as mesh_lib
 from distributed_training_pytorch_tpu.train import NonFiniteLossError, TrainState
 
+from test_engine import CHAINED_VS_SINGLE_ULPS, assert_trees_within_ulps
 from test_trainer import make_trainer, synthetic_images
 
 
@@ -406,7 +407,8 @@ def test_sigterm_resume_crosses_window_boundary_chained(tmp_path, mesh):
     fault-active window [0,4), which therefore runs single-step, preserving
     exact per-step interruption semantics. The resume then REALIGNS: steps
     2-3 run single-step until the next window boundary, and [4,8) chains —
-    finishing bit-exact with an uninterrupted chain_steps=1 run."""
+    finishing on the step count of an uninterrupted chain_steps=1 run, with
+    params and opt_state within CHAINED_VS_SINGLE_ULPS of its."""
     kw = dict(
         max_epoch=2, batch_size=8, have_validate=False, save_best_for=None,
         save_period=None,
@@ -437,18 +439,12 @@ def test_sigterm_resume_crosses_window_boundary_chained(tmp_path, mesh):
     resumed.train()
 
     assert int(resumed.state.step) == int(baseline.state.step)
-    for a, b in zip(
-        jax.tree.leaves(baseline.state.params),
-        jax.tree.leaves(resumed.state.params),
-        strict=True,
-    ):
-        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
-    for a, b in zip(
-        jax.tree.leaves(baseline.state.opt_state),
-        jax.tree.leaves(resumed.state.opt_state),
-        strict=True,
-    ):
-        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    assert_trees_within_ulps(
+        baseline.state.params, resumed.state.params, CHAINED_VS_SINGLE_ULPS
+    )
+    assert_trees_within_ulps(
+        baseline.state.opt_state, resumed.state.opt_state, CHAINED_VS_SINGLE_ULPS
+    )
     # realignment shape: 2 lead singles (steps 2-3), then ONE chained window
     assert resumed.engine.trace_counts["train_step"] == 1
     assert resumed.engine.trace_counts["chained_4"] == 1

@@ -887,7 +887,7 @@ def test_exporter_on_is_historical_program(tmp_path, hb_run):
     # scrape mid-run through the real HTTP surface (piggybacked on the
     # status-update hook so the request lands while training is live)
     scrapes = {}
-    orig = off._update_status
+    orig = off.run_telemetry._update_status
 
     def spy(**kw):
         orig(**kw)
@@ -898,7 +898,7 @@ def test_exporter_on_is_historical_program(tmp_path, hb_run):
             scrapes["metrics"] = urllib.request.urlopen(
                 base + "/metrics", timeout=10).read().decode()
 
-    off._update_status = spy
+    off.run_telemetry._update_status = spy
     off.train()
     assert scrapes, "the exporter never served during the run"
     assert scrapes["status"]["phase"] == "training"

@@ -5,8 +5,8 @@ trainer integration's acceptance pillars:
 * on-device stats add ZERO extra host syncs and ZERO retraces —
   ``TrainEngine.trace_counts`` identical with telemetry on/off — and never
   perturb the update arithmetic (params bit-exact with a stats-off run);
-* chained windows stay bit-exact with single-step runs with stats enabled
-  (the PR 2 invariant extended);
+* chained windows with stats enabled run the arithmetic of single-step runs
+  (params and per-step stats within ``test_engine.CHAINED_VS_SINGLE_ULPS``);
 * goodput bucket fractions sum to 1, and the cumulative counters survive a
   SIGTERM-kill -> resume cycle bit-identically (the test_fault pattern).
 
@@ -48,7 +48,13 @@ from distributed_training_pytorch_tpu.trainer import Trainer
 from distributed_training_pytorch_tpu.train import TrainEngine, make_supervised_loss
 from distributed_training_pytorch_tpu.utils.tensorboard import MetricsWriter
 
-from test_engine import TinyMLP, criterion, synthetic_batch
+from test_engine import (
+    CHAINED_VS_SINGLE_ULPS,
+    TinyMLP,
+    assert_trees_within_ulps,
+    criterion,
+    synthetic_batch,
+)
 
 
 @pytest.fixture(scope="module")
@@ -389,9 +395,10 @@ def test_stats_do_not_perturb_training(devices):
 
 
 def test_stats_chained_bit_exact_with_single_step(devices):
-    """PR 2's acceptance invariant extended: chained windows with stats
-    enabled == sequential single steps with stats enabled — params AND every
-    per-step stat metric (stacked scan outputs) bit-exact."""
+    """Chained windows with stats enabled == sequential single steps with
+    stats enabled: the same step count and ``nonfinite`` flags, params,
+    opt_state and every per-step stat (stacked scan outputs) within
+    CHAINED_VS_SINGLE_ULPS."""
     host = [synthetic_batch(16, seed=20 + i) for i in range(4)]
     eng_a, state_a = make_engine(stats=True)
     eng_b, state_b = make_engine(stats=True)
@@ -402,14 +409,14 @@ def test_stats_chained_bit_exact_with_single_step(devices):
     stacked_host = jax.tree.map(lambda *xs: np.stack(xs), *host)
     gb = mesh_lib.global_chain_array_from_host_local(stacked_host, eng_b.mesh)
     state_b, stacked = eng_b.train_steps_chained(state_b, gb, 4)
-    assert_trees_equal(state_a.params, state_b.params)
-    assert_trees_equal(state_a.opt_state, state_b.opt_state)
+    assert int(state_b.step) == int(state_a.step) == 4
+    assert_trees_within_ulps(state_a.params, state_b.params, CHAINED_VS_SINGLE_ULPS)
+    assert_trees_within_ulps(state_a.opt_state, state_b.opt_state, CHAINED_VS_SINGLE_ULPS)
     stacked = jax.device_get(stacked)
-    for key in ("grad_norm", "param_norm", "update_ratio", "nonfinite", "loss"):
-        for i, m in enumerate(seq):
-            np.testing.assert_array_equal(
-                np.asarray(m[key]), np.asarray(stacked[key][i]), err_msg=key
-            )
+    for i, m in enumerate(seq):
+        np.testing.assert_array_equal(m["nonfinite"], stacked["nonfinite"][i])
+        for key in ("grad_norm", "param_norm", "update_ratio", "loss"):
+            assert_trees_within_ulps(m[key], stacked[key][i], CHAINED_VS_SINGLE_ULPS)
 
 
 def test_stats_compose_with_nan_guard(devices):

@@ -108,6 +108,29 @@ def make_trainer(tmp_path, mesh, cls=ToyTrainer, **kw):
     return cls(**defaults)
 
 
+@pytest.mark.parametrize(
+    "first_row, rest, dtype, warns",
+    [
+        (200.0, 200.0, np.float32, True),  # raw pixels as floats
+        (0.0, 200.0, np.float32, True),  # a dark first row must not hide the rest
+        (1.5, -2.0, np.float32, False),  # normalized
+        (200, 200, np.uint8, False),  # integer pixels are normalized on the device
+    ],
+)
+def test_raw_pixel_float_batch_warns_once(first_row, rest, dtype, warns):
+    """``_check_image_range`` reads the whole first batch, and only the first."""
+    trainer = Trainer.__new__(Trainer)
+    logger = _CaptureLogger()
+    trainer.log = logger.log
+    image = np.full((4, 8, 8, 3), rest, dtype)
+    image[0] = first_row
+    batch = {"image": image, "label": np.zeros(4, np.int32)}
+    assert trainer._check_image_range(batch) is batch
+    assert any("raw 0-255" in line for line in logger.lines) == warns
+    trainer._check_image_range({"image": np.full((4, 8, 8, 3), 255.0, np.float32)})
+    assert sum("raw 0-255" in line for line in logger.lines) == int(warns)
+
+
 @pytest.fixture(scope="module")
 def trained(tmp_path_factory, mesh):
     """One full 3-epoch training run with validation + best/last saves."""

@@ -289,11 +289,12 @@ def phase_kernels(smoke: Smoke, devices) -> None:
 
     # (name, B, T, H, D, causal, valid_len): [0] the chip's shapes, [1] the
     # rehearsal's. ViT-B/16: T=197 padded to 256 by ViT.pad_seq_to, 12 heads
-    # of 64. The LM's default path (T=1024: one block pair), the benchmark's
-    # long cell (T=4096: several block pairs, dq summed over the k-block grid
-    # axis) and the long-context shape (T=8192), each at the blocks
-    # ops.pallas._flash_blocks gives it: heads cut to 4 and 2 so the float32
-    # reference's [B,H,T,T] scores (0.5 GB) fit beside the kernel's operands.
+    # of 64. The LM's default path (T=1024: one block pair, walked in static
+    # sub-tiles), the benchmark's long cell (T=4096: several block pairs, dq
+    # summed over the k-block grid axis) and the long-context shape (T=8192),
+    # each at the blocks ops.pallas._flash_blocks gives it: heads cut to 4 and
+    # 2 so the float32 reference's [B,H,T,T] scores (0.5 GB) fit beside the
+    # kernel's operands.
     for chip, toy in (
         (("flash_vit_valid_len", 8, 256, 12, 64, False, 197),
          ("flash_vit_valid_len", 2, 32, 2, 16, False, 25)),

@@ -226,9 +226,11 @@ def attention_fn(
                 "flash",
                 reason="pallas=True (forced)" if use_flash is True else f"auto: T={seq} >= {min_seq_len}",
                 seq_len=seq,
-                # Static at trace time: the forward's block shape, how many of
-                # a sequence's block pairs it visits (causal skips the rest),
-                # and which backward these shapes get at which block shape.
+                # Static at trace time: the forward's block shape, the
+                # sub-tile size a one-block causal call is walked in, how many
+                # of a sequence's block pairs (or sub-tiles) it visits (causal
+                # skips the rest), and which backward these shapes get at
+                # which block shape and sub-tile size.
                 **flash_block_plan(
                     seq, k.shape[1], causal, kwargs.get("block_q"), kwargs.get("block_k")
                 ),

@@ -42,9 +42,18 @@ builds the iota / compare / select mask: a second, mask-free loop for the
 blocks wholly below the diagonal measured 2-7% *slower* on the v5e (the
 mask's vector work hides under the exponentials and matmuls; a second loop's
 carried accumulators do not — PERF.md §6, PR 26). The block shape comes from
-``(T_q, T_k, causal)``, one shape per kernel (:func:`_flash_blocks`);
-:func:`flash_block_plan` reports both kernels', with the number of block pairs
-the forward visits, for the ``kernel_dispatch`` record.
+``(T_q, T_k, causal)``, one shape per kernel (:func:`_flash_blocks`).
+
+A causal call whose score square is ONE block pair (T <= 1024 under that rule)
+has no block to skip and no loop: both kernels walk the block as static
+sub-tiles instead (:func:`_flash_sub`, :func:`_fwd_subtiles`,
+:func:`_bwd_subtiles`) — a group of rows (forward) or of keys (backward) takes
+everything the mask lets it see as one tile, and the sub-tiles above the
+diagonal are not in the program at all; a row's softmax is whole inside its
+tile, so the forward carries and rescales nothing. :func:`flash_block_plan`
+reports both kernels' block shapes and sub-tile sizes, with the number of
+block pairs (or sub-tiles) the forward visits, for the ``kernel_dispatch``
+record.
 """
 
 from __future__ import annotations
@@ -94,13 +103,21 @@ def _flash_blocks(kernel: str, t_q: int, t_k: int, causal: bool) -> tuple[int, i
     bwd [4,12,8192,64]  15.07        14.77        **14.71**    9.15 + 13.84
     ==================  ===========  ===========  ===========  =================
 
+    (Each entry includes the 0.75-1.6 ms a call that such a one-kernel program
+    spends transposing operands between XLA's entry layout and the kernel's;
+    re-read with ``--kernel-layout`` in PR 32 every row keeps its winner:
+    4.31 / 7.54 at 512 x 512 of T=4096, 1.21 / 2.75 as one tile of T=1024,
+    7.56 / 13.12 at T=8192.)
+
     Smaller blocks skip more of the masked half (44% of block pairs at 512,
     37.5% at 1024, of T=4096) but every loop trip pays for its carried
     accumulators, and a 256-wide block on either side loses everywhere (the
     forward's per-row rescale becomes a quarter of its work; the backward
     reads 9.3-14.0 at T=4096). At T=1024 nothing beats one 1024 x 1024 block
-    pair, which lowers to straight-line code. Non-causal calls have nothing
-    to skip and keep 1024 x 1024. Past T = 4096 the whole-T K/V slabs leave
+    pair, which lowers to straight-line code; a causal call then leaves the
+    masked sub-tiles of that one pair out of the program statically
+    (:func:`_flash_sub`, PR 32). Non-causal calls have nothing to skip and
+    keep 1024 x 1024. Past T = 4096 the whole-T K/V slabs leave
     the forward no room for 1024 x 1024 float32 tiles under the compiler's
     default limit (refused by Mosaic at 8192); the backward is compiled under
     a limit of its own (:func:`_bwd_vmem_bytes`) and is fastest there at
@@ -127,24 +144,86 @@ def _block_size(block: int, t: int) -> int:
     return min(block, ((max(t, 1) + 127) // 128) * 128)
 
 
-def _resolve_blocks(kernel, block_q, block_k, t_q, t_k, causal) -> tuple[int, int]:
-    """The ``(bq, bk)`` one kernel (``"fwd"``, ``"bwd"``) runs at: a
-    caller's explicit size wins, else the shape rule's; both clamped to T."""
+# Groups a side that a one-block causal call is walked in, each kernel's
+# preference first (:func:`_flash_sub` has the sweep they come from).
+_SUB_SPLITS = {"fwd": (2, 4), "bwd": (8, 4, 2)}
+
+
+def _flash_sub(kernel: str, t_q: int, t_k: int, bq: int, bk: int, causal: bool) -> Optional[int]:
+    """The sub-tile size a kernel (``"fwd"``, ``"bwd"``) walks its one block
+    pair in, from what the call observes, or None for the one-tile body.
+
+    A causal call whose score square is one block pair (every causal call
+    with T <= 1024 under :func:`_flash_blocks`) has nothing to skip at block
+    granularity and, as one tile, computes the masked triangle only to mask
+    it: twice the required work. Split into ``n`` groups of ``sub`` rows and
+    columns, the ``n (n - 1) / 2`` sub-tiles above the diagonal are left out
+    of the program statically — no loop, no carried accumulator — and each
+    group takes its whole visible range as one tile, so the forward needs no
+    online rescale either: 3/4 of the square is executed at ``n = 2``, 5/8 at
+    ``n = 4``, 9/16 at ``n = 8``. ``sub`` is the larger side over ``n`` for
+    the first ``n`` of the kernel's preference (``_SUB_SPLITS``) that gives a
+    multiple of the 128-lane tile dividing both sides (``T_q != T_k`` occurs
+    on the ring's diagonal block).
+    None — today's body, bit for bit — for non-causal calls, for any axis of
+    several blocks, and where the block does not split so (T <= 128; 256
+    against 384).
+
+    Measured on the TPU v5e (``scripts/flash_block_sweep.py --sub
+    512,256,128``: bf16, ``[32, 12, 1024, 64]``, causal, each kernel alone at
+    1024 x 1024, ms a call; PR 32; PERF.md §6 has the variants tried):
+
+    =======================  ========  =========  =========  =========
+    kernel                   one tile  sub = 512  sub = 256  sub = 128
+    =======================  ========  =========  =========  =========
+    fwd alone                1.98      **1.72**   1.81       1.92
+    fwd ``--kernel-layout``  1.22      **0.94**   1.03       1.14
+    bwd alone                4.21      3.55       3.27       **3.18**
+    bwd ``--kernel-layout``  2.75      2.11       1.81       **1.67**
+    =======================  ========  =========  =========  =========
+
+    (Alone, a call also transposes its operands between XLA's entry layout
+    and the kernel's, 0.8 / 1.5 ms whatever the sub-tile; ``--kernel-layout``
+    pins them. In ``gpt2s_t1024``'s traced step a call reads 1.17 -> 0.90 ms
+    forward and 2.70 -> 1.62 backward, and the other splits rank as here.)
+
+    The backward keeps gaining down to the lane tile (its five matmuls a
+    tile, each half-filling an MXU pass at head dim 64, are most of its time,
+    and fewer sub-tiles are fewer matmul rows); the forward is best at two
+    row groups: past that its smaller matmuls lose what the skipped
+    sub-tiles save."""
+    if not causal or t_q > bq or t_k > bk:
+        return None
+    for n in _SUB_SPLITS[kernel]:
+        sub = max(bq, bk) // n
+        if sub % 128 == 0 and bq % sub == 0 and bk % sub == 0:
+            return sub
+    return None
+
+
+def _resolve_blocks(kernel, block_q, block_k, t_q, t_k, causal) -> tuple[int, int, Optional[int]]:
+    """The ``(bq, bk, sub)`` one kernel (``"fwd"``, ``"bwd"``) runs at: a
+    caller's explicit size wins, else the shape rule's; both clamped to T;
+    ``sub`` as :func:`_flash_sub` gives it for those blocks."""
     rule_q, rule_k = _flash_blocks(kernel, t_q, t_k, causal)
-    return _block_size(block_q or rule_q, t_q), _block_size(block_k or rule_k, t_k)
+    bq, bk = _block_size(block_q or rule_q, t_q), _block_size(block_k or rule_k, t_k)
+    return bq, bk, _flash_sub(kernel, t_q, t_k, bq, bk, causal)
 
 
 def flash_block_plan(t_q, t_k, causal, block_q=None, block_k=None) -> dict:
     """What the flash path does at these shapes, for the ``kernel_dispatch``
-    record: the forward's block shape and :func:`flash_block_counts` at it,
-    and which backward the custom VJP takes (``"fused"``: dq, dk and dv from
-    one kernel, the only one there is since ISSUE 30) at which block shape."""
-    bq, bk = _resolve_blocks("fwd", block_q, block_k, t_q, t_k, causal)
-    total, computed = flash_block_counts(t_q, t_k, bq, bk, causal)
-    bwd_q, bwd_k = _resolve_blocks("bwd", block_q, block_k, t_q, t_k, causal)
+    record: the forward's block shape, the sub-tile size it walks a one-block
+    causal call in (None: one tile) and :func:`flash_block_counts` at the
+    granularity it skips at, and which backward the custom VJP takes
+    (``"fused"``: dq, dk and dv from one kernel, the only one there is since
+    ISSUE 30) at which block shape and sub-tile size."""
+    bq, bk, sub = _resolve_blocks("fwd", block_q, block_k, t_q, t_k, causal)
+    total, computed = flash_block_counts(t_q, t_k, sub or bq, sub or bk, causal)
+    bwd_q, bwd_k, bwd_sub = _resolve_blocks("bwd", block_q, block_k, t_q, t_k, causal)
     return {
-        "block_q": bq, "block_k": bk, "blocks_total": total, "blocks_computed": computed,
-        "backward": "fused", "bwd_block_q": bwd_q, "bwd_block_k": bwd_k,
+        "block_q": bq, "block_k": bk, "sub_block": sub,
+        "blocks_total": total, "blocks_computed": computed,
+        "backward": "fused", "bwd_block_q": bwd_q, "bwd_block_k": bwd_k, "bwd_sub_block": bwd_sub,
     }
 
 
@@ -193,7 +272,9 @@ def flash_block_counts(t_q: int, t_k: int, bq: int, bk: int, causal: bool) -> tu
     """``(blocks_total, blocks_computed)``: the ``[bq, bk]`` block pairs of one
     (batch, head)'s ``T_q x T_k`` score square, and how many of them the
     forward kernel visits (the backward kernel visits the same pairs at its
-    own block shape)."""
+    own block shape). Sub-tiles of one block pair (:func:`_flash_sub`) count
+    the same way, at ``bq = bk = sub``: the kernels take their static bounds
+    from the same two functions."""
     n_q, n_k = pl.cdiv(t_q, bq), pl.cdiv(t_k, bk)
     total = n_q * n_k
     if not causal:
@@ -204,9 +285,10 @@ def flash_block_counts(t_q: int, t_k: int, bq: int, bk: int, causal: bool) -> tu
 def _grid_index(axis: int, extent: int):
     """``pl.program_id(axis)``, or a plain 0 on an axis of one block: the
     bounds above then fold to Python ints and the kernels' loops to static
-    ones, so a call whose T fits one block (ViT's 197; T=1024 at 1024) lowers
-    to the straight-line program it was before the loops followed the mask
-    (a dynamic trip count of 1 measured +35% on the forward at T=1024)."""
+    ones, so a call whose T fits one block (ViT's 197; a causal block that
+    :func:`_flash_sub` does not split) lowers to the straight-line program it
+    was before the loops followed the mask (a dynamic trip count of 1 measured
+    +35% on the forward at T=1024)."""
     return pl.program_id(axis) if extent > 1 else 0
 
 
@@ -217,7 +299,8 @@ def _grid_index(axis: int, extent: int):
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, block_k, seq_len, causal, n_q):
     """One q-block against the k-blocks it can see, online softmax. Refs are
-    (1, 1, bq, D) / (1, 1, Tp, D) blocks; statistics in f32."""
+    (1, 1, bq, D) / (1, 1, Tp, D) blocks; statistics in f32. (A causal call of
+    one block pair runs :func:`_fwd_subtiles` instead.)"""
     bq = q_ref.shape[2]
     d = q_ref.shape[3]
     t_pad = k_ref.shape[2]
@@ -270,6 +353,65 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, block_k, seq_len,
     lse_ref[0, 0] = jnp.transpose(m + jnp.log(l_safe), (1, 0))  # [1, bq]
 
 
+def _hide(s, q0: int, k0: int, seq_len: int, keys_on_rows: bool = False):
+    """A score tile whose first query is position ``q0`` and first key ``k0``,
+    with NEG_INF where the causal mask or the key padding hides a key; each
+    compare is built only where the tile's place says some element needs it
+    (a sub-tile below the diagonal and short of the padding comes back as it
+    is). ``keys_on_rows``: the tile is ``[keys, queries]``, else
+    ``[queries, keys]``."""
+    n_k, n_q = s.shape if keys_on_rows else s.shape[::-1]
+    k_axis = 0 if keys_on_rows else 1
+    causal = k0 + n_k - 1 > q0  # the last key is past the first query
+    padded = k0 + n_k > seq_len
+    if not (causal or padded):
+        return s
+    k_idx = k0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, k_axis)
+    mask = k_idx < seq_len if padded else None
+    if causal:
+        seen = q0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1 - k_axis) >= k_idx
+        mask = seen if mask is None else jnp.logical_and(mask, seen)
+    return jnp.where(mask, s, NEG_INF)
+
+
+def _fwd_subtiles(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, sub, seq_len):
+    """The forward of a causal call whose score square is one block pair, as
+    static sub-tiles: row group ``r`` (``sub`` rows) takes the columns it can
+    see, ``[0, _k_blocks_end(r) * sub)``, as ONE ``[sub, width]`` tile — one
+    score product, one max, one exponential pass, one sum, one ``p @ v``, one
+    divide. The sub-tiles above the diagonal are never built (every weight
+    there is exactly 0), and a row's softmax is whole inside its tile: no
+    loop, no carried ``acc / m / l``, no rescale. The mask is laid on the
+    group's diagonal sub-tile alone (and on padded keys where there are any):
+    the columns before it are seen by every row of the group."""
+    bq = q_ref.shape[2]
+    n_k = k_ref.shape[2] // sub
+    for r in range(bq // sub):
+        rows = pl.ds(r * sub, sub)
+        width = _k_blocks_end(r, sub, sub, n_k) * sub
+        below = min(r * sub, width)  # columns every row of the group sees
+        q = q_ref[0, 0, rows, :]  # [sub, D]
+        k = k_ref[0, 0, pl.ds(0, width), :]  # [width, D]
+        v = v_ref[0, 0, pl.ds(0, width), :]
+        s = scale * jax.lax.dot_general(
+            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        )  # [sub, width] f32
+        parts = [
+            _hide(s[:, k0:end], r * sub, k0, seq_len)
+            for k0, end in ((0, below), (below, width)) if end > k0
+        ]
+        # Column 0 is seen by every row: m is a real logit and l >= 1.
+        m = functools.reduce(jnp.maximum, [jnp.max(x, axis=1, keepdims=True) for x in parts])
+        parts = [jnp.exp(x - m) for x in parts]
+        l = functools.reduce(jnp.add, [jnp.sum(x, axis=1, keepdims=True) for x in parts])
+        p = parts[0] if len(parts) == 1 else jnp.concatenate(parts, axis=1)
+        acc = jax.lax.dot_general(
+            p.astype(v.dtype), v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
+        )  # [sub, D]
+        o_ref[0, 0, rows, :] = (acc / l).astype(o_ref.dtype)
+        lse_ref[0, 0, :, rows] = jnp.transpose(m + jnp.log(l), (1, 0))  # [1, sub]
+
+
 # ---------------------------------------------------------------------------
 # Backward kernels
 # ---------------------------------------------------------------------------
@@ -296,7 +438,8 @@ def _bwd_kernel(
     rows inside the loop, and scaled, cast and written to the resident dq
     block once, after the last k-block (ascending j, every addition float32).
     Where the axis has one block there is nothing to sum and no scratch: the
-    rows go straight to ``dq_ref``."""
+    rows go straight to ``dq_ref``. (A causal call of one block pair runs
+    :func:`_bwd_subtiles` instead.)"""
     bk = k_ref.shape[2]
     d = k_ref.shape[3]
     t_pad = q_ref.shape[2]
@@ -366,6 +509,67 @@ def _bwd_kernel(
             dq_ref[0, 0] = (dq_acc[...] * scale).astype(dq_ref.dtype)
 
 
+def _bwd_subtiles(
+    q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, dk_ref, dv_ref, *, scale, sub, seq_len
+):
+    """The backward of a causal call whose score square is one block pair, as
+    static sub-tiles in :func:`_bwd_kernel`'s orientation: k row group ``j``
+    (``sub`` rows of k / v) against the q columns that can see it, from
+    ``_q_blocks_start(j) * sub`` on, as ONE ``[sub, width]`` tile. ``dk_j`` and
+    ``dv_j`` come whole from it; the tile's share of dq is added, float32, to
+    the row groups it covers (ascending ``j``, as the slab's sum runs), and
+    each group of dq is scaled, cast and stored once. A k group no row sees
+    (``T_q < T_k``) gets zeros and leaves dq closed."""
+    bq, d = q_ref.shape[2], q_ref.shape[3]
+    n_q = bq // sub
+    dq = [None] * n_q  # float32 [sub, D] a q row group
+    for j in range(k_ref.shape[2] // sub):
+        krows = pl.ds(j * sub, sub)
+        first = min(_q_blocks_start(j, sub, sub), n_q)
+        if first == n_q:
+            dk_ref[0, 0, krows, :] = jnp.zeros((sub, d), dk_ref.dtype)
+            dv_ref[0, 0, krows, :] = jnp.zeros((sub, d), dv_ref.dtype)
+            continue
+        width = bq - first * sub
+        cols = pl.ds(first * sub, width)
+        k = k_ref[0, 0, krows, :]  # [sub, D]
+        v = v_ref[0, 0, krows, :]
+        q = q_ref[0, 0, cols, :]  # [width, D]
+        do = do_ref[0, 0, cols, :]
+        lse = lse_ref[0, 0, :, cols]  # [1, width]
+        delta = delta_ref[0, 0, :, cols]
+        # Both score-shaped products before the exponentials: with dp^T after
+        # them, as _bwd_kernel's loop body has it, the kernel read 3% slower
+        # in the step at sub = 128 (PERF.md §6, PR 32).
+        st = scale * jax.lax.dot_general(
+            k, q, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        )  # [sub, width] f32
+        dpt = jax.lax.dot_general(
+            v, do, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        )  # [sub, width]
+        st = _hide(st, first * sub, j * sub, seq_len, keys_on_rows=True)
+        # Padded q rows give p = 1, and vanish through the zero-padded dO and
+        # delta, as in _bwd_kernel.
+        pt = jnp.exp(st - lse)
+        dv = jax.lax.dot_general(
+            pt.astype(do.dtype), do, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
+        )  # [sub, D]
+        dst = (pt * (dpt - delta)).astype(q.dtype)
+        dk = jax.lax.dot_general(
+            dst, q, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
+        )  # [sub, D]
+        share = jax.lax.dot_general(
+            dst, k, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
+        )  # [width, D]: this k group's share of dq's rows from `first` on
+        dk_ref[0, 0, krows, :] = (dk * scale).astype(dk_ref.dtype)
+        dv_ref[0, 0, krows, :] = dv.astype(dv_ref.dtype)
+        for i in range(first, n_q):
+            part = share[(i - first) * sub:(i - first + 1) * sub]
+            dq[i] = part if dq[i] is None else dq[i] + part
+    for i, rows in enumerate(dq):  # k group 0 is seen by every row: none is None
+        dq_ref[0, 0, pl.ds(i * sub, sub), :] = (rows * scale).astype(dq_ref.dtype)
+
+
 # ---------------------------------------------------------------------------
 # Wrapper with custom VJP
 # ---------------------------------------------------------------------------
@@ -379,10 +583,18 @@ def _from_bhtd(x):
     return jnp.transpose(x, (0, 2, 1, 3))
 
 
-def _fwd_call(qt, kt, vt, t_k, causal, bq, bk, interpret):
+@functools.partial(jax.jit, static_argnums=(3, 4, 5, 6, 7, 8))
+def _fwd_call(qt, kt, vt, t_k, causal, bq, bk, sub, interpret):
     """Forward pallas call on padded [B, H, T*, D] operands -> (o, lse) in the
     padded layout. Shared by flash_attention (square T) and the ring block
     path (Tq from the resident shard, Tk from the visiting block).
+
+    Jitted (as :func:`_bwd_call` is) so that a program traces and lowers a
+    kernel body once, however many layers and chained steps call it at the
+    same shapes: the sub-tile bodies are straight-line code (36 tiles in the
+    backward at T=1024), and lowered anew at each of a GPT-2 step program's 72
+    call sites they cost the benchmark 7 s of ``setup_s`` (PERF.md §6, PR 32).
+    XLA inlines the calls: the compiled program is the same.
 
     Each kernel of this file is called under a stable ``name=`` inside a
     ``jax.named_scope`` of the same name (``flash_fwd``, ``flash_dqkv``,
@@ -391,9 +603,12 @@ def _fwd_call(qt, kt, vt, t_k, causal, bq, bk, interpret):
     ``flash_dq``, which the benchmark's ``flash_bwd_time_share`` looks for)."""
     b, h, tq_pad, d = qt.shape
     tk_pad = kt.shape[2]
-    kernel = functools.partial(
-        _fwd_kernel, scale=d**-0.5, block_k=bk, seq_len=t_k, causal=causal, n_q=tq_pad // bq
-    )
+    if sub is not None:  # causal, one block pair (_flash_sub)
+        kernel = functools.partial(_fwd_subtiles, scale=d**-0.5, sub=sub, seq_len=t_k)
+    else:
+        kernel = functools.partial(
+            _fwd_kernel, scale=d**-0.5, block_k=bk, seq_len=t_k, causal=causal, n_q=tq_pad // bq
+        )
     call = pl.pallas_call(
         kernel,
         grid=(b, h, tq_pad // bq),
@@ -420,9 +635,9 @@ def _fwd_call(qt, kt, vt, t_k, causal, bq, bk, interpret):
 def _fwd_impl(q, k, v, causal, block_q, block_k, interpret, valid_len=None):
     b, t, h, d = q.shape
     t_k = t if valid_len is None else valid_len  # kernels mask keys >= t_k
-    bq, bk = _resolve_blocks("fwd", block_q, block_k, t, t, causal)
+    bq, bk, sub = _resolve_blocks("fwd", block_q, block_k, t, t, causal)
     qt, kt, vt = _pad_bhtd(q, k, v, bq, bk)
-    o, lse = _fwd_call(qt, kt, vt, t_k, causal, bq, bk, interpret)
+    o, lse = _fwd_call(qt, kt, vt, t_k, causal, bq, bk, sub, interpret)
     return o[:, :, :t, :], lse[:, :, :, :t], (qt, kt, vt)
 
 
@@ -446,16 +661,20 @@ def _bwd_vmem_bytes(tq_pad: int, bq: int, bk: int, d: int, itemsize: int) -> int
     return max(16 * 2**20, slabs + blocks + tiles)
 
 
-def _bwd_call(qt, kt, vt, do, lse_p, delta, t_k, causal, bq, bk, interpret):
+@functools.partial(jax.jit, static_argnums=(6, 7, 8, 9, 10, 11))
+def _bwd_call(qt, kt, vt, do, lse_p, delta, t_k, causal, bq, bk, sub, interpret):
     """Backward pallas call on padded [B, H, T*, D] operands -> (dq, dk, dv).
     Padded q rows are harmless because ``do``/``delta`` are zero-padded (see
     _bwd_kernel); ``t_k`` masks padded K rows."""
     b, h, tq_pad, d = qt.shape
     tk_pad = kt.shape[2]
     n_k = tk_pad // bk
-    kernel = functools.partial(
-        _bwd_kernel, scale=d**-0.5, block_q=bq, seq_len=t_k, causal=causal, n_k=n_k
-    )
+    if sub is not None:  # causal, one block pair (_flash_sub)
+        kernel = functools.partial(_bwd_subtiles, scale=d**-0.5, sub=sub, seq_len=t_k)
+    else:
+        kernel = functools.partial(
+            _bwd_kernel, scale=d**-0.5, block_q=bq, seq_len=t_k, causal=causal, n_k=n_k
+        )
     slab = pl.BlockSpec((1, 1, tq_pad, d), lambda bi, hi, ki: (bi, hi, 0, 0))
     stat = pl.BlockSpec((1, 1, 1, tq_pad), lambda bi, hi, ki: (bi, hi, 0, 0))
     block = pl.BlockSpec((1, 1, bk, d), lambda bi, hi, ki: (bi, hi, ki, 0))
@@ -486,13 +705,13 @@ def _bwd_impl(operands, t_q, t_k, seq_len, causal, block_q, block_k, interpret):
     q/k/v/dO and ``[B, H, 1, T*]`` lse/delta, zero-padded or not, are fitted
     to whole blocks of it (zeros on dO / delta, as _bwd_kernel needs)
     -> padded (dq, dk, dv)."""
-    bq, bk = _resolve_blocks("bwd", block_q, block_k, t_q, t_k, causal)
+    bq, bk, sub = _resolve_blocks("bwd", block_q, block_k, t_q, t_k, causal)
     tq_pad, tk_pad = pl.cdiv(t_q, bq) * bq, pl.cdiv(t_k, bk) * bk
     qt, kt, vt, do, lse, delta = operands
     return _bwd_call(
         _fit(qt, tq_pad, 2), _fit(kt, tk_pad, 2), _fit(vt, tk_pad, 2),
         _fit(do, tq_pad, 2), _fit(lse, tq_pad, 3), _fit(delta, tq_pad, 3),
-        seq_len, causal, bq, bk, interpret,
+        seq_len, causal, bq, bk, sub, interpret,
     )
 
 
@@ -562,9 +781,9 @@ def flash_block_fwd(
     owns the VJP."""
     interpret = resolve_interpret(interpret)
     tq, tk = q.shape[1], k.shape[1]
-    bq, bk = _resolve_blocks("fwd", block_q, block_k, tq, tk, causal)
+    bq, bk, sub = _resolve_blocks("fwd", block_q, block_k, tq, tk, causal)
     qt, kt, vt = _pad_bhtd(q, k, v, bq, bk)
-    o, lse = _fwd_call(qt, kt, vt, tk, causal, bq, bk, interpret)
+    o, lse = _fwd_call(qt, kt, vt, tk, causal, bq, bk, sub, interpret)
     return _from_bhtd(o[:, :, :tq, :]), lse[:, :, 0, :tq]
 
 

@@ -1,12 +1,17 @@
 #!/usr/bin/env python
-"""Block-shape sweep of the flash kernels, each alone (ISSUE 26, ISSUE 30).
+"""Block-shape sweep of the flash kernels, each alone (ISSUE 26, ISSUE 30, ISSUE 32).
 
 Times ``flash_fwd`` and the fused backward ``flash_dqkv`` (``ops/pallas.py``)
 at the LM cells' shapes for every ``(block_q, block_k)`` of a small grid and
-prints one JSON line per measurement; the tables in PERF.md §6 (PR 26, PR 30)
-and the rule in ``ops.pallas._flash_blocks`` come from it. TPU only::
+prints one JSON line per measurement; the tables in PERF.md §6 (PR 26, PR 30,
+PR 32) and the rules in ``ops.pallas._flash_blocks`` / ``_flash_sub`` come from
+it. TPU only::
 
     chiprun --chips 1 -- python scripts/flash_block_sweep.py [--parent build/parent]
+
+``--sub 512,256`` sweeps the other axis instead: a causal call of one block
+pair (T <= 1024) as one tile and walked in static sub-tiles of each size
+given, each kernel alone (``ops.pallas._flash_sub``'s table).
 
 ``--parent DIR`` also times the kernels of the checkout unpacked at DIR (the
 parent commit), each at the block shape that checkout's own rule gives it, for
@@ -14,6 +19,16 @@ the before / after columns: a checkout from before ISSUE 30 has two backward
 kernels, ``dq`` and ``dkv``, whose sum is what ``bwd`` replaced.
 ``--compile-only`` compiles every point for a described v5e and times nothing
 (runs without a chip: what Mosaic refuses there it refuses on the chip).
+
+What "alone" includes: XLA gives the entry parameters of such a one-kernel
+program a layout of its own (T minor-most for ``[B, H, T, 64]``), so every
+call also transposes each operand and result into and out of the kernel's
+row-major layout — 0.8 ms a forward call and 1.5 ms a backward call of
+``[32, 12, 1024, 64]``, the same for every block shape of a row, which is why
+the tables rank shapes rightly and overstate a call's time (in the step the
+producers write the kernel's layout and a call takes 1.17 / 2.70 ms where the
+tables say 1.98 / 4.21). ``--kernel-layout`` pins operands and results to the
+kernel's layout and times the kernel by itself.
 """
 
 from __future__ import annotations
@@ -48,14 +63,18 @@ def load_pallas(root):
 def kernel_fns(mod, shape, causal, blocks):
     """``{kernel: (fn, args, (bq, bk))}`` on padded ``[B, H, T, D]`` bf16
     operands: the kernels the checkout behind ``mod`` has, all at ``blocks``
-    or, where that is None, each at the shape the checkout's own rule gives."""
+    (``(bq, bk, sub)``; a checkout from before ISSUE 32 has no sub-tiles and
+    takes ``(bq, bk)``) or, where that is None, each at what the checkout's own
+    rule gives."""
     b, h, t, d = shape
     q = k = v = do = jax.ShapeDtypeStruct(shape, jnp.bfloat16)
     stat = jax.ShapeDtypeStruct((b, h, 1, t), jnp.float32)
     grads = (q, k, v, do, stat, stat)
 
     def at(kernel):
-        return blocks or mod._flash_blocks(kernel, t, t, causal)
+        if hasattr(mod, "_flash_sub"):
+            return blocks or mod._resolve_blocks(kernel, None, None, t, t, causal)
+        return (blocks or mod._flash_blocks(kernel, t, t, causal))[:2]
 
     # kernel -> (the checkout's call, its operands, what it takes between them and the blocks)
     calls = {"fwd": ("_fwd_call", (q, k, v), (t, causal))}
@@ -80,6 +99,14 @@ def materialize(args):
     return out
 
 
+def row_major(sharding):
+    """The kernels' own operand layout, as a format ``jax.jit`` and
+    ``jax.device_put`` take."""
+    from jax.experimental.layout import Format, Layout
+
+    return Format(Layout(major_to_minor=(0, 1, 2, 3)), sharding)
+
+
 def time_ms(compiled, args, iters):
     jax.block_until_ready(compiled(*args))
     t0 = time.perf_counter()
@@ -95,6 +122,8 @@ def main():
     ap.add_argument("--compile-only", action="store_true")
     ap.add_argument("--iters", type=int, default=20)
     ap.add_argument("--shapes", default=CELL_SHAPES, help=f"of {','.join(SHAPES)}")
+    ap.add_argument("--sub", default="", help="sub-tile sizes of a one-block causal call, e.g. 512,256")
+    ap.add_argument("--kernel-layout", action="store_true", help="no layout copies around the kernel")
     ap.add_argument("--out", default="chiprun_out/flash_block_sweep.jsonl")
     args = ap.parse_args()
 
@@ -117,20 +146,34 @@ def main():
         for name in args.shapes.split(","):
             shape = SHAPES[name]
             for side, mod in sides:
-                grid = [None] if side == "parent" else [(bq, bk) for bq in BLOCKS for bk in BLOCKS]
+                t = shape[2]
+                if side == "parent":
+                    grid = [None]
+                elif not args.sub:
+                    grid = [(bq, bk, None) for bq in BLOCKS for bk in BLOCKS]
+                elif t <= max(BLOCKS):  # one block pair: as one tile, in sub-tiles, and as the rule has it
+                    grid = [(t, t, None)] + [(t, t, int(sub)) for sub in args.sub.split(",")] + [None]
+                else:
+                    continue
                 for blocks in grid:
-                    for kernel, (fn, specs, (bq, bk)) in kernel_fns(mod, shape, True, blocks).items():
-                        if bq > shape[2] or bk > shape[2]:
+                    for kernel, (fn, specs, (bq, bk, *sub)) in kernel_fns(mod, shape, True, blocks).items():
+                        if bq > t or bk > t:
                             continue
-                        row = {"shape": name, "side": side, "kernel": kernel, "block_q": bq, "block_k": bk}
+                        row = {"shape": name, "side": side, "kernel": kernel, "block_q": bq, "block_k": bk,
+                               "sub": sub[0] if sub else None, "rule": blocks is None}
                         try:
                             if sharding is not None:
                                 specs = [jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sharding) for s in specs]
-                            compiled = jax.jit(fn).lower(*specs).compile()
+                            fmt = None
+                            if args.kernel_layout:
+                                fmt = row_major(sharding or jax.sharding.SingleDeviceSharding(jax.devices()[0]))
+                                row["kernel_layout"] = True
+                            compiled = jax.jit(fn, in_shardings=fmt, out_shardings=fmt).lower(*specs).compile()
                             if args.compile_only:
                                 row["compiled"] = True
                             else:
-                                row["ms"] = round(time_ms(compiled, materialize(specs), args.iters), 4)
+                                operands = [jax.device_put(x, fmt) if fmt else x for x in materialize(specs)]
+                                row["ms"] = round(time_ms(compiled, operands, args.iters), 4)
                                 row["device_kind"] = jax.devices()[0].device_kind
                         except Exception as e:  # a refused point is a row of the table, not the end of it
                             row["error"] = f"{type(e).__name__}: {str(e)[:300]}"

@@ -22,3 +22,14 @@ def devices():
     devs = jax.devices()
     assert len(devs) == 8, f"expected 8 virtual devices, got {devs}"
     return devs
+
+
+def pytest_configure(config):
+    """Build the native input library (``data/native.py`` runs ``make -C csrc``
+    on first use) once, before xdist starts its workers: on a fresh tree six
+    workers would each start that build while collecting, and the tests that
+    need the library would skip. A worker's config carries ``workerinput``."""
+    if not hasattr(config, "workerinput"):
+        from distributed_training_pytorch_tpu.data import native
+
+        native.available()

@@ -105,7 +105,11 @@ def test_eval_step_metrics(devices):
     engine, state = make_engine()
     batch = engine.shard_batch(synthetic_batch())
     for _ in range(50):
-        state, _ = engine.train_step(state, batch)
+        state, step_metrics = engine.train_step(state, batch)
+        # One step in flight at a time: 50 unsynced dispatches of an 8-device
+        # all-reduce can wedge the CPU backend's in-process rendezvous on a
+        # loaded machine (7 of 8 participants arrive; abort after 40 s).
+        jax.block_until_ready(step_metrics)
     metrics = engine.eval_step(state, batch)
     assert float(metrics["accuracy"]) > 0.8
 

@@ -46,6 +46,14 @@ CASES = [
     (1, 384, 2, 32, True, 128, 256),  # bq < bk: the q-loop's lower bound
     (1, 300, 2, 32, True, 128, 128),  # padding and diagonal in the same last block
     (1, 300, 2, 32, False, 128, 128),  # non-causal: every block, the last one padded
+    # Causal, one block pair: walked as static sub-tiles, the ones above the
+    # diagonal left out (ISSUE 32). The rule gives the forward two row groups
+    # and the backward sub-tiles of 128 where the block splits so.
+    (1, 256, 2, 32, True, None, None),  # 2 x 2 of 128 in both kernels
+    (1, 512, 2, 32, True, None, None),  # forward 2 x 2 of 256, backward 4 x 4 of 128
+    (1, 1024, 1, 64, True, None, None),  # the LM cells' T: forward 2 x 2 of 512, backward 8 x 8
+    (1, 700, 2, 64, True, None, None),  # padded into 768: 2 x 2 of 384, the last one padded
+    (2, 512, 2, 64, True, 512, 512),  # a caller's one block: the same rule
 ]
 # The fused backward (ISSUE 30): dq is summed over the grid's k-block axis in
 # a scratch slab where that axis has several blocks, written straight out
@@ -89,14 +97,23 @@ def test_gradient_parity(b, t, h, d, causal, block_q, block_k):
         )
 
 
-def test_causal_skip_never_reads_future_blocks():
-    """The test that the skip engages: with the last k-block's k and v rows
-    NaN, a kernel that computes the blocks above the diagonal and masks them
-    afterwards poisons every row (0 x NaN in p @ v, NaN in q @ k^T); one that
-    never visits them leaves all earlier q-blocks exactly as if the sequence
-    ended before the NaNs."""
-    t, blk = 512, 128
-    keep = t - blk
+@pytest.mark.parametrize(
+    "t,blk,keep",
+    [
+        (512, 128, 384),  # 4 x 4 block pairs: the loops' bounds
+        # One block pair walked as sub-tiles (ISSUE 32): the forward's last
+        # column group NaN (the backward's last two, and its last four).
+        (512, None, 256),
+        (1024, None, 512),
+    ],
+)
+def test_causal_skip_never_reads_future_blocks(t, blk, keep):
+    """The test that the skip engages: with the last k-block's (or the last
+    sub-tile's) k and v rows NaN, a kernel that computes the blocks above the
+    diagonal and masks them afterwards poisons every row (0 x NaN in p @ v,
+    NaN in q @ k^T; 0 x NaN in ds, so in every row of dq); one that never
+    visits them leaves all earlier q-blocks exactly as if the sequence ended
+    before the NaNs."""
     rng = np.random.RandomState(7)
     q, k, v, g = (jnp.asarray(rng.randn(1, t, 2, 32), jnp.float32) for _ in range(4))
     k_nan = k.at[:, keep:].set(jnp.nan)
@@ -128,13 +145,20 @@ def test_causal_skip_never_reads_future_blocks():
         (256, 384, True, 128, 256),  # a k-block seen by no row still closes dq's sum
         (256, 384, False, 128, 128),  # a visible block: non-causal, Tq != Tk
         (200, 300, False, 128, 128),  # neither side a multiple of its block
+        # One block a side, causal: static sub-tiles (ISSUE 32), Tq != Tk both ways.
+        (256, 512, True, None, None),  # a column group no row sees: dk / dv zero, dq closed
+        (512, 256, True, None, None),  # a row group past every column: no diagonal sub-tile in it
+        (128, 512, True, None, None),  # the forward splits in four: 128 does not hold a half of 512
+        (200, 500, True, None, None),  # both sides padded into their blocks
+        (500, 200, True, None, None),  # padded keys in columns every row of the last group sees
     ],
 )
 def test_flash_block_entry_points_causal_multi_block(tq, tk, causal, block_q, block_k):
     """``flash_block_fwd`` / ``flash_block_bwd`` — the ring path's per-block
     passes — against the reference with no mesh: causal (the diagonal block)
-    and not (a visible one), several blocks a side, square and ``Tq != Tk``
-    (where the last k-block is seen by no row: its dk / dv are exactly 0)."""
+    and not (a visible one), several blocks a side or one walked as sub-tiles,
+    square and ``Tq != Tk`` (where the last k-block is seen by no row: its
+    dk / dv are exactly 0)."""
     from distributed_training_pytorch_tpu.ops.pallas import flash_block_bwd, flash_block_fwd
 
     rng = np.random.RandomState(8)
@@ -183,12 +207,23 @@ def test_block_counts_match_a_brute_force_count_and_reach_the_record():
         x = jnp.zeros((1, 512, 1, 8), jnp.float32)
         jax.eval_shape(fn, x, x, x)
         (rec,) = [r for r in dispatch.records() if r["path"] == "flash"]
+        # The LM cells' call: one block pair, counted at the sub-tiles the kernels skip at.
+        dispatch.reset()  # decisions are recorded once a (model, path, reason)
+        fn = dispatch.attention_fn("transformer_lm", True, causal=True)
+        x = jnp.zeros((1, 1024, 1, 8), jnp.float32)
+        jax.eval_shape(fn, x, x, x)
+        (rec_1024,) = [r for r in dispatch.records() if r["path"] == "flash"]
     finally:
         dispatch.reset()
     plan = flash_block_plan(512, 512, True, 128, 128)
-    assert plan == {"block_q": 128, "block_k": 128, "blocks_total": 16, "blocks_computed": 10,
-                    "backward": "fused", "bwd_block_q": 128, "bwd_block_k": 128}
+    assert plan == {"block_q": 128, "block_k": 128, "sub_block": None,
+                    "blocks_total": 16, "blocks_computed": 10,
+                    "backward": "fused", "bwd_block_q": 128, "bwd_block_k": 128,
+                    "bwd_sub_block": None}
     assert {k: rec[k] for k in plan} == plan
+    subs = (rec_1024["block_q"], rec_1024["sub_block"], rec_1024["bwd_sub_block"])
+    assert subs == (1024, 512, 128)
+    assert (rec_1024["blocks_total"], rec_1024["blocks_computed"]) == (4, 3)
 
 
 def _pallas_calls(jaxpr):
@@ -229,23 +264,33 @@ def test_backward_is_one_pallas_call(t, causal, valid_len):
 
 
 @pytest.mark.parametrize(
-    "t_q,t_k,causal,want",
+    "t_q,t_k,causal,want,want_sub,want_counts",
     [
-        (1024, 1024, True, (1024, 1024)),
-        (4096, 4096, True, (512, 512)),  # the benchmark's long cell
-        (8192, 8192, True, (1024, 1024)),  # the longest T the forward compiles at
-        (256, 256, False, (256, 256)),  # clamped to T
-        (2048, 512, False, (1024, 512)),  # a ring block: the shard's rows against a visiting block
+        (1024, 1024, True, (1024, 1024), (512, 128), (4, 3)),  # the LM cells: sub-tiles of one pair
+        (4096, 4096, True, (512, 512), (None, None), (64, 36)),  # the benchmark's long cell
+        (8192, 8192, True, (1024, 1024), (None, None), (256, 136)),  # the longest T that compiles
+        (256, 256, False, (256, 256), (None, None), (1, 1)),  # clamped to T; non-causal: one tile
+        (2048, 512, False, (1024, 512), (None, None), (2, 2)),  # a ring shard against a visiting block
+        (512, 512, True, (512, 512), (256, 128), (4, 3)),
+        (700, 700, True, (768, 768), (384, 384), (4, 3)),  # 768 halves into 128-multiples, no further
+        (256, 512, True, (256, 512), (256, 128), (2, 1)),  # the ring's diagonal block, Tq < Tk
+        (128, 512, True, (128, 512), (128, 128), (4, 1)),  # forward in four: 128 holds no half of 512
+        (300, 300, True, (384, 384), (None, None), (1, 1)),  # 384 / 2, 4, 8: no 128-multiple
+        (100, 100, True, (128, 128), (None, None), (1, 1)),  # one lane tile: nothing to split
     ],
 )
-def test_block_plan_carries_the_backward(t_q, t_k, causal, want):
+def test_block_plan_carries_the_backward(t_q, t_k, causal, want, want_sub, want_counts):
     """``flash_block_plan`` (spread into the ``kernel_dispatch`` record) says
-    which backward the shapes get and at which block shape."""
+    which backward the shapes get and at which block shape, the sub-tile size
+    each kernel walks a one-block causal call in (None: one tile), and the
+    forward's block pairs at the granularity it skips at."""
     from distributed_training_pytorch_tpu.ops.pallas import flash_block_plan
 
     plan = flash_block_plan(t_q, t_k, causal)
     assert plan["backward"] == "fused"
     assert (plan["bwd_block_q"], plan["bwd_block_k"]) == want
+    assert (plan["sub_block"], plan["bwd_sub_block"]) == want_sub
+    assert (plan["blocks_total"], plan["blocks_computed"]) == want_counts
 
 
 def test_default_attention_fn_selects_by_backend():

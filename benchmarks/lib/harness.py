@@ -5,6 +5,7 @@ look for a chip switched off."""
 
 from __future__ import annotations
 
+import functools
 import gc
 import importlib
 import importlib.util
@@ -72,28 +73,38 @@ def build_mesh(traffic: dict, devices):
 
 def make_weights(ref, cfg, traffic, seed, trainer):
     """The benchmark's own weights: made on the device from the seed in one
-    jitted call by the reference's published initialisation and laid into
-    ``trainer.state`` in the program's tree. Returns a second copy in that
-    layout, in buffers of its own (the step donates the state's)."""
+    jitted call by the reference's published initialisation, in the program's
+    tree and placement, and laid into ``trainer.state``. The parameters that
+    ``Trainer.__init__`` initialised are freed first, so the call's peak is
+    the optimizer's state plus the one tree it makes, and nothing of the
+    benchmark's stays on the device. Returns the call: the same compiled
+    program gives the same bits again to whoever takes a change from the
+    start (made again inside another program they differ: every element of
+    a random leaf by part of a unit in the last place on the CPU, where the
+    compiler takes the difference from the unrounded product)."""
     import jax
-    import jax.numpy as jnp
 
     target = trainer.state.params
     shardings = jax.tree.map(lambda x: x.sharding, target)
 
-    @jax.jit
     def make(key):
-        tree = ref.to_program(ref.init_params(cfg, traffic, key), cfg)
-        return tree, jax.tree.map(jnp.copy, tree)
+        return ref.to_program(ref.init_params(cfg, traffic, key), cfg)
 
     want = jax.tree.map(lambda x: (x.shape, x.dtype), target)
-    got = jax.tree.map(lambda x: (x.shape, x.dtype), jax.eval_shape(make, jax.random.key(seed))[0])
+    got = jax.tree.map(lambda x: (x.shape, x.dtype), jax.eval_shape(make, jax.random.key(seed)))
     if want != got:
         raise NoResult(f"the reference's layout of the weights is not the program's:\n{want}\nvs\n{got}")
-    placed, start = make(jax.random.key(seed))
-    placed = jax.device_put(placed, shardings)
-    trainer.state = trainer.state.replace(params=placed)
-    return start
+    for leaf in jax.tree.leaves(target):
+        leaf.delete()
+    make = functools.partial(jax.jit(make, out_shardings=shardings), jax.random.key(seed))
+    trainer.state = trainer.state.replace(params=make())
+    return make
+
+
+def device_bytes(arrays, device) -> int:
+    """Bytes that ``arrays`` hold on ``device``, by each one's shard there."""
+    return sum(math.prod(x.sharding.shard_shape(x.shape)) * x.dtype.itemsize
+               for x in arrays if device in x.sharding.device_set)
 
 
 def memory_peak_bytes(devices) -> int | None:
@@ -162,12 +173,13 @@ def _run(cell, devices, system, ref, data, weight_seed, trainer_seed, workdir, t
     if len(trainer.train_dataloader) != traffic["steps_per_epoch"]:
         raise NoResult(f"the loader gives {len(trainer.train_dataloader)} steps an epoch, "
                        f"the traffic file says {traffic['steps_per_epoch']}")
-    start_params = make_weights(ref, cfg, traffic, weight_seed, trainer)
+    start_again = make_weights(ref, cfg, traffic, weight_seed, trainer)
     jax.block_until_ready(trainer.state)
     stamp("weights_made")
     if fault is not None:
         fault(trainer)
-    recorder = FirstSteps(trainer, ref, cfg, traffic["check_steps"], start_params)
+    recorder = FirstSteps(trainer, ref, cfg, traffic["check_steps"], start_again)
+    del start_again
 
     epoch = [0]
 
@@ -218,6 +230,12 @@ def _run(cell, devices, system, ref, data, weight_seed, trainer_seed, workdir, t
     steps_before = len(trainer.step_losses)
     goodput_before = dict(trainer.goodput.buckets) if trainer.goodput is not None else None
     jax.block_until_ready(trainer.state)
+    # What the first chip holds as the window opens, and the program's state in
+    # it: the rest is what the run costs the device beyond the program.
+    held = {"live_bytes_at_open": device_bytes(jax.live_arrays(), devices[0]),
+            "state_bytes_at_open": device_bytes(jax.tree.leaves(trainer.state), devices[0]),
+            "params_bytes": device_bytes(jax.tree.leaves(trainer.state.params), devices[0]),
+            "reference_bytes_in_use_max": None}
     t_open = time.perf_counter()
     setup_s = t_open - t_start
     slice_ends_s = []  # in `info`: shows where in the window a stall fell
@@ -241,19 +259,28 @@ def _run(cell, devices, system, ref, data, weight_seed, trainer_seed, workdir, t
 
     # Free the program before the reference runs: the peak is read, the
     # reference must fit on the chip the program filled.
-    del recorder, trainer, start_params
+    del recorder, trainer
     gc.collect()
     jax.clear_caches()
+    # The reference runs some hundred small programs (an update a leaf's shape and step, the norms' eager
+    # operations), each compiled in a fraction of the second under which jax keeps nothing: every run of
+    # every check compiled them anew, 4 s of them in the LM cells. From here on the cache keeps everything.
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+
+    def watch():  # the reference's own high-water mark, where the backend counts bytes
+        in_use = (devices[0].memory_stats() or {}).get("bytes_in_use")
+        if in_use is not None:
+            held["reference_bytes_in_use_max"] = max(in_use, held["reference_bytes_in_use_max"] or 0)
 
     t_ref = time.perf_counter()
-    params0 = jax.jit(lambda key: ref.init_params(cfg, traffic, key))(jax.random.key(weight_seed))
-    reference = refrun.run_reference(ref, cfg, traffic, params0, prog["batches"], devices=devices)
+    reference = refrun.run_reference(ref, cfg, traffic, weight_seed, prog["batches"], devices=devices, watch=watch)
     numbers = refrun.gaps(prog, reference)
     readings = {"program": numbers}
     for name in ([stand_in] if stand_in else []) + (sorted(STAND_INS) if extra_readings else []):
         kwargs = STAND_INS[name](len(prog["batches"][0]["label"]), cfg, len(devices))
         if name not in readings and kwargs is not None:
-            placed = refrun.run_reference(ref, cfg, traffic, params0, prog["batches"], devices=devices, **kwargs)
+            placed = refrun.run_reference(ref, cfg, traffic, weight_seed, prog["batches"], devices=devices,
+                                          watch=watch, **kwargs)
             readings[name] = refrun.gaps(placed, reference)
     if stand_in:
         numbers = readings[stand_in]
@@ -292,7 +319,7 @@ def _run(cell, devices, system, ref, data, weight_seed, trainer_seed, workdir, t
     result["device"] = device
     result["info"] = {"window_s": window_s, "slice_ends_s": slice_ends_s, "reference_s": ref_s, "problems": problems,
                       "recorded_steps": len(prog["losses"]), "leaves_left_out": numbers["leaves_left_out"],
-                      "memory_stats": mem_stats,
+                      "memory_stats": mem_stats, "held": held,
                       "setup_stamps_s": stamps}
     if extra_readings:
         result["readings"] = readings

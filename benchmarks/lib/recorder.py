@@ -25,9 +25,9 @@ def first_moment(opt_state):
 
 
 class FirstSteps:
-    def __init__(self, trainer, ref, cfg: dict, steps: int, start_params):
+    def __init__(self, trainer, ref, cfg: dict, steps: int, start_again):
         self.trainer, self.ref, self.cfg, self.steps = trainer, ref, cfg, steps
-        self.start = start_params  # the program's tree, buffers of our own
+        self.start_again = start_again  # harness.make_weights' call: the laid-in weights, made once more
         self.batches: list = []
         self.metrics: list = []
         self.norms = None
@@ -38,6 +38,10 @@ class FirstSteps:
         engine.train_step = self._single
 
     def _norms(self, state):
+        """The start is on the device for this call alone, between two steps
+        of the warm-up, once the unit that was just dispatched has ended and
+        given back its gradients and activations: with it the device holds
+        less than a step does."""
         view = lambda tree: self._leaves(self.ref.from_program(tree, self.cfg), self.cfg)  # noqa: E731
 
         @jax.jit
@@ -46,12 +50,13 @@ class FirstSteps:
             delta = jax.tree.map(jnp.subtract, view(params), view(start))
             return jax.tree.map(l2, delta), jax.tree.map(l2, view(moment))
 
-        return norms(state.params, self.start, first_moment(state.opt_state))
+        jax.block_until_ready(state)  # or the start is asked for while the unit still runs: a filled chip has no room then
+        return norms(state.params, self.start_again(), first_moment(state.opt_state))
 
     def _after(self, state):
         if sum(n for n, _ in self.metrics) >= self.steps:
             self.norms = self._norms(state)
-            self.start = None
+            self.start_again = None
             engine = self.trainer.engine
             del engine.train_steps_chained, engine.train_step  # back to the class's own
 

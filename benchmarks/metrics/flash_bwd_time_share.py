@@ -1,7 +1,7 @@
-"""Device time of flash attention's two backward kernels (`flash_dq`,
-`flash_dkv`: the `name=` of their `pl.pallas_call`s, which names the
-instruction, and the scope around them) over the traced stretch;
-`flash_time_share` less this is the forward kernel's."""
+"""Device time of flash attention's one backward kernel (`flash_dqkv`: the
+`name=` of its `pl.pallas_call`, which names the instruction, and the scope
+around it) over the traced stretch; `flash_time_share` less this is the
+forward kernel's."""
 
 from benchmarks.lib import spans
 
@@ -10,4 +10,4 @@ DECLARATION = {"name": "flash_bwd_time_share", "unit": "%", "better": "lower", "
 
 
 def read(ctx):
-    return spans.scope_share(ctx, ("flash_dq", "flash_dkv"))
+    return spans.scope_share(ctx, ("flash_dqkv",))

@@ -13,7 +13,7 @@ DECLARATION = {"name": "flash_roofline", "unit": "%", "better": "higher", "sourc
 
 
 def read(ctx):
-    # ops/pallas.py's flash forward, dq and dkv kernels are the only Mosaic calls on the LM's path.
+    # ops/pallas.py's flash_fwd and its one backward kernel flash_dqkv are the only Mosaic calls on the LM's path.
     if ctx["peaks"] is None:
         return None
     spent = ctx["trace"].op_seconds(MOSAIC_CALL)

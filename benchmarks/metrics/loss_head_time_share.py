@@ -1,8 +1,8 @@
 """Device time of the operations under the `loss_head` scope
-(`ops/losses.py:tied_cross_entropy`: forward, recomputation and backward all
-carry it) over the traced stretch. The scope is the `tf_op` stat of the
-event's metadata in the xplane (benchmarks/lib/xplane.py); containers are
-left out, overlaps counted once."""
+(`ops/losses.py:tied_cross_entropy_loss`: the forward scan, which takes the
+loss and both gradients, and the backward's scaling carry it) over the traced
+stretch. The scope is the `tf_op` stat of the event's metadata in the xplane
+(benchmarks/lib/xplane.py); containers are left out, overlaps counted once."""
 
 from benchmarks.lib import spans
 

@@ -132,8 +132,8 @@ HEAD_OPS = [  # (name = the instruction's HLO text, label, start, duration), and
     ("%fusion.1 = f32[8] fusion(%p), kind=kLoop", "jit(step)/jvp(loss_head)/while/body/dot_general", 0.0, 400.0),
     ("%fusion.2 = f32[8] fusion(%p), kind=kLoop", "jit(step)/transpose(jvp(loss_head))/while/body/mul", 300.0, 300.0),
     ("%fusion.3 = f32[8] fusion(%p), kind=kLoop", "jit(step)/optimizer/add", 1000.0, 50.0),
-    ("%flash_dq.7 = bf16[8] custom-call(%q), custom_call_target=\"tpu_custom_call\"", "jit(step)/flash_dq/pallas_call", 2000.0, 70.0),
-    ("%flash_dkv.8 = bf16[8] custom-call(%q), custom_call_target=\"tpu_custom_call\"", None, 2100.0, 30.0),
+    ("%flash_dqkv.7 = bf16[8] custom-call(%q), custom_call_target=\"tpu_custom_call\"", "jit(step)/flash_dqkv/pallas_call", 2000.0, 70.0),
+    ("%flash_dqkv.8 = bf16[8] custom-call(%q), custom_call_target=\"tpu_custom_call\"", None, 2100.0, 30.0),
     ("%flash_fwd.9 = bf16[8] custom-call(%q), custom_call_target=\"tpu_custom_call\"", "jit(step)/flash_fwd/pallas_call", 2200.0, 40.0),
 ]
 
@@ -145,8 +145,8 @@ def test_scoped_device_time_leaves_containers_out_and_counts_overlaps_once():
     assert spans.plane_mean_seconds(summary.devices, ("loss_head",), scopes) == pytest.approx(600e-9)
     assert spans.plane_mean_seconds(summary.devices, ("loss_head",)) is None  # the scope is in no label
     # a kernel's name is in the instruction's own name, with or without the scopes
-    assert spans.plane_mean_seconds(summary.devices, ("flash_dq", "flash_dkv"), scopes) == pytest.approx(100e-9)
-    assert spans.plane_mean_seconds(summary.devices, ("flash_dq", "flash_dkv")) == pytest.approx(100e-9)
+    assert spans.plane_mean_seconds(summary.devices, ("flash_dqkv",), scopes) == pytest.approx(100e-9)
+    assert spans.plane_mean_seconds(summary.devices, ("flash_dqkv",)) == pytest.approx(100e-9)
     assert spans.plane_mean_seconds(summary.devices, ("no_such_scope",), scopes) is None
 
 

@@ -10,9 +10,9 @@ DEV = "/device:TPU:0"
 def _planes():
     ops = [  # (name, label, start_ns, dur_ns)
         ("fusion.1", "fusion.1", 0.0, 100.0),
-        ("custom-call.7", "custom-call.7 jit(chained)/_fwd_kernel", 100.0, 50.0),
+        ("flash_fwd.7", "flash_fwd.7 jit(chained)/flash_fwd/pallas_call", 100.0, 50.0),
         ("fusion.2", "fusion.2", 120.0, 80.0),          # overlaps the call by 30
-        ("custom-call.9", "custom-call.9 jit(chained)/_bwd_dq_kernel", 400.0, 100.0),
+        ("flash_dqkv.9", "flash_dqkv.9 jit(chained)/flash_dqkv/pallas_call", 400.0, 100.0),
         ("fusion.1", "fusion.1", 900.0, 100.0),
     ]
     host = [("bench.slice", "bench.slice", 0.0, 600.0), ("bench.dispatch", "bench.dispatch", 150.0, 200.0),
@@ -31,9 +31,9 @@ def test_summary_busy_idle_kernel_time():
     s = trace.summarize(_planes(), window_s=2e-6)
     assert s.busy_s == pytest.approx(400e-9)  # 200 + 100 + 100: the "Steps" line is not an operation
     assert 100.0 * (1 - s.busy_s / s.window_s) == pytest.approx(80.0)
-    assert s.op_seconds(("_fwd_kernel", "_bwd_dq_kernel", "_bwd_dkv_kernel")) == pytest.approx(150e-9)
+    assert s.op_seconds(("flash_fwd", "flash_dqkv")) == pytest.approx(150e-9)
     assert s.op_seconds(("no_such_kernel",)) is None
-    assert s.top_ops(2) == [["fusion.1", pytest.approx(200e-9)], ["custom-call.9", pytest.approx(100e-9)]]
+    assert s.top_ops(2) == [["fusion.1", pytest.approx(200e-9)], ["flash_dqkv.9", pytest.approx(100e-9)]]
 
 
 def test_idle_gaps_named_by_innermost_host_span():

@@ -17,6 +17,12 @@ from distributed_training_pytorch_tpu.models.transformer_lm import (  # noqa: F4
     LMTiny,
     TransformerLM,
 )
+from distributed_training_pytorch_tpu.models.hybrid_lm import (  # noqa: F401
+    HybridBlock,
+    HybridConfig,
+    HybridLM,
+    HybridTiny,
+)
 
 
 def create_model(name: str, num_classes: int, **kwargs):

@@ -10,8 +10,10 @@ Launch: ``MODEL=lm ./run.sh``. Env knobs: ``LM_CORPUS`` (text/bytes file —
 build a real one offline with ``examples/make_lm_corpus.py``), ``SEQ_LEN``
 (default 256), ``EPOCHS``, ``BATCH``, ``BASE_LR``, ``MOE_EVERY`` (0 = dense),
 ``SAVE_DIR``, ``SNAPSHOT``, ``PROFILE_DIR``, ``LM_SIZE`` (``tiny`` | ``small``
-= GPT-2-small shape), ``SAVE_PERIOD`` / ``LAST_SAVE_PERIOD`` (epochs between
-periodic / `last` saves — raise both when the checkpoint path is slow), ``DTYPE``
+= GPT-2-small shape | ``hybrid_tiny`` = a toy of the Mamba-2 / grouped-query
+hybrid stack of ``models/hybrid_lm.py``, every block rematerialised),
+``SAVE_PERIOD`` / ``LAST_SAVE_PERIOD`` (epochs between periodic / `last`
+saves — raise both when the checkpoint path is slow), ``DTYPE``
 (fp32|bf16|fp16 mixed-precision policy — docs/mixed_precision.md),
 ``PALLAS`` (1|0 kernel-policy knob: forces the flash-attention path on/off;
 unset = the historical auto — ops/dispatch.py, docs/performance.md
@@ -31,7 +33,7 @@ import numpy as np
 import optax
 
 from distributed_training_pytorch_tpu.data import ArrayDataSource
-from distributed_training_pytorch_tpu.models import GPTSmall, LMTiny
+from distributed_training_pytorch_tpu.models import GPTSmall, HybridTiny, LMTiny
 from distributed_training_pytorch_tpu.ops import warmup_cosine_lr
 from distributed_training_pytorch_tpu.ops.dispatch import pallas_from_env
 from distributed_training_pytorch_tpu.parallel import mesh_from_env
@@ -104,12 +106,15 @@ class LMTrainer(Trainer):
     def build_model(self):
         from distributed_training_pytorch_tpu.precision import model_dtype_for_entry
 
+        dtype = model_dtype_for_entry(
+            self.precision, DTYPE is not None or self.precision_requested, jnp.bfloat16
+        )
+        if self.size == "hybrid_tiny":  # no positions and no routed experts to size
+            return HybridTiny(vocab_size=256, dtype=dtype, pallas=PALLAS)
         factory = {"tiny": LMTiny, "small": GPTSmall}[self.size]
         return factory(
             vocab_size=256,
-            dtype=model_dtype_for_entry(
-                self.precision, DTYPE is not None or self.precision_requested, jnp.bfloat16
-            ),
+            dtype=dtype,
             moe_every=self.moe_every,
             max_len=max(self.seq_len, 128),
             pallas=PALLAS,

@@ -22,7 +22,8 @@ Phases
            ``conv1x1_bn_act`` relu/identity at ResNet stage-1 shapes and gelu
            at ConvNeXt-L's expand shapes; and the Mamba-2 scan's two kernels
            (``ops/ssd.py``: ``ssd_fwd`` / ``ssd_bwd``) at granite-4.0-h-micro's
-           widths against the sequential float32 recurrence. Plus two device
+           widths and at nemotron-3-nano-30b-a3b's (eight ``B`` / ``C`` groups,
+           chunk 128, T=8192) against the sequential float32 recurrence. Plus two device
            checks: ``tpu_compiler_options()`` is accepted by the installed
            libtpu, and ``jax.block_until_ready`` really blocks.
   leg_a    ``Cifar10Trainer`` (examples/train_cifar10.py): VGG16 at full
@@ -402,19 +403,23 @@ def phase_kernels(smoke: Smoke, devices) -> None:
     # benchmark cell's batch: bf16 operands, both kernels compiled, against the
     # sequential float32 recurrence on the same bf16-rounded inputs; forward
     # and the gradient of each of its five inputs under a fixed cotangent.
-    def ssd_case(name, b, t, h, p, n, chunk, mesh=None):
+    def ssd_case(name, b, t, h, p, n, chunk, mesh=None, groups=None):
         import contextlib
 
         from jax.sharding import NamedSharding, PartitionSpec
 
-        from benchmarks.reference.granite_hybrid import ssd_sequential
         from distributed_training_pytorch_tpu.ops import ssd
+
+        if groups is None:  # B and C [b, t, n], every head's
+            from benchmarks.reference.granite_hybrid import ssd_sequential
+        else:  # [b, t, groups, n], head i reading group i // (h / groups)
+            from benchmarks.reference.nemotron_h import ssd_sequential
 
         k = jax.random.split(jax.random.key(5), 6)
         x = jax.random.normal(k[0], (b, t, h, p), dt)
         delta = jax.nn.softplus(jax.random.normal(k[1], (b, t, h)) - 4.0)  # Δ of 0.01-0.1: a state lives hundreds of steps
         a = -jax.random.uniform(k[2], (h,), minval=1.0, maxval=16.0)
-        bm, cm = ((0.3 * jax.random.normal(k[i], (b, t, n))).astype(dt) for i in (3, 4))
+        bm, cm = ((0.3 * jax.random.normal(k[i], (b, t, n) if groups is None else (b, t, groups, n))).astype(dt) for i in (3, 4))
         g = jax.random.normal(k[5], (b, t, h, p))
         args = (x, delta, a, bm, cm)
         if mesh is not None:  # rows over the chips, as a data-parallel step hands them
@@ -436,7 +441,7 @@ def phase_kernels(smoke: Smoke, devices) -> None:
         errs = {key: _norm_err(got, ref) for key, got, ref in zip(("y", "dx", "ddt", "da", "db", "dc"), out, want)}
         finite = all(bool(jnp.all(jnp.isfinite(v.astype(jnp.float32)))) for v in out)
         smoke.check(name, finite and max(errs.values()) <= TOL_SSD,
-                    f"x {(b, t, h, p)} states {n} chunk {chunk} norm err {errs}")
+                    f"x {(b, t, h, p)} states {n} groups {groups} chunk {chunk} norm err {errs}")
         mosaic_check(name, kernel)
         if on_tpu:  # one body each a call site: the backward is ONE Mosaic call, and no second forward
             calls = re.findall(r"%(ssd_\w+)\.\d+ = ", kernel.as_text())
@@ -444,6 +449,9 @@ def phase_kernels(smoke: Smoke, devices) -> None:
 
     ssd_shape = (1, 256, 8, 64, 128, 128) if small else (2, 4096, 64, 64, 128, 256)
     smoke.case("kernels.ssd_scan", ssd_case, *ssd_shape)
+    # nemotron-3-nano-30b-a3b's: eight B / C groups of eight heads, chunk 128, the cell's T and batch
+    smoke.case("kernels.ssd_scan_groups", ssd_case, *((1, 256, 16, 64, 128, 128) if small else (2, 8192, 64, 64, 128, 128)),
+               groups=2 if small else 8)
     if len(devices) > 1:
         # A Mosaic call has no partitioning rule: under a mesh ssd_scan runs its kernels in shard_map, a
         # chip's rows each (the per-device HLO still holds one call of each), dA summed over the chips.

@@ -22,6 +22,7 @@ from distributed_training_pytorch_tpu.models.hybrid_lm import (  # noqa: F401
     HybridConfig,
     HybridLM,
     HybridTiny,
+    NemotronHTiny,
 )
 
 

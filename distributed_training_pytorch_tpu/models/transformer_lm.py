@@ -183,6 +183,10 @@ class TransformerLM(nn.Module):
     moe_dispatch_impl: str = "einsum"
     tie_embeddings: bool = True
 
+    def head_matrix(self, params):
+        """The ``[V, d]`` matrix of the tied head (``make_fused_lm_loss`` asks it of a model)."""
+        return params["embed"]["embedding"]
+
     @nn.compact
     def __call__(
         self,
@@ -272,14 +276,22 @@ def make_fused_lm_loss(
     For MoE models (``moe_every > 0``) the routers' sown aux losses join the
     objective: Switch load-balance * ``aux_loss_coef`` + router-z *
     ``z_loss_coef`` (standard coefficients; without them routing collapses
-    onto a few experts)."""
+    onto a few experts).
+
+    What the function asks of ``model`` beyond ``apply(..., return_hidden=True)``:
+    ``head_matrix(params)``, the ``[V, d]`` matrix the head multiplies by (the
+    token embedding where tied; the head's mathematics does not care whose it
+    is); optionally ``moe_every`` (the GPT-2 stack's routers, as above) and
+    ``sows_step_metrics`` / ``step_metrics(intermediates)`` (a stack whose
+    layers sow per-step counts, which join the step's metrics)."""
     from distributed_training_pytorch_tpu.ops.losses import tied_cross_entropy_loss
 
-    has_moe = model.moe_every > 0
+    has_moe = getattr(model, "moe_every", 0) > 0
+    sows = bool(getattr(model, "sows_step_metrics", False))
 
     def loss_fn(params, model_state, batch, rng, train):
         kwargs = {"rngs": {"dropout": rng}} if train else {}
-        if has_moe:
+        if has_moe or sows:
             hidden, inter = model.apply(
                 {"params": params},
                 batch["image"],
@@ -293,9 +305,11 @@ def make_fused_lm_loss(
                 {"params": params}, batch["image"], train=train, return_hidden=True, **kwargs
             )
         loss = tied_cross_entropy_loss(
-            hidden, params["embed"]["embedding"], batch["label"], batch.get("mask")
+            hidden, model.head_matrix(params), batch["label"], batch.get("mask")
         )
         metrics = {"loss": loss, "nll": loss, "ppl": jnp.exp(loss)}
+        if sows:
+            metrics.update(model.step_metrics(inter["intermediates"]))
         if has_moe:
             # mean of each sown metric across the MoE blocks, selected by name
             def collect(name):

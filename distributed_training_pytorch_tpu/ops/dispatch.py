@@ -40,6 +40,10 @@ hybrid_lm      attention                flash on TPU when ``T >= 512``
 hybrid_lm      ssd                      the Pallas scan (``ops/ssd.py:ssd_scan``)
                                         on TPU where the shape tiles, else the
                                         ``jax.numpy`` chunked form
+nemotron_h     attention, ssd           as hybrid_lm (the same modules)
+nemotron_h     moe_experts              no choice yet: ``jax.lax.ragged_dot``,
+                                        recorded by the layer itself
+                                        (``parallel/moe.py:HeldExpertsMlp``)
 =============  =======================  =========================================
 
 Observability (the silent-fall-through fix): each resolution is recorded as
@@ -276,13 +280,14 @@ def ssd_fn(model: str, pallas: Optional[bool]):
     shape the kernels do not take still falls to chunked, with the reason."""
     import jax
 
-    from .ssd import ssd_chunked, ssd_scan, ssd_tiles
+    from .ssd import scan_groups, ssd_chunked, ssd_scan, ssd_tiles
 
     def scan(x, dt, a, b, c, *, chunk, dtype=None):
         (_, t, h, p), n = x.shape, b.shape[-1]
+        groups = scan_groups(b)
         backend = jax.default_backend()
-        shape = f"chunk {chunk}, {h} heads of {p}, d_state {n}"
-        refused = ssd_tiles(chunk, h, p, n, t)
+        shape = f"chunk {chunk}, {h} heads of {p}, d_state {n}" + (f", {groups} groups" if groups > 1 else "")
+        refused = ssd_tiles(chunk, h, p, n, t, groups)
         if pallas is False:
             path, reason = "chunked", "pallas=False"
         elif pallas is None and backend != "tpu":

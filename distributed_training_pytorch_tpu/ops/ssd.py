@@ -4,8 +4,10 @@ the selective scan of Mamba-2 (Dao & Gu 2024, arXiv:2405.21060, "SSD"), as
 causal depthwise convolution in front of it.
 
 The recurrence, per batch row and head (``x_t`` of ``P`` channels, ``B_t`` and
-``C_t`` of ``N`` states shared by every head, ``Δ_t > 0`` and ``A < 0``
-scalars a head), with ``S_0 = 0``::
+``C_t`` of ``N`` states shared by the heads of a group: ``G`` groups of ``H / G``
+consecutive heads, head ``h`` reading group ``h // (H / G)``; one group where
+``B`` and ``C`` come without a group axis; ``Δ_t > 0`` and ``A < 0`` scalars a
+head), with ``S_0 = 0``::
 
     S_t = exp(Δ_t A) · S_{t-1} + Δ_t · x_t B_tᵀ        (S: [P, N])
     y_t = S_t C_t
@@ -66,7 +68,7 @@ from jax.sharding import PartitionSpec as P
 
 from distributed_training_pytorch_tpu.ops.pallas import NEG_INF, _ambient_shard_spec, resolve_interpret
 
-__all__ = ["causal_conv1d", "ssd_chunked", "ssd_scan", "ssd_tiles"]
+__all__ = ["causal_conv1d", "scan_groups", "ssd_chunked", "ssd_scan", "ssd_tiles"]
 
 
 def causal_conv1d(x: jax.Array, w: jax.Array, b: jax.Array | None = None) -> jax.Array:
@@ -83,43 +85,60 @@ def causal_conv1d(x: jax.Array, w: jax.Array, b: jax.Array | None = None) -> jax
     return y if b is None else y + b.astype(jnp.float32)
 
 
+def scan_groups(b) -> int:
+    """Groups of ``B`` / ``C``: ``[B, T, G, N]``, or ``[B, T, N]`` for one."""
+    return b.shape[2] if b.ndim == 4 else 1
+
+
 def ssd_chunked(x, dt, a, b, c, *, chunk: int, dtype=None) -> jax.Array:
     """``y`` of the recurrence above (no ``D`` skip), float32.
 
     ``x``: ``[B, T, H, P]``; ``dt``: ``[B, T, H]`` (after its softplus);
-    ``a``: ``[H]`` (negative); ``b``, ``c``: ``[B, T, N]``. ``chunk``: steps a
-    chunk; a ``T`` that is no multiple of it is padded with steps of ``Δ = 0``,
-    which neither decay the state nor add to it. ``dtype``: the matmuls'
-    operand type (``x``'s if None)."""
+    ``a``: ``[H]`` (negative); ``b``, ``c``: ``[B, T, G, N]``, or ``[B, T, N]``
+    for one group. ``chunk``: steps a chunk; a ``T`` that is no multiple of it
+    is padded with steps of ``Δ = 0``, which neither decay the state nor add
+    to it. ``dtype``: the matmuls' operand type (``x``'s if None)."""
     dtype = dtype or x.dtype
     rows, t, h, p = x.shape
-    n = b.shape[-1]
+    g, n = scan_groups(b), b.shape[-1]
+    hg = h // g  # heads a group
     pad = -t % chunk
     if pad:
         x, dt, b, c = (jnp.pad(v, ((0, 0), (0, pad)) + ((0, 0),) * (v.ndim - 2)) for v in (x, dt, b, c))
     nc = (t + pad) // chunk
     f32 = jnp.float32
     dt = dt.astype(f32)
-    # heads beside the batch axes, so that every product below is a batched matmul
+    # heads beside the batch axes (a group's heads together), so that every product below is a batched matmul
     da = (dt * a.astype(f32)).reshape(rows, nc, chunk, h).transpose(0, 1, 3, 2)  # [B, nc, H, Q]
     cs = jnp.cumsum(da, axis=-1)
     xd = (x.astype(f32) * dt[..., None]).reshape(rows, nc, chunk, h, p).transpose(0, 1, 3, 2, 4)  # Δ ⊙ x: [B, nc, H, Q, P]
-    b = b.reshape(rows, nc, chunk, n).astype(dtype)
-    c = c.reshape(rows, nc, chunk, n).astype(dtype)
+    # One group is the form without a group axis (the program a model without groups has always
+    # traced, to the bit); G groups put the axis beside the batch axes of every product with B or C.
+    at = (g,) if g > 1 else ()
+    gl = "g" if g > 1 else ""  # the axis' letter in the products below
+    b = b.reshape((rows, nc, chunk) + at + (n,)).astype(dtype)
+    c = c.reshape((rows, nc, chunk) + at + (n,)).astype(dtype)
+
+    def by_group(v):  # [B, nc, H, ...] -> [B, nc, G, H/G, ...]
+        return v.reshape(v.shape[:2] + at + (hg,) + v.shape[3:])
+
+    def by_head(v):
+        return v.reshape(v.shape[:2] + (h,) + v.shape[3 + len(at):])
 
     # Inside a chunk: (L ⊙ (C Bᵀ)) (Δ ⊙ x). The mask goes on before the exp:
     # above the diagonal cs_t − cs_s is positive and may overflow.
     seg = cs[..., :, None] - cs[..., None, :]  # [B, nc, H, Q(t), Q(s)]
     causal = jnp.tril(jnp.ones((chunk, chunk), bool))
     decay = jnp.exp(jnp.where(causal, seg, -jnp.inf))
-    cb = jnp.einsum("bctn,bcsn->bcts", c, b, preferred_element_type=f32)
-    y = jnp.einsum("bchts,bchsp->bchtp", (decay * cb[:, :, None]).astype(dtype), xd.astype(dtype),
-                   preferred_element_type=f32)
+    cb = jnp.einsum(f"bct{gl}n,bcs{gl}n->bc{gl}ts", c, b, preferred_element_type=f32)  # a group's heads share it
+    y = jnp.einsum("bchts,bchsp->bchtp", by_head(by_group(decay) * jnp.expand_dims(cb, 2 + len(at))).astype(dtype),
+                   xd.astype(dtype), preferred_element_type=f32)
 
     # Each chunk's own contribution to the state at its end, then the short
     # scan that carries the states from chunk to chunk.
     to_end = jnp.exp(cs[..., -1:] - cs)  # [B, nc, H, Q]
-    own = jnp.einsum("bchsp,bcsn->bchpn", (xd * to_end[..., None]).astype(dtype), b, preferred_element_type=f32)
+    own = by_head(jnp.einsum(f"bc{gl}hsp,bcs{gl}n->bc{gl}hpn", by_group((xd * to_end[..., None]).astype(dtype)), b,
+                             preferred_element_type=f32))
     whole = jnp.exp(cs[..., -1])  # [B, nc, H]: a chunk's decay from end to end
 
     def carry_on(state, per_chunk):
@@ -129,7 +148,8 @@ def ssd_chunked(x, dt, a, b, c, *, chunk: int, dtype=None) -> jax.Array:
     _, entering = jax.lax.scan(carry_on, jnp.zeros((rows, h, p, n), f32),
                                (whole.transpose(1, 0, 2), own.transpose(1, 0, 2, 3, 4)))
     entering = entering.transpose(1, 0, 2, 3, 4)  # [B, nc, H, P, N]
-    carried = jnp.einsum("bctn,bchpn->bchtp", c, entering.astype(dtype), preferred_element_type=f32)
+    carried = by_head(jnp.einsum(f"bct{gl}n,bc{gl}hpn->bc{gl}htp", c, by_group(entering.astype(dtype)),
+                                 preferred_element_type=f32))
     y = y + jnp.exp(cs)[..., None] * carried
     return y.transpose(0, 1, 3, 2, 4).reshape(rows, nc * chunk, h, p)[:, :t]
 
@@ -155,27 +175,35 @@ _F32 = jnp.float32
 _STATES_BYTES = 32 * 2**20  # of VMEM, for the states entering a head block's chunks in the backward
 
 
-def _head_block(h: int) -> int:
+def _head_block(h: int, g: int = 1) -> int:
     """Heads a grid step, one rule for both kernels: 16 where that divides
-    ``H`` (PERF.md §6, PR 35, has the readings against 8), else 8 (the ``[hb,
-    Q]`` rows are then whole sublane tiles), else all of ``H``."""
-    return next((hb for hb in (16, 8) if h % hb == 0), h)
+    a group's ``H / G`` heads (PERF.md §6, PR 35, has the readings against 8),
+    else 8 (the ``[hb, Q]`` rows are then whole sublane tiles), else all of a
+    group's. A head block never spans two groups: its ``B`` and ``C`` are one
+    block of the operands."""
+    return next((hb for hb in (16, 8) if (h // g) % hb == 0), h // g)
 
 
-def ssd_tiles(chunk: int, h: int, p: int, n: int, t: int) -> Optional[str]:
+def ssd_tiles(chunk: int, h: int, p: int, n: int, t: int, g: int = 1) -> Optional[str]:
     """None where :func:`ssd_scan` takes the shape, else why it does not: the
     chunk and the state are the sides of MXU operands (multiples of the
     128-lane tile), a head's channels are rows of a block (whole 16-row tiles
-    of a bfloat16 register), and the backward holds the float32 state
-    ``[hb·P, N]`` that entered each of a head block's chunks in VMEM scratch,
-    so ``T`` is bounded (16,384 at granite-4.0-h's widths)."""
+    of a bfloat16 register), a group's heads (``g`` groups) are whole head
+    blocks whose ``[hb, Q]`` rows are whole sublane tiles, and the backward
+    holds the float32 state ``[hb·P, N]`` that entered each of a head block's
+    chunks in VMEM scratch, so ``T`` is bounded (16,384 at granite-4.0-h's
+    widths)."""
+    if h % g:
+        return f"{h} heads are no multiple of {g} groups"
+    if g > 1 and (h // g) % 8:
+        return f"{h // g} heads a group are no multiple of 8"
     if chunk % 128:
         return f"chunk {chunk} is no multiple of 128"
     if n % 128:
         return f"d_state {n} is no multiple of 128"
     if p % 16:
         return f"d_head {p} is no multiple of 16"
-    chunks, hb = -(-t // chunk), _head_block(h)
+    chunks, hb = -(-t // chunk), _head_block(h, g)
     if chunks * hb * p * n * 4 > _STATES_BYTES:
         return (f"T {t}: the states entering {chunks} chunks of {hb} heads do not fit "
                 f"the backward's {_STATES_BYTES >> 20} MiB of VMEM")
@@ -376,17 +404,33 @@ def _time_last(v):
     return v.transpose(0, 2, 1)
 
 
-def _specs(chunk, p, n, hb, at):
+def _specs(chunk, p, n, hb, at, blocks_a_group=None):
     """The block specs both kernels share, by what they cut; ``at`` maps a
-    grid step's chunk index to the chunk it visits."""
+    grid step's chunk index to the chunk it visits. ``B`` and ``C`` come as
+    ``[B, T, G·N]`` (``[B, G·N, T]`` turned): a head block reads its group's
+    ``N`` columns, ``blocks_a_group`` head blocks to a group (None: one group,
+    block 0, the program a model without groups has always traced)."""
+    group = (lambda hi: 0) if blocks_a_group is None else (lambda hi: hi // blocks_a_group)
     return {
         "x": pl.BlockSpec((1, hb * p, chunk), lambda bi, hi, ci: (bi, hi, at(ci))),
         "per_head": pl.BlockSpec((1, hb, chunk), lambda bi, hi, ci: (bi, hi, at(ci))),
         "a": pl.BlockSpec((hb, 1), lambda bi, hi, ci: (hi, 0)),
-        "bc": pl.BlockSpec((1, chunk, n), lambda bi, hi, ci: (bi, at(ci), 0)),
-        "bc_t": pl.BlockSpec((1, n, chunk), lambda bi, hi, ci: (bi, 0, at(ci))),
+        "bc": pl.BlockSpec((1, chunk, n), lambda bi, hi, ci: (bi, at(ci), group(hi))),
+        "bc_t": pl.BlockSpec((1, n, chunk), lambda bi, hi, ci: (bi, group(hi), at(ci))),
         "bc_part": pl.BlockSpec((1, 1, chunk, n), lambda bi, hi, ci: (bi, hi, at(ci), 0)),
     }
+
+
+def _grouping(h, b):
+    """``(G, hb, head blocks a group, or None for one group)`` of a call."""
+    g = scan_groups(b)
+    hb = _head_block(h, g)
+    return g, hb, (None if g == 1 else h // g // hb)
+
+
+def _side_by_side(v):
+    """``B`` / ``C``'s groups laid side by side: ``[B, T, G, N] -> [B, T, G·N]`` (a bitcast)."""
+    return v.reshape(v.shape[:2] + (-1,))
 
 
 def _compiler_params(chunk, n, hb, p, dtype, chunks=0):
@@ -406,9 +450,9 @@ def _fwd_call(x, dt, a, b, c, chunk, dtype, interpret):
     backward pass that the caller's scope does not reach."""
     rows, t, h, p = x.shape
     n, nc = b.shape[-1], t // chunk
-    hb = _head_block(h)
+    _, hb, blocks_a_group = _grouping(h, b)
     with jax.named_scope("ssd_scan"):
-        spec = _specs(chunk, p, n, hb, lambda ci: ci)
+        spec = _specs(chunk, p, n, hb, lambda ci: ci, blocks_a_group)
         y = pl.pallas_call(
             functools.partial(_ssd_fwd_kernel, p=p, dtype=dtype),
             grid=(rows, h // hb, nc),
@@ -419,8 +463,8 @@ def _fwd_call(x, dt, a, b, c, chunk, dtype, interpret):
             compiler_params=_compiler_params(chunk, n, hb, p, dtype),
             interpret=interpret,
             name="ssd_fwd",
-        )(_time_last(x.reshape(rows, t, h * p)), _time_last(dt.astype(_F32)), a.astype(_F32)[:, None], b.astype(dtype),
-          _time_last(c.astype(dtype)))
+        )(_time_last(x.reshape(rows, t, h * p)), _time_last(dt.astype(_F32)), a.astype(_F32)[:, None],
+          _side_by_side(b.astype(dtype)), _time_last(_side_by_side(c.astype(dtype))))
         return _time_last(y).reshape(rows, t, h, p)
 
 
@@ -433,12 +477,12 @@ def _bwd_call(x, dt, a, b, c, dy, chunk, dtype, interpret):
     back before its time."""
     rows, t, h, p = x.shape
     n, nc = b.shape[-1], t // chunk
-    hb = _head_block(h)
+    g, hb, blocks_a_group = _grouping(h, b)
     with jax.named_scope("ssd_scan"):
         dt32 = _time_last(dt.astype(_F32))
-        bm, cm = b.astype(dtype), c.astype(dtype)
-        both = _specs(chunk, p, n, hb, lambda ci: jnp.minimum(ci, 2 * nc - 1 - ci))  # forward, then back
-        back = _specs(chunk, p, n, hb, lambda ci: jnp.minimum(nc - 1, 2 * nc - 1 - ci))
+        bm, cm = _side_by_side(b.astype(dtype)), _side_by_side(c.astype(dtype))
+        both = _specs(chunk, p, n, hb, lambda ci: jnp.minimum(ci, 2 * nc - 1 - ci), blocks_a_group)  # forward, then back
+        back = _specs(chunk, p, n, hb, lambda ci: jnp.minimum(nc - 1, 2 * nc - 1 - ci), blocks_a_group)
         per_head = jax.ShapeDtypeStruct((rows, h, t), _F32)
         part = jax.ShapeDtypeStruct((rows, h // hb, t, n), _F32)
         dx, ddt, dda, db, dc = pl.pallas_call(
@@ -456,8 +500,14 @@ def _bwd_call(x, dt, a, b, c, dy, chunk, dtype, interpret):
         )(_time_last(x.reshape(rows, t, h * p)), _time_last(dy.reshape(rows, t, h * p)), dt32, a.astype(_F32)[:, None],
           bm, cm, _time_last(bm), _time_last(cm))
         da = jnp.sum(dda * dt32, axis=(0, 2))  # dda: the gradient of Δ A, [B, H, T]
+
+        def over_head_blocks(part):  # [B, H/hb, T, N]: a head block's partial sum -> its group's
+            if g == 1:
+                return jnp.sum(part, axis=1)
+            return jnp.sum(part.reshape(rows, g, blocks_a_group, t, n), axis=2).transpose(0, 2, 1, 3)
+
         return (_time_last(dx).reshape(x.shape), _time_last(ddt).astype(dt.dtype), da.astype(a.dtype),
-                jnp.sum(db, axis=1).astype(b.dtype), jnp.sum(dc, axis=1).astype(c.dtype))
+                over_head_blocks(db).reshape(b.shape).astype(b.dtype), over_head_blocks(dc).reshape(c.shape).astype(c.dtype))
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
@@ -478,7 +528,9 @@ _ssd.defvjp(_ssd_fwd, _ssd_bwd)
 
 def ssd_scan(x, dt, a, b, c, *, chunk: int, dtype=None, interpret: Optional[bool] = None) -> jax.Array:
     """:func:`ssd_chunked`'s ``y`` from the Pallas kernels, with their written
-    backward; same arguments. The shape must tile (:func:`ssd_tiles`);
+    backward; same arguments (``b``, ``c`` with or without a group axis: a grid
+    step's head block lies in one group and reads that group's columns, so the
+    kernels' bodies know nothing of groups). The shape must tile (:func:`ssd_tiles`);
     ``interpret=None`` auto-selects (``ops.pallas.resolve_interpret``).
 
     Under an ambient multi-device mesh the kernels run inside ``jax.shard_map``
@@ -488,7 +540,8 @@ def ssd_scan(x, dt, a, b, c, *, chunk: int, dtype=None, interpret: Optional[bool
     ``B`` and ``C`` (every head's) by rows alone; ``shard_map``'s transpose sums
     ``dA`` over the batch axes and ``dB`` / ``dC`` over ``tensor``."""
     rows, t, h, p = x.shape
-    refused = ssd_tiles(chunk, h, p, b.shape[-1], t)
+    g = scan_groups(b)
+    refused = ssd_tiles(chunk, h, p, b.shape[-1], t, g)
     if refused:
         raise ValueError(f"ssd_scan: {refused}")
     pad = -t % chunk
@@ -502,6 +555,11 @@ def ssd_scan(x, dt, a, b, c, *, chunk: int, dtype=None, interpret: Optional[bool
     spec = _ambient_shard_spec(x.shape)
     if spec is not None:
         batch, _, heads, _ = spec
-        kernels = jax.shard_map(kernels, in_specs=(spec, P(batch, None, heads), P(heads), P(batch), P(batch)),
+        groups = P(batch)
+        if g > 1 and heads is not None:  # a chip's heads read a chip's groups
+            if g % jax.sharding.get_abstract_mesh().shape[heads]:
+                raise ValueError(f"ssd_scan: {g} groups do not split over the {heads!r} axis the heads split over")
+            groups = P(batch, None, heads)
+        kernels = jax.shard_map(kernels, in_specs=(spec, P(batch, None, heads), P(heads), groups, groups),
                                 out_specs=spec, check_vma=False)
     return kernels(x, dt, a, b, c)[:, :t]

@@ -36,6 +36,13 @@ serve decode unchanged.
 
 Aux losses follow Switch/GShard: ``load_balance_loss`` (mean gate fraction x
 mean dispatch fraction per expert, scaled by E) and ``router_z_loss``.
+
+A second layer, :class:`HeldExpertsMlp` (end of the file), is what the
+published sigmoid-routed models run on one expert-parallel rank: told which of
+the published experts it holds, it routes over all of them, **drops nothing**
+and computes its own experts' part of every token's sum through a grouped
+product whose work follows the live rows. docs/parallelism.md sets the two
+side by side.
 """
 
 from __future__ import annotations
@@ -52,8 +59,10 @@ from distributed_training_pytorch_tpu.parallel.mesh import DATA_AXIS, EXPERT_AXI
 
 __all__ = [
     "EXPERT_AXIS",
+    "HeldExpertsMlp",
     "MoEMlp",
     "SORT_DISPATCH_MIN_GROUP",
+    "held_rows",
     "load_balance_loss",
     "manual_expert_ffn_local",
     "manual_expert_mlp",
@@ -551,3 +560,177 @@ def manual_expert_ffn_local(
     if n_exp > 1:
         out = jax.lax.psum(out, expert_axis)
     return out
+
+
+# ---------------------------------------------------------------------------
+# Dropless routing over a chip's share of the experts
+# ---------------------------------------------------------------------------
+#
+# ``MoEMlp`` above sizes a buffer an expert and drops what overflows it. The
+# layer below drops nothing and is told which experts it holds: the unit an
+# expert-parallel rank runs between its two exchanges (which this file does
+# not have yet: ROADMAP M2), and what one chip's share of a published model
+# is (docs/parallelism.md).
+
+
+def held_rows(top, held_first: int, held_count: int):
+    """Where each (token, choice) pair of ``top`` (``[N, k]`` expert ids over
+    the published count) goes in a buffer of ``N·k`` rows that holds the pairs
+    of experts ``held_first … held_first + held_count − 1`` at its front,
+    expert by expert and in token order inside an expert (a counting sort: a
+    running count a held expert, no comparison sort). Returns ``dest`` ``[N,
+    k]`` (a pair's row; 0 where the pair is routed elsewhere), ``live`` ``[N,
+    k]`` (the pair is held here), ``src`` ``[N·k]`` (a row's pair, token-major;
+    0 past the live rows) and ``sizes`` ``[held_count]`` (rows an expert)."""
+    n, k = top.shape
+    local = top - held_first
+    live = (local >= 0) & (local < held_count)
+    one_hot = (live[..., None] & (local[..., None] == jnp.arange(held_count))).reshape(n * k, held_count).astype(jnp.int32)
+    before = jnp.cumsum(one_hot, axis=0) - one_hot  # pairs of the same expert that come first
+    sizes = jnp.sum(one_hot, axis=0)
+    starts = jnp.cumsum(sizes) - sizes
+    dest = jnp.sum(one_hot * (starts + before), axis=-1)
+    nowhere = n * k  # out of range: dropped by the scatter below
+    src = jnp.zeros((n * k,), jnp.int32).at[jnp.where(live.reshape(-1), dest, nowhere)].set(
+        jnp.arange(n * k, dtype=jnp.int32), mode="drop", unique_indices=True)
+    return dest.reshape(n, k), live, src, sizes
+
+
+def _pairs_rows(rows, dest, live):
+    """``[N, k, d]``: each pair's row of the buffer, zeros for a pair held
+    elsewhere (it points at row 0 and is masked: what a dead row holds is no one's)."""
+    return jnp.where(live[..., None], rows[dest], 0)
+
+
+@jax.custom_vjp
+def _rows_in(x, dest, live, src):
+    """``[N, d] -> [N·k, d]``: row ``r`` is the token of pair ``src[r]``. Its
+    transpose is written as the gather it is (a token sums its live pairs'
+    rows), not the scatter-add autodiff would emit."""
+    return x[src // dest.shape[1]]
+
+
+def _rows_in_fwd(x, dest, live, src):
+    return _rows_in(x, dest, live, src), (dest, live)
+
+
+def _rows_in_bwd(res, d_rows):
+    dest, live = res
+    # a custom_vjp's backward half does not inherit the scopes of its call site: name them here for the trace's readers
+    with jax.named_scope("moe_layer"), jax.named_scope("moe_dispatch"):
+        return jnp.sum(_pairs_rows(d_rows, dest, live).astype(jnp.float32), axis=1).astype(d_rows.dtype), None, None, None
+
+
+_rows_in.defvjp(_rows_in_fwd, _rows_in_bwd)
+
+
+@jax.custom_vjp
+def _rows_out(rows, weights, dest, live, src):
+    """``out[t] = Σ_j weights[t, j] · rows[dest[t, j]]`` over the live pairs,
+    float32: the combine. Rows past the live ones are never read unmasked."""
+    return jnp.sum(weights[..., None] * _pairs_rows(rows, dest, live).astype(jnp.float32), axis=1)
+
+
+def _rows_out_fwd(rows, weights, dest, live, src):
+    return _rows_out(rows, weights, dest, live, src), (rows, weights, dest, live, src)
+
+
+def _rows_out_bwd(res, d_out):
+    rows, weights, dest, live, src = res
+    k = dest.shape[1]
+    with jax.named_scope("moe_layer"), jax.named_scope("moe_combine"):
+        is_live = (jnp.arange(src.shape[0]) < jnp.sum(live))[:, None]
+        d_rows = jnp.where(is_live, weights.reshape(-1)[src][:, None] * d_out[src // k], 0).astype(rows.dtype)
+        d_weights = jnp.sum(_pairs_rows(rows, dest, live).astype(jnp.float32) * d_out[:, None, :], axis=-1)
+        return d_rows, d_weights.astype(weights.dtype), None, None, None
+
+
+_rows_out.defvjp(_rows_out_fwd, _rows_out_bwd)
+
+
+def _relu2(x):
+    return jnp.square(nn.relu(x))
+
+
+class HeldExpertsMlp(nn.Module):
+    """Dropless top-k routing over ``experts_published`` experts, computed for
+    the ``held_count`` of them this chip holds (``held_first`` on), plus a
+    shared expert every token passes through.
+
+    Per token ``x`` (the caller's float32 norm output; the router stays in
+    float32, as the published ``nemotron_h`` / DeepSeek-V3 routers do)::
+
+        s = sigmoid(x W_rᵀ)                        W_r [experts_published, d]
+        top = the top_k largest of s + b_corr      (b_corr selects only: no gradient reaches it)
+        w_e = routed_scaling · s_e / (Σ_{e' ∈ top} s_{e'} + 1e-20)
+        out = Σ_{e ∈ top, e held here} w_e · f_e(x)  +  f_shared(x),   f(x) = relu(x U)² V
+
+    A pair routed to an expert held elsewhere contributes nothing here: that
+    partial sum is one expert-parallel rank's, and the ranks' sums (the shared
+    expert counted once) add up to the whole layer (``tests/test_moe.py``).
+    **No pair is dropped**: the (token, choice) pairs of the held experts are
+    sorted to the front of a static ``[tokens · top_k, d]`` buffer
+    (:func:`held_rows`), which holds every pair even if all are routed here,
+    and the two products are ``jax.lax.ragged_dot`` over the held experts'
+    groups of rows: on a TPU the compiler lowers it (and both of its
+    transposes) to its own Mosaic kernel with a scalar-prefetched table of the
+    row tiles that hold a live row (``ragged-dot-metadata`` in the compiled
+    step), so the work follows the live rows and not the static buffer, which
+    a dense product a group or a capacity-padded einsum would not (PERF.md
+    section 6, PR 36, has the timings on the chip; the Pallas ``megablox``
+    kernel jax ships was not tried against it). Rows past the live ones hold
+    nothing a caller may read. The one path there is, recorded as a
+    ``kernel_dispatch`` event under ``model``. ``dtype`` is what the experts'
+    matmuls compute in. Sows ``moe_pairs_local`` (live pairs) and
+    ``moe_pairs_max_expert`` (the fullest held expert's) into ``intermediates``.
+    Scopes: ``moe_layer`` › ``moe_router``, ``moe_dispatch``, ``moe_experts``,
+    ``moe_combine``, ``shared_expert``."""
+
+    expert_width: int
+    shared_width: int
+    experts_published: int
+    held_first: int
+    held_count: int
+    top_k: int
+    routed_scaling: float = 1.0
+    dtype: Any = jnp.float32
+    model: str = "moe"  # whose ``kernel_dispatch`` record the experts' product is
+
+    @nn.compact
+    def __call__(self, x):
+        from distributed_training_pytorch_tpu.ops import dispatch
+
+        if not 0 <= self.held_first <= self.held_first + self.held_count <= self.experts_published:
+            raise ValueError(f"experts {self.held_first}..{self.held_first + self.held_count - 1} are not among "
+                             f"the {self.experts_published} published")
+        lead, d = x.shape[:-1], x.shape[-1]
+        init = nn.initializers.normal(stddev=0.02)
+        with jax.named_scope("moe_layer"):
+            x32 = x.reshape(-1, d).astype(jnp.float32)
+            xc = x32.astype(self.dtype)
+            with jax.named_scope("moe_router"):
+                w_router = self.param("router", init, (self.experts_published, d), jnp.float32)
+                b_corr = self.param("score_correction_bias", nn.initializers.zeros, (self.experts_published,), jnp.float32)
+                scores = jax.nn.sigmoid(jnp.einsum("td,ed->te", x32, w_router, precision=jax.lax.Precision.HIGHEST))
+            with jax.named_scope("moe_dispatch"):
+                _, top = jax.lax.top_k(scores + jax.lax.stop_gradient(b_corr), self.top_k)
+                chosen = jnp.take_along_axis(scores, top, axis=-1)
+                weights = self.routed_scaling * chosen / (jnp.sum(chosen, axis=-1, keepdims=True) + 1e-20)
+                dest, live, src, sizes = held_rows(top, self.held_first, self.held_count)
+                rows = _rows_in(xc, dest, live, src)
+            self.sow("intermediates", "moe_pairs_local", jnp.sum(sizes).astype(jnp.float32))
+            self.sow("intermediates", "moe_pairs_max_expert", jnp.max(sizes).astype(jnp.float32))
+            with jax.named_scope("moe_experts"):
+                up = self.param("experts_up", init, (self.held_count, d, self.expert_width), jnp.float32)
+                down = self.param("experts_down", init, (self.held_count, self.expert_width, d), jnp.float32)
+                dispatch.record(self.model, "moe_experts", "ragged_dot",
+                                reason=f"backend={jax.default_backend()}: jax.lax.ragged_dot over {self.held_count} held experts; "
+                                       "the compiler's grouped-matmul kernel on a TPU (live row tiles only), jax's own lowering elsewhere")
+                hidden = _relu2(jax.lax.ragged_dot(rows, up.astype(self.dtype), sizes, preferred_element_type=self.dtype))
+                rows = jax.lax.ragged_dot(hidden, down.astype(self.dtype), sizes, preferred_element_type=self.dtype)
+            with jax.named_scope("moe_combine"):
+                out = _rows_out(rows, weights, dest, live, src)
+            with jax.named_scope("shared_expert"):
+                dense = lambda width, name: nn.Dense(width, use_bias=False, dtype=self.dtype, kernel_init=init, name=name)  # noqa: E731
+                shared = dense(d, "shared_down")(_relu2(dense(self.shared_width, "shared_up")(xc)))
+            return (out + shared.astype(jnp.float32)).astype(self.dtype).reshape(lead + (d,))

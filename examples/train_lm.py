@@ -11,7 +11,9 @@ build a real one offline with ``examples/make_lm_corpus.py``), ``SEQ_LEN``
 (default 256), ``EPOCHS``, ``BATCH``, ``BASE_LR``, ``MOE_EVERY`` (0 = dense),
 ``SAVE_DIR``, ``SNAPSHOT``, ``PROFILE_DIR``, ``LM_SIZE`` (``tiny`` | ``small``
 = GPT-2-small shape | ``hybrid_tiny`` = a toy of the Mamba-2 / grouped-query
-hybrid stack of ``models/hybrid_lm.py``, every block rematerialised),
+hybrid stack of ``models/hybrid_lm.py``, every block rematerialised |
+``nemotron_h_tiny`` = a toy of the same file's one-mixer-a-layer stack with
+routed experts),
 ``SAVE_PERIOD`` / ``LAST_SAVE_PERIOD`` (epochs between periodic / `last`
 saves — raise both when the checkpoint path is slow), ``DTYPE``
 (fp32|bf16|fp16 mixed-precision policy — docs/mixed_precision.md),
@@ -33,7 +35,7 @@ import numpy as np
 import optax
 
 from distributed_training_pytorch_tpu.data import ArrayDataSource
-from distributed_training_pytorch_tpu.models import GPTSmall, HybridTiny, LMTiny
+from distributed_training_pytorch_tpu.models import GPTSmall, HybridTiny, LMTiny, NemotronHTiny
 from distributed_training_pytorch_tpu.ops import warmup_cosine_lr
 from distributed_training_pytorch_tpu.ops.dispatch import pallas_from_env
 from distributed_training_pytorch_tpu.parallel import mesh_from_env
@@ -109,8 +111,9 @@ class LMTrainer(Trainer):
         dtype = model_dtype_for_entry(
             self.precision, DTYPE is not None or self.precision_requested, jnp.bfloat16
         )
-        if self.size == "hybrid_tiny":  # no positions and no routed experts to size
-            return HybridTiny(vocab_size=256, dtype=dtype, pallas=PALLAS)
+        hybrid = {"hybrid_tiny": HybridTiny, "nemotron_h_tiny": NemotronHTiny}.get(self.size)
+        if hybrid is not None:  # no positions and no `moe_every` to size
+            return hybrid(vocab_size=256, dtype=dtype, pallas=PALLAS)
         factory = {"tiny": LMTiny, "small": GPTSmall}[self.size]
         return factory(
             vocab_size=256,
@@ -121,6 +124,18 @@ class LMTrainer(Trainer):
         )
 
     criterion_uses_mask = True
+
+    def _aggregate_epoch_metrics(self, host, synced=0):
+        """Where the step's metrics hold the expert layers' routing counts
+        (``make_fused_lm_loss``: a stack that sows them), the pairs held here
+        also go to the ``moe.pairs_local`` counter (``profiling.trace.count``; a
+        no-op without a recorder): the epoch's sum, as its steps' metrics reach
+        the host."""
+        if "moe_pairs_local" in host[0]:
+            from distributed_training_pytorch_tpu.profiling.trace import count
+
+            count("moe.pairs_local", float(sum(m["moe_pairs_local"] for m in host)))
+        return super()._aggregate_epoch_metrics(host, synced)
 
     def build_criterion(self):
         def criterion(logits, batch):

@@ -202,12 +202,14 @@ def test_scopes_dispatch_records_and_the_remat_counter():
 
 
 def test_a_config_this_stack_cannot_run_is_refused():
-    with pytest.raises(NotImplementedError, match="mamba_n_groups"):
-        HybridConfig.from_dict({**CFG, "mamba_n_groups": 2})
+    with pytest.raises(NotImplementedError, match="num_local_experts"):
+        HybridConfig.from_dict({**CFG, "num_local_experts": 8})
     with pytest.raises(NotImplementedError, match="position_embedding_type"):
         HybridConfig.from_dict({**CFG, "position_embedding_type": "rope"})
-    with pytest.raises(ValueError, match="mamba_expand"):
-        HybridConfig.from_dict({**CFG, "mamba_n_heads": 4})
+    # since PR 36 the published keys' own values are taken: groups of B / C, and a d_inner that is
+    # heads x head size whatever mamba_expand x hidden_size would give (nemotron_h's are 4096 and 5376)
+    assert HybridConfig.from_dict({**CFG, "mamba_n_groups": 2}).mamba_n_groups == 2
+    assert HybridConfig.from_dict({**CFG, "mamba_n_heads": 4}).mamba_d_inner == 4 * CFG["mamba_d_head"] != 2 * CFG["hidden_size"]
 
 
 # -- through Trainer, from the entry -----------------------------------------

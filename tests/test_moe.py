@@ -356,3 +356,149 @@ def test_manual_expert_mlp_degenerate_mesh(devices):
             )
         )(v["params"], x)
     np.testing.assert_allclose(np.asarray(got), np.asarray(moe.apply(v, x)), atol=1e-6)
+
+
+# -- HeldExpertsMlp: dropless routing over a chip's share of the experts --------
+#
+# Against ``benchmarks/reference/nemotron_h.py``'s layer (a masked dense walk over
+# the held experts, no sort and no buffer), float32, 8 published experts top-3.
+# Tolerance: the same sums in another order; the worst case below (the loss,
+# the tokens' gradient or a leaf's) read 9.9e-7 relative, HELD_GAP is five times that.
+
+HELD_GAP = 5e-6
+HELD = dict(expert_width=24, shared_width=40, experts_published=8, top_k=3, routed_scaling=2.5)
+
+
+def held_layer(first, count):
+    from distributed_training_pytorch_tpu.parallel.moe import HeldExpertsMlp
+
+    return HeldExpertsMlp(held_first=first, held_count=count, **HELD)
+
+
+def held_cfg(first, count):
+    """The reference's keys for the same layer."""
+    return {"n_routed_experts": count, "experts_held_first": first, "published": {"n_routed_experts": 8},
+            "num_experts_per_tok": 3, "routed_scaling_factor": 2.5, "hidden_size": 16, "head_dim": 4,
+            "mamba_num_heads": 1, "mamba_head_dim": 1, "ssm_state_size": 1, "n_groups": 1, "num_attention_heads": 1,
+            "num_key_value_heads": 1}
+
+
+def whole_layer_params(seed, d=16):
+    """All 8 experts' weights under the reference's names, a non-zero selection bias among them."""
+    k = jax.random.split(jax.random.key(seed), 6)
+    return {"mixer.gate.w": jax.random.normal(k[0], (8, d)), "mixer.gate.e_score_correction_bias": 0.3 * jax.random.normal(k[1], (8,)),
+            "mixer.experts.up_proj.w": 0.3 * jax.random.normal(k[2], (8, d, 24)),
+            "mixer.experts.down_proj.w": 0.3 * jax.random.normal(k[3], (8, 24, d)),
+            "mixer.shared_experts.up_proj.w": 0.3 * jax.random.normal(k[4], (d, 40)),
+            "mixer.shared_experts.down_proj.w": 0.3 * jax.random.normal(k[5], (40, d))}
+
+
+def share_of(p, first, count):
+    """(the reference's parameters, the program's) for experts ``first … first + count − 1``."""
+    held = dict(p, **{k: p[k][first:first + count] for k in ("mixer.experts.up_proj.w", "mixer.experts.down_proj.w")})
+    program = {"router": p["mixer.gate.w"], "score_correction_bias": p["mixer.gate.e_score_correction_bias"],
+               "experts_up": held["mixer.experts.up_proj.w"], "experts_down": held["mixer.experts.down_proj.w"],
+               "shared_up": {"kernel": p["mixer.shared_experts.up_proj.w"]}, "shared_down": {"kernel": p["mixer.shared_experts.down_proj.w"]}}
+    return held, {"params": program}
+
+
+def reference_layer(x, p, first, count):
+    from benchmarks.reference import nemotron_h as ref
+
+    with jax.default_matmul_precision("highest"):
+        return ref._moe(x, p, held_cfg(first, count), lambda v: v)
+
+
+def gap(a, b):
+    return float(jnp.linalg.norm(a - b) / jnp.linalg.norm(b))
+
+
+@pytest.mark.parametrize("first,count", [(0, 4), (4, 4), (2, 3), (0, 8)])
+def test_held_experts_match_the_reference_layer_and_its_gradients(first, count):
+    x = jax.random.normal(jax.random.key(7), (2, 19, 16))
+    ref_p, variables = share_of(whole_layer_params(first * 10 + count), first, count)
+    weights = jax.random.normal(jax.random.key(8), x.shape)
+    got, grads = jax.value_and_grad(lambda v, x: jnp.sum(weights * held_layer(first, count).apply(v, x)), argnums=(0, 1))(variables, x)
+    want, want_grads = jax.value_and_grad(lambda p, x: jnp.sum(weights * reference_layer(x, p, first, count)), argnums=(0, 1))(ref_p, x)
+    assert abs(float(got) - float(want)) <= HELD_GAP * abs(float(want))
+    assert gap(grads[1], want_grads[1]) <= HELD_GAP  # the tokens'
+    _, back = share_of(want_grads[0], 0, count)  # the reference's gradients in the program's tree (already the share's)
+    gaps = jax.tree.map(gap, {k: v for k, v in grads[0]["params"].items() if k != "score_correction_bias"},
+                        {k: v for k, v in back["params"].items() if k != "score_correction_bias"})
+    assert max(jax.tree.leaves(gaps)) <= HELD_GAP, gaps
+    assert float(jnp.abs(grads[0]["params"]["score_correction_bias"]).max()) == 0  # it selects, no more
+
+
+@pytest.mark.parametrize("shares", [[(0, 4), (4, 4)], [(0, 2), (2, 2), (4, 2), (6, 2)], [(0, 3), (3, 5)]])
+def test_the_shares_add_up_to_the_uncut_layer(shares):
+    """Each share's layer gives its own experts' part of every token's sum
+    plus the shared expert, which every chip computes alike: the shares' sum,
+    with the shared expert counted once, is the whole layer as the reference
+    computes it with all 8 experts held."""
+    p = whole_layer_params(3)
+    x = jax.random.normal(jax.random.key(5), (3, 17, 16))
+    whole = reference_layer(x, p, 0, 8)
+    shared_only = reference_layer(x, dict(p, **{"mixer.experts.up_proj.w": p["mixer.experts.up_proj.w"][:0],
+                                                "mixer.experts.down_proj.w": p["mixer.experts.down_proj.w"][:0]}), 0, 0)
+    parts = [held_layer(first, count).apply(share_of(p, first, count)[1], x) for first, count in shares]
+    total = sum(parts) - (len(shares) - 1) * shared_only
+    assert gap(total, whole) <= HELD_GAP
+    assert all(gap(part, whole) > 1e-2 for part in parts)  # and no one share is the whole
+
+
+@pytest.mark.parametrize("case", ["all_to_one_held_expert", "none_here", "all_here"])
+def test_no_pair_is_dropped_at_any_imbalance(case):
+    """The worst the router can do: every token's first choice one held
+    expert (its buffer rows are then all live for that expert: 38 of 38 tokens,
+    where a capacity factor of 1.25 would keep 18), every pair routed to
+    experts held elsewhere, and every pair routed here."""
+    p = whole_layer_params(11)
+    bias = {"all_to_one_held_expert": jnp.zeros(8).at[1].set(50.0),  # expert 1 is everyone's first choice
+            "none_here": jnp.zeros(8).at[4:7].set(50.0),  # experts 4, 5, 6 take every pair
+            "all_here": jnp.zeros(8).at[:3].set(50.0)}[case]  # experts 0, 1, 2 take every pair
+    p["mixer.gate.e_score_correction_bias"] = bias
+    x = jax.random.normal(jax.random.key(2), (2, 19, 16))
+    ref_p, variables = share_of(p, 0, 4)
+    out, inter = held_layer(0, 4).apply(variables, x, mutable=["intermediates"])
+    assert gap(out, reference_layer(x, ref_p, 0, 4)) <= HELD_GAP
+    pairs, fullest = (float(inter["intermediates"][k][0]) for k in ("moe_pairs_local", "moe_pairs_max_expert"))
+    assert (pairs, fullest) == {"all_to_one_held_expert": (pairs, 38.0), "none_here": (0.0, 0.0), "all_here": (114.0, 38.0)}[case]
+    assert case != "all_to_one_held_expert" or 38 <= pairs <= 114
+
+
+def test_the_selection_bias_changes_who_is_chosen_and_not_the_weights():
+    from benchmarks.reference import nemotron_h as ref
+
+    p = whole_layer_params(4)
+    x = jax.random.normal(jax.random.key(1), (1, 64, 16))
+    cfg = held_cfg(0, 8)
+    top, weights = ref.routing(x, p, cfg)
+    top0, weights0 = ref.routing(x, dict(p, **{"mixer.gate.e_score_correction_bias": jnp.zeros(8)}), cfg)
+    assert float(jnp.mean(jnp.sort(top, -1) != jnp.sort(top0, -1))) > 0.05  # other experts are chosen
+    scores = jax.nn.sigmoid(x @ p["mixer.gate.w"].T)
+    chosen = jnp.take_along_axis(scores, top, -1)  # and their weights are the unbiased scores', normalised and scaled
+    np.testing.assert_allclose(np.asarray(weights), np.asarray(2.5 * chosen / chosen.sum(-1, keepdims=True)), rtol=1e-5)
+    np.testing.assert_allclose(np.asarray(weights.sum(-1)), 2.5, rtol=1e-5)
+    # the program's layer with the bias is the reference with it, and differs from the layer without
+    ref_p, variables = share_of(p, 0, 8)
+    out = held_layer(0, 8).apply(variables, x)
+    assert gap(out, reference_layer(x, ref_p, 0, 8)) <= HELD_GAP
+    no_bias = jax.tree.map(lambda v: v, variables)
+    no_bias["params"]["score_correction_bias"] = jnp.zeros(8)
+    assert gap(held_layer(0, 8).apply(no_bias, x), out) > 1e-2
+
+
+def test_held_rows_is_a_stable_counting_sort():
+    top = jnp.asarray([[5, 2, 9], [2, 3, 2], [7, 3, 8], [3, 2, 0]])  # experts 2 and 3 are held
+    dest, live, src, sizes = moe_lib.held_rows(top, 2, 2)
+    assert sizes.tolist() == [4, 3]
+    assert live.tolist() == [[False, True, False], [True, True, True], [False, True, False], [True, True, False]]
+    # expert 2's pairs first, in token order, then expert 3's: rows 0-3 and 4-6 of a 12-row buffer
+    assert src[:7].tolist() == [1, 3, 5, 10, 4, 7, 9]
+    assert [int(dest.reshape(-1)[pair]) for pair in src[:7].tolist()] == list(range(7))
+    assert int(jnp.sum(jnp.where(live, 0, dest))) == 0  # a pair held elsewhere points at row 0 and is masked
+
+
+def test_held_experts_outside_the_published_range_are_refused():
+    with pytest.raises(ValueError, match="not among"):
+        held_layer(6, 4).init(jax.random.key(0), jnp.zeros((1, 4, 16)))

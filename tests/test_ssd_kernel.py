@@ -293,3 +293,147 @@ def test_data_parallel_over_four_chips_each_compiles_its_own_rows(v5e):
                              *published_widths(8, 4096, NamedSharding(mesh, P("data")), NamedSharding(mesh, P())))
     calls = [line for line in text.splitlines() if 'custom_call_target="tpu_custom_call"' in line]
     assert len(calls) == 2 and all("[2,4096,4096]" in line for line in calls), calls
+
+
+# -- B and C in groups (nemotron_h: 8 groups of 8 heads; chunk 128) -------------
+
+
+def grouped_inputs(seed, t, rows, heads, groups, channels=16):
+    (x, dt, a, _, _), _ = scan_inputs(seed, t, rows, heads)
+    k = jax.random.split(jax.random.key(seed + 50), 3)
+    x = x[..., :channels]
+    b, c = (jax.random.normal(k[i], (rows, t, groups, STATES)) for i in (0, 1))
+    return (x, dt, a, b, c), jax.random.normal(k[2], x.shape)
+
+
+def sequential(*args):
+    from benchmarks.reference import nemotron_h
+
+    return nemotron_h.ssd_sequential(*args)
+
+
+@pytest.fixture(scope="module")
+def grouped_results():
+    """``(y, gradients)`` of the kernels, the chunked form and the recurrence at
+    2 groups of 8 heads and 8 groups of 8, T = 300 (padded to three chunks of 128)."""
+    out = {}
+    for groups in (2, 8):
+        args, weights = grouped_inputs(groups, 300, 1, 8 * groups, groups)
+        out[groups] = {"kernel": with_gradients(kernel, weights)(*args),
+                       "chunked": with_gradients(lambda *a: ssd_chunked(*a, chunk=CHUNK), weights)(*args),
+                       "sequential": with_gradients(sequential, weights)(*args)}
+    return out
+
+
+@pytest.mark.parametrize("form", ["kernel", "chunked"])
+@pytest.mark.parametrize("name", ("y",) + NAMES)
+@pytest.mark.parametrize("groups", [2, 8])
+def test_grouped_b_and_c_match_the_sequential_recurrence(grouped_results, groups, name, form):
+    """A head reads its own group's ``B`` and ``C``, in ``ssd_chunked`` and in
+    ``ssd_fwd`` / ``ssd_bwd`` (a head block's block specs pick the group's
+    columns): ``y`` and all five gradients against the recurrence a step at a
+    time (worst read 3.0e-6, a group's ``dB`` among them: summed over its heads
+    alone)."""
+    got, want = grouped_results[groups][form], grouped_results[groups]["sequential"]
+    i = ("y",) + NAMES
+    got, want = ((r[0] if name == "y" else r[1][i.index(name) - 1]) for r in (got, want))
+    assert got.shape == want.shape and rel(got, want) <= FLOAT32_GAP, rel(got, want)
+
+
+def test_the_groups_are_not_interchangeable():
+    (x, dt, a, b, c), _ = grouped_inputs(3, 128, 1, 16, 2)
+    y = kernel(x, dt, a, b, c)
+    swapped = kernel(x, dt, a, b[:, :, ::-1], c[:, :, ::-1])
+    assert rel(y, swapped) > 0.1
+    moved = kernel(x, dt, a, b.at[:, :, 1].add(1.0), c)  # group 1's B reaches heads 8-15 alone
+    changed = np.abs(np.asarray(moved - y)).max(axis=(0, 1, 3))
+    assert (changed[:8] == 0).all() and (changed[8:] > 0).all()
+
+
+def pr35_ssd_chunked(x, dt, a, b, c, *, chunk, dtype=None):
+    """``ops/ssd.py:ssd_chunked`` as PR 35 left it (``B``, ``C`` ``[B, T, N]`` for every head), kept to hold today's to its bits."""
+    dtype = dtype or x.dtype
+    rows, t, h, p = x.shape
+    n = b.shape[-1]
+    pad = -t % chunk
+    if pad:
+        x, dt, b, c = (jnp.pad(v, ((0, 0), (0, pad)) + ((0, 0),) * (v.ndim - 2)) for v in (x, dt, b, c))
+    nc = (t + pad) // chunk
+    f32 = jnp.float32
+    dt = dt.astype(f32)
+    da = (dt * a.astype(f32)).reshape(rows, nc, chunk, h).transpose(0, 1, 3, 2)
+    cs = jnp.cumsum(da, axis=-1)
+    xd = (x.astype(f32) * dt[..., None]).reshape(rows, nc, chunk, h, p).transpose(0, 1, 3, 2, 4)
+    b = b.reshape(rows, nc, chunk, n).astype(dtype)
+    c = c.reshape(rows, nc, chunk, n).astype(dtype)
+    seg = cs[..., :, None] - cs[..., None, :]
+    decay = jnp.exp(jnp.where(jnp.tril(jnp.ones((chunk, chunk), bool)), seg, -jnp.inf))
+    cb = jnp.einsum("bctn,bcsn->bcts", c, b, preferred_element_type=f32)
+    y = jnp.einsum("bchts,bchsp->bchtp", (decay * cb[:, :, None]).astype(dtype), xd.astype(dtype), preferred_element_type=f32)
+    to_end = jnp.exp(cs[..., -1:] - cs)
+    own = jnp.einsum("bchsp,bcsn->bchpn", (xd * to_end[..., None]).astype(dtype), b, preferred_element_type=f32)
+    whole = jnp.exp(cs[..., -1])
+
+    def carry_on(state, per_chunk):
+        return per_chunk[0][..., None, None] * state + per_chunk[1], state
+
+    _, entering = jax.lax.scan(carry_on, jnp.zeros((rows, h, p, n), f32), (whole.transpose(1, 0, 2), own.transpose(1, 0, 2, 3, 4)))
+    carried = jnp.einsum("bctn,bchpn->bchtp", c, entering.transpose(1, 0, 2, 3, 4).astype(dtype), preferred_element_type=f32)
+    y = y + jnp.exp(cs)[..., None] * carried
+    return y.transpose(0, 1, 3, 2, 4).reshape(rows, nc * chunk, h, p)[:, :t]
+
+
+@pytest.mark.parametrize("form", ["chunked", "kernel"])
+def test_one_group_is_todays_program_to_the_bit(form):
+    """With ``B`` / ``C`` as ``[B, T, N]`` or as ``[B, T, 1, N]`` both forms give
+    PR 35's ``ssd_chunked`` bits where the form is ``ssd_chunked`` (``y`` and all
+    five gradients), and the kernels give each other's either way; the kernels'
+    call for one group is the one they have always traced (block 0 of ``B`` and
+    ``C`` for every head block, the same grid and head block)."""
+    args, weights = scan_inputs(4, 300, 2, 8)
+    one_group = args[:3] + (args[3][:, :, None], args[4][:, :, None])
+    fn = kernel if form == "kernel" else (lambda *a: ssd_chunked(*a, chunk=CHUNK))
+    y3, g3 = with_gradients(fn, weights)(*args)
+    y4, g4 = with_gradients(fn, weights)(*one_group)
+    same = lambda u, v: bool(jnp.all(u.reshape(v.shape) == v))  # noqa: E731
+    assert same(y3, y4) and all(same(u, v) for u, v in zip(g3, g4, strict=True))
+    if form == "chunked":
+        y, g = with_gradients(lambda *a: pr35_ssd_chunked(*a, chunk=CHUNK), weights)(*args)
+        assert same(y3, y) and all(same(u, v) for u, v in zip(g3, g, strict=True))
+    else:
+        text = jax.jit(lambda *a: kernel(*a, dtype=jnp.float32)).lower(*args).as_text()
+        assert "tensor<2x384x128xf32>" in text  # B as [B, T, 1·N]: no group axis reaches the call
+
+
+@pytest.mark.parametrize("heads,groups,why", [(64, 8, None), (16, 2, None), (64, 1, None), (12, 8, "12 heads are no multiple of 8 groups"),
+                                               (32, 8, "4 heads a group are no multiple of 8")])
+def test_which_grouped_shapes_tile(heads, groups, why):
+    got = ssd_tiles(CHUNK, heads, CHANNELS, STATES, 8192, groups)
+    assert (got is None) if why is None else (why in got), got
+
+
+def test_the_dispatch_record_names_the_groups():
+    args, _ = grouped_inputs(0, 128, 1, 16, 2)
+    dispatch.reset()
+    try:
+        dispatch.ssd_fn("nemotron_h", True)(*args, chunk=CHUNK)
+        (rec,) = dispatch.records()
+        assert (rec["model"], rec["path"]) == ("nemotron_h", "pallas") and "16 heads of 16, d_state 128, 2 groups" in rec["reason"]
+    finally:
+        dispatch.reset()
+
+
+def test_both_kernels_compile_for_the_v5e_at_nemotrons_widths(v5e):
+    """``NVIDIA-Nemotron-3-Nano-30B-A3B``'s scan as its cell runs it: ``[2, 8192, 64, 64]``,
+    8 groups of 128 states, chunk 128 (64 chunks: a head block of 8 keeps 16 MiB
+    of entering states in the backward's VMEM). Mosaic takes both bodies."""
+    from jax.sharding import SingleDeviceSharding
+
+    one = SingleDeviceSharding(v5e[0])
+    rows, t, heads, groups = 2, 8192, 64, 8
+    assert ssd_tiles(128, heads, CHANNELS, STATES, t, groups) is None
+    shapes = tuple(jax.ShapeDtypeStruct(shape, dtype, sharding=one) for shape, dtype in (
+        ((rows, t, heads, CHANNELS), jnp.bfloat16), ((rows, t, heads), jnp.float32), ((heads,), jnp.float32),
+        ((rows, t, groups, STATES), jnp.bfloat16), ((rows, t, groups, STATES), jnp.bfloat16)))
+    text = compiled_text(lambda *a: forward_and_backward(*a, chunk=128), *shapes)
+    assert text.count('custom_call_target="tpu_custom_call"') == 2

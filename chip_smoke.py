@@ -23,7 +23,11 @@ Phases
            at ConvNeXt-L's expand shapes; and the Mamba-2 scan's two kernels
            (``ops/ssd.py``: ``ssd_fwd`` / ``ssd_bwd``) at granite-4.0-h-micro's
            widths and at nemotron-3-nano-30b-a3b's (eight ``B`` / ``C`` groups,
-           chunk 128, T=8192) against the sequential float32 recurrence. Plus two device
+           chunk 128, T=8192) against the sequential float32 recurrence; and the
+           routed expert layer's four row movements (``ops/moe_rows.py``) at
+           that model's shape against their ``jax.numpy`` form at 3%, 6.25% and
+           100% of the pairs held here, and the whole layer with the rows the
+           kernels never write set to NaN. Plus two device
            checks: ``tpu_compiler_options()`` is accepted by the installed
            libtpu, and ``jax.block_until_ready`` really blocks.
   leg_a    ``Cifar10Trainer`` (examples/train_cifar10.py): VGG16 at full
@@ -65,6 +69,7 @@ The compile cache goes where ``utils.enable_compile_cache`` puts it:
 from __future__ import annotations
 
 import argparse
+import functools
 import json
 import os
 import re
@@ -459,6 +464,97 @@ def phase_kernels(smoke: Smoke, devices) -> None:
 
         smoke.case("kernels.ssd_scan_data_mesh", ssd_case, ssd_shape[0] * len(devices), *ssd_shape[1:],
                    mesh=mesh_lib.create_mesh({mesh_lib.DATA_AXIS: len(devices)}, devices=devices))
+
+    # The routed expert layer's four row movements (parallel/moe.py: dispatch, combine and their transposes) at
+    # nemotron-3-nano-30b-a3b's shape and the cell's tokens: the kernels of ops/moe_rows.py against the jax.numpy
+    # form, with the share of the pairs held here as the window has it (3%), as an even routing would (6.25%) and at
+    # the dropless worst (every pair here); each movement's ms in both forms beside it (informational).
+    def moe_rows_case(name, n, k, d, held, published, share):
+        from distributed_training_pytorch_tpu.ops import moe_rows
+        from distributed_training_pytorch_tpu.parallel import moe
+
+        keys = jax.random.split(jax.random.key(11), 8)
+        # a token's k experts differ; each is held here with probability ``share``
+        here = jnp.argsort(jax.random.uniform(keys[0], (n, held)), axis=1)[:, :k]
+        elsewhere = held + jnp.argsort(jax.random.uniform(keys[1], (n, published - held)), axis=1)[:, :k]
+        top = jnp.where(jax.random.uniform(keys[2], (n, k)) < share, here, elsewhere).astype(jnp.int32)
+        dest, live, src, sizes = moe.held_rows(top, 0, held)
+        n_live, tile = jnp.sum(sizes), moe_rows.rows_tile(n)
+        pairs = jax.jit(moe_rows.live_pairs, static_argnums=1)
+        route = moe.Route(dest, live, src, n_live, *pairs(live, tile))
+        x, rows, d_rows = (jax.random.normal(key, shape, dt) for key, shape in zip(keys[3:6], ((n, d), (n * k, d), (n * k, d))))
+        weights, d_out = jax.random.uniform(keys[6], (n, k)), jax.random.normal(keys[7], (n, d))
+        movements = {  # (the movement, its arguments, which of its outputs are the buffer's rows)
+            "dispatch": (lambda t, route, x: (moe._rows_in(t, x, route),), (x,), (0,)),
+            "dispatch_bwd": (lambda t, route, d_rows: moe._rows_in_bwd(t, route, d_rows)[:1], (d_rows,), ()),
+            "combine": (lambda t, route, rows, weights: (moe._rows_out(t, rows, weights, route),), (rows, weights), ()),
+            "combine_bwd": (lambda t, route, rows, weights, d_out: moe._rows_out_bwd(t, (rows, weights, route), d_out)[:2],
+                            (rows, weights, d_out), (0,)),
+        }
+        is_live = (jnp.arange(n * k) < n_live)[:, None]  # what a row past the live ones holds is no one's: left out of the comparison
+        errs, ms, finite = {}, {}, True
+        for key, (fn, args, buffers) in movements.items():
+            outs = {}
+            for form, t in (("gather", None), ("pallas", tile)):
+                run = jax.jit(functools.partial(fn, t))
+                out = jax.block_until_ready(run(route, *args))
+                outs[form] = [jnp.where(is_live, v, 0) if i in buffers else v for i, v in enumerate(out)]
+                t0 = time.perf_counter()
+                for _ in range(5):
+                    out = run(route, *args)
+                jax.block_until_ready(out)
+                ms[f"{key}.{form}"] = round((time.perf_counter() - t0) / 5 * 1e3, 3)
+            finite = finite and all(bool(jnp.all(jnp.isfinite(v.astype(jnp.float32)))) for v in outs["pallas"])
+            errs[key] = max(_norm_err(g, w) for g, w in zip(outs["pallas"], outs["gather"], strict=True))
+        jax.block_until_ready(pairs(live, tile))
+        t0 = time.perf_counter()
+        jax.block_until_ready([pairs(live, tile) for _ in range(5)])
+        ms["live_pairs"] = round((time.perf_counter() - t0) / 5 * 1e3, 3)
+        smoke.info(f"{name}.ms", ms)
+        # the same float32 sums in another order, rounded to bf16 once where the jax.numpy form rounds
+        smoke.check(name, finite and max(errs.values()) <= 1e-5 + (2**-8 if dt == jnp.bfloat16 else 0),
+                    f"x {(n, d)} top-{k}, {held} of {published} held, {int(n_live)} of {n * k} pairs live; norm err {errs}")
+
+    # The whole layer with the rows the kernels never write poisoned: the grouped products, relu² and every
+    # transpose between the two movements run over a buffer whose dead rows are NaN, and nothing may reach an
+    # output or a gradient (the allocation's own leftovers are what they hold in a training step).
+    def moe_poisoned_case(name, n, d, width, held, published, k):
+        from distributed_training_pytorch_tpu.ops import dispatch, moe_rows
+        from distributed_training_pytorch_tpu.parallel.moe import HeldExpertsMlp
+
+        layer = HeldExpertsMlp(width, width, published, 0, held, k, 2.5, dt, "chip_smoke")
+        x = jax.random.normal(jax.random.key(3), (1, n, d))
+        variables = jax.jit(layer.init)(jax.random.key(4), x)
+        cot = jax.random.normal(jax.random.key(5), x.shape)
+
+        def both(v, x):
+            return jax.value_and_grad(lambda v, x: jnp.sum(cot * layer.apply(v, x).astype(jnp.float32)), argnums=(0, 1))(v, x)
+
+        clean, backends = moe_rows.rows_from_table, dispatch.MOE_ROWS_BACKENDS
+
+        def poisoned(table, src, n_live, weights=None, dot_with=None, fill=None, *, out_dtype=None, **kw):
+            out_dtype = out_dtype or table.dtype
+            return clean(table, src, n_live, weights, dot_with, jnp.full((src.shape[0], table.shape[1]), jnp.nan, out_dtype),
+                         out_dtype=out_dtype, **kw)
+
+        try:
+            dispatch.MOE_ROWS_BACKENDS = ()  # the jax.numpy form
+            want = jax.jit(both)(variables, x)
+            dispatch.MOE_ROWS_BACKENDS = (devices[0].platform,)
+            moe_rows.rows_from_table = poisoned
+            got = jax.jit(both)(variables, x)
+        finally:
+            moe_rows.rows_from_table, dispatch.MOE_ROWS_BACKENDS = clean, backends
+        leaves = list(zip(jax.tree.leaves(got), jax.tree.leaves(want), strict=True))
+        finite = all(bool(jnp.all(jnp.isfinite(g.astype(jnp.float32)))) for g, _ in leaves)
+        err = max(_norm_err(g, w) for g, w in leaves if float(jnp.linalg.norm(w.astype(jnp.float32))) > 0)
+        smoke.check(name, finite and err <= 2e-2, f"{n} tokens of {d}, {held} of {published} held: finite {finite}, worst norm err "
+                                                  f"of loss and gradients against the jax.numpy form {err:.2e}")
+
+    rows_shape = (64, 3, 128, 4, 8) if small else (16384, 6, 2688, 8, 128)
+    for share in (0.03, 0.0625, 1.0):
+        smoke.case(f"kernels.moe_rows_{round(share * 100)}pct", moe_rows_case, *rows_shape, share=0.4 if small and share < 1 else share)
+    smoke.case("kernels.moe_rows_poisoned", moe_poisoned_case, *((64, 128, 128, 4, 8, 3) if small else (16384, 2688, 1856, 8, 128, 6)))
 
     # bench.py's per-compile options against the installed libtpu.
     opts = tpu_compiler_options()

@@ -44,6 +44,12 @@ nemotron_h     attention, ssd           as hybrid_lm (the same modules)
 nemotron_h     moe_experts              no choice yet: ``jax.lax.ragged_dot``,
                                         recorded by the layer itself
                                         (``parallel/moe.py:HeldExpertsMlp``)
+nemotron_h     moe_rows                 the layer's four row movements: the
+                                        kernels of ``ops/moe_rows.py`` (work
+                                        follows the live pairs) on TPU where
+                                        the shape tiles and one device holds
+                                        the tokens, else the ``jax.numpy``
+                                        gathers over the whole buffer
 =============  =======================  =========================================
 
 Observability (the silent-fall-through fix): each resolution is recorded as
@@ -68,6 +74,7 @@ __all__ = [
     "attention_fn",
     "lm_attention_impl",
     "ssd_fn",
+    "moe_rows_tile",
     "conv1x1_policy",
     "record",
     "records",
@@ -301,6 +308,41 @@ def ssd_fn(model: str, pallas: Optional[bool]):
         return (ssd_scan if path == "pallas" else ssd_chunked)(x, dt, a, b, c, chunk=chunk, dtype=dtype)
 
     return scan
+
+
+# ---------------------------------------------------------------------------
+# the routed expert layer's row movements (nemotron_h)
+# ---------------------------------------------------------------------------
+
+MOE_ROWS_BACKENDS = ("tpu",)  # where the row kernels are the default (a test that wants them interpreted adds its own)
+
+
+def moe_rows_tile(model: str, tokens: int, k: int, width: int, dtype) -> Optional[int]:
+    """Resolve the form of ``parallel/moe.py:HeldExpertsMlp``'s four row
+    movements (dispatch, combine and their transposes) and record it: the
+    tokens a grid step of the kernels of ``ops/moe_rows.py``, whose work
+    follows the live pairs, or None for the ``jax.numpy`` form over all
+    ``tokens · k`` pairs. The kernels on a TPU where the shape tiles
+    (``moe_rows.rows_refused``) and the tokens are on one device; no knob:
+    the layer has one answer a shape and a platform."""
+    import jax
+
+    from .moe_rows import rows_refused, rows_tile
+
+    backend = jax.default_backend()
+    mesh = jax.sharding.get_abstract_mesh()
+    refused = rows_refused(tokens, k, width, dtype)
+    if backend not in MOE_ROWS_BACKENDS:
+        path, reason = "gather", f"auto: backend={backend} (the row kernels are TPU-default only)"
+    elif refused:
+        path, reason = "gather", f"backend={backend}: {refused}"
+    elif mesh.axis_names and mesh.size > 1 and not mesh.manual_axes:
+        path, reason = "gather", f"backend={backend}: a mesh of {mesh.size} devices may split the tokens, which the kernels take whole"
+    else:
+        path, reason = "pallas", (f"auto: backend={backend}, {tokens} tokens x top-{k} of width {width} tile: "
+                                  "work follows the live pairs (moe.pairs_local), not the buffer")
+    record(model, "moe_rows", path, reason=reason)
+    return rows_tile(tokens) if path == "pallas" else None
 
 
 # ---------------------------------------------------------------------------

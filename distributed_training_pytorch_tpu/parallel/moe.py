@@ -47,7 +47,8 @@ side by side.
 
 from __future__ import annotations
 
-from typing import Any
+import functools
+from typing import Any, NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -570,7 +571,12 @@ def manual_expert_ffn_local(
 # layer below drops nothing and is told which experts it holds: the unit an
 # expert-parallel rank runs between its two exchanges (which this file does
 # not have yet: ROADMAP M2), and what one chip's share of a published model
-# is (docs/parallelism.md).
+# is (docs/parallelism.md). Its pairs' buffer has a row for every (token,
+# choice) pair, of which the held experts fill a few per cent; everything the
+# layer does to rows follows the live ones where it can: the grouped products
+# (``jax.lax.ragged_dot``) everywhere, the four row movements around them
+# (``_rows_in``, ``_rows_out`` and their written transposes) where the kernels of
+# ``ops/moe_rows.py`` run.
 
 
 def held_rows(top, held_first: int, held_count: int):
@@ -596,53 +602,103 @@ def held_rows(top, held_first: int, held_count: int):
     return dest.reshape(n, k), live, src, sizes
 
 
+class Route(NamedTuple):
+    """A layer's routing as the four row movements read it: :func:`held_rows`'
+    ``dest``, ``live`` and ``src``, the live count, and (where the kernels of
+    ``ops/moe_rows.py`` run, else None) its ``live_pairs``."""
+
+    dest: Any
+    live: Any
+    src: Any
+    n_live: Any
+    starts: Any = None
+    pair: Any = None
+
+
+# The four row movements. Each has two forms and takes ``tile`` to say which:
+# None is the ``jax.numpy`` form, which works over all ``N·k`` pairs and is the
+# definition; a number is the tokens a grid step of the kernels of
+# ``ops/moe_rows.py``, whose work follows the ``n_live`` live pairs
+# (``ops/dispatch.py:moe_rows_tile`` picks and records). What the buffer's rows
+# past the live ones hold: in the ``jax.numpy`` form copies of token 0
+# (``_rows_in``) and zeros (``d_rows``); from the kernels zeros up to the end
+# of the last live tile and, past it, **whatever the allocation held, NaN
+# included**. No one may read them unmasked, and no one does: the grouped
+# products and both of their transposes stop at the groups' last row, relu² and
+# its gradient work a row at a time, and the movements back read live rows only
+# (``tests/test_moe.py`` poisons them; ``chip_smoke.py`` does on the chip).
+
+
 def _pairs_rows(rows, dest, live):
     """``[N, k, d]``: each pair's row of the buffer, zeros for a pair held
     elsewhere (it points at row 0 and is masked: what a dead row holds is no one's)."""
     return jnp.where(live[..., None], rows[dest], 0)
 
 
-@jax.custom_vjp
-def _rows_in(x, dest, live, src):
+def _kernels():
+    """``ops/moe_rows.py``, imported where a layer first takes its kernels (it brings Pallas with it)."""
+    from distributed_training_pytorch_tpu.ops import moe_rows
+
+    return moe_rows
+
+
+def _tokens_from_rows(tile, rows, route, weights, out_dtype):
+    return _kernels().tokens_from_rows(rows, route.starts, route.pair, route.dest, weights, out_dtype=out_dtype, tile=tile)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _rows_in(tile, x, route):
     """``[N, d] -> [N·k, d]``: row ``r`` is the token of pair ``src[r]``. Its
     transpose is written as the gather it is (a token sums its live pairs'
     rows), not the scatter-add autodiff would emit."""
-    return x[src // dest.shape[1]]
+    k = route.dest.shape[1]
+    if tile is None:
+        return x[route.src // k]
+    return _kernels().rows_from_table(x, route.src, route.n_live, k=k)
 
 
-def _rows_in_fwd(x, dest, live, src):
-    return _rows_in(x, dest, live, src), (dest, live)
+def _rows_in_fwd(tile, x, route):
+    return _rows_in(tile, x, route), route
 
 
-def _rows_in_bwd(res, d_rows):
-    dest, live = res
+def _rows_in_bwd(tile, route, d_rows):
     # a custom_vjp's backward half does not inherit the scopes of its call site: name them here for the trace's readers
     with jax.named_scope("moe_layer"), jax.named_scope("moe_dispatch"):
-        return jnp.sum(_pairs_rows(d_rows, dest, live).astype(jnp.float32), axis=1).astype(d_rows.dtype), None, None, None
+        if tile is None:
+            dx = jnp.sum(_pairs_rows(d_rows, route.dest, route.live).astype(jnp.float32), axis=1).astype(d_rows.dtype)
+        else:
+            dx = _tokens_from_rows(tile, d_rows, route, None, d_rows.dtype)
+        return dx, None
 
 
 _rows_in.defvjp(_rows_in_fwd, _rows_in_bwd)
 
 
-@jax.custom_vjp
-def _rows_out(rows, weights, dest, live, src):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _rows_out(tile, rows, weights, route):
     """``out[t] = Σ_j weights[t, j] · rows[dest[t, j]]`` over the live pairs,
     float32: the combine. Rows past the live ones are never read unmasked."""
-    return jnp.sum(weights[..., None] * _pairs_rows(rows, dest, live).astype(jnp.float32), axis=1)
+    if tile is None:
+        return jnp.sum(weights[..., None] * _pairs_rows(rows, route.dest, route.live).astype(jnp.float32), axis=1)
+    return _tokens_from_rows(tile, rows, route, weights, jnp.float32)
 
 
-def _rows_out_fwd(rows, weights, dest, live, src):
-    return _rows_out(rows, weights, dest, live, src), (rows, weights, dest, live, src)
+def _rows_out_fwd(tile, rows, weights, route):
+    return _rows_out(tile, rows, weights, route), (rows, weights, route)
 
 
-def _rows_out_bwd(res, d_out):
-    rows, weights, dest, live, src = res
+def _rows_out_bwd(tile, res, d_out):
+    rows, weights, route = res
+    dest, live, src = route.dest, route.live, route.src
     k = dest.shape[1]
     with jax.named_scope("moe_layer"), jax.named_scope("moe_combine"):
-        is_live = (jnp.arange(src.shape[0]) < jnp.sum(live))[:, None]
-        d_rows = jnp.where(is_live, weights.reshape(-1)[src][:, None] * d_out[src // k], 0).astype(rows.dtype)
-        d_weights = jnp.sum(_pairs_rows(rows, dest, live).astype(jnp.float32) * d_out[:, None, :], axis=-1)
-        return d_rows, d_weights.astype(weights.dtype), None, None, None
+        if tile is None:
+            is_live = (jnp.arange(src.shape[0]) < route.n_live)[:, None]
+            d_rows = jnp.where(is_live, weights.reshape(-1)[src][:, None] * d_out[src // k], 0).astype(rows.dtype)
+            d_weights = jnp.sum(_pairs_rows(rows, dest, live).astype(jnp.float32) * d_out[:, None, :], axis=-1)
+        else:  # the same visit has a row and its token's ``d_out`` in VMEM: their product goes to the row's pair
+            d_rows, d_weights = _kernels().rows_from_table(d_out, src, route.n_live, weights, rows, k=k, out_dtype=rows.dtype)
+        return d_rows, d_weights.astype(weights.dtype), None
 
 
 _rows_out.defvjp(_rows_out_fwd, _rows_out_bwd)
@@ -678,9 +734,23 @@ class HeldExpertsMlp(nn.Module):
     step), so the work follows the live rows and not the static buffer, which
     a dense product a group or a capacity-padded einsum would not (PERF.md
     section 6, PR 36, has the timings on the chip; the Pallas ``megablox``
-    kernel jax ships was not tried against it). Rows past the live ones hold
-    nothing a caller may read. The one path there is, recorded as a
-    ``kernel_dispatch`` event under ``model``. ``dtype`` is what the experts'
+    kernel jax ships was not tried against it). The rows' way into the
+    buffer and back (dispatch, combine) and the transposes of both take one
+    of two forms, which ``ops/dispatch.py:moe_rows_tile`` picks from what it
+    can see and records as ``(model, "moe_rows", "pallas" | "gather", reason)``:
+    on a TPU, where ``d`` is whole 128-lane registers, the tokens whole tiles
+    and on one device, the two kernels of ``ops/moe_rows.py``, which copy, scale
+    and sum one row a live pair (``moe_pairs_local`` of them: 3% of the buffer
+    in ``nemotron3nano_t8192``) and visit no tile of the buffer past the last
+    live one; anywhere else gathers and masked sums over all ``tokens · top_k``
+    pairs in ``jax.numpy``, which are the definition the kernels are held to
+    (``tests/test_moe.py``). Both keep the router and the weights float32, sum
+    a token's pairs in float32 and round to ``dtype`` at the same two places
+    (``d_rows``, the dispatch's ``dx``). **Rows past the live ones hold nothing
+    a caller may read**: copies of token 0 or zeros in the ``jax.numpy`` form,
+    zeros to the end of the last live tile and past it whatever the
+    allocation held from the kernels (the comment above ``_pairs_rows``).
+    The products' one path is recorded too (``moe_experts``). ``dtype`` is what the experts'
     matmuls compute in. Sows ``moe_pairs_local`` (live pairs) and
     ``moe_pairs_max_expert`` (the fullest held expert's) into ``intermediates``.
     Scopes: ``moe_layer`` › ``moe_router``, ``moe_dispatch``, ``moe_experts``,
@@ -717,7 +787,9 @@ class HeldExpertsMlp(nn.Module):
                 chosen = jnp.take_along_axis(scores, top, axis=-1)
                 weights = self.routed_scaling * chosen / (jnp.sum(chosen, axis=-1, keepdims=True) + 1e-20)
                 dest, live, src, sizes = held_rows(top, self.held_first, self.held_count)
-                rows = _rows_in(xc, dest, live, src)
+                tile = dispatch.moe_rows_tile(self.model, dest.shape[0], self.top_k, d, self.dtype)
+                route = Route(dest, live, src, jnp.sum(sizes), *(_kernels().live_pairs(live, tile) if tile is not None else ()))
+                rows = _rows_in(tile, xc, route)
             self.sow("intermediates", "moe_pairs_local", jnp.sum(sizes).astype(jnp.float32))
             self.sow("intermediates", "moe_pairs_max_expert", jnp.max(sizes).astype(jnp.float32))
             with jax.named_scope("moe_experts"):
@@ -729,7 +801,7 @@ class HeldExpertsMlp(nn.Module):
                 hidden = _relu2(jax.lax.ragged_dot(rows, up.astype(self.dtype), sizes, preferred_element_type=self.dtype))
                 rows = jax.lax.ragged_dot(hidden, down.astype(self.dtype), sizes, preferred_element_type=self.dtype)
             with jax.named_scope("moe_combine"):
-                out = _rows_out(rows, weights, dest, live, src)
+                out = _rows_out(tile, rows, weights, route)
             with jax.named_scope("shared_expert"):
                 dense = lambda width, name: nn.Dense(width, use_bias=False, dtype=self.dtype, kernel_init=init, name=name)  # noqa: E731
                 shared = dense(d, "shared_down")(_relu2(dense(self.shared_width, "shared_up")(xc)))

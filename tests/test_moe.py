@@ -6,6 +6,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from distributed_training_pytorch_tpu.ops import dispatch
 from distributed_training_pytorch_tpu.parallel import mesh as mesh_lib
 from distributed_training_pytorch_tpu.parallel import moe as moe_lib
 from distributed_training_pytorch_tpu.parallel.moe import EXPERT_AXIS, MoEMlp
@@ -384,9 +385,10 @@ def held_cfg(first, count):
 
 
 def whole_layer_params(seed, d=16):
-    """All 8 experts' weights under the reference's names, a non-zero selection bias among them."""
+    """All 8 experts' weights under the reference's names, a non-zero selection bias among them
+    (the router's scaled so that its scores spread at any ``d`` as they do at 16)."""
     k = jax.random.split(jax.random.key(seed), 6)
-    return {"mixer.gate.w": jax.random.normal(k[0], (8, d)), "mixer.gate.e_score_correction_bias": 0.3 * jax.random.normal(k[1], (8,)),
+    return {"mixer.gate.w": (16 / d) ** 0.5 * jax.random.normal(k[0], (8, d)), "mixer.gate.e_score_correction_bias": 0.3 * jax.random.normal(k[1], (8,)),
             "mixer.experts.up_proj.w": 0.3 * jax.random.normal(k[2], (8, d, 24)),
             "mixer.experts.down_proj.w": 0.3 * jax.random.normal(k[3], (8, 24, d)),
             "mixer.shared_experts.up_proj.w": 0.3 * jax.random.normal(k[4], (d, 40)),
@@ -413,10 +415,25 @@ def gap(a, b):
     return float(jnp.linalg.norm(a - b) / jnp.linalg.norm(b))
 
 
+@pytest.fixture(params=[("gather", 16, 19), ("pallas", 128, 20)], ids=["gather", "pallas"])
+def rows(request, monkeypatch):
+    """``(d, tokens a batch row)`` for a layer whose four row movements take
+    their ``jax.numpy`` form (what the CPU runs), or the kernels of
+    ``ops/moe_rows.py`` interpreted, at a width and a token count that tile.
+    The program has no knob for it: the test widens the platforms the kernels
+    are the default on."""
+    form, d, t = request.param
+    if form == "pallas":
+        monkeypatch.setattr(dispatch, "MOE_ROWS_BACKENDS", (jax.default_backend(),))
+    yield d, t
+    assert ("moe", "moe_rows", form) in {(r["model"], r["op"], r["path"]) for r in dispatch.records()}
+
+
 @pytest.mark.parametrize("first,count", [(0, 4), (4, 4), (2, 3), (0, 8)])
-def test_held_experts_match_the_reference_layer_and_its_gradients(first, count):
-    x = jax.random.normal(jax.random.key(7), (2, 19, 16))
-    ref_p, variables = share_of(whole_layer_params(first * 10 + count), first, count)
+def test_held_experts_match_the_reference_layer_and_its_gradients(first, count, rows):
+    d, t = rows
+    x = jax.random.normal(jax.random.key(7), (2, t, d))
+    ref_p, variables = share_of(whole_layer_params(first * 10 + count, d), first, count)
     weights = jax.random.normal(jax.random.key(8), x.shape)
     got, grads = jax.value_and_grad(lambda v, x: jnp.sum(weights * held_layer(first, count).apply(v, x)), argnums=(0, 1))(variables, x)
     want, want_grads = jax.value_and_grad(lambda p, x: jnp.sum(weights * reference_layer(x, p, first, count)), argnums=(0, 1))(ref_p, x)
@@ -430,13 +447,14 @@ def test_held_experts_match_the_reference_layer_and_its_gradients(first, count):
 
 
 @pytest.mark.parametrize("shares", [[(0, 4), (4, 4)], [(0, 2), (2, 2), (4, 2), (6, 2)], [(0, 3), (3, 5)]])
-def test_the_shares_add_up_to_the_uncut_layer(shares):
+def test_the_shares_add_up_to_the_uncut_layer(shares, rows):
     """Each share's layer gives its own experts' part of every token's sum
     plus the shared expert, which every chip computes alike: the shares' sum,
     with the shared expert counted once, is the whole layer as the reference
     computes it with all 8 experts held."""
-    p = whole_layer_params(3)
-    x = jax.random.normal(jax.random.key(5), (3, 17, 16))
+    d, t = rows
+    p = whole_layer_params(3, d)
+    x = jax.random.normal(jax.random.key(5), (2, t, d))
     whole = reference_layer(x, p, 0, 8)
     shared_only = reference_layer(x, dict(p, **{"mixer.experts.up_proj.w": p["mixer.experts.up_proj.w"][:0],
                                                 "mixer.experts.down_proj.w": p["mixer.experts.down_proj.w"][:0]}), 0, 0)
@@ -447,23 +465,25 @@ def test_the_shares_add_up_to_the_uncut_layer(shares):
 
 
 @pytest.mark.parametrize("case", ["all_to_one_held_expert", "none_here", "all_here"])
-def test_no_pair_is_dropped_at_any_imbalance(case):
+def test_no_pair_is_dropped_at_any_imbalance(case, rows):
     """The worst the router can do: every token's first choice one held
     expert (its buffer rows are then all live for that expert: 38 of 38 tokens,
     where a capacity factor of 1.25 would keep 18), every pair routed to
     experts held elsewhere, and every pair routed here."""
-    p = whole_layer_params(11)
+    d, t = rows
+    n = 2.0 * t
+    p = whole_layer_params(11, d)
     bias = {"all_to_one_held_expert": jnp.zeros(8).at[1].set(50.0),  # expert 1 is everyone's first choice
             "none_here": jnp.zeros(8).at[4:7].set(50.0),  # experts 4, 5, 6 take every pair
             "all_here": jnp.zeros(8).at[:3].set(50.0)}[case]  # experts 0, 1, 2 take every pair
     p["mixer.gate.e_score_correction_bias"] = bias
-    x = jax.random.normal(jax.random.key(2), (2, 19, 16))
+    x = jax.random.normal(jax.random.key(2), (2, t, d))
     ref_p, variables = share_of(p, 0, 4)
     out, inter = held_layer(0, 4).apply(variables, x, mutable=["intermediates"])
     assert gap(out, reference_layer(x, ref_p, 0, 4)) <= HELD_GAP
     pairs, fullest = (float(inter["intermediates"][k][0]) for k in ("moe_pairs_local", "moe_pairs_max_expert"))
-    assert (pairs, fullest) == {"all_to_one_held_expert": (pairs, 38.0), "none_here": (0.0, 0.0), "all_here": (114.0, 38.0)}[case]
-    assert case != "all_to_one_held_expert" or 38 <= pairs <= 114
+    assert (pairs, fullest) == {"all_to_one_held_expert": (pairs, n), "none_here": (0.0, 0.0), "all_here": (3 * n, n)}[case]
+    assert case != "all_to_one_held_expert" or n <= pairs <= 3 * n
 
 
 def test_the_selection_bias_changes_who_is_chosen_and_not_the_weights():
@@ -502,3 +522,135 @@ def test_held_rows_is_a_stable_counting_sort():
 def test_held_experts_outside_the_published_range_are_refused():
     with pytest.raises(ValueError, match="not among"):
         held_layer(6, 4).init(jax.random.key(0), jnp.zeros((1, 4, 16)))
+
+
+# -- the row movements' kernels (ops/moe_rows.py), interpreted, against their jax.numpy form --------
+#
+# 64 tokens of width 128, top-3 over 8 published experts of which 4 are held: a
+# buffer of 192 rows in tiles of 64, 64 tokens in one tile. The kernels add a
+# token's pairs in the order j = 0 … k−1; the jax.numpy form's sum over the k
+# axis is the compiler's to order, so sums are held to float32 round-off and
+# what is only moved or scaled to the bit.
+
+ROWS_N, ROWS_K, ROWS_D, ROWS_HELD = 64, 3, 128, 4
+
+
+def routed(share):
+    """``top`` ``[64, 3]`` for a share of the pairs held here (experts 0-3 of 8)."""
+    k = jax.random.split(jax.random.key(17), 3)
+    here = jnp.argsort(jax.random.uniform(k[0], (ROWS_N, ROWS_HELD)), axis=1)[:, :ROWS_K]  # a token's experts differ
+    elsewhere = ROWS_HELD + here[:, ::-1]
+    if share == "one_expert":  # every token's first choice is expert 1, its others are held elsewhere
+        return elsewhere.at[:, 0].set(1)
+    return jnp.where(jax.random.uniform(k[2], (ROWS_N, ROWS_K)) < share, here, elsewhere)
+
+
+SHARES = [pytest.param(0.0, id="none_here"), pytest.param(0.03, id="the_windows_3pct"), pytest.param(0.5, id="even"),
+          pytest.param("one_expert", id="one_expert_takes_every_token"), pytest.param(1.0, id="every_pair_here")]
+
+
+def route_of(top, tile):
+    from distributed_training_pytorch_tpu.ops import moe_rows
+
+    dest, live, src, sizes = moe_lib.held_rows(top, 0, ROWS_HELD)
+    return moe_lib.Route(dest, live, src, jnp.sum(sizes), *moe_rows.live_pairs(live, tile))
+
+
+def live_rows(route, rows):
+    """What a row past the live ones holds is no one's: zeros, for a comparison."""
+    return jnp.where((jnp.arange(rows.shape[0]) < route.n_live)[:, None], rows, 0)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("share", SHARES)
+def test_each_row_movement_and_its_transpose_equal_the_jax_numpy_form(share, dtype):
+    tile = 64
+    route = route_of(routed(share), tile)
+    k = jax.random.split(jax.random.key(23), 5)
+    x, rows, d_rows = (jax.random.normal(key, shape).astype(dtype) for key, shape in
+                       zip(k[:3], ((ROWS_N, ROWS_D), (ROWS_N * ROWS_K, ROWS_D), (ROWS_N * ROWS_K, ROWS_D))))
+    weights, d_out = jax.random.uniform(k[3], (ROWS_N, ROWS_K)), jax.random.normal(k[4], (ROWS_N, ROWS_D))
+
+    def movements(t):
+        into, back = jax.vjp(lambda x: moe_lib._rows_in(t, x, route), x)
+        out, back_out = jax.vjp(lambda rows, weights: moe_lib._rows_out(t, rows, weights, route), rows, weights)
+        (dx,), (d_buffer, d_weights) = back(d_rows), back_out(d_out)
+        return {"dispatch": live_rows(route, into), "dx": dx, "combine": out, "d_rows": live_rows(route, d_buffer), "d_weights": d_weights}
+
+    got, want = movements(tile), movements(None)
+    for name in want:
+        assert got[name].dtype == want[name].dtype and got[name].shape == want[name].shape, name
+        a, b = np.asarray(got[name], np.float32), np.asarray(want[name], np.float32)
+        if name in ("dispatch", "d_rows"):  # a row chosen, or one product a row: nothing to reorder
+            np.testing.assert_array_equal(a, b, err_msg=name)
+        else:  # float32 sums (a token's k rows, a row's d products) in another order; `dx` then rounded to `dtype` once
+            np.testing.assert_allclose(a, b, rtol=2.0**-7 if got[name].dtype == jnp.bfloat16 else 1e-5, atol=1e-5, err_msg=name)
+    assert float(jnp.abs(want["combine"]).sum()) > 0 or share == 0.0
+
+
+@pytest.mark.parametrize("share", SHARES)
+def test_the_buffers_side_visits_the_live_tiles_and_no_other(share):
+    """A tile the kernel visits is written (rows, then zeros to its end); one it
+    does not keeps what the allocation held, here NaN: the tiles written are
+    ``ceil(n_live / tile)`` (one where nothing is live: the buffer's first rows
+    are then zeros), whatever the buffer's size."""
+    from distributed_training_pytorch_tpu.ops import moe_rows
+
+    tile = 16
+    route = route_of(routed(share), 64)
+    n_live = int(route.n_live)
+    x = jax.random.normal(jax.random.key(1), (ROWS_N, ROWS_D))
+    d_out, weights = jax.random.normal(jax.random.key(2), (ROWS_N, ROWS_D)), jax.random.uniform(jax.random.key(3), (ROWS_N, ROWS_K))
+    nan = jnp.full((ROWS_N * ROWS_K, ROWS_D), jnp.nan)
+    tokens = route.src // ROWS_K
+    plain = moe_rows.rows_from_table(x, route.src, route.n_live, fill=nan, k=ROWS_K, tile=tile)
+    scaled, dots = moe_rows.rows_from_table(d_out, route.src, route.n_live, weights, plain, fill=nan, k=ROWS_K, tile=tile)
+    for out in (plain, scaled):
+        written = ~np.isnan(np.asarray(out)).all(axis=1).reshape(-1, tile)
+        assert written.all(axis=1).sum() == written.any(axis=1).sum() == max(-(-n_live // tile), 1)  # whole tiles, from the front
+        assert not np.isnan(np.asarray(out)[:n_live]).any() and (np.asarray(out)[n_live:written.sum()] == 0).all()
+    np.testing.assert_array_equal(np.asarray(plain[:n_live]), np.asarray(x[tokens][:n_live]))
+    # a live row's product sits at its pair, and every other pair reads zero (the rows past the live ones are NaN here)
+    want = jnp.where(route.live, jnp.sum(x[:, None, :] * d_out[:, None, :], -1), 0)
+    np.testing.assert_allclose(np.asarray(dots), np.asarray(want), rtol=1e-6)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["float32", "bfloat16"])
+def test_rows_the_kernels_never_write_reach_no_output_and_no_gradient(dtype, monkeypatch):
+    """The whole layer, kernels forced, with every row of the buffer that the
+    buffer's side leaves unwritten poisoned with NaN (in a training step they
+    hold the allocation's leftovers): the grouped products, relu² and every
+    transpose between the two movements run over them, and the loss and all
+    gradients are finite and those of the ``jax.numpy`` form."""
+    from distributed_training_pytorch_tpu.ops import moe_rows
+    from distributed_training_pytorch_tpu.parallel.moe import HeldExpertsMlp
+
+    layer = HeldExpertsMlp(held_first=0, held_count=2, dtype=dtype, **HELD)  # 2 of 8 held: most of the buffer is dead
+    x = jax.random.normal(jax.random.key(1), (2, 64, 128))
+    variables = jax.tree.map(lambda v: 4 * v, layer.init(jax.random.key(0), x))
+    cot = jax.random.normal(jax.random.key(2), x.shape)
+    both = jax.value_and_grad(lambda v, x: jnp.sum(cot * layer.apply(v, x).astype(jnp.float32)), argnums=(0, 1))
+    want = both(variables, x)
+    clean, seen = moe_rows.rows_from_table, []
+
+    def poisoned(table, src, n_live, weights=None, dot_with=None, fill=None, *, out_dtype=None, **kw):
+        out_dtype = out_dtype or table.dtype
+        seen.append(int(src.shape[0]) - int(n_live))
+        return clean(table, src, n_live, weights, dot_with, jnp.full((src.shape[0], table.shape[1]), jnp.nan, out_dtype), out_dtype=out_dtype, **kw)
+
+    monkeypatch.setattr(dispatch, "MOE_ROWS_BACKENDS", (jax.default_backend(),))
+    monkeypatch.setattr(moe_rows, "rows_from_table", poisoned)
+    got = both(variables, x)
+    assert len(seen) == 2 and min(seen) > 2 * 128  # dispatch and the combine's transpose, each with dead tiles behind the live ones
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want), strict=True):
+        assert bool(jnp.all(jnp.isfinite(a.astype(jnp.float32))))
+        np.testing.assert_allclose(np.asarray(a, np.float32), np.asarray(b, np.float32), rtol=1e-6, atol=1e-6)
+
+
+@pytest.mark.parametrize("tokens,width,dtype,why", [(64, 100, jnp.float32, "no multiple of 128"), (60, 128, jnp.float32, "no multiple of 8"),
+                                                    (64, 128, jnp.float16, "neither float32 nor bfloat16")])
+def test_a_shape_the_row_kernels_do_not_take_keeps_the_jax_numpy_form(tokens, width, dtype, why, monkeypatch):
+    monkeypatch.setattr(dispatch, "MOE_ROWS_BACKENDS", (jax.default_backend(),))
+    assert dispatch.moe_rows_tile("refused", tokens, 3, width, dtype) is None
+    assert why in [r["reason"] for r in dispatch.records() if r["model"] == "refused" and r["path"] == "gather"][-1]
+    assert dispatch.moe_rows_tile("taken", 64, 3, 128, jnp.bfloat16) == 64

@@ -168,9 +168,12 @@ def test_scopes_dispatch_records_and_step_metrics():
             assert scope in text, scope
         assert "gated_mlp" not in text  # a layer is its one mixer
         recs = {(r["model"], r["op"], r["path"]) for r in dispatch.records()}
-        assert recs == {("nemotron_h", "attention", "plain"), ("nemotron_h", "ssd", "chunked"), ("nemotron_h", "moe_experts", "ragged_dot")}
+        assert recs == {("nemotron_h", "attention", "plain"), ("nemotron_h", "ssd", "chunked"), ("nemotron_h", "moe_experts", "ragged_dot"),
+                        ("nemotron_h", "moe_rows", "gather")}
         (reason,) = [r["reason"] for r in dispatch.records() if r["op"] == "moe_experts"]
         assert "ragged_dot over 4 held experts" in reason and "backend=cpu" in reason
+        (reason,) = [r["reason"] for r in dispatch.records() if r["op"] == "moe_rows"]
+        assert "backend=cpu" in reason and "TPU-default only" in reason  # off the chip the jax.numpy form: no test needs a TPU
         batch = make_batch(0, 2, 16)
         _, (metrics, _) = make_fused_lm_loss(model)(variables["params"], {}, batch, jax.random.key(0), True)
         offered = 2 * 16 * 3 * 2  # tokens x top-k x expert layers

@@ -437,3 +437,34 @@ def test_both_kernels_compile_for_the_v5e_at_nemotrons_widths(v5e):
         ((rows, t, groups, STATES), jnp.bfloat16), ((rows, t, groups, STATES), jnp.bfloat16)))
     text = compiled_text(lambda *a: forward_and_backward(*a, chunk=128), *shapes)
     assert text.count('custom_call_target="tpu_custom_call"') == 2
+
+
+def test_the_expert_layers_row_kernels_compile_for_the_v5e_at_nemotrons_widths(v5e):
+    """``ops/moe_rows.py`` as ``nemotron3nano_t8192`` calls it (here because the
+    described chips are this file's: one worker loads the TPU's library): 16,384
+    tokens of 2,688, top-6, a buffer of 98,304 rows. Mosaic takes the four calls:
+    a copy of a row's group of eight from a float32 and from a bfloat16 table in
+    HBM (it refuses a one-row slice of either), 98,304 positions an array in the
+    scalar core's memory, a bfloat16 row read through the 32-bit view of its
+    packed pair."""
+    from jax.sharding import SingleDeviceSharding
+
+    from distributed_training_pytorch_tpu.ops import moe_rows
+
+    one = SingleDeviceSharding(v5e[0])
+    n, k, d = 16384, 6, 2688
+    assert moe_rows.rows_refused(n, k, d, jnp.bfloat16) is None and moe_rows.rows_tile(n) == 256
+
+    def s(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    def movements(x, d_out, rows, src, n_live, weights, starts, pair, dest):
+        into = moe_rows.rows_from_table(x, src, n_live, k=k, interpret=False)
+        d_rows, d_weights = moe_rows.rows_from_table(d_out, src, n_live, weights, rows, k=k, out_dtype=jnp.bfloat16, interpret=False)
+        out = moe_rows.tokens_from_rows(rows, starts, pair, dest, weights, tile=256, interpret=False)
+        dx = moe_rows.tokens_from_rows(rows, starts, pair, dest, out_dtype=jnp.bfloat16, tile=256, interpret=False)
+        return into, d_rows, d_weights, out, dx
+
+    text = compiled_text(movements, s((n, d), jnp.bfloat16), s((n, d), jnp.float32), s((n * k, d), jnp.bfloat16), s((n * k,), jnp.int32),
+                         s((), jnp.int32), s((n, k), jnp.float32), s((n // 256 + 1,), jnp.int32), s((n * k,), jnp.int32), s((n, k), jnp.int32))
+    assert text.count('custom_call_target="tpu_custom_call"') == 4

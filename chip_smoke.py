@@ -23,7 +23,10 @@ Phases
            at ConvNeXt-L's expand shapes; and the Mamba-2 scan's two kernels
            (``ops/ssd.py``: ``ssd_fwd`` / ``ssd_bwd``) at granite-4.0-h-micro's
            widths and at nemotron-3-nano-30b-a3b's (eight ``B`` / ``C`` groups,
-           chunk 128, T=8192) against the sequential float32 recurrence; and the
+           chunk 128, T=8192) against the sequential float32 recurrence; the
+           causal convolution's two kernels (``ops/causal_conv.py``:
+           ``conv_fwd`` / ``conv_bwd``) at both models' widths and cells' T
+           against its ``jax.numpy`` form; and the
            routed expert layer's four row movements (``ops/moe_rows.py``) at
            that model's shape against their ``jax.numpy`` form at 3%, 6.25% and
            100% of the pairs held here, and the whole layer with the rows the
@@ -464,6 +467,46 @@ def phase_kernels(smoke: Smoke, devices) -> None:
 
         smoke.case("kernels.ssd_scan_data_mesh", ssd_case, ssd_shape[0] * len(devices), *ssd_shape[1:],
                    mesh=mesh_lib.create_mesh({mesh_lib.DATA_AXIS: len(devices)}, devices=devices))
+
+    # The Mamba-2 mixer's causal convolution with its SiLU (ops/causal_conv.py) at both models' widths and their
+    # cells' T: the kernels read xBC out of the whole in_proj output as the mixer hands it; values and the three
+    # gradients against the jax.numpy form (ops/ssd.py:causal_conv1d) on the same bf16 inputs, and each form's ms.
+    def conv_case(name, b, t, d_inner, c, heads):
+        from distributed_training_pytorch_tpu.ops.causal_conv import causal_conv_silu
+        from distributed_training_pytorch_tpu.ops.ssd import causal_conv1d
+
+        k = jax.random.split(jax.random.key(7), 4)
+        proj = jax.random.normal(k[0], (b, t, d_inner + c + heads), dt)
+        w = jax.random.uniform(k[1], (4, c), minval=-0.5, maxval=0.5)
+        bias = 0.1 * jax.random.normal(k[2], (c,))
+        g = jax.random.normal(k[3], (b, t, c), dt)
+
+        def run(fn):
+            def both(g, *args):
+                y, vjp = jax.vjp(fn, *args)
+                return (y,) + vjp(g)
+            return both
+
+        forms = {"kernels": lambda *v: causal_conv_silu(*v, start=d_inner, dtype=dt),
+                 "jnp": lambda p, w, b: jax.nn.silu(causal_conv1d(p[..., d_inner:d_inner + c], w, b)).astype(dt)}
+        compiled = {f: _compile(run(fn), g, proj, w, bias) for f, fn in forms.items()}
+        out = {f: jax.block_until_ready(fn(g, proj, w, bias)) for f, fn in compiled.items()}
+        for f, fn in compiled.items():
+            t0 = time.perf_counter()
+            for _ in range(10):
+                jax.block_until_ready(fn(g, proj, w, bias))
+            smoke.info(f"{name}.{f}_fwd_bwd_ms", round((time.perf_counter() - t0) * 100, 3))
+        errs = {key: _norm_err(got, ref) for key, got, ref in zip(("y", "dx", "dw", "db"), out["kernels"], out["jnp"])}
+        finite = all(bool(jnp.all(jnp.isfinite(v.astype(jnp.float32)))) for v in out["kernels"])
+        # both round y and dx to bf16 once; the float32 sums differ in order only (PR 39: a bf16 ulp at most)
+        smoke.check(name, finite and max(errs.values()) <= 4e-3, f"xBC {(b, t, c)} from channel {d_inner}: norm err {errs}")
+        mosaic_check(name, compiled["kernels"])
+        if on_tpu:
+            calls = re.findall(r"%(conv_\w+)\.\d+ = ", compiled["kernels"].as_text())
+            smoke.check(f"{name}.one_kernel_each", sorted(calls) == ["conv_bwd", "conv_fwd"], f"Mosaic calls {calls}")
+
+    smoke.case("kernels.conv_granite", conv_case, *((1, 256, 128, 160, 16) if small else (2, 4096, 4096, 4352, 64)))
+    smoke.case("kernels.conv_nemotron", conv_case, *((1, 512, 128, 384, 16) if small else (2, 8192, 4096, 6144, 64)))
 
     # The routed expert layer's four row movements (parallel/moe.py: dispatch, combine and their transposes) at
     # nemotron-3-nano-30b-a3b's shape and the cell's tokens: the kernels of ops/moe_rows.py against the jax.numpy

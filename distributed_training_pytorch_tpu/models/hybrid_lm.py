@@ -38,10 +38,12 @@ Per token, ``n(x) = x / sqrt(mean(x²) + eps) * w``::
 
 Attention resolves through ``ops/dispatch.py`` like every model's (flash on a
 TPU; the kernels' scale is ``head_dim**-0.5``, so the configuration's is
-folded into ``q``), and so does the scan (``ops/ssd.py``: its Pallas kernels on
-a TPU where the shape tiles, its ``jax.numpy`` chunked form elsewhere), under
-the same ``pallas`` knob. Parameters and norms' statistics are float32;
-``dtype`` is what the matmuls compute in.
+folded into ``q``), and so do the scan (``ops/ssd.py``: its Pallas kernels on
+a TPU where the shape tiles, its ``jax.numpy`` chunked form elsewhere) and the
+causal convolution with its SiLU (``ops/causal_conv.py``'s kernels on a TPU,
+``ops/ssd.py:causal_conv1d`` elsewhere), under the same ``pallas`` knob.
+Parameters and norms' statistics are float32; ``dtype`` is what the matmuls
+compute in.
 
 ``remat=True`` rematerialises every block (``nn.remat``): the backward pass
 keeps one hidden state a block and computes each block's forward pass again
@@ -58,8 +60,6 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 from flax import linen as nn
-
-from distributed_training_pytorch_tpu.ops.ssd import causal_conv1d
 
 MAMBA, ATTENTION, MOE = "mamba", "attention", "moe"
 
@@ -169,7 +169,7 @@ def _dt_bias_init(key, shape, dtype=jnp.float32):
 class MambaMixer(nn.Module):
     cfg: HybridConfig
     dtype: Any = jnp.float32
-    pallas: Any = None  # ops/dispatch.py's knob: True the scan's kernels, False its chunked form, None auto
+    pallas: Any = None  # ops/dispatch.py's knob: True the scan's and the conv's kernels, False their jnp forms, None auto
 
     @nn.compact
     def __call__(self, u):
@@ -181,12 +181,12 @@ class MambaMixer(nn.Module):
         conv_dim = d_inner + 2 * groups * n
         rows, t, _ = u.shape
         with jax.named_scope("mamba_mixer"):
-            z, xbc, dt = jnp.split(_dense(d_inner + conv_dim + heads, self.dtype, "in_proj")(u),
-                                   [d_inner, d_inner + conv_dim], axis=-1)
+            proj = _dense(d_inner + conv_dim + heads, self.dtype, "in_proj")(u)
+            z, dt = proj[..., :d_inner], proj[..., d_inner + conv_dim:]
             conv_w = self.param("conv_kernel", _conv_init, (cfg.mamba_d_conv, conv_dim), jnp.float32)
             conv_b = self.param("conv_bias", nn.initializers.zeros, (conv_dim,), jnp.float32)
-            with jax.named_scope("mamba_conv"):
-                xbc = nn.silu(causal_conv1d(xbc, conv_w, conv_b)).astype(self.dtype)
+            with jax.named_scope("mamba_conv"):  # xBC read where in_proj left it: the conv takes the whole projection
+                xbc = dispatch.conv_fn(cfg.dispatch_name, self.pallas)(proj, conv_w, conv_b, start=d_inner, dtype=self.dtype)
             x, b, c = jnp.split(xbc, [d_inner, d_inner + groups * n], axis=-1)
             x = x.reshape(rows, t, heads, p)
             if groups > 1:

@@ -40,7 +40,11 @@ hybrid_lm      attention                flash on TPU when ``T >= 512``
 hybrid_lm      ssd                      the Pallas scan (``ops/ssd.py:ssd_scan``)
                                         on TPU where the shape tiles, else the
                                         ``jax.numpy`` chunked form
-nemotron_h     attention, ssd           as hybrid_lm (the same modules)
+hybrid_lm      conv                     the causal convolution's kernels
+                                        (``ops/causal_conv.py``) on TPU where
+                                        the shape tiles and the rows split
+                                        over the mesh, else ``jax.numpy``
+nemotron_h     attention, ssd, conv     as hybrid_lm (the same modules)
 nemotron_h     moe_experts              no choice yet: ``jax.lax.ragged_dot``,
                                         recorded by the layer itself
                                         (``parallel/moe.py:HeldExpertsMlp``)
@@ -74,6 +78,7 @@ __all__ = [
     "attention_fn",
     "lm_attention_impl",
     "ssd_fn",
+    "conv_fn",
     "moe_rows_tile",
     "conv1x1_policy",
     "record",
@@ -270,7 +275,7 @@ def lm_attention_impl(attention_impl: str, pallas: Optional[bool]) -> str:
 
 
 # ---------------------------------------------------------------------------
-# the Mamba-2 scan (hybrid_lm)
+# the Mamba-2 scan and causal convolution (hybrid_lm)
 # ---------------------------------------------------------------------------
 
 
@@ -308,6 +313,46 @@ def ssd_fn(model: str, pallas: Optional[bool]):
         return (ssd_scan if path == "pallas" else ssd_chunked)(x, dt, a, b, c, chunk=chunk, dtype=dtype)
 
     return scan
+
+
+def conv_fn(model: str, pallas: Optional[bool]):
+    """Resolve the path of the Mamba-2 mixer's causal convolution with its
+    SiLU for ``model``: a callable ``conv(x, w, b, *, start, dtype)`` giving
+    ``silu(causal_conv1d(x[..., start:start+C], w, b)).astype(dtype)`` (``w``
+    ``[K, C]``; ``x`` the whole input projection) that, at each call's shapes,
+    runs the Pallas kernels (:func:`~distributed_training_pytorch_tpu.ops.causal_conv.causal_conv_silu`)
+    or the ``jax.numpy`` form, and records which and why.
+
+    ``pallas=False`` -> jnp. ``None`` -> the kernels on a TPU where the shape
+    tiles (``conv_tiles``) and the rows split over the ambient mesh, jnp
+    elsewhere. ``True`` -> the kernels wherever they can run, interpreted off
+    the chip (parity tests); a shape they do not take still falls to jnp, with
+    the reason."""
+    import jax
+
+    from .causal_conv import causal_conv_silu, conv_tiles, rows_unsplit
+    from .ssd import causal_conv1d
+
+    def conv(x, w, b, *, start, dtype):
+        (rows, t, _), (k, c) = x.shape, w.shape
+        backend = jax.default_backend()
+        shape = f"{c} channels, T {t}, {k} taps"
+        refused = conv_tiles(c, t, k, start) or rows_unsplit(rows)
+        if pallas is False:
+            path, reason = "jnp", "pallas=False"
+        elif pallas is None and backend != "tpu":
+            path, reason = "jnp", f"auto: backend={backend} (the conv kernels are TPU-default only)"
+        elif refused:
+            path, reason = "jnp", f"backend={backend}: {refused} ({shape})"
+        else:
+            path = "pallas"
+            reason = f"pallas=True (forced), backend={backend}: {shape}" if pallas else f"auto: backend={backend}, {shape} tile"
+        record(model, "conv", path, reason=reason)
+        if path == "pallas":
+            return causal_conv_silu(x, w, b, start=start, dtype=dtype)
+        return jax.nn.silu(causal_conv1d(jax.lax.slice_in_dim(x, start, start + c, axis=2), w, b)).astype(dtype)
+
+    return conv
 
 
 # ---------------------------------------------------------------------------

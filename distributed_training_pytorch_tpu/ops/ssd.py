@@ -1,7 +1,8 @@
 """The state-space layer's two sequence operations: the chunked (dual) form of
 the selective scan of Mamba-2 (Dao & Gu 2024, arXiv:2405.21060, "SSD"), as
 ``jax.numpy`` and as a pair of Pallas kernels with a written backward, and the
-causal depthwise convolution in front of it.
+causal depthwise convolution in front of it as ``jax.numpy`` (its kernels are
+``ops/causal_conv.py``'s).
 
 The recurrence, per batch row and head (``x_t`` of ``P`` channels, ``B_t`` and
 ``C_t`` of ``N`` states shared by the heads of a group: ``G`` groups of ``H / G``
@@ -77,7 +78,13 @@ def causal_conv1d(x: jax.Array, w: jax.Array, b: jax.Array | None = None) -> jax
 
     ``x``: ``[B, T, C]``; ``w``: ``[K, C]`` (tap ``K-1`` meets the current
     step); ``b``: ``[C]`` or None. Float32 inside and out: ``K`` shifted
-    multiply-adds that the compiler fuses into one pass over ``x``."""
+    multiply-adds of a padded float32 copy of ``x``. The forward fuses, but on
+    the chip autodiff's transpose of the pad and the slices writes float32
+    ``[B, T, C]`` arrays and each tap's gradient reads ``x`` and ``dy`` again
+    (PERF.md §5: some eight times one pass over the operands), which is why the
+    Mamba-2 mixer runs ``ops/causal_conv.py``'s kernels there; this form is what
+    the CPU runs, what a shape those kernels refuse falls to, and their parity
+    reference."""
     k, t = w.shape[0], x.shape[1]
     padded = jnp.pad(x.astype(jnp.float32), ((0, 0), (k - 1, 0), (0, 0)))
     w = w.astype(jnp.float32)

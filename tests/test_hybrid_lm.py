@@ -187,7 +187,7 @@ def test_scopes_dispatch_records_and_the_remat_counter():
         for scope in ("mamba_mixer", "mamba_mixer/mamba_conv", "mamba_mixer/ssd_scan", "gqa_attention", "gated_mlp"):
             assert scope in text, scope
         recs = {(r["model"], r["op"], r["path"]) for r in dispatch.records()}
-        assert recs == {("hybrid_lm", "attention", "plain"), ("hybrid_lm", "ssd", "chunked")}
+        assert recs == {("hybrid_lm", "attention", "plain"), ("hybrid_lm", "ssd", "chunked"), ("hybrid_lm", "conv", "jnp")}
     finally:
         dispatch.reset()
 

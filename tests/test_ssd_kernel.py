@@ -177,7 +177,7 @@ def test_the_model_hands_its_knob_to_the_scan():
     try:
         got, grads = jax.jit(jax.value_and_grad(lambda v: loss(v, True)))(variables)
         paths = {(r["op"], r["path"]) for r in dispatch.records()}
-        assert paths == {("attention", "flash"), ("ssd", "pallas")}
+        assert paths == {("attention", "flash"), ("ssd", "pallas"), ("conv", "jnp")}  # T 200: no whole lane tiles for the conv
         want, want_grads = jax.jit(jax.value_and_grad(lambda v: loss(v, False)))(variables)
     finally:
         dispatch.reset()
@@ -468,3 +468,74 @@ def test_the_expert_layers_row_kernels_compile_for_the_v5e_at_nemotrons_widths(v
     text = compiled_text(movements, s((n, d), jnp.bfloat16), s((n, d), jnp.float32), s((n * k, d), jnp.bfloat16), s((n * k,), jnp.int32),
                          s((), jnp.int32), s((n, k), jnp.float32), s((n // 256 + 1,), jnp.int32), s((n * k,), jnp.int32), s((n, k), jnp.int32))
     assert text.count('custom_call_target="tpu_custom_call"') == 4
+
+
+# -- the causal convolution's kernels (ops/causal_conv.py), compiled for the chip -----------------------------
+# Here because the described chips are this file's (one worker loads the TPU's library); the convolution's
+# interpreted tests are tests/test_conv_kernel.py.
+
+# name: (rows, T, d_inner, groups, chunk): the cells' batch and T at the configurations' mixer widths
+CONV_CELLS = {"granite4h_t4096": (2, 4096, 4096, 1, 256), "nemotron3nano_t8192": (2, 8192, 4096, 8, 128)}
+
+
+@pytest.mark.parametrize("cell", CONV_CELLS)
+def test_the_conv_kernels_compile_for_the_v5e_at_the_cells_widths(v5e, cell):
+    """Mosaic takes ``conv_fwd`` and ``conv_bwd`` under the VMEM limit reckoned
+    for them, the convolution's channels read out of the whole projection."""
+    from jax.sharding import SingleDeviceSharding
+
+    from distributed_training_pytorch_tpu.ops.causal_conv import causal_conv_silu, conv_tiles
+
+    one = SingleDeviceSharding(v5e[0])
+    rows, t, d_inner, groups, _ = CONV_CELLS[cell]
+    c = d_inner + 2 * groups * STATES
+    assert conv_tiles(c, t, 4, d_inner) is None
+    shapes = tuple(jax.ShapeDtypeStruct(shape, dtype, sharding=one) for shape, dtype in (
+        ((rows, t, d_inner + c + 64), jnp.bfloat16), ((4, c), jnp.float32), ((c,), jnp.float32)))
+    conv = lambda *a: jnp.sum(causal_conv_silu(*a, start=d_inner, dtype=jnp.bfloat16, interpret=False).astype(jnp.float32))  # noqa: E731
+    text = compiled_text(jax.value_and_grad(conv, argnums=range(3)), *shapes)
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
+
+
+@pytest.mark.parametrize("cell", CONV_CELLS)
+def test_the_mixer_hands_its_activations_between_kernels_without_copies(v5e, cell, monkeypatch):
+    """The Mamba-2 mixer's forward and backward at the cell's widths with the
+    scan's and the convolution's kernels compiled (on the chip ``pallas=None``
+    picks both; here the test steers them off the interpreter): four Mosaic
+    calls, the convolution reads ``xBC`` out of ``in_proj``'s output where it
+    lies (``[B, F, T]``: no slice of it is made), no ``[B, T, C]`` / ``[B, C, T]``
+    array of the convolution's or the projection's width is copied or
+    transposed between them, and the backward kernel carries ``mamba_conv`` in
+    its scope (a ``custom_vjp``'s backward half does not inherit its call
+    site's)."""
+    import re
+
+    from jax.sharding import SingleDeviceSharding
+
+    from distributed_training_pytorch_tpu.models.hybrid_lm import MambaMixer
+    from distributed_training_pytorch_tpu.ops import causal_conv, ssd
+
+    for module in (causal_conv, ssd):
+        monkeypatch.setattr(module, "resolve_interpret", lambda interpret=None: False)
+    one = SingleDeviceSharding(v5e[0])
+    rows, t, d_inner, groups, chunk = CONV_CELLS[cell]
+    cfg = HybridConfig(vocab_size=64, hidden_size=2048, layer_types=("mamba",), num_attention_heads=32, num_key_value_heads=8,
+                       mamba_n_heads=64, mamba_d_head=CHANNELS, mamba_d_state=STATES, mamba_n_groups=groups,
+                       mamba_chunk_size=chunk)
+    mixer = MambaMixer(cfg, jnp.bfloat16, pallas=True)
+    params = jax.eval_shape(lambda: mixer.init(jax.random.key(0), jnp.zeros((1, t, cfg.hidden_size), jnp.bfloat16)))
+    params = jax.tree.map(lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one), params)
+    u = jax.ShapeDtypeStruct((rows, t, cfg.hidden_size), jnp.bfloat16, sharding=one)
+    text = compiled_text(jax.value_and_grad(lambda p, u: jnp.sum(mixer.apply(p, u).astype(jnp.float32)), argnums=(0, 1)),
+                         params, u)
+    calls = re.findall(r"%((?:conv|ssd)_(?:fwd|bwd))\.?\d* = [^\n]*? custom-call\(", text)
+    assert sorted(calls) == ["conv_bwd", "conv_fwd", "ssd_bwd", "ssd_fwd"], calls
+    c = d_inner + 2 * groups * STATES
+    f = d_inner + c + 64
+    wide = "|".join(f"{rows},{t},{n}\\]|{rows},{n},{t}\\]" for n in (c, f))
+    moved = [line.strip()[:160] for line in text.splitlines() if re.search(rf"\[({wide})\S* (copy|transpose)\(", line)]
+    assert not moved, moved
+    (fwd,) = [line for line in text.splitlines() if re.search(r"%conv_fwd\.?\d* = ", line)]
+    assert f"operand_layout_constraints={{bf16[{rows},{f},{t}]" in fwd
+    (bwd,) = [line for line in text.splitlines() if re.search(r"%conv_bwd\.?\d* = ", line)]
+    assert re.search(r'op_name="[^"]*mamba_conv[^"]*"', bwd), bwd[:400]

@@ -280,8 +280,10 @@ def phase_kernels(smoke: Smoke, devices) -> None:
         if on_tpu:
             smoke.check(f"{name}.mosaic_in_hlo", _has_mosaic_call(compiled.as_text()))
 
-    def flash_case(name, b, t, h, d, *, causal, valid_len=None):
+    def flash_case(name, b, t, h, d, *, causal, valid_len=None, dv=None, ref_heads=None):
         q, k, v, g = _qkv(1, (b, t, h, d), dt)
+        if dv is not None:  # values (and the output, and its gradient) at a width of their own
+            v, g = v[..., :dv], g[..., :dv]
         rows = valid_len or t
         if valid_len is not None:
             g = g.at[:, valid_len:].set(0)  # the loss ignores pad rows
@@ -296,18 +298,25 @@ def phase_kernels(smoke: Smoke, devices) -> None:
         fwd_bwd = _compile(lambda q, k, v: jax.vjp(f, q, k, v)[1](g) + (f(q, k, v),), q, k, v)
         out = jax.block_until_ready(fwd_bwd(q, k, v))
         smoke.info(f"{name}.compile_and_run_s", round(time.perf_counter() - t_c, 2))
-        dq, dk, dv, o = out
+        if ref_heads is not None:  # heads are independent: the float32 reference's [B, H, T, T] scores for a few
+            t_r = time.perf_counter()
+            for _ in range(3):
+                out = jax.block_until_ready(fwd_bwd(q, k, v))
+            smoke.info(f"{name}.fwd_bwd_ms", round((time.perf_counter() - t_r) / 3 * 1e3, 2))
+            out = tuple(x[:, :, :ref_heads] for x in out)
+            q, k, v, g = (x[:, :, :ref_heads] for x in (q, k, v, g))
+        got_q, got_k, got_v, o = out
         rq, rk, rv = jax.jit(lambda q, k, v: jax.vjp(f_ref, q, k, v)[1](g.astype(jnp.float32)))(q, k, v)
         ro = jax.jit(f_ref)(q, k, v)
         errs = {
             "o": _rel_err(o[:, :rows], ro[:, :rows]),
-            "dq": _rel_err(dq[:, :rows], rq[:, :rows]),
-            "dk": _rel_err(dk[:, :rows], rk[:, :rows]),
-            "dv": _rel_err(dv[:, :rows], rv[:, :rows]),
+            "dq": _rel_err(got_q[:, :rows], rq[:, :rows]),
+            "dk": _rel_err(got_k[:, :rows], rk[:, :rows]),
+            "dv": _rel_err(got_v[:, :rows], rv[:, :rows]),
         }
         finite = all(bool(jnp.all(jnp.isfinite(x.astype(jnp.float32)))) for x in out)
         ok = finite and errs["o"] <= TOL_FWD and max(errs["dq"], errs["dk"], errs["dv"]) <= TOL_GRAD
-        smoke.check(name, ok, f"shape {(b, t, h, d)} rel err {errs}")
+        smoke.check(name, ok, f"shape {(b, t, h, d)}" + (f", v {dv} wide" if dv else "") + f" rel err {errs}")
         mosaic_check(name, fwd_bwd)
         if on_tpu:  # the backward is ONE Mosaic call (dq, dk, dv together), whatever T
             calls = re.findall(r"%(flash_\w+)\.\d+ = ", fwd_bwd.as_text())
@@ -335,6 +344,10 @@ def phase_kernels(smoke: Smoke, devices) -> None:
     ):
         name, b, t, h, d, causal, valid_len = toy if small else chip
         smoke.case(f"kernels.{name}", flash_case, b, t, h, d, causal=causal, valid_len=valid_len)
+    # Latent attention's widths (joyaiflash_t8192): queries and keys 192 wide, values 128, at the cell's
+    # whole shape; the reference takes heads 0-1 of the same call (2 x 2 x 8192^2 float32 scores).
+    b, t, h = (1, 256, 2) if small else (2, 8192, 32)
+    smoke.case("kernels.flash_mla", flash_case, b, t, h, 192, causal=True, dv=128, ref_heads=2)
 
     # flash_block_fwd/bwd — the ring path's per-block passes (a 2048-token
     # resident q shard against one visiting K/V block: 8192 tokens over 4

@@ -281,16 +281,25 @@ def make_fused_lm_loss(
     What the function asks of ``model`` beyond ``apply(..., return_hidden=True)``:
     ``head_matrix(params)``, the ``[V, d]`` matrix the head multiplies by (the
     token embedding where tied; the head's mathematics does not care whose it
-    is); optionally ``moe_every`` (the GPT-2 stack's routers, as above) and
+    is); optionally ``moe_every`` (the GPT-2 stack's routers, as above),
     ``sows_step_metrics`` / ``step_metrics(intermediates)`` (a stack whose
-    layers sow per-step counts, which join the step's metrics)."""
+    layers sow per-step counts, which join the step's metrics) and
+    ``mtp_loss_weight`` (a multi-token-prediction module: given the batch's
+    ``label`` as ``labels``, the model returns a second hidden state, whose
+    loss through the same head against the token after the next, over
+    positions 0 … T − 2 under the scope ``mtp_loss_head``, joins the objective at that
+    weight and the step's metrics as ``mtp_loss``; ``nll`` stays the first
+    head's)."""
     from distributed_training_pytorch_tpu.ops.losses import tied_cross_entropy_loss
 
     has_moe = getattr(model, "moe_every", 0) > 0
     sows = bool(getattr(model, "sows_step_metrics", False))
+    mtp_weight = float(getattr(model, "mtp_loss_weight", 0.0))
 
     def loss_fn(params, model_state, batch, rng, train):
         kwargs = {"rngs": {"dropout": rng}} if train else {}
+        if mtp_weight:
+            kwargs["labels"] = batch["label"]
         if has_moe or sows:
             hidden, inter = model.apply(
                 {"params": params},
@@ -304,10 +313,21 @@ def make_fused_lm_loss(
             hidden = model.apply(
                 {"params": params}, batch["image"], train=train, return_hidden=True, **kwargs
             )
+        if mtp_weight:
+            hidden, mtp_hidden = hidden
         loss = tied_cross_entropy_loss(
             hidden, model.head_matrix(params), batch["label"], batch.get("mask")
         )
         metrics = {"loss": loss, "nll": loss, "ppl": jnp.exp(loss)}
+        if mtp_weight:
+            labels = batch["label"]
+            t = labels.shape[1]
+            # position i predicts label i + 1; the last position has nothing to predict
+            ahead = jnp.concatenate([labels[:, 1:], labels[:, -1:]], axis=1)
+            mtp_loss = tied_cross_entropy_loss(mtp_hidden, model.head_matrix(params), ahead, batch.get("mask"),
+                                               positions=jnp.arange(t) < t - 1, scope="mtp_loss_head")
+            loss = loss + mtp_weight * mtp_loss
+            metrics.update(loss=loss, mtp_loss=mtp_loss)
         if sows:
             metrics.update(model.step_metrics(inter["intermediates"]))
         if has_moe:

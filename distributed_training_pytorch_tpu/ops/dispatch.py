@@ -255,6 +255,9 @@ def attention_fn(
                 **flash_block_plan(
                     seq, k.shape[1], causal, kwargs.get("block_q"), kwargs.get("block_k")
                 ),
+                # the widths the kernels run at: q / k, and v's own (never padded to q's)
+                head_dim_qk=q.shape[-1],
+                head_dim_v=v.shape[-1],
             )
         if valid_len is None:
             return inner(q, k, v)

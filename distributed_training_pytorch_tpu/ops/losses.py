@@ -74,6 +74,8 @@ def tied_cross_entropy_loss(
     embedding: jax.Array,
     targets: jax.Array,
     weights: jax.Array | None = None,
+    positions: jax.Array | None = None,
+    scope: str = "loss_head",
 ) -> jax.Array:
     """Mean next-token NLL of a tied-embedding LM head WITHOUT materializing
     the ``[B, T, V]`` logits (13 GB float32 for GPT-2-small at batch 64 /
@@ -83,7 +85,9 @@ def tied_cross_entropy_loss(
     ``hidden``: ``[B, T, d]`` final hidden states; ``embedding``: ``[V, d]``
     (the tied token embedding); ``targets``: ``[B, T]`` integer ids;
     ``weights``: optional ``[B]`` per-example weights (the loader's pad
-    ``mask``; not differentiated). Returns the scalar
+    ``mask``; not differentiated); ``positions``: optional ``[T]`` 0 / 1, the
+    positions a row's mean is taken over (a prediction two tokens ahead has no
+    target at the last position). Returns the scalar
     ``weighted_mean(nll.mean(-1), weights)``, 0 under all-zero weights.
 
     The sequence is scanned a slice at a time (the length comes from the
@@ -102,12 +106,14 @@ def tied_cross_entropy_loss(
 
     Every op, the backward's scaling included, carries the ``loss_head``
     scope in its name (HLO metadata only): the benchmark's
-    ``loss_head_time_share`` matches it.
+    ``loss_head_time_share`` matches it. A second head pass names its own
+    (``scope``: the multi-token-prediction module's is ``mtp_loss_head``):
+    the scope of a call site does not reach inside the custom VJP.
     """
     if hidden.ndim != 3 or targets.shape != hidden.shape[:2]:
         raise ValueError(f"want hidden [B, T, d] and targets [B, T], got {hidden.shape}, {targets.shape}")
     b, t = targets.shape
-    with jax.named_scope("loss_head"):
+    with jax.named_scope(scope):
         if weights is None:
             coef = jnp.full((b,), 1.0 / (b * t), jnp.float32)
         else:
@@ -115,7 +121,10 @@ def tied_cross_entropy_loss(
             w = jax.lax.stop_gradient(weights).astype(jnp.float32)
             total = w.sum()
             coef = jnp.where(total > 0, w / (jnp.maximum(total, 1e-8) * t), 0.0)
-    return _tied_head(hidden, embedding, targets, coef, (hidden.dtype, embedding.dtype))
+        if positions is not None:  # a factor a position: [B, T], each row's mean over the positions kept
+            kept = jax.lax.stop_gradient(positions).astype(jnp.float32)
+            coef = coef[:, None] * (kept * t / jnp.maximum(kept.sum(), 1.0))[None, :]
+    return _tied_head(hidden, embedding, targets, coef, (hidden.dtype, embedding.dtype), scope)
 
 
 def _slice_len(rows: int, seq_len: int, vocab: int) -> int:
@@ -133,7 +142,9 @@ def _head_scan(hidden, embedding, targets, coef, *, with_grads: bool):
     axes and ``chips`` is a batch dimension of every matmul, the embedding
     gradient's included: each chip carries its own partial sum through the
     loop, and the one sum over ``chips`` after it is the partitioner's one
-    exchange (on one chip the axis has length 1)."""
+    exchange (on one chip the axis has length 1). ``coef`` is a factor a row
+    ``[B]`` or a factor a token ``[B, T]``, which the scan takes a slice at a
+    time beside the tokens."""
     from distributed_training_pytorch_tpu.parallel.mesh import ambient_batch_axes
 
     b, t, d = hidden.shape
@@ -144,22 +155,27 @@ def _head_scan(hidden, embedding, targets, coef, *, with_grads: bool):
     # [T/s, chips, rows, s, ...]: the scan runs over slices of T
     h_slices = jnp.moveaxis(hidden.reshape(c, r, t // s, s, d), 2, 0)
     t_slices = jnp.moveaxis(targets.reshape(c, r, t // s, s), 2, 0)
-    coef = coef.reshape(c, r, 1)
+    xs = (h_slices, t_slices)
+    if coef.ndim == 2:
+        xs += (jnp.moveaxis(coef.reshape(c, r, t // s, s), 2, 0),)
+    else:
+        coef = coef.reshape(c, r, 1)
 
     def one_slice(carry, xs):
-        h, tgt = xs
+        h, tgt, *by_token = xs
+        coef_s = by_token[0] if by_token else coef
         # float32 logits from the operands as they arrive: one MXU pass
         logits = jnp.einsum("crsd,vd->crsv", h, embedding, preferred_element_type=jnp.float32)
         m = logits.max(axis=-1)
         p = jnp.exp(logits - m[..., None])
         l = p.sum(axis=-1)
         picked = jnp.take_along_axis(logits, tgt[..., None], axis=-1)[..., 0]
-        loss = ((m + jnp.log(l) - picked) * coef).sum(axis=(1, 2))
+        loss = ((m + jnp.log(l) - picked) * coef_s).sum(axis=(1, 2))
         if not with_grads:
             return carry + loss, None
         loss_sum, d_emb = carry
         hit = jnp.arange(v) == tgt[..., None]
-        d_logits = (p / l[..., None] - hit) * coef[..., None]
+        d_logits = (p / l[..., None] - hit) * coef_s[..., None]
         # float32 operands at the default precision: one bf16 MXU pass on the
         # chip, exact on the CPU (what autodiff of the logits matmul gives)
         d_h = jnp.einsum("crsv,vd->crsd", d_logits, embedding.astype(jnp.float32))
@@ -171,31 +187,31 @@ def _head_scan(hidden, embedding, targets, coef, *, with_grads: bool):
         return jax.lax.with_sharding_constraint(zeros, P(axes)) if axes else zeros
 
     if not with_grads:
-        return jax.lax.scan(one_slice, per_chip((c,)), (h_slices, t_slices))[0].sum()
+        return jax.lax.scan(one_slice, per_chip((c,)), xs)[0].sum()
     (loss, d_emb), d_h = jax.lax.scan(
-        one_slice, (per_chip((c,)), per_chip((c, v, d))), (h_slices, t_slices)
+        one_slice, (per_chip((c,)), per_chip((c, v, d))), xs
     )
     return loss.sum(), jnp.moveaxis(d_h, 0, 2).reshape(b, t, d), d_emb.sum(axis=0)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
-def _tied_head(hidden, embedding, targets, coef, dtypes):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
+def _tied_head(hidden, embedding, targets, coef, dtypes, scope):
     """``dtypes`` = (hidden's, embedding's): what the backward casts to."""
-    with jax.named_scope("loss_head"):
+    with jax.named_scope(scope):
         return _head_scan(hidden, embedding, targets, coef, with_grads=False)
 
 
-def _tied_head_fwd(hidden, embedding, targets, coef, dtypes):
-    with jax.named_scope("loss_head"):
+def _tied_head_fwd(hidden, embedding, targets, coef, dtypes, scope):
+    with jax.named_scope(scope):
         loss, d_hidden, d_emb = _head_scan(hidden, embedding, targets, coef, with_grads=True)
     return loss, (d_hidden, d_emb)
 
 
-def _tied_head_bwd(dtypes, res, g):
+def _tied_head_bwd(dtypes, scope, res, g):
     d_hidden, d_emb = res
     # scaled in float32 before the cast: under fp16 the unscaled gradients
     # would underflow, which is what a loss scale is there to prevent
-    with jax.named_scope("loss_head"):
+    with jax.named_scope(scope):
         return (d_hidden * g).astype(dtypes[0]), (d_emb * g).astype(dtypes[1]), None, None
 
 

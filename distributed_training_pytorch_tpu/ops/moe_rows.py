@@ -56,6 +56,12 @@ _DTYPES = (jnp.dtype(jnp.float32), jnp.dtype(jnp.bfloat16))  # what a table's ro
 _TILE = 256  # rows of the buffer, or tokens, a grid step
 _GROUP = 8  # rows a copy: a slice of a table in HBM starts and ends at a tile of 8 rows
 _IN_FLIGHT = 16  # copies in flight
+# What :func:`rows_from_table` may prefetch whole into the scalar core's memory
+# (1 MiB on the v5e, where the kernel's own words also live): ``src`` and the
+# weights, four bytes a pair each. Past it Mosaic refuses the call ("Ran out of
+# memory in memory space smem": 131,072 pairs, top-8 of 16,384 tokens, ask for
+# 1 MiB and 1.6 KiB).
+_SMEM_TABLES_BYTES = (1 << 20) - (16 << 10)
 
 
 def rows_tile(n: int) -> Optional[int]:
@@ -69,8 +75,8 @@ def rows_refused(tokens: int, k: int, width: int, dtype) -> Optional[str]:
     """None where the kernels take a layer of ``tokens`` tokens, ``k`` choices
     and rows of ``width`` in ``dtype``, else why they do not: the rows are whole
     128-lane registers of float32 or bfloat16, the tokens whole tiles (so the
-    tables are whole groups of eight rows), and a pair's position fits the
-    scalar core's 32-bit word."""
+    tables are whole groups of eight rows), a pair's position fits the
+    scalar core's 32-bit word, and the pairs' two tables fit its memory."""
     if jnp.dtype(dtype) not in _DTYPES:
         return f"rows of {jnp.dtype(dtype).name} are neither float32 nor bfloat16"
     if width % 128:
@@ -79,6 +85,8 @@ def rows_refused(tokens: int, k: int, width: int, dtype) -> Optional[str]:
         return f"{tokens} tokens are no multiple of 8"
     if tokens * k >= 2**31:
         return f"{tokens * k} pairs do not fit an int32"
+    if 8 * tokens * k > _SMEM_TABLES_BYTES:
+        return f"{tokens * k} pairs' source and weight tables ({8 * tokens * k} bytes) do not fit the scalar core's memory"
     return None
 
 

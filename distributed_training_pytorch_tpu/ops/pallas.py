@@ -10,9 +10,11 @@ never materializes the scores.
 
 Public surface:
 
-* :func:`flash_attention` — ``[B, T, H, D]`` q/k/v -> ``[B, T, H, D]``, same
-  contract as ``models.vit.dot_product_attention`` (scale = D**-0.5, optional
-  causal mask), differentiable (custom VJP, one flash backward kernel).
+* :func:`flash_attention` — q / k ``[B, T, H, Dqk]`` and v ``[B, T, H, Dv]``
+  -> ``[B, T, H, Dv]``, same contract as ``models.vit.dot_product_attention``
+  (scale = Dqk**-0.5, optional causal mask), differentiable (custom VJP, one
+  flash backward kernel). ``Dv`` is ``Dqk`` for every model but latent
+  attention's (192 against 128), whose v is never padded to the wider side.
 * :func:`make_attention_fn` — adapter for ``models.vit.MultiHeadAttention``'s
   ``attention_fn`` hook; picks the kernel on TPU and the plain XLA path
   elsewhere.
@@ -20,6 +22,7 @@ Public surface:
 Kernel design (see /opt/skills/guides/pallas_guide.md): grid over
 ``(batch, head, q-block)``; K/V live in VMEM as whole ``[T, D]`` slabs per
 (batch, head) — compile-checked for the v5e through T = 8192 at D = 64/128
+and at q / k 192 with v 128
 (at 16,384 the slabs alone are refused); beyond that, sequence
 parallelism (``parallel.ring_attention``) shards T across chips and each shard
 re-enters this kernel. Softmax statistics are carried in float32; matmuls run
@@ -299,10 +302,11 @@ def _grid_index(axis: int, extent: int):
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, block_k, seq_len, causal, n_q):
     """One q-block against the k-blocks it can see, online softmax. Refs are
-    (1, 1, bq, D) / (1, 1, Tp, D) blocks; statistics in f32. (A causal call of
-    one block pair runs :func:`_fwd_subtiles` instead.)"""
+    (1, 1, bq, Dqk) / (1, 1, Tp, Dqk) blocks, v and o ``Dv`` wide; statistics
+    in f32. (A causal call of one block pair runs :func:`_fwd_subtiles`
+    instead.)"""
     bq = q_ref.shape[2]
-    d = q_ref.shape[3]
+    d = v_ref.shape[3]  # the accumulator is o's width
     t_pad = k_ref.shape[2]
     n_k = t_pad // block_k
     qi = _grid_index(2, n_q)
@@ -315,8 +319,8 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, block_k, seq_len,
 
     def body(j, carry):
         acc, m, l = carry
-        k = k_ref[0, 0, pl.ds(j * block_k, block_k), :]  # [bk, D]
-        v = v_ref[0, 0, pl.ds(j * block_k, block_k), :]  # [bk, D]
+        k = k_ref[0, 0, pl.ds(j * block_k, block_k), :]  # [bk, Dqk]
+        v = v_ref[0, 0, pl.ds(j * block_k, block_k), :]  # [bk, Dv]
         s = scale * jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         )  # [bq, bk] f32
@@ -441,7 +445,7 @@ def _bwd_kernel(
     rows go straight to ``dq_ref``. (A causal call of one block pair runs
     :func:`_bwd_subtiles` instead.)"""
     bk = k_ref.shape[2]
-    d = k_ref.shape[3]
+    d, dv_width = k_ref.shape[3], v_ref.shape[3]
     t_pad = q_ref.shape[2]
     n_q = t_pad // block_q
     ki = _grid_index(2, n_k)
@@ -496,7 +500,7 @@ def _bwd_kernel(
         return dk_new, dv_new
 
     dk0 = jnp.zeros((bk, d), jnp.float32)
-    dv0 = jnp.zeros((bk, d), jnp.float32)
+    dv0 = jnp.zeros((bk, dv_width), jnp.float32)
     # Causal: start at the first q-block the diagonal lets see this k-block.
     start = _q_blocks_start(ki, block_q, bk) if causal else 0
     dk, dv = jax.lax.fori_loop(start, n_q, body, (dk0, dv0))
@@ -520,15 +524,15 @@ def _bwd_subtiles(
     the row groups it covers (ascending ``j``, as the slab's sum runs), and
     each group of dq is scaled, cast and stored once. A k group no row sees
     (``T_q < T_k``) gets zeros and leaves dq closed."""
-    bq, d = q_ref.shape[2], q_ref.shape[3]
+    bq, d, dv_width = q_ref.shape[2], q_ref.shape[3], v_ref.shape[3]
     n_q = bq // sub
-    dq = [None] * n_q  # float32 [sub, D] a q row group
+    dq = [None] * n_q  # float32 [sub, Dqk] a q row group
     for j in range(k_ref.shape[2] // sub):
         krows = pl.ds(j * sub, sub)
         first = min(_q_blocks_start(j, sub, sub), n_q)
         if first == n_q:
             dk_ref[0, 0, krows, :] = jnp.zeros((sub, d), dk_ref.dtype)
-            dv_ref[0, 0, krows, :] = jnp.zeros((sub, d), dv_ref.dtype)
+            dv_ref[0, 0, krows, :] = jnp.zeros((sub, dv_width), dv_ref.dtype)
             continue
         width = bq - first * sub
         cols = pl.ds(first * sub, width)
@@ -585,9 +589,10 @@ def _from_bhtd(x):
 
 @functools.partial(jax.jit, static_argnums=(3, 4, 5, 6, 7, 8))
 def _fwd_call(qt, kt, vt, t_k, causal, bq, bk, sub, interpret):
-    """Forward pallas call on padded [B, H, T*, D] operands -> (o, lse) in the
-    padded layout. Shared by flash_attention (square T) and the ring block
-    path (Tq from the resident shard, Tk from the visiting block).
+    """Forward pallas call on padded [B, H, T*, D] operands (v and o at their
+    own width) -> (o, lse) in the padded layout. Shared by flash_attention
+    (square T) and the ring block path (Tq from the resident shard, Tk from
+    the visiting block).
 
     Jitted (as :func:`_bwd_call` is) so that a program traces and lowers a
     kernel body once, however many layers and chained steps call it at the
@@ -602,7 +607,7 @@ def _fwd_call(qt, kt, vt, t_k, causal, bq, bk, sub, interpret):
     today's runtime, and a reader of one matches these (the backward's holds
     ``flash_dq``, which the benchmark's ``flash_bwd_time_share`` looks for)."""
     b, h, tq_pad, d = qt.shape
-    tk_pad = kt.shape[2]
+    tk_pad, dv = kt.shape[2], vt.shape[3]
     if sub is not None:  # causal, one block pair (_flash_sub)
         kernel = functools.partial(_fwd_subtiles, scale=d**-0.5, sub=sub, seq_len=t_k)
     else:
@@ -615,14 +620,14 @@ def _fwd_call(qt, kt, vt, t_k, causal, bq, bk, sub, interpret):
         in_specs=[
             pl.BlockSpec((1, 1, bq, d), lambda bi, hi, qi: (bi, hi, qi, 0)),
             pl.BlockSpec((1, 1, tk_pad, d), lambda bi, hi, qi: (bi, hi, 0, 0)),
-            pl.BlockSpec((1, 1, tk_pad, d), lambda bi, hi, qi: (bi, hi, 0, 0)),
+            pl.BlockSpec((1, 1, tk_pad, dv), lambda bi, hi, qi: (bi, hi, 0, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, 1, bq, d), lambda bi, hi, qi: (bi, hi, qi, 0)),
+            pl.BlockSpec((1, 1, bq, dv), lambda bi, hi, qi: (bi, hi, qi, 0)),
             pl.BlockSpec((1, 1, 1, bq), lambda bi, hi, qi: (bi, hi, 0, qi)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((b, h, tq_pad, d), qt.dtype),
+            jax.ShapeDtypeStruct((b, h, tq_pad, dv), qt.dtype),
             jax.ShapeDtypeStruct((b, h, 1, tq_pad), jnp.float32),
         ],
         interpret=interpret,
@@ -641,12 +646,13 @@ def _fwd_impl(q, k, v, causal, block_q, block_k, interpret, valid_len=None):
     return o[:, :, :t, :], lse[:, :, :, :t], (qt, kt, vt)
 
 
-def _bwd_vmem_bytes(tq_pad: int, bq: int, bk: int, d: int, itemsize: int) -> int:
+def _bwd_vmem_bytes(tq_pad: int, bq: int, bk: int, d: int, dv: int, itemsize: int) -> int:
     """The scoped-VMEM limit the fused backward is compiled under, reckoned
     from its shapes as an upper bound on what it holds: every pipelined block
-    double-buffered and padded to the 128-lane tile (the q / dO / dq whole-T
-    slabs, the two statistics rows on 8 sublanes, the k / v / dk / dv
-    blocks), dq's float32 scratch slab, and six float32 ``[bq, bk]`` tiles
+    double-buffered and padded to the 128-lane tile, each width ``d`` (q, k
+    and their gradients) or ``dv`` (v, dO, dv) in its own lanes (the q / dO /
+    dq whole-T slabs, the two statistics rows on 8 sublanes, the k / v / dk /
+    dv blocks), dq's float32 scratch slab, and six float32 ``[bq, bk]`` tiles
     for s, p, dp, ds and their cast and transposed copies. Mosaic's own count
     is a half to a third of this (binary search on the limit, compiled for a
     described v5e: 10.2 MiB at T=4096 and 12.2 at 8192 with 1024 x 1024
@@ -654,20 +660,21 @@ def _bwd_vmem_bytes(tq_pad: int, bq: int, bk: int, d: int, itemsize: int) -> int
     34.5 / 43 / 34.5), so the compiler's 16 MiB default would refuse D=128
     at that tile and nothing else a caller has today. Never under that
     default: the limit is a ceiling, not a reservation."""
-    lanes = pl.cdiv(d, 128) * 128
-    slabs = 3 * 2 * tq_pad * lanes * itemsize + tq_pad * lanes * 4 + 2 * 2 * 8 * tq_pad * 4
-    blocks = 4 * 2 * bk * lanes * itemsize
+    lanes, v_lanes = pl.cdiv(d, 128) * 128, pl.cdiv(dv, 128) * 128
+    slabs = 2 * tq_pad * (2 * lanes + v_lanes) * itemsize + tq_pad * lanes * 4 + 2 * 2 * 8 * tq_pad * 4
+    blocks = 2 * 2 * bk * (lanes + v_lanes) * itemsize
     tiles = 6 * bq * bk * 4
     return max(16 * 2**20, slabs + blocks + tiles)
 
 
 @functools.partial(jax.jit, static_argnums=(6, 7, 8, 9, 10, 11))
 def _bwd_call(qt, kt, vt, do, lse_p, delta, t_k, causal, bq, bk, sub, interpret):
-    """Backward pallas call on padded [B, H, T*, D] operands -> (dq, dk, dv).
-    Padded q rows are harmless because ``do``/``delta`` are zero-padded (see
-    _bwd_kernel); ``t_k`` masks padded K rows."""
+    """Backward pallas call on padded [B, H, T*, D] operands -> (dq, dk, dv);
+    v, ``do`` and dv at v's own width. Padded q rows are harmless because
+    ``do``/``delta`` are zero-padded (see _bwd_kernel); ``t_k`` masks padded
+    K rows."""
     b, h, tq_pad, d = qt.shape
-    tk_pad = kt.shape[2]
+    tk_pad, dv = kt.shape[2], vt.shape[3]
     n_k = tk_pad // bk
     if sub is not None:  # causal, one block pair (_flash_sub)
         kernel = functools.partial(_bwd_subtiles, scale=d**-0.5, sub=sub, seq_len=t_k)
@@ -676,22 +683,24 @@ def _bwd_call(qt, kt, vt, do, lse_p, delta, t_k, causal, bq, bk, sub, interpret)
             _bwd_kernel, scale=d**-0.5, block_q=bq, seq_len=t_k, causal=causal, n_k=n_k
         )
     slab = pl.BlockSpec((1, 1, tq_pad, d), lambda bi, hi, ki: (bi, hi, 0, 0))
+    v_slab = pl.BlockSpec((1, 1, tq_pad, dv), lambda bi, hi, ki: (bi, hi, 0, 0))
     stat = pl.BlockSpec((1, 1, 1, tq_pad), lambda bi, hi, ki: (bi, hi, 0, 0))
     block = pl.BlockSpec((1, 1, bk, d), lambda bi, hi, ki: (bi, hi, ki, 0))
+    v_block = pl.BlockSpec((1, 1, bk, dv), lambda bi, hi, ki: (bi, hi, ki, 0))
     call = pl.pallas_call(
         kernel,
         grid=(b, h, n_k),
-        in_specs=[slab, block, block, slab, stat, stat],
-        out_specs=[slab, block, block],
+        in_specs=[slab, block, v_block, v_slab, stat, stat],
+        out_specs=[slab, block, v_block],
         out_shape=[
             jax.ShapeDtypeStruct((b, h, tq_pad, d), qt.dtype),
             jax.ShapeDtypeStruct((b, h, tk_pad, d), kt.dtype),
-            jax.ShapeDtypeStruct((b, h, tk_pad, d), vt.dtype),
+            jax.ShapeDtypeStruct((b, h, tk_pad, dv), vt.dtype),
         ],
         scratch_shapes=[pltpu.VMEM((tq_pad, d), jnp.float32)] if n_k > 1 else [],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
-            vmem_limit_bytes=_bwd_vmem_bytes(tq_pad, bq, bk, d, qt.dtype.itemsize),
+            vmem_limit_bytes=_bwd_vmem_bytes(tq_pad, bq, bk, d, dv, qt.dtype.itemsize),
         ),
         interpret=interpret,
         name="flash_dqkv",
@@ -814,10 +823,13 @@ def flash_attention(
     interpret: Optional[bool] = None,
     valid_len: Optional[int] = None,
 ) -> jax.Array:
-    """Fused flash attention on ``[B, T, H, D]`` tensors.
+    """Fused flash attention: q and k ``[B, T, H, Dqk]``, v ``[B, T, H, Dv]``,
+    output ``[B, T, H, Dv]``. The two widths are equal for every model but
+    latent attention's (queries and keys 192 wide, values 128); v keeps its
+    own width in both kernels, never padded to q's.
 
     Numerics match ``models.vit.dot_product_attention`` (softmax statistics in
-    float32, scale ``D**-0.5``); memory is O(T) per (batch, head) instead of
+    float32, scale ``Dqk**-0.5``); memory is O(T) per (batch, head) instead of
     the O(T^2) score tensor. ``block_q`` / ``block_k`` set one block shape for
     both kernels; left ``None``, each kernel takes the shape
     :func:`_flash_blocks` gives it. ``interpret=None`` auto-selects
@@ -826,8 +838,9 @@ def flash_attention(
     kernels' own seq_len masking does the work, no score tensor or bias mask
     is ever built.
     """
-    if q.ndim != 4 or q.shape != k.shape or q.shape != v.shape:
-        raise ValueError(f"expected matching [B,T,H,D] q/k/v, got {q.shape}/{k.shape}/{v.shape}")
+    if q.ndim != 4 or v.ndim != 4 or q.shape != k.shape or q.shape[:3] != v.shape[:3]:
+        raise ValueError(f"expected q and k [B,T,H,Dqk] and v [B,T,H,Dv] (Dv may differ from Dqk), "
+                         f"got {q.shape}/{k.shape}/{v.shape}")
     if valid_len is not None:
         if causal:
             raise ValueError("valid_len composes with non-causal attention only")

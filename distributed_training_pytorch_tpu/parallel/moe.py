@@ -708,6 +708,12 @@ def _relu2(x):
     return jnp.square(nn.relu(x))
 
 
+def _swiglu(x):
+    """``silu(g) ⊙ u`` of ``x = [g | u]``: the gated form, gate and up as one product's two halves."""
+    gate, up = jnp.split(x, 2, axis=-1)
+    return nn.silu(gate) * up
+
+
 class HeldExpertsMlp(nn.Module):
     """Dropless top-k routing over ``experts_published`` experts, computed for
     the ``held_count`` of them this chip holds (``held_first`` on), plus a
@@ -720,6 +726,11 @@ class HeldExpertsMlp(nn.Module):
         top = the top_k largest of s + b_corr      (b_corr selects only: no gradient reaches it)
         w_e = routed_scaling · s_e / (Σ_{e' ∈ top} s_{e'} + 1e-20)
         out = Σ_{e ∈ top, e held here} w_e · f_e(x)  +  f_shared(x),   f(x) = relu(x U)² V
+
+    or, with ``activation="swiglu"`` (DeepSeek-V3's experts), ``f(x) = (silu(x G)
+    ⊙ x U) D``, ``G`` and ``U`` held side by side as one ``[d, 2 · width]``
+    matrix (``experts_gate_up``, ``shared_gate_up``) so that each is one
+    grouped product.
 
     A pair routed to an expert held elsewhere contributes nothing here: that
     partial sum is one expert-parallel rank's, and the ranks' sums (the shared
@@ -765,11 +776,16 @@ class HeldExpertsMlp(nn.Module):
     routed_scaling: float = 1.0
     dtype: Any = jnp.float32
     model: str = "moe"  # whose ``kernel_dispatch`` record the experts' product is
+    activation: str = "relu2"  # or "swiglu"
 
     @nn.compact
     def __call__(self, x):
         from distributed_training_pytorch_tpu.ops import dispatch
 
+        if self.activation not in ("relu2", "swiglu"):
+            raise ValueError(f"expert activation {self.activation!r}: want 'relu2' or 'swiglu'")
+        gated = self.activation == "swiglu"
+        act, widen = (_swiglu, 2) if gated else (_relu2, 1)
         if not 0 <= self.held_first <= self.held_first + self.held_count <= self.experts_published:
             raise ValueError(f"experts {self.held_first}..{self.held_first + self.held_count - 1} are not among "
                              f"the {self.experts_published} published")
@@ -793,16 +809,18 @@ class HeldExpertsMlp(nn.Module):
             self.sow("intermediates", "moe_pairs_local", jnp.sum(sizes).astype(jnp.float32))
             self.sow("intermediates", "moe_pairs_max_expert", jnp.max(sizes).astype(jnp.float32))
             with jax.named_scope("moe_experts"):
-                up = self.param("experts_up", init, (self.held_count, d, self.expert_width), jnp.float32)
+                up = self.param("experts_gate_up" if gated else "experts_up", init,
+                                (self.held_count, d, widen * self.expert_width), jnp.float32)
                 down = self.param("experts_down", init, (self.held_count, self.expert_width, d), jnp.float32)
                 dispatch.record(self.model, "moe_experts", "ragged_dot",
                                 reason=f"backend={jax.default_backend()}: jax.lax.ragged_dot over {self.held_count} held experts; "
-                                       "the compiler's grouped-matmul kernel on a TPU (live row tiles only), jax's own lowering elsewhere")
-                hidden = _relu2(jax.lax.ragged_dot(rows, up.astype(self.dtype), sizes, preferred_element_type=self.dtype))
+                                       "the compiler's grouped-matmul kernel on a TPU (live row tiles only), jax's own lowering elsewhere",
+                                activation=self.activation)
+                hidden = act(jax.lax.ragged_dot(rows, up.astype(self.dtype), sizes, preferred_element_type=self.dtype))
                 rows = jax.lax.ragged_dot(hidden, down.astype(self.dtype), sizes, preferred_element_type=self.dtype)
             with jax.named_scope("moe_combine"):
                 out = _rows_out(tile, rows, weights, route)
             with jax.named_scope("shared_expert"):
                 dense = lambda width, name: nn.Dense(width, use_bias=False, dtype=self.dtype, kernel_init=init, name=name)  # noqa: E731
-                shared = dense(d, "shared_down")(_relu2(dense(self.shared_width, "shared_up")(xc)))
+                shared = dense(d, "shared_down")(act(dense(widen * self.shared_width, "shared_gate_up" if gated else "shared_up")(xc)))
             return (out + shared.astype(jnp.float32)).astype(self.dtype).reshape(lead + (d,))

@@ -13,7 +13,9 @@ build a real one offline with ``examples/make_lm_corpus.py``), ``SEQ_LEN``
 = GPT-2-small shape | ``hybrid_tiny`` = a toy of the Mamba-2 / grouped-query
 hybrid stack of ``models/hybrid_lm.py``, every block rematerialised |
 ``nemotron_h_tiny`` = a toy of the same file's one-mixer-a-layer stack with
-routed experts),
+routed experts | ``joyai_tiny`` = a toy of its DeepSeek-V3 block: latent
+attention, a dense first layer, routed SwiGLU experts, one multi-token
+prediction module),
 ``SAVE_PERIOD`` / ``LAST_SAVE_PERIOD`` (epochs between periodic / `last`
 saves — raise both when the checkpoint path is slow), ``DTYPE``
 (fp32|bf16|fp16 mixed-precision policy — docs/mixed_precision.md),
@@ -35,7 +37,7 @@ import numpy as np
 import optax
 
 from distributed_training_pytorch_tpu.data import ArrayDataSource
-from distributed_training_pytorch_tpu.models import GPTSmall, HybridTiny, LMTiny, NemotronHTiny
+from distributed_training_pytorch_tpu.models import GPTSmall, HybridTiny, JoyAITiny, LMTiny, NemotronHTiny
 from distributed_training_pytorch_tpu.ops import warmup_cosine_lr
 from distributed_training_pytorch_tpu.ops.dispatch import pallas_from_env
 from distributed_training_pytorch_tpu.parallel import mesh_from_env
@@ -111,7 +113,7 @@ class LMTrainer(Trainer):
         dtype = model_dtype_for_entry(
             self.precision, DTYPE is not None or self.precision_requested, jnp.bfloat16
         )
-        hybrid = {"hybrid_tiny": HybridTiny, "nemotron_h_tiny": NemotronHTiny}.get(self.size)
+        hybrid = {"hybrid_tiny": HybridTiny, "nemotron_h_tiny": NemotronHTiny, "joyai_tiny": JoyAITiny}.get(self.size)
         if hybrid is not None:  # no positions and no `moe_every` to size
             return hybrid(vocab_size=256, dtype=dtype, pallas=PALLAS)
         factory = {"tiny": LMTiny, "small": GPTSmall}[self.size]

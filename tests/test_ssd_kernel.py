@@ -539,3 +539,31 @@ def test_the_mixer_hands_its_activations_between_kernels_without_copies(v5e, cel
     assert f"operand_layout_constraints={{bf16[{rows},{f},{t}]" in fwd
     (bwd,) = [line for line in text.splitlines() if re.search(r"%conv_bwd\.?\d* = ", line)]
     assert re.search(r'op_name="[^"]*mamba_conv[^"]*"', bwd), bwd[:400]
+
+
+# -- latent attention's flash widths (joyaiflash_t8192: queries and keys 192, values 128) --
+
+
+def test_flash_compiles_for_the_v5e_at_latent_attentions_two_widths(v5e):
+    """The cell's attention, ``[2, 8192, 32, 192]`` queries and keys against
+    ``[2, 8192, 32, 128]`` values, forward and backward: Mosaic takes both
+    bodies (the backward under the VMEM limit reckoned with each width in its
+    own lanes), the program holds the two calls, and v, dO and dv stay 128
+    wide (nothing is padded to q's 192)."""
+    from jax.sharding import SingleDeviceSharding
+
+    from distributed_training_pytorch_tpu.ops.pallas import flash_attention
+
+    one_chip = SingleDeviceSharding(v5e[0])
+    qk = jax.ShapeDtypeStruct((2, 8192, 32, 192), jnp.bfloat16, sharding=one_chip)
+    v = jax.ShapeDtypeStruct((2, 8192, 32, 128), jnp.bfloat16, sharding=one_chip)
+
+    def step(q, k, v):
+        out, back = jax.vjp(lambda q, k, v: flash_attention(q, k, v, causal=True, interpret=False), q, k, v)
+        return back(out)
+
+    text = compiled_text(step, qk, qk, v)
+    calls = [line for line in text.splitlines() if 'custom_call_target="tpu_custom_call"' in line]
+    assert sorted(line.split("=")[0].strip().lstrip("%").split(".")[0] for line in calls) == ["flash_dqkv", "flash_fwd"], calls
+    assert all("[2,32,8192,192]" not in line.split("custom-call(")[0] or "flash_dqkv" in line for line in calls)
+    assert "bf16[2,32,8192,128]" in calls[0] + calls[1] and "[2,32,8192,256]" not in text

@@ -29,8 +29,9 @@ Phases
            against its ``jax.numpy`` form; and the
            routed expert layer's four row movements (``ops/moe_rows.py``) at
            that model's shape against their ``jax.numpy`` form at 3%, 6.25% and
-           100% of the pairs held here, and the whole layer with the rows the
-           kernels never write set to NaN. Plus two device
+           100% of the pairs held here and at joyai-llm-flash's (131,072
+           pairs) at 6.25%, and the whole layer, relu² and SwiGLU, with the
+           rows the kernels never write set to NaN. Plus two device
            checks: ``tpu_compiler_options()`` is accepted by the installed
            libtpu, and ``jax.block_until_ready`` really blocks.
   leg_a    ``Cifar10Trainer`` (examples/train_cifar10.py): VGG16 at full
@@ -574,11 +575,11 @@ def phase_kernels(smoke: Smoke, devices) -> None:
     # The whole layer with the rows the kernels never write poisoned: the grouped products, relu² and every
     # transpose between the two movements run over a buffer whose dead rows are NaN, and nothing may reach an
     # output or a gradient (the allocation's own leftovers are what they hold in a training step).
-    def moe_poisoned_case(name, n, d, width, held, published, k):
+    def moe_poisoned_case(name, n, d, width, held, published, k, activation="relu2"):
         from distributed_training_pytorch_tpu.ops import dispatch, moe_rows
         from distributed_training_pytorch_tpu.parallel.moe import HeldExpertsMlp
 
-        layer = HeldExpertsMlp(width, width, published, 0, held, k, 2.5, dt, "chip_smoke")
+        layer = HeldExpertsMlp(width, width, published, 0, held, k, 2.5, dt, "chip_smoke", activation)
         x = jax.random.normal(jax.random.key(3), (1, n, d))
         variables = jax.jit(layer.init)(jax.random.key(4), x)
         cot = jax.random.normal(jax.random.key(5), x.shape)
@@ -611,6 +612,12 @@ def phase_kernels(smoke: Smoke, devices) -> None:
     for share in (0.03, 0.0625, 1.0):
         smoke.case(f"kernels.moe_rows_{round(share * 100)}pct", moe_rows_case, *rows_shape, share=0.4 if small and share < 1 else share)
     smoke.case("kernels.moe_rows_poisoned", moe_poisoned_case, *((64, 128, 128, 4, 8, 3) if small else (16384, 2688, 1856, 8, 128, 6)))
+    # joyai-llm-flash's layer as its cell runs it: 16,384 tokens of 2,048, top-8, 16 of 256 held, SwiGLU experts of 768;
+    # its 131,072 pairs are where the kernels' scalar tables first had to come a tile at a time
+    smoke.case("kernels.moe_rows_joyai_6pct", moe_rows_case, *((64, 4, 128, 4, 16) if small else (16384, 8, 2048, 16, 256)),
+               share=0.4 if small else 0.0625)
+    smoke.case("kernels.moe_rows_poisoned_joyai", moe_poisoned_case,
+               *((64, 128, 128, 4, 16, 4) if small else (16384, 2048, 768, 16, 256, 8)), activation="swiglu")
 
     # bench.py's per-compile options against the installed libtpu.
     opts = tpu_compiler_options()

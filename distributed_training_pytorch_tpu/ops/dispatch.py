@@ -375,7 +375,7 @@ def moe_rows_tile(model: str, tokens: int, k: int, width: int, dtype) -> Optiona
     the layer has one answer a shape and a platform."""
     import jax
 
-    from .moe_rows import rows_refused, rows_tile
+    from .moe_rows import rows_refused, rows_tile, scalar_bytes
 
     backend = jax.default_backend()
     mesh = jax.sharding.get_abstract_mesh()
@@ -387,7 +387,8 @@ def moe_rows_tile(model: str, tokens: int, k: int, width: int, dtype) -> Optiona
     elif mesh.axis_names and mesh.size > 1 and not mesh.manual_axes:
         path, reason = "gather", f"backend={backend}: a mesh of {mesh.size} devices may split the tokens, which the kernels take whole"
     else:
-        path, reason = "pallas", (f"auto: backend={backend}, {tokens} tokens x top-{k} of width {width} tile: "
+        path, reason = "pallas", (f"auto: backend={backend}, {tokens} tokens x top-{k} of width {width} tile and their scalar tables "
+                                  f"take {scalar_bytes(tokens, k)} B of the scalar core's memory: "
                                   "work follows the live pairs (moe.pairs_local), not the buffer")
     record(model, "moe_rows", path, reason=reason)
     return rows_tile(tokens) if path == "pallas" else None

@@ -27,7 +27,12 @@ walked as one token-major list (:func:`live_pairs`: a sort of the pairs'
 numbers), a tile's stretch of it given by a table of starts.
 
 Both fetch a row from HBM by an asynchronous copy, ``_IN_FLIGHT`` of them in
-flight, and both work a row at a time on the scalar core's word. A copy
+flight, and both work a row at a time on the scalar core's word. What the
+scalar core's memory holds is one ``N·k`` table whole, four bytes a pair (the
+weights, read at a row's pair; ``pair``), and a grid step's own stretch of the
+tables read by the grid's axis (a tile's ``src``; a tile's ``dest`` and
+weights), each a block of that memory: about 250,000 pairs fit the v5e's 1 MiB
+(:func:`rows_refused`), top-8 of 16,384 tokens among them. A copy
 moves the row's **group of eight**: Mosaic takes a slice of a table in HBM only
 where it starts and ends at the ``(8, 128)`` tile, so a row costs eight rows'
 bytes, which at a few per cent of live rows is still a few per cent of the
@@ -48,7 +53,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from distributed_training_pytorch_tpu.ops.pallas import resolve_interpret
 
-__all__ = ["live_pairs", "rows_from_table", "rows_refused", "rows_tile", "tokens_from_rows"]
+__all__ = ["live_pairs", "rows_from_table", "rows_refused", "rows_tile", "scalar_bytes", "tokens_from_rows"]
 
 _F32 = jnp.float32
 _U32 = jnp.uint32
@@ -56,12 +61,28 @@ _DTYPES = (jnp.dtype(jnp.float32), jnp.dtype(jnp.bfloat16))  # what a table's ro
 _TILE = 256  # rows of the buffer, or tokens, a grid step
 _GROUP = 8  # rows a copy: a slice of a table in HBM starts and ends at a tile of 8 rows
 _IN_FLIGHT = 16  # copies in flight
-# What :func:`rows_from_table` may prefetch whole into the scalar core's memory
-# (1 MiB on the v5e, where the kernel's own words also live): ``src`` and the
-# weights, four bytes a pair each. Past it Mosaic refuses the call ("Ran out of
-# memory in memory space smem": 131,072 pairs, top-8 of 16,384 tokens, ask for
-# 1 MiB and 1.6 KiB).
+_SMEM_WORDS = 1024  # the scalar core's memory takes a vector in blocks of 1024 words
+# What the kernels' scalar tables may take of the scalar core's memory (1 MiB on
+# the v5e, where the kernel's own words also live). Past it Mosaic refuses the
+# call ("Ran out of memory in memory space smem": compiled for a described v5e,
+# top-8 of 30,720 tokens is taken and of 32,768 refused by 9.6 KiB, and
+# :func:`scalar_bytes` puts the line between).
 _SMEM_TABLES_BYTES = (1 << 20) - (16 << 10)
+
+
+def _block(n: int) -> int:
+    """Words a vector of ``n`` takes in the scalar core's memory."""
+    return -(-n // _SMEM_WORDS) * _SMEM_WORDS
+
+
+def scalar_bytes(tokens: int, k: int) -> int:
+    """Bytes of scalar tables the fuller of the two kernels holds:
+    :func:`tokens_from_rows` (``starts`` and ``pair`` whole, a tile's ``dest``
+    and weights twice over for the pipeline) holds more than
+    :func:`rows_from_table` (``meta`` and the weights whole, a tile's ``src``
+    twice)."""
+    tile = rows_tile(tokens)
+    return 4 * (_block(tokens // tile + 1) + _block(tokens * k) + 2 * 2 * _block(tile * k))
 
 
 def rows_tile(n: int) -> Optional[int]:
@@ -76,7 +97,8 @@ def rows_refused(tokens: int, k: int, width: int, dtype) -> Optional[str]:
     and rows of ``width`` in ``dtype``, else why they do not: the rows are whole
     128-lane registers of float32 or bfloat16, the tokens whole tiles (so the
     tables are whole groups of eight rows), a pair's position fits the
-    scalar core's 32-bit word, and the pairs' two tables fit its memory."""
+    scalar core's 32-bit word, and the kernels' scalar tables fit its memory
+    (:func:`scalar_bytes`: top-8 of 16,384 tokens asks for 561,152 bytes)."""
     if jnp.dtype(dtype) not in _DTYPES:
         return f"rows of {jnp.dtype(dtype).name} are neither float32 nor bfloat16"
     if width % 128:
@@ -85,9 +107,22 @@ def rows_refused(tokens: int, k: int, width: int, dtype) -> Optional[str]:
         return f"{tokens} tokens are no multiple of 8"
     if tokens * k >= 2**31:
         return f"{tokens * k} pairs do not fit an int32"
-    if 8 * tokens * k > _SMEM_TABLES_BYTES:
-        return f"{tokens * k} pairs' source and weight tables ({8 * tokens * k} bytes) do not fit the scalar core's memory"
+    if scalar_bytes(tokens, k) > _SMEM_TABLES_BYTES:
+        return f"{tokens * k} pairs' scalar tables ({scalar_bytes(tokens, k)} bytes) do not fit the scalar core's memory"
     return None
+
+
+def _by_tile(values, tiles: int):
+    """``values`` (``[tiles · n]``) as ``tiles`` blocks of the scalar core's
+    memory one after the other, a tile's ``n`` in each."""
+    n = values.shape[0] // tiles
+    return jnp.pad(values.reshape(tiles, n), ((0, 0), (0, _block(n) - n))).reshape(-1)
+
+
+def _tile_spec(n: int, index):
+    """Grid step ``i``'s block of a :func:`_by_tile` table of ``n`` a tile: the
+    tile ``index(i, *prefetched)``."""
+    return pl.BlockSpec((_block(n),), lambda i, *refs: (index(i, *refs),), memory_space=pltpu.SMEM)
 
 
 def _row_of(slots, slot, at):
@@ -145,10 +180,11 @@ def _compiler_params(tile, d):
 # ---------------------------------------------------------------------------
 
 
-def _from_table_kernel(meta_ref, src_ref, *refs, tile, k, scaled, dotted, filled):
+def _from_table_kernel(meta_ref, *refs, tile, k, scaled, dotted, filled):
     refs = list(refs)
-    w_ref = refs.pop(0) if scaled else None  # prefetched with ``meta`` and ``src``
+    w_ref = refs.pop(0) if scaled else None  # prefetched whole with ``meta``: read at a row's pair
     table_ref = refs.pop(0)
+    src_ref = refs.pop(0)  # this tile's rows' pairs
     with_ref = refs.pop(0) if dotted else None
     if filled:
         refs.pop(0)  # aliased to the output: what the unwritten rows hold, never read here
@@ -171,9 +207,9 @@ def _from_table_kernel(meta_ref, src_ref, *refs, tile, k, scaled, dotted, filled
         def keep(r, row):
             got[pl.ds(r, 1), :] = row
             if scaled:
-                column[pl.ds(r, 1), :] = jnp.full((1, 128), w_ref[src_ref[first + r]], _F32)
+                column[pl.ds(r, 1), :] = jnp.full((1, 128), w_ref[src_ref[r]], _F32)
 
-        _ring(count, lambda r: src_ref[first + r] // k, table_ref, slots, sems, keep)
+        _ring(count, lambda r: src_ref[r] // k, table_ref, slots, sems, keep)
         here = jax.lax.broadcasted_iota(jnp.int32, (tile, 1), 0) < count  # past it ``got`` holds an earlier tile's rows, or nothing
         rows = got[...]
         scale = column[:, :1] if scaled else None
@@ -183,7 +219,7 @@ def _from_table_kernel(meta_ref, src_ref, *refs, tile, k, scaled, dotted, filled
             lane = jax.lax.broadcasted_iota(jnp.int32, (1, 128), 1)
 
             def place(r, carry):  # a row's product to its pair's place among the tokens' ``[N, k]``, 128 pairs a register row
-                q = src_ref[first + r]
+                q = src_ref[r]
                 at = pl.ds(q // 128, 1)
                 dots_ref[at, :] = jnp.where(lane == q % 128, column[pl.ds(r, 1), :], dots_ref[at, :])
                 return carry
@@ -216,9 +252,14 @@ def rows_from_table(table, src, n_live, weights=None, dot_with=None, fill=None, 
     if n % _GROUP or r % tile:
         raise ValueError(f"a table of {n} rows is not whole groups of {_GROUP}, or {r} rows are not whole tiles of {tile}")
     meta = jnp.stack([n_live, jnp.maximum((n_live + tile - 1) // tile, 1) - 1]).astype(jnp.int32)  # the last live tile
-    rows_spec = pl.BlockSpec((tile, d), lambda i, meta, *_: (jnp.minimum(i, meta[1]), 0))
-    prefetched = [meta, src.astype(jnp.int32)] + ([weights.astype(_F32).reshape(-1)] if scaled else [])
-    operands, in_specs = [table], [pl.BlockSpec(memory_space=pl.ANY)]
+
+    def live_tile(i, meta, *_):  # a grid step past the last live tile gets that tile's blocks: nothing is fetched or flushed
+        return jnp.minimum(i, meta[1])
+
+    rows_spec = pl.BlockSpec((tile, d), lambda *a: (live_tile(*a), 0))
+    prefetched = [meta] + ([weights.astype(_F32).reshape(-1)] if scaled else [])
+    operands = [table, _by_tile(src.astype(jnp.int32), r // tile)]
+    in_specs = [pl.BlockSpec(memory_space=pl.ANY), _tile_spec(tile, live_tile)]
     if dotted:
         operands.append(dot_with)
         in_specs.append(rows_spec)
@@ -304,15 +345,10 @@ def tokens_from_rows(rows, starts, pair, dest, weights=None, *, tile, out_dtype=
     weighted = weights is not None
     if r % _GROUP or n % tile or starts.shape != (tiles + 1,):
         raise ValueError(f"a buffer of {r} rows is not whole groups of {_GROUP}, or {n} tokens and {starts.shape[0]} starts are not tiles of {tile}")
-    block = -(-tile * k // 1024) * 1024  # the scalar core's memory takes a vector in blocks of 1024 words
-
-    def by_tile(pairs):  # a tile's pairs, one after the other
-        return jnp.pad(pairs.reshape(tiles, tile * k), ((0, 0), (0, block - tile * k))).reshape(-1)
-
-    scalars = pl.BlockSpec((block,), lambda i, *_: (i,), memory_space=pltpu.SMEM)
-    operands, in_specs = [by_tile(dest.astype(jnp.int32))], [scalars]
+    scalars = _tile_spec(tile * k, lambda i, *_: i)  # a tile's pairs
+    operands, in_specs = [_by_tile(dest.astype(jnp.int32).reshape(-1), tiles)], [scalars]
     if weighted:
-        operands.append(by_tile(weights.astype(_F32)))
+        operands.append(_by_tile(weights.astype(_F32).reshape(-1), tiles))
         in_specs.append(scalars)
     scratch = _slots(rows, d) + ([] if out_dtype == _F32 else [pltpu.VMEM((tile, d), _F32)])
     return pl.pallas_call(

@@ -751,9 +751,12 @@ class HeldExpertsMlp(nn.Module):
     can see and records as ``(model, "moe_rows", "pallas" | "gather", reason)``:
     on a TPU, where ``d`` is whole 128-lane registers, the tokens whole tiles
     and on one device, the two kernels of ``ops/moe_rows.py``, which copy, scale
-    and sum one row a live pair (``moe_pairs_local`` of them: 3% of the buffer
-    in ``nemotron3nano_t8192``) and visit no tile of the buffer past the last
-    live one; anywhere else gathers and masked sums over all ``tokens · top_k``
+    and sum one row a live pair (``moe_pairs_local`` of them: a few per cent of
+    the buffer on one rank of an expert-parallel cut, up to all of it at the
+    router's worst) and visit no tile of the buffer past the last live one,
+    holding one table of four bytes a pair and a tile's ``src`` in the scalar
+    core's memory (about 250,000 pairs fit: ``moe_rows.rows_refused``);
+    anywhere else gathers and masked sums over all ``tokens · top_k``
     pairs in ``jax.numpy``, which are the definition the kernels are held to
     (``tests/test_moe.py``). Both keep the router and the weights float32, sum
     a token's pairs in float32 and round to ``dtype`` at the same two places

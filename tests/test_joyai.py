@@ -213,12 +213,13 @@ def test_the_swiglu_layer_and_its_gradients_match_the_reference(first, count):
     assert float(jnp.abs(grads[0]["params"]["score_correction_bias"]).max()) == 0
 
 
-@pytest.mark.parametrize("tokens,k,taken", [(16384, 6, True), (16384, 8, False), (8192, 8, True)])
+@pytest.mark.parametrize("tokens,k,taken", [(16384, 6, True), (16384, 8, True), (8192, 8, True), (32768, 8, False), (65536, 8, False)])
 def test_the_row_kernels_take_a_layer_whose_pair_tables_fit_the_scalar_memory(tokens, k, taken):
-    """``nemotron3nano_t8192``'s 98,304 pairs are taken; ``joyaiflash_t8192``'s
-    131,072 (top-8 of 16,384 tokens) are not: ``src`` and the weights, four
-    bytes a pair each, would fill the 1 MiB the scalar core has, and the layer
-    keeps its ``jax.numpy`` form with the reason recorded."""
+    """``nemotron3nano_t8192``'s 98,304 pairs and ``joyaiflash_t8192``'s
+    131,072 (top-8 of 16,384 tokens) are taken: the kernels hold one table of
+    four bytes a pair whole and a tile's blocks, about 250,000 pairs in the 1 MiB
+    the scalar core has. Past that (262,144 pairs: 1 MiB of ``pair`` alone) the
+    layer keeps its ``jax.numpy`` form with the reason recorded."""
     from distributed_training_pytorch_tpu.ops.moe_rows import rows_refused
 
     why = rows_refused(tokens, k, 2048, jnp.bfloat16)

@@ -439,20 +439,27 @@ def test_both_kernels_compile_for_the_v5e_at_nemotrons_widths(v5e):
     assert text.count('custom_call_target="tpu_custom_call"') == 2
 
 
-def test_the_expert_layers_row_kernels_compile_for_the_v5e_at_nemotrons_widths(v5e):
-    """``ops/moe_rows.py`` as ``nemotron3nano_t8192`` calls it (here because the
-    described chips are this file's: one worker loads the TPU's library): 16,384
-    tokens of 2,688, top-6, a buffer of 98,304 rows. Mosaic takes the four calls:
-    a copy of a row's group of eight from a float32 and from a bfloat16 table in
-    HBM (it refuses a one-row slice of either), 98,304 positions an array in the
-    scalar core's memory, a bfloat16 row read through the 32-bit view of its
-    packed pair."""
+# name: (tokens, top-k, width) of the expert layers the cells run
+MOE_ROWS_CELLS = {"nemotron3nano_t8192": (16384, 6, 2688), "joyaiflash_t8192": (16384, 8, 2048)}
+
+
+@pytest.mark.parametrize("cell", MOE_ROWS_CELLS)
+def test_the_expert_layers_row_kernels_compile_for_the_v5e_at_the_cells_widths(v5e, cell):
+    """``ops/moe_rows.py`` as the cell calls it (here because the described
+    chips are this file's: one worker loads the TPU's library): 16,384 tokens,
+    a buffer of 98,304 rows (top-6 of 2,688) or 131,072 (top-8 of 2,048).
+    Mosaic takes the four calls: a copy of a row's group of eight from a
+    float32 and from a bfloat16 table in HBM (it refuses a one-row slice of
+    either), a bfloat16 row read through the 32-bit view of its packed pair, and
+    the scalar tables in the scalar core's memory: one of a position a pair
+    whole and a tile's blocks, which at 131,072 pairs is where the whole
+    ``src`` beside the weights did not fit."""
     from jax.sharding import SingleDeviceSharding
 
     from distributed_training_pytorch_tpu.ops import moe_rows
 
     one = SingleDeviceSharding(v5e[0])
-    n, k, d = 16384, 6, 2688
+    n, k, d = MOE_ROWS_CELLS[cell]
     assert moe_rows.rows_refused(n, k, d, jnp.bfloat16) is None and moe_rows.rows_tile(n) == 256
 
     def s(shape, dtype):

@@ -65,7 +65,17 @@ compute in.
 ``remat=True`` rematerialises every block (``nn.remat``): the backward pass
 keeps one hidden state a block and computes each block's forward pass again
 when it reaches it, which is what lets a stack whose training state fills
-most of a chip train on thousands of tokens a step (docs/memory.md).
+most of a chip train on thousands of tokens a step (docs/memory.md). One
+thing crosses the recomputation besides the hidden state (``REMAT_POLICY``):
+where attention runs the flash kernels, their output ``o`` and log-sum-exp
+``lse``, ``B·T·H·(Dv·itemsize + 4)`` bytes an attention layer (at ``[2, 8192]``
+in bfloat16 0.76 GiB over the benchmark's five latent layers of
+``joyai-llm-flash`` and its prediction module's, 0.13 GiB for
+``nemotron-3-nano``'s one attention layer; 0.03 GiB for
+``granite-4.0-h-micro``'s at ``[2, 4096]``), so the forward kernel runs once a
+step and not twice. Where attention takes the plain path nothing carries
+those names and every block is recomputed whole. The model records which
+(``kernel_dispatch`` op ``remat``, with the bytes kept).
 """
 
 from __future__ import annotations
@@ -320,6 +330,14 @@ class LatentAttention(nn.Module):
             return _dense(cfg.hidden_size, self.dtype, "o_proj")(y)
 
 
+# What rematerialising a block keeps from the first forward pass: the flash
+# kernel's residuals, under the names ops/pallas.py:_flash_fwd gives them
+# (FLASH_OUT, FLASH_LSE; repeated here so that building a model does not
+# import Pallas). Everything else a block computes is computed again.
+FLASH_RESIDUALS = ("flash_out", "flash_lse")
+REMAT_POLICY = jax.checkpoint_policies.save_only_these_names(*FLASH_RESIDUALS)
+
+
 class HybridBlock(nn.Module):
     cfg: HybridConfig
     kind: str
@@ -389,7 +407,7 @@ class MultiTokenPrediction(nn.Module):
         with jax.named_scope("mtp"):
             joined = jnp.concatenate([RMSNorm(eps, self.dtype, name="hnorm")(h), RMSNorm(eps, self.dtype, name="enorm")(after)], axis=-1)
             x = _dense(cfg.hidden_size, self.dtype, "eh_proj")(joined)
-            block = nn.remat(HybridBlock) if self.remat else HybridBlock
+            block = nn.remat(HybridBlock, policy=REMAT_POLICY) if self.remat else HybridBlock
             last = len(cfg.layer_types) - 1
             x = block(cfg, cfg.layer_types[last], self.dtype, self.pallas, cfg.ffn(last), name="block")(x)
             return RMSNorm(eps, self.dtype, name="final_norm")(x)
@@ -415,7 +433,7 @@ class HybridLM(nn.Module):
         head = embed.embedding if cfg.tie_word_embeddings else self.param(
             "lm_head", _normal, (cfg.vocab_size, cfg.hidden_size), jnp.float32)
         h = (cfg.embedding_multiplier * embed(tokens)).astype(self.dtype)
-        block = nn.remat(HybridBlock) if self.remat else HybridBlock
+        block = nn.remat(HybridBlock, policy=REMAT_POLICY) if self.remat else HybridBlock
         for i, kind in enumerate(cfg.layer_types):
             h = block(cfg, kind, self.dtype, self.pallas, cfg.ffn(i), name=f"layer_{i}")(h)
         out = RMSNorm(cfg.rms_norm_eps, self.dtype, name="final_norm")(h)
@@ -424,10 +442,24 @@ class HybridLM(nn.Module):
         if cfg.mtp_layers and (labels is not None or self.is_initializing()):
             after = (cfg.embedding_multiplier * embed(tokens if labels is None else labels)).astype(self.dtype)
             mtp = MultiTokenPrediction(cfg, self.dtype, self.pallas, self.remat, name="mtp")(h, after)
+        if self.remat and not self.is_initializing():
+            self._record_remat(tokens.shape, with_mtp=mtp is not None)
         if return_hidden:
             scaled = lambda x: x / jnp.asarray(cfg.logits_scaling, x.dtype)  # noqa: E731
             return (scaled(out), scaled(mtp)) if labels is not None and mtp is not None else scaled(out)
         return out.astype(jnp.float32) @ head.T.astype(jnp.float32) / cfg.logits_scaling
+
+    def _record_remat(self, shape, with_mtp: bool):
+        """Record what ``REMAT_POLICY`` keeps in this stack: one ``o`` and
+        ``lse`` an attention layer (the prediction module's block too, where it
+        runs) if attention resolves to flash, else nothing."""
+        from distributed_training_pytorch_tpu.ops import dispatch
+
+        cfg = self.cfg
+        kinds = cfg.layer_types + (cfg.layer_types[-1:] if with_mtp else ())
+        widths = [cfg.v_head_dim if kind == MLA else cfg.head_dim for kind in kinds if kind in (ATTENTION, MLA)]
+        dispatch.remat_kept(cfg.dispatch_name, self.pallas, rows=shape[0], seq_len=shape[1],
+                            heads=cfg.num_attention_heads, v_widths=widths, itemsize=jnp.dtype(self.dtype).itemsize)
 
     # -- what make_fused_lm_loss asks of a model --------------------------------
 

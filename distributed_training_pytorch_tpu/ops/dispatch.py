@@ -44,6 +44,10 @@ hybrid_lm      conv                     the causal convolution's kernels
                                         (``ops/causal_conv.py``) on TPU where
                                         the shape tiles and the rows split
                                         over the mesh, else ``jax.numpy``
+hybrid_lm      remat                    no choice: what block rematerialisation
+                                        keeps follows from the attention's path
+                                        (flash's o and lse, or nothing); the
+                                        record states the bytes kept a step
 nemotron_h     attention, ssd, conv     as hybrid_lm (the same modules)
 nemotron_h     moe_experts              no choice yet: ``jax.lax.ragged_dot``,
                                         recorded by the layer itself
@@ -77,6 +81,8 @@ __all__ = [
     "resolve",
     "attention_fn",
     "lm_attention_impl",
+    "flash_resolves",
+    "remat_kept",
     "ssd_fn",
     "conv_fn",
     "moe_rows_tile",
@@ -230,7 +236,7 @@ def attention_fn(
 
     def dispatching_attention(q, k, v, valid_len=None):
         seq = q.shape[1]
-        if seq < min_seq_len:
+        if not flash_resolves(use_flash, seq):
             # The formerly-silent fall-through: make_attention_fn drops to
             # the plain path below min_seq_len.  Same routing — now named.
             record(
@@ -266,6 +272,18 @@ def attention_fn(
     return dispatching_attention
 
 
+def flash_resolves(use_flash: Optional[bool], seq_len: int) -> bool:
+    """Whether :func:`attention_fn` with the same tri-state runs the flash
+    kernels at ``seq_len`` (and not the caller's plain path)."""
+    import jax
+
+    if use_flash is False or (use_flash is None and jax.default_backend() != "tpu"):
+        return False
+    from .pallas import FLASH_MIN_SEQ_LEN
+
+    return seq_len >= (1 if use_flash is True else FLASH_MIN_SEQ_LEN)
+
+
 def lm_attention_impl(attention_impl: str, pallas: Optional[bool]) -> str:
     """Map TransformerLM's ``pallas`` knob onto its legacy ``attention_impl``
     string: True → "flash", False → "plain", None → keep the legacy value
@@ -275,6 +293,35 @@ def lm_attention_impl(attention_impl: str, pallas: Optional[bool]) -> str:
     if pallas is False:
         return "plain"
     return attention_impl
+
+
+# ---------------------------------------------------------------------------
+# block rematerialisation (hybrid_lm)
+# ---------------------------------------------------------------------------
+
+
+def remat_kept(model: str, use_flash: Optional[bool], *, rows: int, seq_len: int, heads: int,
+               v_widths, itemsize: int) -> int:
+    """Record what rematerialising ``model``'s blocks keeps from the first
+    forward pass, and return its bytes a step. ``models/hybrid_lm.py``'s
+    policy keeps the flash kernel's ``o`` (``[B, H, T, Dv]`` at ``itemsize``)
+    and ``lse`` (``[B, H, 1, T]`` float32) of each attention layer, one
+    ``Dv`` a layer in ``v_widths``: ``keep_attention_residuals`` where
+    attention resolves to flash, ``full`` where it takes the plain path or
+    the stack has no attention (nothing carries the names, every block is
+    recomputed whole)."""
+    if not v_widths:
+        record(model, "remat", "full", reason="no attention layer: every block is recomputed whole")
+        return 0
+    if not flash_resolves(use_flash, seq_len):
+        record(model, "remat", "full", reason="attention takes the plain path: every block is recomputed whole")
+        return 0
+    kept = sum(rows * seq_len * heads * (dv * itemsize + 4) for dv in v_widths)
+    record(model, "remat", "keep_attention_residuals",
+           reason=(f"flash's o and lse of {len(v_widths)} attention layers kept across the recomputation: "
+                   f"{kept} B a step (B {rows} x T {seq_len} x H {heads} x (Dv x {itemsize} + 4) a layer, "
+                   f"Dv {'/'.join(map(str, sorted(set(v_widths))))})"))
+    return kept
 
 
 # ---------------------------------------------------------------------------

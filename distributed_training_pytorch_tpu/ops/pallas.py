@@ -66,11 +66,17 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import PartitionSpec as P
 
 NEG_INF = -1e30  # large-negative logit for masked positions (f32-safe)
+
+# What the forward kernel hands the backward besides q / k / v, under names a
+# ``jax.checkpoint`` policy can keep (``models/hybrid_lm.py:REMAT_POLICY``):
+# outside a checkpoint ``checkpoint_name`` is an identity that lowers to nothing.
+FLASH_OUT, FLASH_LSE = "flash_out", "flash_lse"
 
 
 def resolve_interpret(interpret: Optional[bool] = None) -> bool:
@@ -731,9 +737,13 @@ def _flash(q, k, v, causal, block_q, block_k, interpret, valid_len):
 
 
 def _flash_fwd(q, k, v, causal, block_q, block_k, interpret, valid_len):
+    """The forward kernel's ``o`` and ``lse`` are named ``FLASH_OUT`` /
+    ``FLASH_LSE``: a rematerialising caller whose policy keeps them reads
+    them back in the backward pass instead of running this kernel again."""
     o, lse, (qt, kt, vt) = _fwd_impl(
         q, k, v, causal, block_q, block_k, interpret, valid_len
     )
+    o, lse = checkpoint_name(o, FLASH_OUT), checkpoint_name(lse, FLASH_LSE)
     return _from_bhtd(o), (qt, kt, vt, o, lse, q.shape)
 
 

@@ -136,6 +136,51 @@ def test_conv1x1_policy_auto_stays_off_and_is_named():
     assert any("opt in" in r or "auto" in r for r in by_reason)
 
 
+@pytest.mark.parametrize("size,pallas,dtype,path", [
+    ("joyai_tiny", True, jnp.float32, "keep_attention_residuals"),
+    ("joyai_tiny", True, jnp.bfloat16, "keep_attention_residuals"),
+    ("nemotron_h_tiny", True, jnp.bfloat16, "keep_attention_residuals"),
+    ("hybrid_tiny", None, jnp.float32, "full"),  # auto off the chip: plain attention, nothing named to keep
+])
+def test_the_remat_record_names_the_model_and_the_bytes_it_keeps(size, pallas, dtype, path):
+    """One ``remat`` record a model and process, under the model's
+    ``dispatch_name``, however often the step is traced: flash's ``o`` and
+    ``lse`` of every attention layer on the loss's path (the prediction
+    module's too), ``B·T·H·(Dv·itemsize + 4)`` bytes a layer."""
+    from distributed_training_pytorch_tpu.models import HybridTiny, JoyAITiny, NemotronHTiny
+    from distributed_training_pytorch_tpu.models.transformer_lm import make_fused_lm_loss
+
+    model = {"joyai_tiny": JoyAITiny, "nemotron_h_tiny": NemotronHTiny, "hybrid_tiny": HybridTiny}[size](
+        vocab_size=97, dtype=dtype, pallas=pallas)
+    rows, t = 2, 40
+    batch = {"image": jnp.zeros((rows, t), jnp.int32), "label": jnp.ones((rows, t), jnp.int32)}
+    params = jax.eval_shape(lambda: model.init(jax.random.key(0), batch["image"]))["params"]
+    assert not [r for r in dispatch.records() if r["op"] == "remat"]  # making the parameters differentiates nothing
+    loss = make_fused_lm_loss(model)
+    for _ in range(2):
+        jax.eval_shape(jax.grad(lambda p: loss(p, {}, batch, jax.random.key(0), True)[0]), params)
+    (rec,) = [r for r in dispatch.records() if r["op"] == "remat"]
+    cfg = model.cfg
+    assert (rec["model"], rec["path"]) == (cfg.dispatch_name, path)
+    if path == "full":
+        assert "plain path" in rec["reason"]
+        return
+    layers = {"joyai_tiny": 4, "nemotron_h_tiny": 1}[size]  # three latent layers and the module's; one grouped-query layer
+    v_width = {"joyai_tiny": cfg.v_head_dim, "nemotron_h_tiny": cfg.head_dim}[size]
+    kept = layers * rows * t * cfg.num_attention_heads * (v_width * jnp.dtype(dtype).itemsize + 4)
+    assert f"of {layers} attention layers" in rec["reason"] and f" {kept} B a step" in rec["reason"], rec["reason"]
+
+
+def test_remat_kept_is_the_residuals_bytes():
+    """``joyaiflash_t8192``'s six latent layers at ``[2, 8192]``, 32 heads,
+    values 128 wide in bfloat16: 0.76 GiB kept where flash runs, none where not."""
+    kw = dict(rows=2, seq_len=8192, heads=32, v_widths=[128] * 6, itemsize=2)
+    assert dispatch.remat_kept("m", True, **kw) == 6 * 2 * 8192 * 32 * (128 * 2 + 4) == 817_889_280
+    assert dispatch.remat_kept("m", False, **kw) == 0
+    assert dispatch.remat_kept("m", True, **dict(kw, v_widths=[])) == 0
+    assert {r["path"] for r in dispatch.records()} == {"keep_attention_residuals", "full"}
+
+
 @pytest.mark.slow  # soak-shaped: moved out of tier-1 to keep it inside its cap (PR 21)
 def test_model_builds_record_their_resolutions():
     from distributed_training_pytorch_tpu.models import ConvNeXtTiny, ResNet18Slim

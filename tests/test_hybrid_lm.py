@@ -31,7 +31,8 @@ import numpy as np
 import pytest
 
 from benchmarks.reference import granite_hybrid as ref
-from distributed_training_pytorch_tpu.models import HybridConfig, HybridLM, HybridTiny
+from distributed_training_pytorch_tpu.models import HybridConfig, HybridLM, HybridTiny, JoyAITiny, NemotronHTiny, hybrid_lm
+from distributed_training_pytorch_tpu.models.hybrid_lm import ATTENTION, MLA
 from distributed_training_pytorch_tpu.models.transformer_lm import make_fused_lm_loss
 from distributed_training_pytorch_tpu.ops import dispatch
 from distributed_training_pytorch_tpu.ops.ssd import causal_conv1d, ssd_chunked
@@ -161,8 +162,61 @@ def test_the_backward_pass_recomputes_each_block_only_when_asked():
         text = jax.jit(jax.grad(lambda p: program_loss(p, batch, remat=remat))).lower(params).as_text(debug_info=True)
         return re.findall(r'loc\("([^"]*)"', text)
 
-    assert any("rematted_computation" in n and "ssd_scan" in n for n in names(True))
+    recomputed = [n for n in names(True) if "rematted_computation" in n]
+    assert any("ssd_scan" in n for n in recomputed)
+    # attention on its plain path carries none of the names REMAT_POLICY keeps:
+    # its scores and softmax are recomputed with the rest of the block
+    assert any("gqa_attention" in n and n.endswith("/exp") for n in recomputed), sorted(n for n in recomputed if "gqa" in n)
     assert not any("rematted_computation" in n for n in names(False))
+
+
+def attention_layers(cfg):
+    """Attention layers on the fused loss's path: the stack's, and the
+    prediction module's block where there is one (the last layer's kinds)."""
+    return sum(kind in (ATTENTION, MLA) for kind in cfg.layer_types + cfg.layer_types[-1:] * cfg.mtp_layers)
+
+
+@pytest.mark.parametrize("pallas", [True, False], ids=["flash", "plain"])
+@pytest.mark.parametrize("factory", [HybridTiny, NemotronHTiny, JoyAITiny], ids=lambda f: f.__name__)
+def test_rematerialisation_runs_each_attention_layers_forward_kernel_once(factory, pallas, monkeypatch):
+    """``REMAT_POLICY`` keeps the flash kernel's ``o`` and ``lse`` across the
+    recomputation: the gradient program calls the forward kernel once an
+    attention layer, as often as the backward (without the names it called it
+    twice: 8 against 4 in ``JoyAITiny`` with its module). The plain path
+    carries no names, so its program is the full rematerialisation it was,
+    to the bit. Either way the result is the unrematerialised one."""
+    batch = make_batch(3, 2, 32)
+
+    def grad_fn(remat):
+        model = factory(vocab_size=97, pallas=pallas, remat=remat)
+        return jax.jit(jax.value_and_grad(lambda p: make_fused_lm_loss(model)(p, {}, batch, jax.random.key(0), True)[0]))
+
+    model = factory(vocab_size=97, pallas=pallas)
+    params = model.init(jax.random.key(3), batch["image"])["params"]
+    dispatch.reset()
+    try:
+        text = grad_fn(True).lower(params).as_text()
+        recs = dispatch.records()
+    finally:
+        dispatch.reset()
+    # the scan's and the convolution's kernels (their jitted calls are named _fwd_call
+    # and _bwd_call too) refuse these toy widths: every such call below is flash's
+    assert not {(r["op"], r["path"]) for r in recs} & {("ssd", "pallas"), ("conv", "pallas")}, recs
+    layers = attention_layers(model.cfg) if pallas else 0
+    assert len(re.findall(r"call @_fwd_call\w*\(", text)) == layers
+    assert len(re.findall(r"call @_bwd_call\w*\(", text)) == layers
+    (rec,) = [r for r in recs if r["op"] == "remat"]
+    assert (rec["model"], rec["path"]) == (model.cfg.dispatch_name, "keep_attention_residuals" if pallas else "full")
+    loss, grads = grad_fn(True)(params)
+    want_loss, want = grad_fn(False)(params)
+    assert abs(float(loss) - float(want_loss)) <= REMAT_GAP * abs(float(want_loss))
+    # a leaf with no gradient (the routers' selection-only bias) must stay at none
+    gaps = jax.tree.map(lambda g, w: rel(g, w) if np.linalg.norm(w) else float(np.linalg.norm(g)), grads, want)
+    assert max(jax.tree.leaves(gaps)) <= REMAT_GAP, gaps
+    if not pallas:  # the program a policy-free nn.remat gives
+        monkeypatch.setattr(hybrid_lm, "REMAT_POLICY", None)
+        assert grad_fn(True).lower(params).as_text() == text
+        assert jax.tree.all(jax.tree.map(np.array_equal, grad_fn(True)(params)[1], grads))
 
 
 def test_bfloat16_compute_is_outside_the_float32_tolerance():
@@ -187,7 +241,8 @@ def test_scopes_dispatch_records_and_the_remat_counter():
         for scope in ("mamba_mixer", "mamba_mixer/mamba_conv", "mamba_mixer/ssd_scan", "gqa_attention", "gated_mlp"):
             assert scope in text, scope
         recs = {(r["model"], r["op"], r["path"]) for r in dispatch.records()}
-        assert recs == {("hybrid_lm", "attention", "plain"), ("hybrid_lm", "ssd", "chunked"), ("hybrid_lm", "conv", "jnp")}
+        assert recs == {("hybrid_lm", "attention", "plain"), ("hybrid_lm", "ssd", "chunked"), ("hybrid_lm", "conv", "jnp"),
+                        ("hybrid_lm", "remat", "full")}  # plain attention: nothing is kept across the recomputation
     finally:
         dispatch.reset()
 

@@ -355,7 +355,7 @@ def test_scopes_and_dispatch_records():
             assert scope in text, scope
         recs = {(r["model"], r["op"], r["path"]) for r in dispatch.records()}
         assert recs == {("joyai_llm_flash", "attention", "plain"), ("joyai_llm_flash", "moe_experts", "ragged_dot"),
-                        ("joyai_llm_flash", "moe_rows", "gather")}
+                        ("joyai_llm_flash", "moe_rows", "gather"), ("joyai_llm_flash", "remat", "full")}
         (experts,) = [r for r in dispatch.records() if r["op"] == "moe_experts"]
         assert experts["activation"] == "swiglu"
     finally:

@@ -169,7 +169,7 @@ def test_scopes_dispatch_records_and_step_metrics():
         assert "gated_mlp" not in text  # a layer is its one mixer
         recs = {(r["model"], r["op"], r["path"]) for r in dispatch.records()}
         assert recs == {("nemotron_h", "attention", "plain"), ("nemotron_h", "ssd", "chunked"), ("nemotron_h", "moe_experts", "ragged_dot"),
-                        ("nemotron_h", "moe_rows", "gather"), ("nemotron_h", "conv", "jnp")}
+                        ("nemotron_h", "moe_rows", "gather"), ("nemotron_h", "conv", "jnp"), ("nemotron_h", "remat", "full")}
         (reason,) = [r["reason"] for r in dispatch.records() if r["op"] == "moe_experts"]
         assert "ragged_dot over 4 held experts" in reason and "backend=cpu" in reason
         (reason,) = [r["reason"] for r in dispatch.records() if r["op"] == "moe_rows"]

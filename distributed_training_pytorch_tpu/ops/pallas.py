@@ -26,7 +26,9 @@ and at q / k 192 with v 128
 (at 16,384 the slabs alone are refused); beyond that, sequence
 parallelism (``parallel.ring_attention``) shards T across chips and each shard
 re-enters this kernel. Softmax statistics are carried in float32; matmuls run
-on the MXU with ``preferred_element_type=float32``. The backward pass is the
+on the MXU with ``preferred_element_type=float32``. Both kernels hold their
+score tiles keys x queries, so the statistics are the ``[1, bq]`` lane-major
+rows lse is stored as. The backward pass is the
 standard flash decomposition in one kernel: a delta precompute
 (``rowsum(dO * O)``, plain XLA), then a grid over ``(batch, head, k-block)``
 with q / dO / lse / delta resident as whole-T slabs and a loop over q-blocks
@@ -97,41 +99,44 @@ def _flash_blocks(kernel: str, t_q: int, t_k: int, causal: bool) -> tuple[int, i
     """``(block_q, block_k)`` for one of the kernels ``"fwd"``, ``"bwd"``, from
     the shapes alone; :func:`_block_size` then clamps to T.
 
-    Measured on the TPU v5e (``scripts/flash_block_sweep.py``: bf16, head dim
-    64, causal, each kernel alone over ``{256, 512, 1024}^2``, ms a call;
-    forward PR 26, backward PR 30; PERF.md §6 has the tables):
+    Measured on the TPU v5e (``scripts/flash_block_sweep.py``: bf16, causal,
+    each kernel alone over ``{256, 512, 1024}^2``, ms a call; PERF.md §6 has
+    the whole grids). The forward rows time the keys x queries kernel with
+    ``--kernel-layout`` (operands already in its layout); the backward rows
+    also carry the 0.75-1.6 ms a call of layout copies that a one-kernel
+    program adds, and keep their winners without them (7.54 at 512 x 512 of
+    T=4096, 2.75 as one tile of T=1024, 13.12 at 1024 x 1024 of T=8192):
 
-    ==================  ===========  ===========  ===========  =================
-    shape               512 x 512    1024 x 512   1024 x 1024  dq + dkv (PR 29)
-    ==================  ===========  ===========  ===========  =================
-    fwd [8,12,4096,64]  **5.06**     5.57         5.26         —
-    bwd [8,12,4096,64]  **8.90**     9.00         9.13         5.56 + 7.93
-    fwd [32,12,1024,64] 2.62         2.75         **1.98**     —
-    bwd [32,12,1024,64] 4.24         4.78         **4.21**     2.75 + 3.56
-    fwd [4,12,8192,64]  **8.30**     8.87         refused      —
-    bwd [4,12,8192,64]  15.07        14.77        **14.71**    9.15 + 13.84
-    ==================  ===========  ===========  ===========  =================
+    =======================  =========  ==========  ===========
+    shape                    512 x 512  1024 x 512  1024 x 1024
+    =======================  =========  ==========  ===========
+    fwd [8,12,4096,64]       3.48       **3.29**    3.39
+    bwd [8,12,4096,64]       **8.90**   9.00        9.13
+    fwd [32,12,1024,64]      1.35       1.43        1.11
+    bwd [32,12,1024,64]      4.24       4.78        **4.21**
+    fwd [4,12,8192,64]       6.32       **5.73**    5.90
+    bwd [4,12,8192,64]       15.07      14.77       **14.71**
+    fwd [2,32,8192,128]      10.65      **9.55**    9.68
+    fwd [2,32,8192,192/128]  12.07      **11.93**   12.23
+    =======================  =========  ==========  ===========
 
-    (Each entry includes the 0.75-1.6 ms a call that such a one-kernel program
-    spends transposing operands between XLA's entry layout and the kernel's;
-    re-read with ``--kernel-layout`` in PR 32 every row keeps its winner:
-    4.31 / 7.54 at 512 x 512 of T=4096, 1.21 / 2.75 as one tile of T=1024,
-    7.56 / 13.12 at T=8192.)
-
-    Smaller blocks skip more of the masked half (44% of block pairs at 512,
-    37.5% at 1024, of T=4096) but every loop trip pays for its carried
-    accumulators, and a 256-wide block on either side loses everywhere (the
-    forward's per-row rescale becomes a quarter of its work; the backward
-    reads 9.3-14.0 at T=4096). At T=1024 nothing beats one 1024 x 1024 block
-    pair, which lowers to straight-line code; a causal call then leaves the
-    masked sub-tiles of that one pair out of the program statically
-    (:func:`_flash_sub`, PR 32). Non-causal calls have nothing to skip and
-    keep 1024 x 1024. Past T = 4096 the whole-T K/V slabs leave
-    the forward no room for 1024 x 1024 float32 tiles under the compiler's
-    default limit (refused by Mosaic at 8192); the backward is compiled under
-    a limit of its own (:func:`_bwd_vmem_bytes`) and is fastest there at
-    1024 x 1024. At 16,384 the forward's slabs alone are refused."""
+    A forward block of 1024 query rows streams twice the rows through each
+    tile of k^T the MXU holds, and a 256-wide block on either side loses
+    everywhere. So a causal forward past T=1024 runs 1024 x 512: it visits 20
+    of T=4096's 32 block pairs where 512 x 512 visits 36 of 64 (more masked
+    elements, half the loop trips). Latent attention's widths (q / k 192, v
+    128) need 18 MiB there, over the compiler's 16 MiB default, and are
+    compiled under :func:`_fwd_vmem_bytes` (their last two columns were read
+    under it). At T=1024 a causal call is one
+    1024 x 1024 block pair whose masked sub-tiles are left out of the program
+    (:func:`_flash_sub`: 0.96 ms a call forward, against 1.11 as one tile).
+    Non-causal calls have nothing to skip and keep 1024 x 1024 to T=4096 and
+    512 x 512 past it. The backward is compiled under a limit of its own
+    (:func:`_bwd_vmem_bytes`) and is fastest at 512 x 512 to T=4096 and at
+    1024 x 1024 past it. At 16,384 the forward's slabs alone are refused."""
     t = max(t_q, t_k)
+    if kernel == "fwd" and causal and t > 1024:
+        return 1024, 512
     if t > 4096:
         return (1024, 1024) if kernel == "bwd" else (512, 512)
     if causal and t > 1024:
@@ -223,14 +228,16 @@ def flash_block_plan(t_q, t_k, causal, block_q=None, block_k=None) -> dict:
     """What the flash path does at these shapes, for the ``kernel_dispatch``
     record: the forward's block shape, the sub-tile size it walks a one-block
     causal call in (None: one tile) and :func:`flash_block_counts` at the
-    granularity it skips at, and which backward the custom VJP takes
-    (``"fused"``: dq, dk and dv from one kernel, the only one there is since
-    ISSUE 30) at which block shape and sub-tile size."""
+    granularity it skips at, which forward body runs (``fwd_tiles``:
+    ``"sub"``, :func:`_fwd_subtiles`; ``"kq"``, :func:`_fwd_kernel`'s keys x
+    queries tiles), and which backward the custom VJP takes (``"fused"``:
+    dq, dk and dv from one kernel, the only backward there is) at which block
+    shape and sub-tile size."""
     bq, bk, sub = _resolve_blocks("fwd", block_q, block_k, t_q, t_k, causal)
     total, computed = flash_block_counts(t_q, t_k, sub or bq, sub or bk, causal)
     bwd_q, bwd_k, bwd_sub = _resolve_blocks("bwd", block_q, block_k, t_q, t_k, causal)
     return {
-        "block_q": bq, "block_k": bk, "sub_block": sub,
+        "block_q": bq, "block_k": bk, "sub_block": sub, "fwd_tiles": "sub" if sub else "kq",
         "blocks_total": total, "blocks_computed": computed,
         "backward": "fused", "bwd_block_q": bwd_q, "bwd_block_k": bwd_k, "bwd_sub_block": bwd_sub,
     }
@@ -310,43 +317,57 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, block_k, seq_len,
     """One q-block against the k-blocks it can see, online softmax. Refs are
     (1, 1, bq, Dqk) / (1, 1, Tp, Dqk) blocks, v and o ``Dv`` wide; statistics
     in f32. (A causal call of one block pair runs :func:`_fwd_subtiles`
-    instead.)"""
+    instead.)
+
+    The tiles are held as :func:`_bwd_kernel` holds them, keys x queries
+    (``s^T = k q^T``, ``[bk, bq]``): the running maximum and sum are then
+    ``[1, bq]`` lane-major rows, reduced over the tile's sublanes, and the
+    accumulator is ``o^T`` (``[Dv, bq]``, ``+= v^T p^T``), rescaled by a
+    sublane broadcast of the ``[1, bq]`` correction and transposed once after
+    the loop. (The other way up — ``[bq, bk]`` tiles, ``[bq, 1]`` statistics
+    reduced across lanes and broadcast across them to rescale ``[bq, Dv]`` —
+    carries 192 vregs a trip where this carries 40 at ``bq`` = 512 and width
+    64: 4.28 ms a call of ``[8, 12, 4096, 64]`` at 512 x 512 against 3.48,
+    PERF.md §6.)"""
     bq = q_ref.shape[2]
     d = v_ref.shape[3]  # the accumulator is o's width
     t_pad = k_ref.shape[2]
     n_k = t_pad // block_k
+    padded = t_pad > seq_len  # only then does a key need hiding for its position
     qi = _grid_index(2, n_q)
 
     # Matmuls run in the input dtype (bf16 in production — one MXU pass; an
     # f32 cast would force the 3x-slower f32 path) with f32 accumulation;
     # softmax statistics and the scale multiply stay f32.
     q = q_ref[0, 0]  # [bq, D]
-    q_idx = qi * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, block_k), 0)
+    q_idx = qi * bq + jax.lax.broadcasted_iota(jnp.int32, (block_k, bq), 1)
 
     def body(j, carry):
         acc, m, l = carry
         k = k_ref[0, 0, pl.ds(j * block_k, block_k), :]  # [bk, Dqk]
         v = v_ref[0, 0, pl.ds(j * block_k, block_k), :]  # [bk, Dv]
-        s = scale * jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )  # [bq, bk] f32
-        k_idx = j * block_k + jax.lax.broadcasted_iota(jnp.int32, (bq, block_k), 1)
-        mask = k_idx < seq_len
-        if causal:
-            mask = jnp.logical_and(mask, q_idx >= k_idx)
-        s = jnp.where(mask, s, NEG_INF)
-        m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))  # [bq, 1]
-        p = jnp.exp(s - m_new)  # [bq, bk]
-        alpha = jnp.exp(m - m_new)  # [bq, 1]
-        l_new = l * alpha + jnp.sum(p, axis=1, keepdims=True)
+        st = scale * jax.lax.dot_general(
+            k, q, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        )  # [bk, bq] f32
+        if padded or causal:
+            k_idx = j * block_k + jax.lax.broadcasted_iota(jnp.int32, (block_k, bq), 0)
+            mask = k_idx < seq_len if padded else None
+            if causal:
+                seen = q_idx >= k_idx
+                mask = seen if mask is None else jnp.logical_and(mask, seen)
+            st = jnp.where(mask, st, NEG_INF)
+        m_new = jnp.maximum(m, jnp.max(st, axis=0, keepdims=True))  # [1, bq]
+        pt = jnp.exp(st - m_new)  # [bk, bq]
+        alpha = jnp.exp(m - m_new)  # [1, bq]
+        l_new = l * alpha + jnp.sum(pt, axis=0, keepdims=True)
         acc_new = acc * alpha + jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-        )
+            v, pt.astype(v.dtype), (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
+        )  # [Dv, bq]
         return acc_new, m_new, l_new
 
-    acc0 = jnp.zeros((bq, d), jnp.float32)
-    m0 = jnp.full((bq, 1), NEG_INF, jnp.float32)
-    l0 = jnp.zeros((bq, 1), jnp.float32)
+    acc0 = jnp.zeros((d, bq), jnp.float32)
+    m0 = jnp.full((1, bq), NEG_INF, jnp.float32)
+    l0 = jnp.zeros((1, bq), jnp.float32)
     # Causal: stop at the last k-block the diagonal reaches — every weight
     # past it is exactly 0. Ascending j, so the first block visited holds
     # column 0, which every row sees: m is a real logit before a wholly
@@ -356,11 +377,11 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, block_k, seq_len,
     # Padded q rows (and fully-masked causal rows cannot occur: row i always
     # sees k=i) have l=0 only when the whole row was padding; guard the divide.
     l_safe = jnp.where(l == 0.0, 1.0, l)
-    o_ref[0, 0] = (acc / l_safe).astype(o_ref.dtype)
+    o_ref[0, 0] = jnp.transpose(acc / l_safe, (1, 0)).astype(o_ref.dtype)  # [bq, Dv]
     # Stats live as [1, bq] lane-major rows: a [B, H, 1, T] buffer pads only
     # its singleton sublane dim (8x on 1), where a [..., T, 1] layout would
     # pad the lane dim 128x (measured 384MB/layer on ViT-B — OOM).
-    lse_ref[0, 0] = jnp.transpose(m + jnp.log(l_safe), (1, 0))  # [1, bq]
+    lse_ref[0, 0] = m + jnp.log(l_safe)  # [1, bq]
 
 
 def _hide(s, q0: int, k0: int, seq_len: int, keys_on_rows: bool = False):
@@ -616,9 +637,13 @@ def _fwd_call(qt, kt, vt, t_k, causal, bq, bk, sub, interpret):
     tk_pad, dv = kt.shape[2], vt.shape[3]
     if sub is not None:  # causal, one block pair (_flash_sub)
         kernel = functools.partial(_fwd_subtiles, scale=d**-0.5, sub=sub, seq_len=t_k)
+        params = None
     else:
         kernel = functools.partial(
             _fwd_kernel, scale=d**-0.5, block_k=bk, seq_len=t_k, causal=causal, n_q=tq_pad // bq
+        )
+        params = pltpu.CompilerParams(
+            vmem_limit_bytes=_fwd_vmem_bytes(tk_pad, bq, bk, d, dv, qt.dtype.itemsize)
         )
     call = pl.pallas_call(
         kernel,
@@ -636,11 +661,30 @@ def _fwd_call(qt, kt, vt, t_k, causal, bq, bk, sub, interpret):
             jax.ShapeDtypeStruct((b, h, tq_pad, dv), qt.dtype),
             jax.ShapeDtypeStruct((b, h, 1, tq_pad), jnp.float32),
         ],
+        compiler_params=params,
         interpret=interpret,
         name="flash_fwd",
     )
     with jax.named_scope("flash_fwd"):
         return call(qt, kt, vt)
+
+
+def _fwd_vmem_bytes(tk_pad: int, bq: int, bk: int, d: int, dv: int, itemsize: int) -> int:
+    """The scoped-VMEM limit :func:`_fwd_kernel` is compiled under, reckoned
+    from its shapes as :func:`_bwd_vmem_bytes` reckons the backward's: the
+    whole-T k / v slabs and the q / o blocks double-buffered and padded to the
+    128-lane tile, the lse row on 8 sublanes, four float32 ``[bk, bq]`` tiles
+    (s, p, the exponential's operand and p's cast) and the ``[Dv, bq]``
+    accumulator with its transpose. Latent attention's widths at ``1024 x 512``
+    and T=8192 need 18 MiB by Mosaic's own count (binary search on the limit,
+    compiled for a described v5e), over the compiler's 16 MiB default; every
+    other caller fits that default. Never under it: a ceiling, not a
+    reservation."""
+    lanes, v_lanes = pl.cdiv(d, 128) * 128, pl.cdiv(dv, 128) * 128
+    slabs = 2 * tk_pad * (lanes + v_lanes) * itemsize
+    blocks = 2 * bq * (lanes + v_lanes) * itemsize + 2 * 8 * bq * 4
+    tiles = 4 * bq * bk * 4 + 2 * bq * v_lanes * 4
+    return max(16 * 2**20, slabs + blocks + tiles)
 
 
 def _fwd_impl(q, k, v, causal, block_q, block_k, interpret, valid_len=None):

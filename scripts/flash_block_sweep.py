@@ -19,6 +19,9 @@ the before / after columns: a checkout from before ISSUE 30 has two backward
 kernels, ``dq`` and ``dkv``, whose sum is what ``bwd`` replaced.
 ``--compile-only`` compiles every point for a described v5e and times nothing
 (runs without a chip: what Mosaic refuses there it refuses on the chip).
+``--kernels fwd`` (or ``bwd``) times one kernel only. Besides the GPT-2 cells'
+shapes, ``t8192_b2_mla`` is latent attention's (q / k 192, v 128) and
+``t8192_b2_h128`` the grouped-query layer's at head width 128.
 
 What "alone" includes: XLA gives the entry parameters of such a one-kernel
 program a layout of its own (T minor-most for ``[B, H, T, 64]``), so every
@@ -46,7 +49,16 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 import jax
 import jax.numpy as jnp
 
-SHAPES = {"t4096_b8": (8, 12, 4096, 64), "t1024_b32": (32, 12, 1024, 64), "t8192_b4": (4, 12, 8192, 64)}
+# name -> [B, H, T, Dqk, Dv] as the kernels see it: the GPT-2 cells, latent
+# attention's (joyaiflash_t8192: q / k 192, v 128) and nemotron3nano_t8192's
+# grouped-query layer (its 2 key / value heads repeated to 32 before the call).
+SHAPES = {
+    "t4096_b8": (8, 12, 4096, 64, 64),
+    "t1024_b32": (32, 12, 1024, 64, 64),
+    "t8192_b4": (4, 12, 8192, 64, 64),
+    "t8192_b2_mla": (2, 32, 8192, 192, 128),
+    "t8192_b2_h128": (2, 32, 8192, 128, 128),
+}
 CELL_SHAPES = "t4096_b8,t1024_b32"
 BLOCKS = (256, 512, 1024)
 
@@ -66,8 +78,9 @@ def kernel_fns(mod, shape, causal, blocks):
     (``(bq, bk, sub)``; a checkout from before ISSUE 32 has no sub-tiles and
     takes ``(bq, bk)``) or, where that is None, each at what the checkout's own
     rule gives."""
-    b, h, t, d = shape
-    q = k = v = do = jax.ShapeDtypeStruct(shape, jnp.bfloat16)
+    b, h, t, d, dv = shape
+    q = k = jax.ShapeDtypeStruct((b, h, t, d), jnp.bfloat16)
+    v = do = jax.ShapeDtypeStruct((b, h, t, dv), jnp.bfloat16)
     stat = jax.ShapeDtypeStruct((b, h, 1, t), jnp.float32)
     grads = (q, k, v, do, stat, stat)
 
@@ -124,6 +137,7 @@ def main():
     ap.add_argument("--shapes", default=CELL_SHAPES, help=f"of {','.join(SHAPES)}")
     ap.add_argument("--sub", default="", help="sub-tile sizes of a one-block causal call, e.g. 512,256")
     ap.add_argument("--kernel-layout", action="store_true", help="no layout copies around the kernel")
+    ap.add_argument("--kernels", default="", help="only these kernels, e.g. fwd (default: every one)")
     ap.add_argument("--out", default="chiprun_out/flash_block_sweep.jsonl")
     args = ap.parse_args()
 
@@ -157,7 +171,7 @@ def main():
                     continue
                 for blocks in grid:
                     for kernel, (fn, specs, (bq, bk, *sub)) in kernel_fns(mod, shape, True, blocks).items():
-                        if bq > t or bk > t:
+                        if bq > t or bk > t or (args.kernels and kernel not in args.kernels.split(",")):
                             continue
                         row = {"shape": name, "side": side, "kernel": kernel, "block_q": bq, "block_k": bk,
                                "sub": sub[0] if sub else None, "rule": blocks is None}

@@ -46,6 +46,12 @@ CASES = [
     (1, 384, 2, 32, True, 128, 256),  # bq < bk: the q-loop's lower bound
     (1, 300, 2, 32, True, 128, 128),  # padding and diagonal in the same last block
     (1, 300, 2, 32, False, 128, 128),  # non-causal: every block, the last one padded
+    # The forward's keys x queries tiles with v narrower than q / k (latent
+    # attention's two widths, cut down), padding in the last key block, bq != bk
+    # both ways.
+    (1, 300, 2, (48, 32), True, 256, 128),
+    (1, 300, 2, (48, 32), True, 128, 256),
+    (1, 512, 2, 32, False, 256, 128),  # every key block whole: neither compare is built
     # Causal, one block pair: walked as static sub-tiles, the ones above the
     # diagonal left out (ISSUE 32). The rule gives the forward two row groups
     # and the backward sub-tiles of 128 where the block splits so.
@@ -67,10 +73,16 @@ BWD_CASES = [
 GRAD_CASES = CASES[:1] + CASES[2:3] + CASES[4:] + BWD_CASES
 
 
+def _qkv(rng, b, t, h, d):
+    """q, k, v ``[b, t, h, ·]`` from ``rng``; ``d`` is one width or ``(Dqk, Dv)``."""
+    d_qk, d_v = d if isinstance(d, tuple) else (d, d)
+    return tuple(jnp.asarray(rng.randn(b, t, h, w), jnp.float32) for w in (d_qk, d_qk, d_v))
+
+
 @pytest.mark.parametrize("b,t,h,d,causal,block_q,block_k", CASES)
 def test_forward_parity(b, t, h, d, causal, block_q, block_k):
     rng = np.random.RandomState(0)
-    q, k, v = (jnp.asarray(rng.randn(b, t, h, d), jnp.float32) for _ in range(3))
+    q, k, v = _qkv(rng, b, t, h, d)
     out = flash_attention(q, k, v, causal=causal, block_q=block_q, block_k=block_k)
     ref = reference_attention(q, k, v, causal)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5)
@@ -79,8 +91,8 @@ def test_forward_parity(b, t, h, d, causal, block_q, block_k):
 @pytest.mark.parametrize("b,t,h,d,causal,block_q,block_k", GRAD_CASES)
 def test_gradient_parity(b, t, h, d, causal, block_q, block_k):
     rng = np.random.RandomState(1)
-    q, k, v = (jnp.asarray(rng.randn(b, t, h, d), jnp.float32) for _ in range(3))
-    cotangent = jnp.cos(jnp.arange(b * t * h * d, dtype=jnp.float32)).reshape(b, t, h, d) * 0.1
+    q, k, v = _qkv(rng, b, t, h, d)
+    cotangent = jnp.cos(jnp.arange(v.size, dtype=jnp.float32)).reshape(v.shape) * 0.1
 
     def loss_flash(q, k, v):
         out = flash_attention(q, k, v, causal=causal, block_q=block_q, block_k=block_k)
@@ -140,6 +152,9 @@ def test_causal_skip_never_reads_future_blocks(t, blk, keep):
     [
         (384, 384, True, 128, 128),
         (256, 384, True, 128, 128),
+        # The forward's [1, bq] statistics rows written straight out as lse:
+        (300, 300, True, 256, 128),  # bq != bk, the keys padded into their last block
+        (520, 520, True, 128, 256),  # bq < bk, both sides padded
         # The fused backward (ISSUE 30) under the ring's shapes:
         (384, 256, True, 256, 128),  # more rows than columns, bq != bk
         (256, 384, True, 128, 256),  # a k-block seen by no row still closes dq's sum
@@ -216,25 +231,30 @@ def test_block_counts_match_a_brute_force_count_and_reach_the_record():
     finally:
         dispatch.reset()
     plan = flash_block_plan(512, 512, True, 128, 128)
-    assert plan == {"block_q": 128, "block_k": 128, "sub_block": None,
+    assert plan == {"block_q": 128, "block_k": 128, "sub_block": None, "fwd_tiles": "kq",
                     "blocks_total": 16, "blocks_computed": 10,
                     "backward": "fused", "bwd_block_q": 128, "bwd_block_k": 128,
                     "bwd_sub_block": None}
     assert {k: rec[k] for k in plan} == plan
     subs = (rec_1024["block_q"], rec_1024["sub_block"], rec_1024["bwd_sub_block"])
-    assert subs == (1024, 512, 128)
+    assert subs == (1024, 512, 128) and rec_1024["fwd_tiles"] == "sub"
     assert (rec_1024["blocks_total"], rec_1024["blocks_computed"]) == (4, 3)
+
+
+def _pallas_eqns(jaxpr):
+    """Every ``pallas_call`` equation in a jaxpr, sub-jaxprs included."""
+    eqns = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            eqns.append(eqn)
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            eqns += _pallas_eqns(sub)
+    return eqns
 
 
 def _pallas_calls(jaxpr):
     """Names of every ``pallas_call`` in a jaxpr, sub-jaxprs included."""
-    names = []
-    for eqn in jaxpr.eqns:
-        if eqn.primitive.name == "pallas_call":
-            names.append(eqn.params["name"])
-        for sub in jax.core.jaxprs_in_params(eqn.params):
-            names += _pallas_calls(sub)
-    return names
+    return [eqn.params["name"] for eqn in _pallas_eqns(jaxpr)]
 
 
 @pytest.mark.parametrize(
@@ -263,12 +283,57 @@ def test_backward_is_one_pallas_call(t, causal, valid_len):
     assert any(pattern in backward for pattern in ("flash_dq", "flash_dkv"))  # the benchmark reader's two
 
 
+def _loop_body_primitives(jaxpr):
+    """Primitive names inside the first loop of a kernel's jaxpr (a ``while``'s
+    body or a ``scan``'s), or None where the kernel has no loop."""
+    for eqn in jaxpr.eqns:
+        body = {"while": "body_jaxpr", "scan": "jaxpr"}.get(eqn.primitive.name)
+        if body:
+            return {e.primitive.name for e in eqn.params[body].jaxpr.eqns}
+    return None
+
+
+@pytest.mark.parametrize(
+    "t,causal,block,body,compares",
+    [
+        (1024, True, None, "_fwd_subtiles", None),  # gpt2s_t1024's call: the sub-tile body, no loop
+        (4096, True, None, "_fwd_kernel", {"ge"}),  # whole key blocks: the causal compare alone
+        (300, True, 128, "_fwd_kernel", {"ge", "lt"}),  # padded keys: the padding compare too
+        (512, False, 128, "_fwd_kernel", set()),  # non-causal, whole key blocks: no compare
+        (256, False, None, "_fwd_kernel", {"lt"}),  # ViT's valid_len 197 in one block of 256
+    ],
+)
+def test_forward_body_follows_the_shapes(t, causal, block, body, compares):
+    """Traced, not run: the forward's ``pallas_call`` runs ``body`` — a causal
+    call of one block pair keeps the straight-line sub-tile kernel, every other
+    call the keys x queries loop — and the loop builds a padding compare
+    (``lt``) only where the keys are padded and a causal one (``ge``) only
+    where the call is causal."""
+    from distributed_training_pytorch_tpu.ops import pallas
+
+    x = jax.ShapeDtypeStruct((1, t, 2, 64), jnp.bfloat16)
+    valid_len = 197 if not causal and block is None else None
+
+    def fwd(q, k, v):
+        return flash_attention(q, k, v, causal=causal, block_q=block, block_k=block, valid_len=valid_len)
+
+    (call,) = _pallas_eqns(jax.make_jaxpr(fwd)(x, x, x).jaxpr)
+    kernel = call.params["jaxpr"]
+    line = getattr(pallas, body).__code__.co_firstlineno
+    assert kernel.debug_info.func_src_info.endswith(f"pallas.py:{line}"), kernel.debug_info.func_src_info
+    loop = _loop_body_primitives(kernel)
+    if compares is None:
+        assert loop is None
+    else:
+        assert loop is not None and loop & {"lt", "ge"} == compares
+
+
 @pytest.mark.parametrize(
     "t_q,t_k,causal,want,want_sub,want_counts",
     [
         (1024, 1024, True, (1024, 1024), (512, 128), (4, 3)),  # the LM cells: sub-tiles of one pair
-        (4096, 4096, True, (512, 512), (None, None), (64, 36)),  # the benchmark's long cell
-        (8192, 8192, True, (1024, 1024), (None, None), (256, 136)),  # the longest T that compiles
+        (4096, 4096, True, (512, 512), (None, None), (32, 20)),  # the benchmark's long cell
+        (8192, 8192, True, (1024, 1024), (None, None), (128, 72)),  # the longest T that compiles
         (256, 256, False, (256, 256), (None, None), (1, 1)),  # clamped to T; non-causal: one tile
         (2048, 512, False, (1024, 512), (None, None), (2, 2)),  # a ring shard against a visiting block
         (512, 512, True, (512, 512), (256, 128), (4, 3)),
@@ -282,11 +347,14 @@ def test_backward_is_one_pallas_call(t, causal, valid_len):
 def test_block_plan_carries_the_backward(t_q, t_k, causal, want, want_sub, want_counts):
     """``flash_block_plan`` (spread into the ``kernel_dispatch`` record) says
     which backward the shapes get and at which block shape, the sub-tile size
-    each kernel walks a one-block causal call in (None: one tile), and the
-    forward's block pairs at the granularity it skips at."""
+    each kernel walks a one-block causal call in (None: one tile), which body
+    the forward runs (``"sub"`` where it walks sub-tiles, else the keys x
+    queries tiles ``"kq"``), and the forward's block pairs at the granularity
+    it skips at."""
     from distributed_training_pytorch_tpu.ops.pallas import flash_block_plan
 
     plan = flash_block_plan(t_q, t_k, causal)
+    assert plan["fwd_tiles"] == ("sub" if want_sub[0] else "kq")
     assert plan["backward"] == "fused"
     assert (plan["bwd_block_q"], plan["bwd_block_k"]) == want
     assert (plan["sub_block"], plan["bwd_sub_block"]) == want_sub
